@@ -8,10 +8,14 @@ p99 of 2259 ms vs p50 269 ms "still includes first-compiles of new batch
 sizes inside the timed window"). This module converts those request-path
 compiles into startup/background cost, three ways:
 
-  * **Persistent compilation cache** (``oryx.compile.cache-dir``):
-    :func:`configure` points jax's disk cache at a directory so process
-    restarts and horizontal serving replicas deserialize XLA binaries
-    instead of recompiling them. ``min-entry-size-bytes`` /
+  * **Persistent compilation cache**: :func:`configure` points jax's disk
+    cache at a directory so process restarts and horizontal serving
+    replicas deserialize XLA binaries instead of recompiling them. The
+    directory is placed from outside first: ``JAX_COMPILATION_CACHE_DIR``
+    if set (jax's own reading of it stands and this module sets none),
+    else ``oryx.compile.cache-dir``, else a fixed ``<checkout>/.jax_cache``
+    — fixed because the path is part of the cache key, so a directory
+    that moves never hits. ``min-entry-size-bytes`` /
     ``min-compile-time-sec`` bound what gets written (jax's own defaults
     skip sub-second compiles, which is exactly the wrong default for a
     serving tier that wants EVERY bucket binary on disk).
@@ -66,9 +70,9 @@ _WARMUP_SECONDS = metrics_mod.default_registry().histogram(
     buckets=metrics_mod.STEP_BUCKETS,
 )
 
-# jax.monitoring event names (stable across the 0.4.x line). backend_compile
-# fires for every compile_or_get_cached call that missed the in-memory
-# dispatch cache; the cache_* pair fires only on persistent-cache hits.
+# jax.monitoring event names. backend_compile fires for every
+# compile_or_get_cached call that missed the in-memory dispatch cache; the
+# cache_* pair fires only on persistent-cache hits.
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 _CACHE_SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
@@ -99,20 +103,16 @@ def _on_event(event: str, duration: float, **_kw) -> None:
         _CACHE_SAVED.inc(max(0.0, duration))
 
 
-def install_compile_listener() -> bool:
-    """Register the jax.monitoring duration listener once per process.
-    Returns False when the running jax has no monitoring API."""
+def install_compile_listener() -> None:
+    """Register the jax.monitoring duration listener once per process."""
     global _installed
     with _install_lock:
         if _installed:
-            return True
-        try:
-            from jax import monitoring
-        except Exception:  # noqa: BLE001 — stub/ancient jax
-            return False
+            return
+        from jax import monitoring
+
         monitoring.register_event_duration_secs_listener(_on_event)
         _installed = True
-        return True
 
 
 def compiles_total() -> int:
@@ -125,31 +125,47 @@ def cache_hits_total() -> int:
     return _cache_hit_events
 
 
-_configured_cache_dir: "str | None" = None
+#: Where the cache lives when nothing outside the program places it: one
+#: fixed directory beside the package, shared by every entry point run from
+#: this checkout (listed in .gitignore).
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 
 def cache_dir() -> "str | None":
-    """The persistent cache directory this process configured, or None."""
-    return _configured_cache_dir
+    """The persistent cache directory jax is using in this process."""
+    import jax
+
+    return jax.config.jax_compilation_cache_dir
 
 
 def configure(config) -> None:
-    """Apply ``oryx.compile.*``: install the compile listener and, when
-    ``cache-dir`` is set, enable jax's persistent compilation cache.
+    """Install the compile listener and enable jax's persistent compilation
+    cache, with the ``oryx.compile.*`` thresholds.
+
+    THE one place a cache directory is chosen — ``chip_smoke.py``, the
+    benches and every layer come through here, and none builds a path of
+    its own. ``JAX_COMPILATION_CACHE_DIR``, when set, wins and this code
+    sets no directory (jax has already read it); otherwise
+    ``oryx.compile.cache-dir``; otherwise :data:`DEFAULT_CACHE_DIR`. Call
+    before the process's first compile: jax initializes its cache once.
 
     Safe to call repeatedly (every layer entry point calls it, like
     ``metrics.configure``); config errors degrade to a warning — a broken
     cache dir must never stop a layer from serving."""
-    global _configured_cache_dir
     install_compile_listener()
-    cdir = config.get_string("oryx.compile.cache-dir", None)
-    if not cdir:
-        return
-    try:
-        import jax
+    import jax
 
-        os.makedirs(cdir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cdir)
+    cdir = None
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        cdir = (config.get_string("oryx.compile.cache-dir", None)
+                or DEFAULT_CACHE_DIR)
+    try:
+        if cdir:
+            os.makedirs(cdir, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", cdir)
         jax.config.update(
             "jax_persistent_cache_min_entry_size_bytes",
             config.get_int("oryx.compile.min-entry-size-bytes", 0),
@@ -158,8 +174,7 @@ def configure(config) -> None:
             "jax_persistent_cache_min_compile_time_secs",
             config.get_float("oryx.compile.min-compile-time-sec", 0.0),
         )
-        _configured_cache_dir = cdir
-        log.info("persistent compilation cache at %s", cdir)
+        log.info("persistent compilation cache at %s", cache_dir())
     except Exception:  # noqa: BLE001 — cache is an optimization, not a dep
         log.warning("could not enable persistent compilation cache at %s",
                     cdir, exc_info=True)
@@ -186,7 +201,7 @@ def aot_compile(jitted, *args, cost_key: "str | None" = None, **kwargs):
     try:
         compiled = lower(*args, **kwargs).compile()
     except Exception:  # noqa: BLE001 — warm path must never take a layer down
-        log.debug("AOT compile failed", exc_info=True)
+        log.warning("AOT compile failed", exc_info=True)
         return None
     if cost_key:
         from oryx_tpu.common import profiling

@@ -287,9 +287,12 @@ oryx = {
   compile = {
     # Directory for jax's persistent compilation cache. Restarted processes
     # and horizontal serving replicas sharing it deserialize XLA binaries
-    # instead of recompiling. null disables. Same shared-filesystem caveat
-    # as the file: broker (docs/admin.md): local disk or a real shared FS;
-    # the cache tolerates concurrent writers (content-keyed entries).
+    # instead of recompiling. The environment places it first:
+    # JAX_COMPILATION_CACHE_DIR, when set, wins over this key. null = a
+    # fixed <checkout>/.jax_cache (the path is part of the cache key, so it
+    # must not move between runs). Same shared-filesystem caveat as the
+    # file: broker (docs/admin.md): local disk or a real shared FS; the
+    # cache tolerates concurrent writers (content-keyed entries).
     cache-dir = null
     # Only cache compiled binaries at least this large (bytes). 0 caches
     # everything — the serving tier wants EVERY bucket binary on disk.
@@ -374,14 +377,16 @@ oryx = {
   # here and the consistency gate recomputes what the kernels may claim.
   analyze = {
     kernel = {
-      # Per-core VMEM (TPU v4/v5e ~16 MB): the ceiling a kernel's whole
-      # resident footprint (pipelined blocks x2 + scratch) is checked
-      # against by kernel-vmem-budget.
+      # The scoped-VMEM limit the TPU compiler holds one kernel to (16 MiB
+      # on a v5e): the ceiling a kernel's whole resident footprint
+      # (pipelined blocks x2 + scratch) is checked against by
+      # kernel-vmem-budget.
       vmem-limit-bytes = 16777216
       # Scoped-VMEM budget for the LARGEST single buffer of a grid-tiled
-      # kernel ((7 << 17) f32 elements ~ 3.5 MB) — what spd_solve_batched
-      # sizes its batch tile under.
-      scoped-budget-bytes = 3670016
+      # kernel — what spd_solve_batched sizes its batch tile under. The
+      # compiler allocates ~4.75x that buffer for the kernel, so 3 MiB is
+      # the most that stays inside the limit above with margin.
+      scoped-budget-bytes = 3145728
       # Resident-state budget for accumulator kernels whose output blocks
       # stay VMEM-resident across grid steps (the gather-Gramian shape);
       # 1.5 MB ratifies _GG_MAX_FEATURES = 256 exactly

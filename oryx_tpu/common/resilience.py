@@ -70,7 +70,7 @@ class DeadlineExceeded(Exception):
 
 def default_retryable(exc: BaseException) -> bool:
     """Transient by default: I/O errors (a flaky shared filesystem under the
-    ``file:`` broker, a dropped tunnel) — never programming errors."""
+    ``file:`` broker, a dropped connection) — never programming errors."""
     return isinstance(exc, OSError)
 
 

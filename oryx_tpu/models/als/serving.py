@@ -192,10 +192,7 @@ def _sharded_top_k_fn(mesh, axis: str, k: int, k_final: int, n_real: int,
     scatter: ``excl`` is (B, E) GLOBAL row indices, -1-padded; each shard
     rebases to local coordinates and drops out-of-range entries, so the mask
     costs O(E) scatter per shard instead of a host round-trip."""
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(mat_blk, qs_blk, excl_blk, lut_blk, buckets_blk):

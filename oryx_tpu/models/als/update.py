@@ -85,12 +85,7 @@ class ALSUpdate(MLUpdate):
         )
         if batch.nnz == 0 or len(batch.users) == 0 or len(batch.items) == 0:
             return None
-        # factor/Gramian rows shard over the mesh's model axis when the batch
-        # tier runs multi-device (ComputeContext, SURVEY §2.14 block-ALS map)
-        mesh = row_axis = None
-        ctx_mesh = getattr(context, "mesh", None)
-        if ctx_mesh is not None and ctx_mesh.size > 1 and "model" in ctx_mesh.axis_names:
-            mesh, row_axis = ctx_mesh, "model"
+        mesh, row_axis = row_sharding(context)
         # preemption tolerance: the checkpoint identity is the generation's
         # DATA fingerprint — input-topic offsets (stamped on the context by
         # the batch layer; None for direct/test callers), the candidate's
@@ -249,6 +244,20 @@ class ALSUpdate(MLUpdate):
                 )
             else:
                 producer.send("UP", json.dumps(["X", id_, [float(v) for v in vec]]))
+
+
+def row_sharding(context) -> tuple:
+    """``(mesh, row_axis)`` the trainer shards factor/Gramian rows over when
+    the batch tier runs multi-device (ComputeContext, SURVEY §2.14 block-ALS
+    map), or ``(None, None)`` on one device. Rows split over EVERY mesh axis
+    jointly: ALS has one thing to partition, so whichever axis the
+    configured mesh shape put the devices on — all on the first with
+    ``mesh-shape = null`` — each device solves its own blocks. Naming one
+    axis here once left four chips doing the same work four times."""
+    mesh = getattr(context, "mesh", None)
+    if mesh is None or mesh.size == 1:
+        return None, None
+    return mesh, tuple(mesh.axis_names)
 
 
 def _load_matrix(path: Path, mapping: als_data.IDIndexMapping, features: int) -> np.ndarray:

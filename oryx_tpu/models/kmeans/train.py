@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from oryx_tpu.ops import pallas_kernels as pk
+
 INIT_RANDOM = "random"
 INIT_KMEANS_PARALLEL = "k-means||"
 
@@ -115,8 +117,6 @@ def _kmeans_pallas_run(key, points, weights, k, iterations, init, interpret):
     distances, argmin, and sum/count/cost accumulation in one pass per sweep —
     the (N, k) intermediates never touch HBM. Points/weights are padded once
     for the whole run; only the (small) centers re-pad per sweep."""
-    from oryx_tpu.ops import pallas_kernels as pk
-
     centers = _init_centers(key, points, k, init)
     n, d = points.shape
     n_pad = pk._pad_dim(max(n, 1), pk.TILE_N)
@@ -235,14 +235,15 @@ def kmeans_train(
     init: str = INIT_KMEANS_PARALLEL,
     key=None,
     use_pallas: "bool | None" = None,
-    interpret: bool = False,
 ):
     """Train on (N, d) points; returns (centers (k,d) np, counts (k,) np).
 
     ``runs`` restarts execute as one vmapped program; best-cost run wins
     (MLlib KMeans ``runs`` semantics). On TPU (or with ``use_pallas=True``)
     each Lloyd sweep instead runs the fused Pallas kernel, restarts
-    sequentially.
+    sequentially. Both the default and the kernel's interpret mode come
+    from the device that holds the points: forced on off-TPU (tests), the
+    kernel is emulated.
     """
     from oryx_tpu.common import rand
 
@@ -256,11 +257,13 @@ def kmeans_train(
     pts = jnp.asarray(points)
     weights = jnp.ones((n,), dtype=jnp.float32)
     keys = jax.random.split(key, max(runs, 1))
+    on_tpu = pk.on_tpu(pts)
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = on_tpu
     if use_pallas:
         results = [
-            _kmeans_pallas_run(kk, pts, weights, k, iterations, init, interpret)
+            _kmeans_pallas_run(kk, pts, weights, k, iterations, init,
+                               not on_tpu)
             for kk in keys
         ]
         centers = jnp.stack([r[0] for r in results])

@@ -32,7 +32,17 @@ Here each factor row crosses HBM exactly once:
                            ``_GG_BUFS`` in-flight copies)
                            Gramian (k,T)·(T,k) + RHS (1,T)·(T,k)   (MXU)
                            accumulate into the slot's OWNER ROW's
-                           (1, k, k)/(1, k) output block in VMEM
+                           (1, k, k)/(1, 1, k) output block in VMEM
+
+Mosaic slices a DMA only along untiled (leading) dims, and a 16-bit row
+shares its 32-bit sublane word with its neighbour — so the wrapper hands the
+kernel the factors as float32 ``(R, 1, pad128(k))``: one row = one (1, 128)-
+tiled slab, addressable by a leading-dim index. A bfloat16 compute dtype
+still feeds the MXU bf16 (the kernel casts after the gather); what it no
+longer buys is a halved gather, which at one ~200 B row per descriptor was
+descriptor-bound anyway. Every blocked operand is 3-D so that the block's
+last two dims EQUAL the array's — the TPU lowering refuses a ``(1, t)``
+block over an ``(S, T)`` array.
 
 Slots arrive row-sorted (the pack guarantees it), so the per-row output
 block — selected by a scalar-prefetched ``srow`` index map — is revisited
@@ -55,8 +65,13 @@ ops; here both live only tile-at-a-time in VMEM:
 
 Outputs revisit the same block every grid step (constant index map), the
 standard Pallas accumulation pattern: initialized at step 0 with ``pl.when``,
-accumulated thereafter. Off-TPU callers run the same kernel under
-``interpret=True`` (that is how the test suite exercises it on CPU).
+accumulated thereafter.
+
+Every wrapper here takes ``interpret`` as a REQUIRED argument: the caller
+knows where its operands live (``on_tpu``) and passes ``not on_tpu``, so no
+kernel can be emulated on the chip — or compiled for a CPU — because a
+process-wide default disagreed with the operand's device. The CPU suite
+runs the same kernels under ``interpret=True``.
 
 Tile sizes honor the f32 (8, 128) VMEM tiling: points tiles are
 (TILE_N, D_pad) with D and K padded to lane multiples by the wrapper.
@@ -84,6 +99,16 @@ FAR_AWAY = 3.4e38 ** 0.5
 
 def _pad_dim(n: int, multiple: int) -> int:
     return ((n + multiple - 1) // multiple) * multiple
+
+
+def on_tpu(operand=None, mesh=None) -> bool:
+    """Whether a computation over ``operand`` (or over ``mesh``) runs on a
+    TPU: the ONE place kernel selection and ``interpret`` are decided, from
+    the devices that hold the data. A tracer has no devices; callers resolve
+    before they enter jit and pass the answer down as a static."""
+    if mesh is not None:
+        return mesh.devices.flat[0].platform == "tpu"
+    return next(iter(operand.devices())).platform == "tpu"
 
 
 def _spd_solve_kernel(a_ref, b_ref, x_ref, aug_ref):
@@ -139,13 +164,18 @@ def _spd_solve_call(a, b, *, tile_b: int, interpret: bool):
 
 # Batch-tile sizing for the SPD solve: the largest single VMEM buffer is
 # the augmented scratch (tile_b, k, k+1) — its k+1 lanes pad to the NEXT
-# 128 multiple (at k=128 that is 256, not 128) — and the scoped-VMEM stack
-# limit is 16 MB, so budget ~3.5 MB for that largest buffer. The budget and
-# cap below are pinned against the static kernel model's padded-byte math
+# 128 multiple (at k=128 that is 256, not 128). What the TPU compiler
+# actually allocates for the kernel is ~4.75× that buffer (measured by
+# compiling for a v5e: 16.62 MiB at k=50, tile_b=128 — the double-buffered
+# A block, the scratch, and ~1.75 scratch-sized temporaries for the step's
+# full-block values), against a 16 MiB scoped-VMEM limit that the old
+# 3.5 MiB budget overran at every production batch size. 3 MiB keeps the
+# worst case (A as wide as the scratch) at 14.25 MiB. The budget and cap
+# below are pinned against the static kernel model's padded-byte math
 # (tools/analyze/kernelmodel.py + oryx.analyze.kernel.scoped-budget-bytes)
 # by tests/test_kernel_differential.py: drift in either direction fails
 # tier-1.
-_SPD_SCOPED_BUDGET_BYTES = (7 << 17) * 4
+_SPD_SCOPED_BUDGET_BYTES = 3 << 20
 _SPD_MAX_TILE = 256
 
 
@@ -159,7 +189,7 @@ def spd_tile_b(k: int) -> int:
                (_SPD_SCOPED_BUDGET_BYTES // (4 * max(1, k_padded))) & ~7)
 
 
-def spd_solve_batched(a, b, *, interpret: "bool | None" = None):
+def spd_solve_batched(a, b, *, interpret: bool):
     """Solve ``a[i] @ x[i] = b[i]`` for a batch of SPD k×k systems.
 
     Args: a (B, k, k) f32 regularized-SPD, b (B, k) f32.
@@ -169,15 +199,13 @@ def spd_solve_batched(a, b, *, interpret: "bool | None" = None):
     a = jnp.asarray(a, dtype=jnp.float32)
     b = jnp.asarray(b, dtype=jnp.float32)
     n, k = b.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     tile_b = spd_tile_b(k)
     if tile_b < 8:
-        # k so large (~>=300 features with this budget) that even an 8-row
+        # k so large (> 256 features with this budget) that even an 8-row
         # tile risks overflowing the scoped-VMEM stack: fall back to XLA's
         # cholesky rather than fail to compile — and say so, because the
         # performance difference is large
-        log.info(
+        log.warning(
             "spd_solve_batched: k=%d exceeds the VMEM tile budget; using "
             "the XLA cholesky fallback", k,
         )
@@ -202,8 +230,9 @@ _GG_BUFS = 4
 # _auto_slot_width) — the kernel's resident budget is evaluated at the cap.
 _GG_SLOT_WIDTH_MAX = 512
 # Features past this would push the kernel's resident VMEM state — the
-# double-buffered (1, k, k)/(1, k) accumulator blocks, the (T, k) gather
-# scratch, and the (1, T) weight blocks — past the resident-state budget
+# double-buffered (1, k, k)/(1, 1, k) accumulator blocks, the
+# (T, 1, pad128(k)) gather scratch, and the (1, 2, T) weight block — past
+# the resident-state budget
 # (oryx.analyze.kernel.resident-budget-bytes, 1.5 MB); callers fall back to
 # the einsum formulation (same numerics, more HBM traffic). The value is
 # the max k whose padded footprint at T = _GG_SLOT_WIDTH_MAX fits that
@@ -211,43 +240,49 @@ _GG_SLOT_WIDTH_MAX = 512
 # tests/test_kernel_differential.py so the constant can never silently
 # drift from the kernel it guards.
 _GG_MAX_FEATURES = 256
+# The per-slot owner rows ride whole in SMEM (scalar prefetch), which the
+# compiler caps at 1 MiB per program: 4 B × 196,608 slots leaves a quarter
+# of it for the double-buffered index blocks. The pack stays far below this
+# unless a block's rows average more than ~11,000 interactions each.
+_GG_MAX_SLOTS = 3 << 16
 
 
-def gather_gramian_supported(features: int) -> bool:
-    """Whether the fused gather-Gramian kernel fits its VMEM budget."""
-    return features <= _GG_MAX_FEATURES
+def gather_gramian_supported(features: int, slots: int) -> bool:
+    """Whether the fused gather-Gramian kernel fits its VMEM budget at
+    ``features`` and its SMEM budget at ``slots`` slots per block."""
+    return features <= _GG_MAX_FEATURES and slots <= _GG_MAX_SLOTS
 
 
-def _make_gather_gramian_kernel(t: int, k: int):
-    def kernel(srow_ref, scols_ref, slens_ref, w_ref, coef_ref, y_ref,
-               a0_ref, b0_ref, a_ref, b_ref, yg, sems):
+def _make_gather_gramian_kernel(t: int, k: int, kp: int, block: int, cd):
+    def kernel(srow_ref, scols_ref, wc_ref, y_ref, a0_ref, b0_ref,
+               a_ref, b_ref, yg, sems):
         i = pl.program_id(0)
         row = srow_ref[i]
         prev_row = srow_ref[jnp.maximum(i - 1, 0)]
 
-        # first slot of a new output row: the (1, k, k)/(1, k) blocks just
-        # rotated in (their VMEM content is undefined) — zero before the
-        # first accumulation. Slots are row-sorted, so a row's block stays
-        # resident for all of its slots and flushes to HBM exactly once.
+        # first slot of a new output row: the (1, k, k)/(1, 1, k) blocks
+        # just rotated in (their VMEM content is undefined) — zero before
+        # the first accumulation. Slots are row-sorted, so a row's block
+        # stays resident for all of its slots and flushes to HBM exactly
+        # once.
         @pl.when(jnp.logical_or(i == 0, prev_row != row))
         def _():
-            a_ref[:] = jnp.zeros_like(a_ref)
-            b_ref[:] = jnp.zeros_like(b_ref)
+            a_ref[...] = jnp.zeros_like(a_ref)
+            b_ref[...] = jnp.zeros_like(b_ref)
 
-        ls = slens_ref[0, 0]
-
-        # pad slots (no valid entries) skip the gather AND the matmuls:
-        # their owner is the spill row, initialized above and sliced off by
-        # the caller — issuing T DMAs of row 0 for them would only burn
-        # bandwidth
-        @pl.when(ls > 0)
+        # pad slots skip the gather AND the matmuls: their owner is the
+        # spill row, initialized above and sliced off by the caller —
+        # issuing T DMAs of row 0 for them would only burn bandwidth
+        @pl.when(row < block)
         def _():
             def dma(tt):
-                # one factor row per copy; within a slot the column indices
-                # are ascending (pack sorts by (row, col)), so consecutive
-                # copies walk y in HBM address order
+                # one factor row per copy, selected on y's LEADING dim (the
+                # only dim Mosaic lets a DMA slice below tile size); within
+                # a slot the column indices are ascending (pack sorts by
+                # (row, col)), so consecutive copies walk y in HBM address
+                # order
                 return pltpu.make_async_copy(
-                    y_ref.at[scols_ref[0, tt]], yg.at[tt],
+                    y_ref.at[scols_ref[0, 0, tt]], yg.at[tt],
                     sems.at[tt % _GG_BUFS],
                 )
 
@@ -268,40 +303,47 @@ def _make_gather_gramian_kernel(t: int, k: int):
 
                 return carry
 
-            jax.lax.fori_loop(0, t, body, 0, unroll=True)
+            jax.lax.fori_loop(0, t, body, 0)
 
-            ygv = yg[:]  # (T, k), y's dtype (bf16 = MXU-native inputs)
-            cd = ygv.dtype
-            # per-entry weights arrive precomputed (confidence/mask algebra
-            # is cheap VPU work best left to XLA); cast to the gather dtype
-            # so bf16 inputs hit the MXU's bf16×bf16→f32 path like the
-            # einsum formulation does
-            wcol = w_ref[:].reshape(t, 1).astype(cd)
+            ygv = yg[...].reshape(t, kp)[:, :k]  # (T, k) f32
+            wc = wc_ref[0]  # (2, T): Gramian weights, RHS coefficients
+            # the Gramian weights are a lane-major row and must scale the
+            # gathered rows along SUBLANES: mask the broadcast row to the
+            # diagonal and lane-reduce — iota/select/reduce only, where a
+            # (1, T) → (T, 1) reshape is a relayout Mosaic may not have
+            diag = (jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+                    == jax.lax.broadcasted_iota(jnp.int32, (t, t), 1))
+            wcol = jnp.sum(jnp.where(diag, wc[0:1, :], 0.0), axis=1,
+                           keepdims=True)  # (T, 1)
+            # cast to the compute dtype AFTER the 32-bit gather so bf16
+            # inputs hit the MXU's bf16×bf16→f32 path like the einsum
+            # formulation does
+            ygc = ygv.astype(cd)
             ga = jax.lax.dot_general(
-                ygv * wcol, ygv, (((0,), (0,)), ((), ())),
+                (ygv * wcol).astype(cd), ygc, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # (k, k): sum_t w_t · y_t ⊗ y_t
-            gb = jnp.dot(coef_ref[:].astype(cd), ygv,
+            gb = jnp.dot(wc[1:2, :].astype(cd), ygc,
                          preferred_element_type=jnp.float32)  # (1, k)
-            a_ref[:] = a_ref[:] + ga[None]
-            b_ref[:] = b_ref[:] + gb
+            a_ref[...] = a_ref[...] + ga[None]
+            b_ref[...] = b_ref[...] + gb[None]
 
     return kernel
 
 
-def gather_gramian_accumulate(y, srow, scols, w, coef, slens, *, block: int,
+def gather_gramian_accumulate(y, srow, scols, w, coef, *, block: int,
                               interpret: bool):
     """Fused gather → per-slot Gramian → per-row accumulate for one block.
 
     Args:
-      y: (R, k) opposite-side factors (f32 or bf16), HBM-resident.
+      y: (R, k) opposite-side factors, f32 or bf16 (the dtype is the MXU
+        input precision; the gather itself always moves 32-bit rows).
       srow: (S,) int32 block-local owner row per slot, SORTED ascending,
         pad = ``block`` (the spill row).
       scols: (S, T) int32 gather indices into ``y`` (column-ascending
         within each slot).
       w / coef: (S, T) f32 per-entry Gramian / RHS weights, zero on padding
         entries (the mask and confidence algebra are applied by the caller).
-      slens: (S,) int32 valid entries per slot (0 = pad slot).
       block: rows per block; outputs carry the extra spill row.
 
     Returns (big_a (block+1, k, k) f32, big_b (block+1, k) f32). Rows with
@@ -309,49 +351,49 @@ def gather_gramian_accumulate(y, srow, scols, w, coef, slens, *, block: int,
     """
     s, t = scols.shape
     k = y.shape[1]
+    kp = _pad_dim(k, _LANE)
+    y3 = jnp.pad(y.astype(jnp.float32), ((0, 0), (0, kp - k))).reshape(
+        -1, 1, kp)
+    wc = jnp.stack([w, coef], axis=1)  # (S, 2, T)
     a0 = jnp.zeros((block + 1, k, k), jnp.float32)
-    b0 = jnp.zeros((block + 1, k), jnp.float32)
+    b0 = jnp.zeros((block + 1, 1, k), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # srow drives the output index maps
         grid=(s,),
         in_specs=[
-            # gather indices + lengths are scalars (DMA addresses / loop
-            # bounds): SMEM, one slot per grid step
-            pl.BlockSpec((1, t), lambda i, sr: (i, 0),
+            # gather indices are DMA addresses: SMEM, one slot per step
+            pl.BlockSpec((1, 1, t), lambda i, sr: (i, 0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i, sr: (i, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, t), lambda i, sr: (i, 0),
+            pl.BlockSpec((1, 2, t), lambda i, sr: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, t), lambda i, sr: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # y stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # big_a zero donor
-            pl.BlockSpec(memory_space=pltpu.ANY),  # big_b zero donor
+            pl.BlockSpec(memory_space=pl.ANY),  # y stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # big_a zero donor
+            pl.BlockSpec(memory_space=pl.ANY),  # big_b zero donor
         ],
         out_specs=[
             pl.BlockSpec((1, k, k), lambda i, sr: (sr[i], 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, k), lambda i, sr: (sr[i], 0),
+            pl.BlockSpec((1, 1, k), lambda i, sr: (sr[i], 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         scratch_shapes=[
-            pltpu.VMEM((t, k), y.dtype),  # gathered factor rows
+            pltpu.VMEM((t, 1, kp), jnp.float32),  # gathered factor rows
             pltpu.SemaphoreType.DMA((_GG_BUFS,)),
         ],
     )
-    return pl.pallas_call(
-        _make_gather_gramian_kernel(t, k),
+    big_a, big_b = pl.pallas_call(
+        _make_gather_gramian_kernel(t, k, kp, block, y.dtype),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((block + 1, k, k), jnp.float32),
-            jax.ShapeDtypeStruct((block + 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((block + 1, 1, k), jnp.float32),
         ],
         # zero donors alias the outputs: rows no slot ever visits keep
         # exact zeros — deterministic on hardware AND under interpret
-        input_output_aliases={6: 0, 7: 1},
+        input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
-    )(srow, scols, slens.reshape(s, 1), w, coef, y, a0, b0)
+    )(srow, scols.reshape(s, 1, t), wc, y3, a0, b0)
+    return big_a, big_b[:, 0, :]
 
 
 def _kernel(points_ref, weights_ref, centers_ref, sums_ref, counts_ref, cost_ref):
@@ -415,9 +457,7 @@ def _call(points, weights, centers, *, interpret: bool):
     )(points, weights, centers)
 
 
-def kmeans_assign_accumulate(
-    points, weights, centers, *, interpret: "bool | None" = None
-):
+def kmeans_assign_accumulate(points, weights, centers, *, interpret: bool):
     """Fused Lloyd accumulation.
 
     Args: points (N, D) f32, weights (N,) f32 (0 = padding), centers (K, D).
@@ -428,8 +468,6 @@ def kmeans_assign_accumulate(
     centers = jnp.asarray(centers, dtype=jnp.float32)
     n, d = points.shape
     k = centers.shape[0]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     n_pad = _pad_dim(max(n, 1), TILE_N)
     d_pad = _pad_dim(d, _LANE)

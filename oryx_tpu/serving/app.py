@@ -766,7 +766,8 @@ class _BatchWarmer(threading.Thread):
                                 self.WARM_HOW_MANY,
                             )
                 except Exception:  # noqa: BLE001 — e.g. no items yet
-                    log.debug("batch warm at size %d failed", b, exc_info=True)
+                    log.warning("batch warm at size %d failed", b,
+                                exc_info=True)
                     return False
                 compilecache.observe_warmup(
                     "bucket", _time.perf_counter() - t0

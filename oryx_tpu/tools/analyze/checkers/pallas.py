@@ -13,10 +13,12 @@ kernel it guards. These five checks ride the parsed kernel models
     pipelined + scratch) against the per-core VMEM limit, naming the
     dominant buffer. Symbolic kernels render in ``analyze --cost`` and are
     pinned to their runtime gates by tests/test_kernel_differential.py.
-  * ``kernel-tile-alignment`` — concrete block tails against the
-    dtype-native tiling ((8,128) f32, (16,128) bf16, (32,128) int8):
-    pad-waste when the hardware rounds a dim up, hard misalignment when a
-    grid-varying map makes later blocks start mid-tile.
+  * ``kernel-tile-alignment`` — concrete block tails against the rule the
+    Pallas TPU lowering enforces (each of a block's last two dims is a
+    multiple of 8 / 128 or spans the operand's whole dim) and against the
+    tiling Mosaic infers: a refused block when an indivisible dim provably
+    does not span its operand (a ``(1, t)`` row-select over ``(S, T)``
+    included), pad-waste when the hardware rounds a dim up.
   * ``kernel-index-bounds`` — index map × block shape against operand
     extents over the grid: flags what it can PROVE out of bounds (concrete
     arithmetic, or a positive constant offset past a proven-exact cover),
@@ -28,8 +30,9 @@ kernel it guards. These five checks ride the parsed kernel models
   * ``kernel-interpret-default`` — wrappers whose ``interpret`` defaults
     ``True`` (or hard-coded ``interpret=True`` calls): on TPU they silently
     EMULATE the kernel instead of compiling it — the PR 6
-    ``spd_solve_batched`` fix class. ``None``-defaulted backend dispatch
-    and caller-threaded flags are the sanctioned shapes.
+    ``spd_solve_batched`` fix class. Required caller-threaded flags (what
+    ``ops/pallas_kernels.py`` uses) and ``None`` resolved from the target
+    devices are the sanctioned shapes.
 """
 
 from __future__ import annotations
@@ -39,11 +42,11 @@ import re
 
 from oryx_tpu.tools.analyze.kernelmodel import (
     LANE,
-    SUBLANE,
     budgets,
     kernel_models,
     kernel_param_name,
     kernel_zeroes_param,
+    sublane_tile,
     _dim_value,
     _operand_dtype,
 )
@@ -90,58 +93,88 @@ class KernelVmemBudgetChecker:
         return out
 
 
+def _operand_shape(model, b) -> "tuple | None":
+    """The whole-array shape a blocked buffer windows into, where the
+    wrapper's source shows it: ``out_shape`` for outputs, the shape
+    environment's view of the operand expression for inputs."""
+    if b.kind == "out":
+        if b.index < len(model.out_shapes):
+            return model.out_shapes[b.index][0]
+        return None
+    shape_of = model.senv.get("__shape_of__")
+    pos = model.num_prefetch + b.index
+    if shape_of and pos < len(model.operands):
+        return shape_of(model.operands[pos])
+    return None
+
+
 class KernelTileAlignmentChecker:
     id = TILE_ID
-    version = 1
+    version = 2
 
     def check(self, project) -> list:
         out = []
         for model in kernel_models(project):
-            for b in model.vmem_buffers():
-                if not b.shape:
+            for b in model.buffers():
+                if b.space not in ("vmem", "smem") or not b.shape:
                     continue
                 dims = [_dim_value(d, {}) for d in b.shape]
-                sub = SUBLANE.get(b.dtype or "float32", 8)
-                # (dim position from the end, required multiple, axis name)
-                checks = [(1, LANE, "lane")]
-                if len(dims) >= 2:
-                    checks.append((2, sub, "sublane"))
-                for back, mult, axis in checks:
+                full = (_operand_shape(model, b) if b.kind != "scratch"
+                        else None)
+                if full is not None and len(full) != len(dims):
+                    full = None
+                # (dim position from the end, the multiple the lowering
+                # demands, the rows/lanes Mosaic pads to, axis name)
+                checks = [(1, LANE, LANE, "lane")]
+                if len(dims) >= 2 and dims[-2] is not None:
+                    checks.append(
+                        (2, 8, sublane_tile(dims[-2], b.dtype), "sublane"))
+                for back, mult, tile, axis in checks:
                     d = dims[-back]
-                    # size-1 dims are the per-step row-select idiom (the
-                    # hardware broadcasts them); symbolic dims are the
-                    # wrapper-padded case — neither is checkable here
-                    if d is None or d <= 1 or d % mult == 0:
+                    # symbolic dims are the wrapper-padded case: unprovable
+                    if d is None or d % mult == 0:
                         continue
-                    padded = ((d + mult - 1) // mult) * mult
-                    waste = 100.0 * (padded - d) / padded
+                    shape_txt = "×".join(str(x) for x in b.shape)
+                    whole = _dim_value(full[-back], {}) if full else None
                     varies = bool(
                         b.index_map
                         and len(b.index_map) >= back
                         and b.index_map[-back][0] != "const"
                     )
-                    if varies:
-                        msg = (
+                    # a map that moves along the dim walks more than one
+                    # block of it, so the block cannot span the operand
+                    if b.kind != "scratch" and (
+                            varies or (whole is not None and whole != d)):
+                        out.append(model.fctx.finding(
+                            TILE_ID, b.spec_node,
                             f"kernel `{model.name}`: the {axis} dim of the "
-                            f"({'×'.join(str(x) for x in b.shape)}) "
-                            f"{b.kind} block is {d}, not a multiple of the "
-                            f"{b.dtype or 'float32'} tile ({mult}), and its "
-                            "index map varies along that dim — every block "
-                            "past the first starts mid-tile (Mosaic "
-                            "hard-misalignment); pad the block to the tile"
-                        )
-                    else:
-                        msg = (
-                            f"kernel `{model.name}`: the {axis} dim of the "
-                            f"({'×'.join(str(x) for x in b.shape)}) "
-                            f"{b.kind} block is {d}; the "
-                            f"{b.dtype or 'float32'} tile rounds it up to "
-                            f"{padded} ({waste:.0f}% of the block's VMEM "
-                            "and bandwidth is padding) — pad the dim in the "
-                            "wrapper or fold it into a tiled axis"
-                        )
+                            f"({shape_txt}) {b.kind} block is {d} — neither "
+                            f"a multiple of {mult} nor the operand's whole "
+                            f"{axis} dim"
+                            + (f" ({whole})" if whole is not None else "")
+                            + "; the Pallas TPU lowering refuses the block "
+                            "before Mosaic is reached (interpret mode "
+                            "accepts it). Make the windowed dim a LEADING "
+                            "dim — (S, T) → (S, 1, T) with a (1, 1, T) "
+                            "block — or pad the block to the tile",
+                            symbol=f"{model.name}:{b.kind}{b.index}:{axis}",
+                        ))
+                        continue
+                    # a size-1 tail is the deliberate column/scalar idiom
+                    # ((TILE_N, 1) weights): its padding is the price of the
+                    # layout, not an oversight
+                    padded = ((d + tile - 1) // tile) * tile
+                    if b.space != "vmem" or d <= 1 or padded == d:
+                        continue
+                    waste = 100.0 * (padded - d) / padded
                     out.append(model.fctx.finding(
-                        TILE_ID, b.spec_node, msg,
+                        TILE_ID, b.spec_node,
+                        f"kernel `{model.name}`: the {axis} dim of the "
+                        f"({shape_txt}) {b.kind} block is {d}; the "
+                        f"{b.dtype or 'float32'} tile rounds it up to "
+                        f"{padded} ({waste:.0f}% of the block's VMEM "
+                        "and bandwidth is padding) — pad the dim in the "
+                        "wrapper or fold it into a tiled axis",
                         symbol=f"{model.name}:{b.kind}{b.index}:{axis}",
                     ))
         return out
@@ -201,18 +234,10 @@ class KernelIndexBoundsChecker:
     def check(self, project) -> list:
         out = []
         for model in kernel_models(project):
-            shape_of = model.senv.get("__shape_of__")
             for b in (*model.inputs, *model.outputs):
                 if not (b.shape and b.index_map):
                     continue
-                operand_shape = None
-                if b.kind == "out":
-                    if b.index < len(model.out_shapes):
-                        operand_shape = model.out_shapes[b.index][0]
-                else:
-                    pos = model.num_prefetch + b.index
-                    if shape_of and pos < len(model.operands):
-                        operand_shape = shape_of(model.operands[pos])
+                operand_shape = _operand_shape(model, b)
                 if operand_shape is None:
                     continue
                 for d, comp in enumerate(b.index_map):
@@ -330,8 +355,8 @@ class KernelInterpretDefaultChecker:
                     f"kernel `{model.name}`: hard-coded interpret=True — on "
                     "TPU this silently EMULATES the kernel at Python speed "
                     "instead of compiling it; thread the caller's platform "
-                    "decision (interpret=<param>) or resolve None via "
-                    "jax.default_backend()",
+                    "decision (interpret=<param>), taken from the operand's "
+                    "device (pallas_kernels.on_tpu)",
                     symbol=f"{model.name}:interpret:literal",
                 ))
             elif kind == "param" and model.enclosing is not None:
@@ -428,8 +453,9 @@ class KernelInterpretDefaultChecker:
                     f"`{key[1]}` threads `{pname}` into a Pallas kernel's "
                     "interpret flag but DEFAULTS it to True — every caller "
                     "that forgets the flag emulates the kernel on TPU at "
-                    "Python speed, silently; default to None and resolve "
-                    "from jax.default_backend(), or make the flag required",
+                    "Python speed, silently; make the flag required, or "
+                    "default to None and resolve from the operand's device "
+                    "(pallas_kernels.on_tpu)",
                     symbol=f"{key[1]}:interpret:default",
                 ))
         return out

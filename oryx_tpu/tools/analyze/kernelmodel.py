@@ -22,8 +22,9 @@ One :class:`KernelModel` per ``pallas_call`` site carries everything the
   * the ``interpret`` argument's provenance (literal / parameter / absent).
 
 On top of the parsed buffers sit the VMEM budget math the checkers and the
-consistency tests share: padded byte counts under the dtype-native tiling
-((8, 128) f32, (16, 128) bf16, (32, 128) int8), a ×2 pipelining multiplier
+consistency tests share: padded byte counts under the tiling Mosaic infers
+((8, 128) f32, (16, 128) bf16, (32, 128) int8, fewer rows when the
+second-minor dim is smaller — :func:`sublane_tile`), a ×2 pipelining multiplier
 for grid-varying blocks (Mosaic double-buffers them), and symbolic
 :class:`dataflow.Poly` renderings for the ``--cost`` table. The registered
 budget knobs (``oryx.analyze.kernel.*``) are the single source of truth the
@@ -56,13 +57,15 @@ LANE = 128
 #: dtype -> minimum sublane count of one native VMEM tile (guide table).
 SUBLANE = {"int8": 32, "bfloat16": 16, "float32": 8, "float64": 8}
 
-#: Per-core VMEM (v4/v5e ≈ 16 MB) — the ceiling the whole-kernel resident
-#: footprint is checked against.
+#: The scoped-VMEM limit the TPU compiler holds one kernel to (16 MiB on a
+#: v5e, from its own out-of-memory message) — the ceiling the whole-kernel
+#: resident footprint is checked against.
 VMEM_LIMIT_BYTES = 16 << 20
 #: Scoped-VMEM budget for the LARGEST single buffer of a grid-tiled kernel
-#: (the discipline ``spd_solve_batched`` sizes its batch tile under:
-#: (7 << 17) f32 elements ≈ 3.5 MB, "budget ~4 MB for the largest buffer").
-SCOPED_BUDGET_BYTES = (7 << 17) * 4
+#: (the discipline ``spd_solve_batched`` sizes its batch tile under). The
+#: compiler's allocation for that kernel measures ~4.75× its largest buffer
+#: against the 16 MiB scoped limit, so 3 MiB is the most that leaves margin.
+SCOPED_BUDGET_BYTES = 3 << 20
 #: Resident-state budget for accumulator kernels whose output blocks stay
 #: VMEM-resident across grid steps (the gather-Gramian shape): double-
 #: buffered (k, k) accumulators + the gather scratch must leave the bulk of
@@ -105,6 +108,20 @@ def budgets(config=None) -> dict:
 
 def pad_up(n: int, multiple: int) -> int:
     return ((n + multiple - 1) // multiple) * multiple
+
+
+def sublane_tile(dim: int, dtype: "str | None") -> int:
+    """Rows of the tile Mosaic lays a buffer out in, given its second-minor
+    dim: the smallest power of two covering ``dim``, no smaller than the
+    dtype's packing (rows sharing one 32-bit sublane word) and no larger
+    than the native tile. A ``(T, 1, k)`` f32 buffer is ``(1, 128)``-tiled
+    — one row per tile, which is what makes a per-row DMA expressible — not
+    padded to 8 rows."""
+    native = SUBLANE.get(dtype or "float32", 8)
+    rows = native // 8  # packing
+    while rows < min(native, dim):
+        rows *= 2
+    return rows
 
 
 # -- index-map classification ------------------------------------------------
@@ -203,18 +220,19 @@ class KernelBuffer:
 
     def padded_bytes(self, bindings: dict) -> "float | None":
         """Concrete VMEM bytes of ONE buffer instance under ``bindings``,
-        with the dtype-native tiling applied to the trailing two dims (the
-        hardware pads them whether the block asks or not)."""
+        with Mosaic's tiling applied to the trailing two dims (the hardware
+        pads them whether the block asks or not): lanes to 128, sublanes to
+        :func:`sublane_tile`."""
         if self.shape is None:
             return None
         dims = [_dim_value(d, bindings) for d in self.shape]
         if any(d is None for d in dims):
             return None
-        sub = SUBLANE.get(self.dtype or "float32", 8)
         if len(dims) >= 1:
             dims[-1] = pad_up(max(1, dims[-1]), LANE)
         if len(dims) >= 2:
-            dims[-2] = pad_up(max(1, dims[-2]), sub)
+            rows = max(1, dims[-2])
+            dims[-2] = pad_up(rows, sublane_tile(rows, self.dtype))
         total = float(self.itemsize)
         for d in dims:
             total *= max(1, d)

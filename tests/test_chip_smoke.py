@@ -1,0 +1,232 @@
+"""The chip smoke, on the CPU (ISSUE 21).
+
+``chip_smoke.py`` is the quickest proof that the ALS lambda loop starts on
+a TPU; the driver runs it there. Tier-1 cannot, so it keeps the two halves
+of that proof that need no chip:
+
+  * the smoke's BODY in its explicit tiny CPU mode — the same phases, the
+    same result and no-fallback checks, a few thousand interactions, kernels
+    interpreted — passes; and fails, with the reason, when
+    ``ALSUpdate.build_model`` is made to raise (the candidate is skipped,
+    nothing is published, every layer exits clean: exactly the failure a
+    first chip run would otherwise not show);
+  * every Pallas kernel and the TPU-default trainer half-iteration LOWER for
+    ``("tpu",)`` at k=50 and k=250, so a block shape the TPU lowering
+    refuses — what stopped the trainer at the parent of this PR — fails
+    here without a chip.
+
+Each smoke run is a fresh process with ``JAX_ENABLE_X64`` removed: the
+program runs with it off and the chip has no float64, whatever conftest
+does for the rest of the suite.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_RUN = """
+import sys
+import chip_smoke
+{patch}
+sys.exit(chip_smoke.main(["--tiny-cpu"]))
+"""
+
+_PATCH = """
+from oryx_tpu.models.als.update import ALSUpdate
+
+def _refuse(self, *args, **kwargs):
+    raise RuntimeError("planted: the TPU lowering refused a block shape")
+
+ALSUpdate.build_model = _refuse
+"""
+
+
+def _smoke(patch: str = "", args: "list | None" = None):
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("JAX_ENABLE_X64", None)
+    env.pop("XLA_FLAGS", None)  # one device, like one chip
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    cmd = ([sys.executable, os.path.join(REPO, "chip_smoke.py"), *args]
+           if args is not None
+           else [sys.executable, "-c", _RUN.format(patch=patch)])
+    return subprocess.run(cmd, capture_output=True, text=True, timeout=180,
+                          env=env, cwd=REPO)
+
+
+def test_tiny_mode_passes_every_check_and_names_the_cpu():
+    proc = _smoke()
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    summary = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert summary["ok"] is True and summary["mode"] == "tiny-cpu"
+    assert summary["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
+    assert list(summary)[-1] == "claim" and summary["claim"] is None
+    loop = summary["loop"]
+    # results, not status codes
+    assert loop["generation_auc"] > 0.75 and loop["auc_published"] > 0.75
+    assert abs(loop["auc_published"] - loop["auc_reference"]) <= 0.05
+    assert loop["recommend"]["requests"] >= 32
+    assert loop["recommend"]["top10_overlap_mean"] >= 0.8
+    assert loop["fold_in"]["new_item_excluded"] is True
+    # the coalescer really coalesced; the warm ladder really finished
+    assert loop["coalescer"]["requests"] > loop["coalescer"]["flushes"] > 0
+    assert loop["warmup"]["done"] == loop["warmup"]["total"] > 0
+    # the formulation a TPU trains with carries both kernels
+    assert loop["tpu_custom_calls"] == 2
+    assert set(summary["kernels"]) == {
+        "gather_gramian/float32", "gather_gramian/bfloat16", "spd_solve",
+        "kmeans",
+    }
+    # set-up is reported as set-up; no rate, utilization or roofline figure
+    flat = json.dumps(summary)
+    assert not any(w in flat for w in ("qps", "mfu", "per_s", "roofline"))
+
+
+def test_skipped_candidate_fails_the_smoke_with_its_reason():
+    """``build_model`` raises → MLUpdate logs "candidate failed to build",
+    returns None, logs "unable to build any model" and the generation
+    SUCCEEDS with nothing published. The smoke must call that a failure."""
+    proc = _smoke(patch=_PATCH)
+    assert proc.returncode != 0
+    assert "chip_smoke FAILED" in proc.stderr
+    assert "candidate 0 failed to build" in proc.stderr
+    assert "planted: the TPU lowering refused a block shape" in proc.stderr
+    # no result line: nothing on stdout parses as an ok summary
+    assert '"ok"' not in proc.stdout
+
+
+def test_full_mode_refuses_a_cpu_and_says_why():
+    proc = _smoke(args=[])
+    assert proc.returncode != 0 and '"ok"' not in proc.stdout
+    assert "no TPU" in proc.stderr and "JAX_PLATFORMS='cpu'" in proc.stderr
+
+
+# ---------------------------------------------------------------------------
+# cross-lowering for the TPU: free, and it already failed at the parent
+# ---------------------------------------------------------------------------
+
+
+def _lower_tpu(fn, *args) -> str:
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.fixture
+def _program_dtypes():
+    """conftest turns 64-bit mode on for the suite; the program — and the
+    chip, which has no float64 — run with it off. Lower as the program
+    does: under x64 every Python float literal in a kernel body becomes a
+    float64 constant Mosaic cannot cast."""
+    with jax.enable_x64(False):
+        yield
+
+
+@pytest.mark.parametrize("k", [50, 250])
+def test_every_kernel_and_the_tpu_half_iteration_lower_for_tpu(
+        k, _program_dtypes):
+    from oryx_tpu.models.als import train as tr
+    from oryx_tpu.ops import pallas_kernels as pk
+
+    f32 = jnp.float32
+    for dtype in (jnp.float32, jnp.bfloat16):
+        for t in (8, 128, 512):
+            s, block = 64, 32
+            text = _lower_tpu(
+                lambda y, sr, sc, w, c: pk.gather_gramian_accumulate(
+                    y, sr, sc, w, c, block=block, interpret=False),
+                jnp.zeros((1000, k), dtype), jnp.zeros((s,), jnp.int32),
+                jnp.zeros((s, t), jnp.int32), jnp.zeros((s, t), f32),
+                jnp.zeros((s, t), f32))
+            assert text.count("tpu_custom_call") == 1, (k, dtype, t)
+
+    text = _lower_tpu(
+        lambda a, b: pk.spd_solve_batched(a, b, interpret=False),
+        jnp.zeros((1000, k, k), f32), jnp.zeros((1000, k), f32))
+    assert text.count("tpu_custom_call") == 1
+
+    text = _lower_tpu(
+        lambda p, w, c: pk.kmeans_assign_accumulate(p, w, c, interpret=False),
+        jnp.zeros((5000, k), f32), jnp.ones((5000,), f32),
+        jnp.zeros((20, k), f32))
+    assert text.count("tpu_custom_call") == 1
+
+    # what als_train picks on a TPU: both kernels, resolved by the trainer's
+    # own gate at this width and slot count
+    n_blocks, block, s, t = 2, 512, 1024, 32
+    assert tr._resolve_fused(None, True, k, s) is True
+    text = tr._solve_side_blocked_jit.trace(
+        jnp.zeros((2000, k), f32), jnp.zeros((n_blocks, s), jnp.int32),
+        jnp.zeros((n_blocks, s, t), jnp.int32), jnp.zeros((n_blocks, s, t), f32),
+        jnp.zeros((n_blocks, s), jnp.int32), 0.01, 1.0, block=block,
+        features=k, implicit=True, slot_chunk=s, dtype="float32",
+        spd_kernel=True, fused_gramian=True, kernel_interpret=False,
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 2
+
+
+# ---------------------------------------------------------------------------
+# one step further, still without a chip: XLA:TPU + Mosaic, compile-only
+# ---------------------------------------------------------------------------
+
+_COMPILE_ONLY = """
+import functools, os, sys
+os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+except Exception as e:
+    print("SKIP", type(e).__name__, e)
+    sys.exit(0)
+from oryx_tpu.models.als import train as tr
+
+sharding = SingleDeviceSharding(topo.devices[0])
+def spec(shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+def half(k, n_blocks, block, s, t, dtype):
+    fn = functools.partial(
+        tr._solve_side_blocked_jit.__wrapped__, block=block, features=k,
+        implicit=True, slot_chunk=s, dtype=dtype, spd_kernel=True,
+        fused_gramian=True, kernel_interpret=False)
+    jax.jit(lambda y, a, b, c, d: fn(y, a, b, c, d, 0.01, 1.0)).lower(
+        spec((20000, k)), spec((n_blocks, s), jnp.int32),
+        spec((n_blocks, s, t), jnp.int32), spec((n_blocks, s, t)),
+        spec((n_blocks, s), jnp.int32)).compile()
+
+# the smoke's own user side (13 blocks of 7693 rows, T=32): a batch large
+# enough that the SPD kernel's scoped VMEM is what production allocates
+half(50, 13, 7693, 10240, 32, "float32")
+half(256, 2, 1000, 2048, 512, "bfloat16")  # both kernels' last supported width
+print("COMPILED")
+"""
+
+
+def test_tpu_compiler_accepts_the_trainer_compile_only():
+    """Where libtpu is installed, jax can build a v5e topology with no chip
+    attached and run XLA:TPU and Mosaic against it. This is how the SPD
+    kernel's scoped-VMEM overrun (16.62 MiB of 16 at k=50, every production
+    batch size) and the gather kernel's sub-tile DMA slices were found
+    before any chip time was spent. Skips where no topology can be built."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("JAX_ENABLE_X64", None)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    proc = subprocess.run([sys.executable, "-c", _COMPILE_ONLY],
+                          capture_output=True, text=True, timeout=240,
+                          env=env, cwd=REPO)
+    last = (proc.stdout.strip().splitlines() or [""])[-1]
+    if last.startswith("SKIP"):
+        pytest.skip(f"no compile-only TPU topology here: {last}")
+    assert proc.returncode == 0 and last == "COMPILED", proc.stderr[-3000:]

@@ -192,7 +192,12 @@ import time
 from oryx_tpu.common import compilecache
 from oryx_tpu.common import config as cfg
 
-config = cfg.overlay_on({"oryx.compile.cache-dir": sys.argv[1]}, cfg.get_default())
+# argv[2] = how the directory is placed: "key" sets oryx.compile.cache-dir,
+# "env"/"default" leave the config alone (the environment, or nothing, does)
+overlay = {"oryx.compile.cache-dir": sys.argv[1]} if sys.argv[2] == "key" else {}
+if sys.argv[2] == "default":
+    compilecache.DEFAULT_CACHE_DIR = sys.argv[1]  # a scratch "checkout"
+config = cfg.overlay_on(overlay, cfg.get_default())
 compilecache.configure(config)
 
 import jax, jax.numpy as jnp
@@ -209,9 +214,72 @@ print(json.dumps({
     "compiles": compilecache.compiles_total(),
     "cache_hits": compilecache.cache_hits_total(),
     "elapsed": elapsed,
+    "cache_dir": compilecache.cache_dir(),
     "entries": sorted(f for f in os.listdir(sys.argv[1]) if f.endswith("-cache")),
 }))
 """
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run_cache_probe(tmp_path, cache_dir, placed_by: str, env_dir=None):
+    """One fresh process through ``compilecache.configure`` with the cache
+    directory placed by the config key, the environment, or nothing."""
+    script = tmp_path / "probe.py"
+    script.write_text(_CACHE_PROBE)
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "true"  # conftest turns it off
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    # the probe lives in tmp: python only adds the SCRIPT's dir to
+    # sys.path, so the repo must come via PYTHONPATH
+    env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
+    proc = subprocess.run(
+        [sys.executable, str(script), str(cache_dir), placed_by],
+        capture_output=True, text=True, timeout=180, env=env, cwd=REPO_ROOT,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_cache_dir_precedence_env_then_key_then_checkout(tmp_path):
+    """ISSUE 21 §5: ``JAX_COMPILATION_CACHE_DIR`` set → jax's own reading
+    stands and the code sets nothing (not even when the key is set too);
+    the key set → the key; neither → ``<checkout>/.jax_cache``."""
+    env_dir, key_dir = tmp_path / "from-env", tmp_path / "from-key"
+    env_dir.mkdir()
+    key_dir.mkdir()
+    # environment beats the key: entries land in env_dir, key_dir stays empty
+    got = _run_cache_probe(tmp_path, key_dir, "key", env_dir=env_dir)
+    assert got["cache_dir"] == str(env_dir)
+    assert got["entries"] == [] and os.listdir(env_dir)
+    # the key alone is test_persistent_cache_hit_across_processes below;
+    # neither: the fixed directory beside the package (the next test runs
+    # under it); with the environment set, nothing is written there
+    from oryx_tpu.common import compilecache
+
+    assert compilecache.DEFAULT_CACHE_DIR == os.path.join(REPO_ROOT,
+                                                          ".jax_cache")
+    default_dir = tmp_path / "checkout" / ".jax_cache"
+    default_dir.mkdir(parents=True)
+    _run_cache_probe(tmp_path, default_dir, "default", env_dir=env_dir)
+    assert os.listdir(default_dir) == []
+
+
+def test_default_cache_shared_by_two_fresh_processes(tmp_path):
+    """Two fresh processes under the DEFAULT placement (no key, no
+    environment) share entries: the second records a hit on what the first
+    wrote — the property the chip tool's one-command, several-process runs
+    rely on."""
+    default_dir = tmp_path / "checkout" / ".jax_cache"
+    first = _run_cache_probe(tmp_path, default_dir, "default")
+    assert first["cache_dir"] == str(default_dir)
+    assert first["cache_hits"] == 0 and first["entries"]
+    second = _run_cache_probe(tmp_path, default_dir, "default")
+    assert second["cache_hits"] >= 1, second
+    assert second["entries"] == first["entries"]
 
 
 def test_persistent_cache_hit_across_processes(tmp_path):
@@ -220,26 +288,12 @@ def test_persistent_cache_hit_across_processes(tmp_path):
     and as faster-than-cold."""
     cache_dir = tmp_path / "xla-cache"
     cache_dir.mkdir()
-    script = tmp_path / "probe.py"
-    script.write_text(_CACHE_PROBE)
-
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
     def run():
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
-        # the probe lives in tmp: python only adds the SCRIPT's dir to
-        # sys.path, so the repo must come via PYTHONPATH
-        env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
-        proc = subprocess.run(
-            [sys.executable, str(script), str(cache_dir)],
-            capture_output=True, text=True, timeout=180, env=env,
-            cwd=repo_root,
-        )
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        return json.loads(proc.stdout.strip().splitlines()[-1])
+        return _run_cache_probe(tmp_path, cache_dir, "key")
 
     first = run()
+    assert first["cache_dir"] == str(cache_dir)
     assert first["compiles"] >= 1
     assert first["cache_hits"] == 0
     assert first["entries"], "first process wrote no cache entries"

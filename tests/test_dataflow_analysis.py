@@ -38,7 +38,7 @@ _TRAIN_SHAPED = """
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     def _solver(mesh, axis):
         def local(y, scols, svals):
@@ -52,7 +52,7 @@ _TRAIN_SHAPED = """
             in_specs=(P(), P(axis), P(axis)),   # y fully replicated
             out_specs=P(axis),
         )
-        return jax.jit(shard_map(local, check_rep=False, **specs))
+        return jax.jit(shard_map(local, check_vma=False, **specs))
 """
 
 
@@ -77,7 +77,7 @@ def test_replicated_collective_quiet_on_batch_replication():
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def _topk(mesh, axis):
             def local(mat, qs, excl):
@@ -104,7 +104,7 @@ def test_replicated_collective_fires_on_closure_capture():
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def build(mesh, axis, table_np):
             table = jnp.asarray(table_np)

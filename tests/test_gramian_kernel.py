@@ -97,7 +97,7 @@ def test_kernel_direct_against_numpy_reference():
     big_a, big_b = jax.jit(
         lambda *a: gather_gramian_accumulate(*a, block=block, interpret=True)
     )(jnp.asarray(y), jnp.asarray(srow), jnp.asarray(scols), jnp.asarray(w),
-      jnp.asarray(coef), jnp.asarray(slens))
+      jnp.asarray(coef))
 
     yg = y[scols]  # (S, T, k)
     ra = np.zeros((block + 1, k, k), np.float32)
@@ -115,8 +115,10 @@ def test_kernel_direct_against_numpy_reference():
 
 
 def test_supported_gate():
-    assert gather_gramian_supported(50)
-    assert not gather_gramian_supported(512)
+    assert gather_gramian_supported(50, 4096)
+    assert not gather_gramian_supported(512, 4096)
+    # the owner rows ride whole in the compiler's 1 MiB of SMEM
+    assert not gather_gramian_supported(50, 1 << 18)
     # above the gate, the platform default must fall back, not fail
     batch, _ = _skewed_batch(5)
     side, item_side = tr.prepare_blocked(batch, 300, block=64)

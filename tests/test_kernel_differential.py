@@ -47,16 +47,16 @@ SEED = 0x0F15
 
 def _spd_cases():
     """The seeded shape matrix: every k-class the tile formula produces
-    (full 256-tile, mid tiles, the 8-row boundary tile at k=296, and the
+    (full 256-tile, mid tiles, the 8-row boundary tile at k=256, and the
     cholesky fallback past it) × batch sizes around the pad tile."""
     rng = random.Random(SEED)
     cases = []
     for k in (1, 2, 5, 8, 13, 50, 64):
         b = rng.choice((1, 2, 7, 9, 33))
         cases.append((b, k))
-    cases.append((257, 50))   # straddles the k=50 tile (tile_b=128)
-    cases.append((2, 296))    # the LAST kernel k: tile_b == 8
-    cases.append((2, 304))    # first fallback k: cholesky path
+    cases.append((209, 50))   # straddles the k=50 tile (tile_b=104)
+    cases.append((2, 256))    # the LAST kernel k: tile_b == 8
+    cases.append((2, 264))    # first fallback k: cholesky path
     return cases
 
 
@@ -75,11 +75,12 @@ def test_spd_differential_matches_numpy(b, k):
 
 
 def test_spd_boundary_tile_is_the_modeled_boundary():
-    """The (2, 296) case above really did run at the smallest legal tile,
-    and 304 really fell back — the fuzz matrix covers the budget boundary,
+    """The (2, 256) case above really did run at the smallest legal tile,
+    and 264 really fell back — the fuzz matrix covers the budget boundary,
     not just round shapes."""
-    assert pk.spd_tile_b(296) == 8
-    assert pk.spd_tile_b(304) < 8
+    assert pk.spd_tile_b(50) == 104
+    assert pk.spd_tile_b(256) == 8
+    assert pk.spd_tile_b(264) < 8
 
 
 # ---------------------------------------------------------------------------
@@ -163,7 +164,7 @@ def test_gg_differential_matches_numpy(k, t, block, n_slots, n_pad, skew,
         lambda *args: pk.gather_gramian_accumulate(
             *args, block=block, interpret=True)
     )(yj, jnp.asarray(srow), jnp.asarray(scols), jnp.asarray(w),
-      jnp.asarray(coef), jnp.asarray(slens))
+      jnp.asarray(coef))
     big_a, big_b = np.asarray(big_a), np.asarray(big_b)
 
     ra, rb = _gg_reference(y_ref, srow, scols, w_ref, coef_ref, block)
@@ -183,7 +184,7 @@ def test_gg_supported_gate_spans_the_fuzz_matrix():
     """Every kernel-run case above sits inside the runtime gate, and the
     matrix's boundary case IS the gate's last legal k."""
     ks = [c[0] for c in _gg_cases()]
-    assert all(pk.gather_gramian_supported(k) for k in ks)
+    assert all(pk.gather_gramian_supported(k, 64) for k in ks)
     assert max(ks) == pk._GG_MAX_FEATURES
 
 
@@ -210,20 +211,22 @@ def test_gg_max_features_equals_modeled_budget(ops_kernel_models):
     parsed, tile-padded resident footprint at the pack's maximum slot width
     fits the registered resident budget — and the runtime boolean gate must
     agree with the model at EVERY k, so neither side can move alone."""
-    from oryx_tpu.tools.analyze.kernelmodel import budgets
+    from oryx_tpu.tools.analyze.kernelmodel import budgets, pad_up
 
     gg = ops_kernel_models["gather_gramian_accumulate"]
     budget = budgets()["resident_budget_bytes"]
 
     def fits(k: int) -> bool:
-        nbytes = gg.vmem_bytes({"k": k, "t": pk._GG_SLOT_WIDTH_MAX})
+        # kp is the wrapper's lane-padded gather width, pad128(k)
+        nbytes = gg.vmem_bytes({"k": k, "t": pk._GG_SLOT_WIDTH_MAX,
+                                "kp": pad_up(k, 128)})
         assert nbytes is not None, "gg model no longer evaluates — reparse"
         return nbytes <= budget
 
     modeled_max = max(k for k in range(8, 1025, 8) if fits(k))
     assert modeled_max == pk._GG_MAX_FEATURES
     for k in (1, 7, 8, 50, 200, 249, 255, 256, 257, 264, 300, 511, 512):
-        assert pk.gather_gramian_supported(k) == fits(k), k
+        assert pk.gather_gramian_supported(k, 64) == fits(k), k
 
 
 def test_spd_tile_formula_equals_modeled_budget(ops_kernel_models):
@@ -244,8 +247,8 @@ def test_spd_tile_formula_equals_modeled_budget(ops_kernel_models):
                 return tb
         return 0
 
-    for k in (1, 2, 8, 13, 50, 64, 100, 127, 128, 200, 256, 288, 296, 304,
-              350, 480):
+    for k in (1, 2, 8, 13, 50, 64, 100, 127, 128, 200, 250, 256, 257, 264,
+              296, 350, 480):
         assert pk.spd_tile_b(k) == modeled_tile(k), k
 
 

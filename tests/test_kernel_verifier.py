@@ -135,32 +135,79 @@ def test_tile_alignment_fires_on_pad_waste_and_hard_misalignment():
     # lane dim 100 under a constant lane map: pure pad-waste (128 rounds)
     assert "wasteful:in0:lane" in by_symbol
     assert "padding" in by_symbol["wasteful:in0:lane"].message
-    # sublane dim 100 with a grid-varying map: blocks start mid-tile
+    # sublane dim 100 with a grid-varying map over an 800-row operand: not
+    # a multiple of 8 and not the whole dim — the lowering refuses it
     assert "wasteful:out0:sublane" in by_symbol
-    assert "mid-tile" in by_symbol["wasteful:out0:sublane"].message
+    assert "refuses" in by_symbol["wasteful:out0:sublane"].message
 
 
-def test_tile_alignment_quiet_on_native_tiles_and_unit_dims():
+def test_tile_alignment_fires_on_unit_row_select_in_any_space():
+    """The block shapes the real TPU lowering refused at ISSUE 21 — a
+    ``(1, t)`` row-select over ``(S, T)`` in SMEM and in VMEM, a ``(1, 1)``
+    scalar block, a ``(1, k)`` data-indexed output — while the old checker
+    called size-1 dims "the per-step row-select idiom" and stayed quiet."""
+    hits = _run(
+        """
+        def kern(sr_ref, c_ref, n_ref, w_ref, a_ref):
+            a_ref[:] = w_ref[:]
+
+        def refused(srow, scols, slens, w, block, interpret):
+            s, t = scols.shape
+            k = w.shape[1]
+            grid_spec = pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(s,),
+                in_specs=[
+                    pl.BlockSpec((1, t), lambda i, sr: (i, 0),
+                                 memory_space=pltpu.SMEM),
+                    pl.BlockSpec((1, 1), lambda i, sr: (i, 0),
+                                 memory_space=pltpu.SMEM),
+                    pl.BlockSpec((1, t), lambda i, sr: (i, 0),
+                                 memory_space=pltpu.VMEM),
+                ],
+                out_specs=pl.BlockSpec((1, k), lambda i, sr: (sr[i], 0),
+                                       memory_space=pltpu.VMEM),
+            )
+            return pl.pallas_call(
+                kern,
+                grid_spec=grid_spec,
+                out_shape=jax.ShapeDtypeStruct((block + 1, k), jnp.float32),
+                interpret=interpret,
+            )(srow, scols, slens, w)
+        """,
+        "kernel-tile-alignment",
+    )
+    assert {f.symbol for f in hits} == {
+        "refused:in0:sublane", "refused:in1:sublane", "refused:in2:sublane",
+        "refused:out0:sublane",
+    }
+    assert all("refuses" in f.message for f in hits)
+
+
+def test_tile_alignment_quiet_on_native_tiles_and_leading_unit_dims():
     clean = """
         def kern(x_ref, o_ref):
             o_ref[:] = x_ref[:]
 
-        def ok(x, t, interpret):
+        def ok(x, w, t, interpret):
             return pl.pallas_call(
                 kern,
                 grid=(8,),
                 in_specs=[
                     pl.BlockSpec((8, 128), lambda i: (i, 0),
                                  memory_space=pltpu.VMEM),
-                    # size-1 dims are the per-step row-select idiom
-                    pl.BlockSpec((1, t), lambda i: (i, 0),
+                    # the row-select the lowering accepts: the windowed
+                    # dim LEADS, the block's last two dims span the array's
+                    pl.BlockSpec((1, 1, t), lambda i: (i, 0, 0),
+                                 memory_space=pltpu.SMEM),
+                    pl.BlockSpec((1, 2, t), lambda i: (i, 0, 0),
                                  memory_space=pltpu.VMEM),
                 ],
                 out_specs=pl.BlockSpec((8, 256), lambda i: (i, 0),
                                        memory_space=pltpu.VMEM),
                 out_shape=jax.ShapeDtypeStruct((64, 256), jnp.float32),
                 interpret=interpret,
-            )(x, x)
+            )(x, x.reshape(8, 1, t), w)
         """
     assert _run(clean, "kernel-tile-alignment") == []
 
@@ -506,9 +553,9 @@ def test_real_kernels_parse_with_expected_structure(kernels_project):
     gg = models["gather_gramian_accumulate"]
     assert gg.num_prefetch == 1
     assert [b.space for b in gg.inputs] == [
-        "smem", "smem", "vmem", "vmem", "any", "any", "any",
+        "smem", "vmem", "any", "any", "any",
     ]
-    assert gg.aliases == {6: 0, 7: 1}
+    assert gg.aliases == {4: 0, 5: 1}
     # the scalar-prefetch-driven output maps are data-dependent: revisited
     assert all(b.revisits_across_grid(gg.grid) for b in gg.outputs)
     # and the kernel zero-initializes both refs on first visit
@@ -531,22 +578,25 @@ def test_real_kernels_parse_with_expected_structure(kernels_project):
 
 def test_gg_vmem_model_matches_hand_computed_budget(kernels_project):
     """The acceptance numbers: the gather-Gramian resident footprint at
-    (k=256, T=512) — double-buffered (1,k,k)/(1,k) accumulators, (1,T)
-    weight blocks, (T,k) gather scratch, all tile-padded — is exactly
-    1,130,496 B, inside the 1.5 MiB resident budget; the next k tile (264)
-    overflows it."""
-    from oryx_tpu.tools.analyze.kernelmodel import budgets, kernel_models
+    (k=256, T=512) — double-buffered (1,k,k)/(1,1,k) accumulators, the
+    (1,2,T) weight block, the (T,1,pad128(k)) gather scratch, each under
+    the tiling Mosaic infers for it ((1,128) and (2,128) for the 1- and
+    2-row tails, not 8 rows) — is exactly 1,058,816 B, inside the 1.5 MiB
+    resident budget; the next k tile (264) overflows it."""
+    from oryx_tpu.tools.analyze.kernelmodel import (
+        budgets, kernel_models, pad_up,
+    )
 
     gg = next(m for m in kernel_models(kernels_project)
               if m.name == "gather_gramian_accumulate")
-    at = lambda k: gg.vmem_bytes({"k": k, "t": 512})
+    at = lambda k: gg.vmem_bytes({"k": k, "t": 512, "kp": pad_up(k, 128)})
     expected_256 = (
         2 * 256 * 256 * 4       # (1,256,256) f32 out block, double-buffered
-        + 2 * 8 * 256 * 4       # (1,256) out block, sublane-padded to 8
-        + 2 * 2 * 8 * 512 * 4   # two (1,512) f32 weight blocks
-        + 512 * 256 * 4         # (512,256) gather scratch
+        + 2 * 1 * 256 * 4       # (1,1,256) out block: one (1,128)-tiled row
+        + 2 * 2 * 512 * 4       # (1,2,512) f32 weight block, (2,128)-tiled
+        + 512 * 1 * 256 * 4     # (512,1,256) gather scratch, row per tile
     )
-    assert at(256) == expected_256 == 1_130_496
+    assert at(256) == expected_256 == 1_058_816
     budget = budgets()["resident_budget_bytes"]
     assert at(256) <= budget < at(264)
 
@@ -561,8 +611,7 @@ def test_cli_cost_renders_kernel_rows(capsys):
     rows = {r["kernel"]: r for r in data["kernels"]}
     spd = rows["oryx_tpu.ops.pallas_kernels._spd_solve_call"]
     # largest buffer = the augmented (tile_b, k, k+1) scratch: its padded
-    # bytes at tile_b=128, k=50 are 128·56·128·4 = 3,670,016 — exactly the
-    # scoped budget the runtime gate sizes against
+    # bytes at tile_b=128, k=50 are 128·56·128·4 = 3,670,016
     assert spd["vmem_bytes"]["value"] is not None
     assert "tile_b" in spd["vmem_bytes"]["expr"]
     gg = rows["oryx_tpu.ops.pallas_kernels.gather_gramian_accumulate"]

@@ -171,3 +171,58 @@ def test_als_update_build_model_on_mesh():
         assert xs.keys() == x1.keys()
         for id_ in xs:
             np.testing.assert_allclose(xs[id_], x1[id_], rtol=2e-3, atol=2e-4)
+
+
+def test_default_mesh_shape_splits_als_rows_over_every_device(
+        tmp_path, monkeypatch):
+    """ISSUE 21 §6: with ``mesh-shape = null`` ComputeContext puts every
+    device on the FIRST axis ("data"). ALSUpdate used to shard rows over
+    "model" — size 1 there — so eight devices each solved every block. Drive
+    a real BatchLayer's update with the default config and look at the
+    factors the trainer hands back: not fully replicated, and their row
+    blocks split over all eight devices."""
+    from oryx_tpu.api.keymessage import KeyMessage
+    from oryx_tpu.lambda_rt.batch import BatchLayer
+    from oryx_tpu.models.als.update import row_sharding
+
+    config = cfg.overlay_on(
+        {
+            "oryx.batch.update-class": "oryx_tpu.models.als.update.ALSUpdate",
+            "oryx.batch.storage.data-dir": str(tmp_path / "data"),
+            "oryx.batch.storage.model-dir": str(tmp_path / "model"),
+            "oryx.als.iterations": 2,
+            "oryx.als.hyperparams.features": 5,
+        },
+        cfg.get_default(),
+    )
+    assert config.get("oryx.batch.streaming.config.mesh-shape", None) is None
+    layer = BatchLayer(config)
+    context = layer.get_context()
+    assert context.mesh.size == 8 and context.mesh.shape["data"] == 8
+    mesh, row_axis = row_sharding(context)
+    assert mesh is context.mesh and row_axis == ("data", "model")
+
+    trained = []
+    real_train = als_train_mod.als_train
+
+    def recording_train(*args, **kwargs):
+        out = real_train(*args, **kwargs)
+        trained.append(out)
+        return out
+
+    monkeypatch.setattr(als_train_mod, "als_train", recording_train)
+    rng = np.random.default_rng(9)
+    data = [
+        KeyMessage(None, f"u{u},i{i},1,{u * 50 + int(i)}")
+        for u in range(64) for i in rng.choice(40, 6, replace=False)
+    ]
+    update = layer.load_update_instance()
+    pmml = update.build_model(context, data, [5, 0.001, 1.0], tmp_path)
+    assert pmml is not None and len(trained) == 1
+    for arr in trained[0]:
+        assert not arr.sharding.is_fully_replicated
+        assert len({s.device for s in arr.addressable_shards}) == 8
+        # eight DIFFERENT row ranges, not eight copies of one
+        starts = {s.index[0].start or 0 for s in arr.addressable_shards}
+        assert len(starts) == 8
+    layer.close()

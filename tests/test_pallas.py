@@ -82,8 +82,7 @@ def test_pallas_lloyd_path_matches_xla_path():
         pts, 3, iterations=8, runs=1, init="random", key=key, use_pallas=False
     )
     c_pl, n_pl = kmtrain.kmeans_train(
-        pts, 3, iterations=8, runs=1, init="random", key=key,
-        use_pallas=True, interpret=True,
+        pts, 3, iterations=8, runs=1, init="random", key=key, use_pallas=True
     )
     np.testing.assert_allclose(c_pl, c_xla, rtol=1e-4, atol=1e-4)
     np.testing.assert_array_equal(n_pl, n_xla)

@@ -23,7 +23,7 @@ def test_matches_lapack(b, k):
     rng = np.random.default_rng(b * 100 + k)
     a = _random_spd(rng, b, k)
     rhs = rng.standard_normal((b, k)).astype(np.float32)
-    x = np.asarray(spd_solve_batched(a, rhs))
+    x = np.asarray(spd_solve_batched(a, rhs, interpret=True))
     ref = np.stack([np.linalg.solve(a[i], rhs[i]) for i in range(b)])
     err = np.abs(x - ref).max() / np.abs(ref).max()
     assert err < 1e-4, (b, k, err)
@@ -34,7 +34,7 @@ def test_padding_rows_produce_no_nan():
     rng = np.random.default_rng(0)
     a = _random_spd(rng, 9, 50)
     rhs = rng.standard_normal((9, 50)).astype(np.float32)
-    x = np.asarray(spd_solve_batched(a, rhs))
+    x = np.asarray(spd_solve_batched(a, rhs, interpret=True))
     assert x.shape == (9, 50)
     assert np.isfinite(x).all()
 
@@ -45,7 +45,7 @@ def test_huge_k_falls_back_to_cholesky():
     k = 480
     a = _random_spd(rng, 2, k, shift=5.0)
     rhs = rng.standard_normal((2, k)).astype(np.float32)
-    x = np.asarray(spd_solve_batched(a, rhs))
+    x = np.asarray(spd_solve_batched(a, rhs, interpret=True))
     ref = np.stack([np.linalg.solve(a[i], rhs[i]) for i in range(2)])
     assert np.abs(x - ref).max() / np.abs(ref).max() < 1e-3
 
