@@ -23,12 +23,15 @@ expired request, swallowed compile failure or kernel fallback.
 With four or more devices the same run shards training over every device
 and serving over a 4-way mesh, and checks PLACEMENT as well as results.
 
-The last line of stdout is one JSON object: ``{"ok": true, "device": {...},
-..., "claim": null}``. Times in it are set-up (seconds to first MODEL, warm
-ladder, compiles, cache hits), never a rate: rates belong to the benchmark.
-On any failure the script prints the reason to stderr, prints no result
-line, and exits non-zero. Everything it writes lands under
-``chiprun_out/chip_smoke/``.
+Stdout carries two lines, each one JSON object. The first is the summary
+(``{"ok": true, "device": {...}, ..., "claim": null}``, also written to
+``chiprun_out/chip_smoke/summary.json``); times in it are set-up (seconds to
+first MODEL, warm ladder, compiles, cache hits), never a rate: rates belong
+to the benchmark. The LAST line is the verdict the driver reads, with exactly
+these keys: ``{"ok": true, "device": {"platform": ..., "kind": ...,
+"count": ...}}``, the device as jax reports it. On any failure the script
+prints the reason to stderr, prints nothing to stdout, and exits non-zero.
+Everything it writes lands under ``chiprun_out/chip_smoke/``.
 """
 
 from __future__ import annotations
@@ -568,6 +571,8 @@ def _check_published(size, users, items, client, config, broker, up_topic,
         raise SmokeFailure(f"served scores are off by {np.max(score_errs):.3g} "
                            f"of the score scale > {SCORE_TOL}")
 
+    log(f"/recommend ok: {out['recommend']}")
+
     # one each of the other endpoint families
     u0, u1 = sample[0], sample[1]
     r = client.get(f"/recommendToMany/u{u0}/u{u1}", params={"howMany": HOW_MANY})
@@ -617,6 +622,7 @@ def _check_published(size, users, items, client, config, broker, up_topic,
     }
     if not out["fold_in"]["new_item_excluded"]:
         raise SmokeFailure("the folded-in item is still recommended")
+    log(f"fold-in ok: {out['fold_in']}")
     keys = [km.key for km in broker.read(up_topic, 0, broker.size(up_topic))]
     n_models = sum(1 for k in keys if k in ("MODEL", "MODEL-REF"))
     if n_models != 1:
@@ -736,6 +742,7 @@ def _check_against_reference(size, config, users, items, x_pub, y_pub, x_row,
         raise SmokeFailure(
             f"published AUC {out['auc_published']} and reference-formulation "
             f"AUC {out['auc_reference']} differ by more than {size.auc_band}")
+    log(f"reference formulation ok: {out}")
     return out
 
 
@@ -887,7 +894,10 @@ def main(argv=None) -> int:
         return 2
     with open(os.path.join(OUT_DIR, "summary.json"), "w") as f:
         json.dump(summary, f, indent=1)
-    print(json.dumps(summary), flush=True)
+    log(f"every phase passed in {summary['setup']['wall_seconds']}s")
+    print(json.dumps(summary))
+    # the verdict, last: these keys and no others
+    print(json.dumps({"ok": True, "device": summary["device"]}), flush=True)
     return 0
 
 

@@ -66,9 +66,13 @@ def _smoke(patch: str = "", args: "list | None" = None):
 def test_tiny_mode_passes_every_check_and_names_the_cpu():
     proc = _smoke()
     assert proc.returncode == 0, proc.stderr[-3000:]
-    summary = json.loads(proc.stdout.strip().splitlines()[-1])
+    out_lines = proc.stdout.strip().splitlines()
+    # the driver reads the LAST line and refuses any key beyond these
+    assert json.loads(out_lines[-1]) == {
+        "ok": True, "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    summary = json.loads(out_lines[-2])
     assert summary["ok"] is True and summary["mode"] == "tiny-cpu"
-    assert summary["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
+    assert summary["device"] == json.loads(out_lines[-1])["device"]
     assert list(summary)[-1] == "claim" and summary["claim"] is None
     loop = summary["loop"]
     # results, not status codes
