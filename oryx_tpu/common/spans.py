@@ -29,6 +29,12 @@ request path; arXiv:2501.10546 makes the tier-crossing case):
     per-route min-heap *always* keeps the slowest N per route even after
     the ring has wrapped — the p99 outlier survives until a slower one
     displaces it.
+  * **stages on the profiler's clock**: the device call is cut into
+    child spans (:func:`stage`) that also enter a
+    ``jax.profiler.TraceAnnotation`` of the same name, so a profiler
+    capture names the chip's idle gaps by the program's own stages, and
+    ``start_walltime − profile_start_time`` puts every span of the ring
+    into that trace (docs/observability.md "Request tracing").
   * ``GET /trace`` (serving/resources/common.py) renders both views;
     ``tools/trace_summary.py --trace-id`` prints one trace as a tree.
 
@@ -45,14 +51,22 @@ import dataclasses
 import heapq
 import itertools
 import logging
+import os
+import random
+import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 #: Response/request header and topic-message header key (W3C Trace Context).
 TRACEPARENT = "traceparent"
 
-_rand = __import__("random").SystemRandom()
+# A trace id has to be unique, not secret: one seed from the OS, then the
+# Mersenne Twister (``SystemRandom`` is a ``getrandom`` syscall per id, on
+# the request path). ``getrandbits`` is one C call, atomic under the GIL.
+_rand = random.Random(os.urandom(16))
+# a forked child must not repeat its parent's ids
+os.register_at_fork(after_in_child=lambda: _rand.seed(os.urandom(16)))
 
 
 def new_trace_id() -> str:
@@ -399,24 +413,61 @@ def finish_span(span) -> None:
         _STATE.recorder.record(span)
 
 
+_NO_ANNOTATION = nullcontext()
+
+
+def _trace_annotation(name: str):
+    """The profiler's own annotation of ``name``. jax is looked up, never
+    imported: where nothing has imported it no capture can be running, and
+    a request handler must not pay for the import."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_ANNOTATION
+    return jax.profiler.TraceAnnotation(name)
+
+
 @contextmanager
 def span(name: str, parent=_USE_CURRENT, links=(),
-         attributes: "dict | None" = None):
+         attributes: "dict | None" = None, annotate: bool = False):
     """Context manager: open a span, make it current, record on exit.
-    Exceptions mark the span status and propagate."""
+    Exceptions mark the span status and propagate.
+
+    ``annotate`` also enters a ``jax.profiler.TraceAnnotation`` of the same
+    name for the span's duration — for a stage that starts and ends on ONE
+    thread with no ``await`` inside. Under a profiler capture the stage is
+    then in the device trace too (it names the chip's idle gaps there), and
+    the pair — ``start_walltime`` in the ring, the annotation on the
+    profiler's clock — is the check that both are one clock. With no
+    capture running it is one inactive TraceMe."""
     sp = start_span(name, parent=parent, links=links, attributes=attributes)
     if sp is NOOP_SPAN:
         yield sp
         return
     token = _CURRENT.set(sp)
     try:
-        yield sp
+        with _trace_annotation(name) if annotate else _NO_ANNOTATION:
+            yield sp
     except BaseException as e:
         sp.record_exception(e)
         raise
     finally:
         _CURRENT.reset(token)
         finish_span(sp)
+
+
+def stage(name: str):
+    """One stage of the CURRENT span's work that starts and ends on this
+    thread: a child span that carries the current span's id as ``call``
+    and is annotated into the profiler's trace (see :func:`span`). The
+    coalescer's device call is cut into stages this way; ``call`` joins
+    them to every request of the flush, whose trace id is only the first
+    waiter's. With no current span (a direct call of the model, tracing
+    off) nothing is recorded: a stage never starts a trace of its own."""
+    cur = _CURRENT.get()
+    if cur is None or not _STATE.enabled:
+        return nullcontext(NOOP_SPAN)
+    return span(name, parent=cur, attributes={"call": cur.span_id},
+                annotate=True)
 
 
 @contextmanager

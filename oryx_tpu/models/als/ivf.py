@@ -51,6 +51,7 @@ import numpy as np
 from oryx_tpu.common import compilecache
 from oryx_tpu.common import metrics as metrics_mod
 from oryx_tpu.common import profiling
+from oryx_tpu.common import spans
 
 log = logging.getLogger(__name__)
 
@@ -632,7 +633,8 @@ def _scan(model, snap: IVFSnapshot, qs_host: np.ndarray, probes: int,
     positions, quantized scores. Registers/records the probe and scan
     programs under their own cost keys so attribution separates candidate
     generation from the exact rescore."""
-    qs = jnp.asarray(qs_host)
+    with spans.stage("topn.upload"):
+        qs = jnp.asarray(qs_host)
     b = qs_host.shape[0]
     c = snap.n_cells
     pk = probe_cost_key(b, c, probes)
@@ -647,27 +649,29 @@ def _scan(model, snap: IVFSnapshot, qs_host: np.ndarray, probes: int,
                 (snap.cell_pos, snap.cell_q, snap.cell_scale, qs, cells,
                  excl))
 
-    if register and metrics_mod.default_registry().enabled:
-        if pk not in snap.cost_keys_attempted:
-            snap.cost_keys_attempted.add(pk)
-            compilecache.aot_compile(
-                _probe_cells, snap.centroids, qs, probes, cost_key=pk
-            )
-        if sk not in snap.cost_keys_attempted:
-            snap.cost_keys_attempted.add(sk)
-            fn, a = scan_args(
-                jax.ShapeDtypeStruct((b, probes), jnp.int32)
-            )
-            compilecache.aot_compile(fn, *a, r, cost_key=sk)
-    cells = _probe_cells(snap.centroids, qs, probes)
-    fn, a = scan_args(cells)
-    vals, idx = fn(*a, r)
-    if register:
-        profiling.costs().record(pk)
-        profiling.costs().record(sk)
-    _INDEX_PROBED.inc(b * probes)
-    _INDEX_CANDIDATES.inc(b * r)
-    return np.asarray(vals), np.asarray(idx)
+    with spans.stage("topn.dispatch"):
+        if register and metrics_mod.default_registry().enabled:
+            if pk not in snap.cost_keys_attempted:
+                snap.cost_keys_attempted.add(pk)
+                compilecache.aot_compile(
+                    _probe_cells, snap.centroids, qs, probes, cost_key=pk
+                )
+            if sk not in snap.cost_keys_attempted:
+                snap.cost_keys_attempted.add(sk)
+                fn, a = scan_args(
+                    jax.ShapeDtypeStruct((b, probes), jnp.int32)
+                )
+                compilecache.aot_compile(fn, *a, r, cost_key=sk)
+        cells = _probe_cells(snap.centroids, qs, probes)
+        fn, a = scan_args(cells)
+        vals, idx = fn(*a, r)
+        if register:
+            profiling.costs().record(pk)
+            profiling.costs().record(sk)
+        _INDEX_PROBED.inc(b * probes)
+        _INDEX_CANDIDATES.inc(b * r)
+    with spans.stage("topn.wait_download"):
+        return np.asarray(vals), np.asarray(idx)
 
 
 def top_n(model, snap: IVFSnapshot, q_host: np.ndarray, how_many: int,
@@ -727,28 +731,26 @@ def top_n_batch(model, snap: IVFSnapshot, qs_host: np.ndarray,
     r = _candidate_width(model, snap, snap.probes, how_many)
     v, i = _scan(model, snap, qs_host, snap.probes, r, excl, lut,
                  register=True)
-    vals, idx = model._rescore_exact(snap, qs_host, v, i)
-    if not filtering:
-        ids = snap.ids
-        vb, ib = vals[:, :how_many], idx[:, :how_many]
-        return [
-            [(ids[int(i_)], float(v_)) for v_, i_ in zip(vb[q], ib[q])
-             if np.isfinite(v_)]
-            for q in range(b)
-        ]
-    out = []
-    for q in range(b):
-        allowed = alloweds[q] if alloweds else None
-        got = model._collect(
-            snap, vals[q], idx[q], how_many, allowed, None
-        )[:how_many]
-        if len(got) < how_many and r < snap.n:
-            got = top_n(
-                model, snap, qs_host[q], how_many, 0, allowed, None,
-                excluded[q] if excluded else None,
-            )
-        out.append(got)
-    return out
+    with spans.stage("topn.rescore"):
+        vals, idx = model._rescore_exact(snap, qs_host, v, i)
+    with spans.stage("topn.ids"):
+        if not filtering:
+            from oryx_tpu.models.als.serving import _id_lists
+
+            return _id_lists(snap.ids, vals, idx, how_many)
+        out = []
+        for q in range(b):
+            allowed = alloweds[q] if alloweds else None
+            got = model._collect(
+                snap, vals[q], idx[q], how_many, allowed, None
+            )[:how_many]
+            if len(got) < how_many and r < snap.n:
+                got = top_n(
+                    model, snap, qs_host[q], how_many, 0, allowed, None,
+                    excluded[q] if excluded else None,
+                )
+            out.append(got)
+        return out
 
 
 def top_n_cosine(model, snap: IVFSnapshot, qs_host: np.ndarray,

@@ -117,6 +117,17 @@ def _topn_cost_key(batch_size: int, excl: bool, quant: bool = False) -> str:
             + ("+excl" if excl else "") + ("+int8" if quant else ""))
 
 
+def _id_lists(ids, vals: np.ndarray, idx: np.ndarray, how_many: int) -> list:
+    """(B, >= how_many) scores and row indices, best first -> per query its
+    ``(id, score)`` list; masked candidates (-inf from the scan) left out."""
+    vb, ib = vals[:, :how_many], idx[:, :how_many]
+    return [
+        [(ids[int(i)], float(v)) for v, i in zip(vb[b], ib[b])
+         if np.isfinite(v)]
+        for b in range(len(vb))
+    ]
+
+
 def _score(qs, mat):
     """(B, n) scores with f32 accumulation. ``mat`` may be bfloat16 (the MXU's
     native input dtype — half the HBM traffic of f32); accumulation stays f32
@@ -845,23 +856,27 @@ class ALSServingModel(ServingModel):
         a per-query (B, num_buckets) LSH lookup table (selects the masked
         program). One registration/record/rescore sequence serves every
         variant."""
-        qs = jnp.asarray(qs_host)
-        if lut is not None:
-            fn = _quant_candidates_masked
-            args = (snap.qmat, snap.qscale, qs, lut, snap.buckets, excl, r)
-        else:
-            fn = _quant_candidates
-            args = (snap.qmat, snap.qscale, qs, valid, excl, r)
-        if register_cost is not None and (
-                register_cost not in snap.cost_keys_attempted
-                and metrics_mod.default_registry().enabled):
-            snap.cost_keys_attempted.add(register_cost)
-            compilecache.aot_compile(fn, *args, cost_key=register_cost)
-        vals, idx = fn(*args)
-        if register_cost is not None:
-            profiling.costs().record(register_cost)
-        return self._rescore_exact(snap, qs_host, np.asarray(vals),
-                                   np.asarray(idx))
+        with spans.stage("topn.upload"):
+            qs = jnp.asarray(qs_host)
+        with spans.stage("topn.dispatch"):
+            if lut is not None:
+                fn = _quant_candidates_masked
+                args = (snap.qmat, snap.qscale, qs, lut, snap.buckets, excl, r)
+            else:
+                fn = _quant_candidates
+                args = (snap.qmat, snap.qscale, qs, valid, excl, r)
+            if register_cost is not None and (
+                    register_cost not in snap.cost_keys_attempted
+                    and metrics_mod.default_registry().enabled):
+                snap.cost_keys_attempted.add(register_cost)
+                compilecache.aot_compile(fn, *args, cost_key=register_cost)
+            vals, idx = fn(*args)
+            if register_cost is not None:
+                profiling.costs().record(register_cost)
+        with spans.stage("topn.wait_download"):
+            vals, idx = np.asarray(vals), np.asarray(idx)
+        with spans.stage("topn.rescore"):
+            return self._rescore_exact(snap, qs_host, vals, idx)
 
     # -- query primitives ----------------------------------------------------
     @staticmethod
@@ -902,23 +917,28 @@ class ALSServingModel(ServingModel):
         k = min(n_local, _round_up_pow2(max(want, 16)))
         k_final = min(ndev * k, _round_up_pow2(max(want, 16)))
         use_lut = self.lsh is not None and snap.buckets is not None
-        lut_j = (
-            jnp.asarray(self._build_lut(qs_host))
-            if use_lut
-            else jnp.zeros((B, 1), dtype=bool)
-        )
-        use_excl = excluded is not None and any(e for e in excluded)
-        excl = jnp.asarray(
-            self._excluded_indices(snap, excluded, B)
-            if use_excl
-            else np.full((B, 1), -1, dtype=np.int32)  # fixed shard_map arity
-        )
-        fn = _sharded_top_k_fn(
-            snap.mesh, snap.shard_axis, k, k_final, snap.n, use_lut, use_excl
-        )
-        vals, idx = fn(snap.sharded_mat, jnp.asarray(qs_host), excl, lut_j,
-                       snap.sharded_buckets)
-        return np.asarray(vals), np.asarray(idx)
+        with spans.stage("topn.upload"):
+            lut_j = (
+                jnp.asarray(self._build_lut(qs_host))
+                if use_lut
+                else jnp.zeros((B, 1), dtype=bool)
+            )
+            use_excl = excluded is not None and any(e for e in excluded)
+            excl = jnp.asarray(
+                self._excluded_indices(snap, excluded, B)
+                if use_excl
+                else np.full((B, 1), -1, dtype=np.int32)  # fixed shard_map arity
+            )
+            qs = jnp.asarray(qs_host)
+        with spans.stage("topn.dispatch"):
+            fn = _sharded_top_k_fn(
+                snap.mesh, snap.shard_axis, k, k_final, snap.n, use_lut,
+                use_excl,
+            )
+            vals, idx = fn(snap.sharded_mat, qs, excl, lut_j,
+                           snap.sharded_buckets)
+        with spans.stage("topn.wait_download"):
+            return np.asarray(vals), np.asarray(idx)
 
     def top_n(
         self,
@@ -1068,27 +1088,37 @@ class ALSServingModel(ServingModel):
                 f"als.top_n_batch/b{len(qs_host)}+sharded"
             )
             vals, idx = self._sharded_query(snap, qs_host, how_many, excluded)
-            vals, idx = vals[:, :how_many], idx[:, :how_many]
-            ids = snap.ids
-            return [
-                [(ids[int(i)], float(v)) for v, i in zip(vals[b], idx[b])
-                 if np.isfinite(v)]
-                for b in range(len(query_vecs))
-            ]
-        qs = jnp.asarray(qs_host)
-        use_excl = excluded is not None and any(e for e in excluded)
-        excl = (
-            jnp.asarray(self._excluded_indices(snap, excluded, len(qs_host)))
-            if use_excl
-            else None
-        )
-        cost_reg = profiling.costs()
-        cost_key = _topn_cost_key(len(qs_host), use_excl)
-        if self.lsh is None or snap.buckets is None:
-            k = min(
-                snap.n,
-                _round_up_pow2(max(2 * how_many, 64) if filtering else max(how_many, 16)),
+            with spans.stage("topn.ids"):
+                return _id_lists(snap.ids, vals, idx, how_many)
+        # the stages of one call, end to end (docs/observability.md): what
+        # the coalescer's device-call span is made of on this side
+        masked = self.lsh is not None and snap.buckets is not None
+        with spans.stage("topn.upload"):
+            qs = jnp.asarray(qs_host)
+            use_excl = excluded is not None and any(e for e in excluded)
+            excl = (
+                jnp.asarray(
+                    self._excluded_indices(snap, excluded, len(qs_host)))
+                if use_excl
+                else None
             )
+            # per-query LSH candidate masks: (B, num_buckets) lookup table
+            # indexed by item bucket on device
+            lut = jnp.asarray(self._build_lut(qs_host)) if masked else None
+        with spans.stage("topn.dispatch"):
+            cost_key = _topn_cost_key(len(qs_host), use_excl)
+            if masked:
+                k = min(snap.n, _round_up_pow2(max(2 * how_many, 64)))
+                fn = _top_k_dot_batch_masked
+                args = (snap.score_mat, qs, lut, snap.buckets, excl, k)
+            else:
+                k = min(
+                    snap.n,
+                    _round_up_pow2(max(2 * how_many, 64) if filtering
+                                   else max(how_many, 16)),
+                )
+                fn = _top_k_dot_batch
+                args = (snap.score_mat, qs, None, excl, k)
             if (cost_key not in snap.cost_keys_attempted
                     and metrics_mod.default_registry().enabled):
                 # first use of this signature this generation: the dispatch
@@ -1098,48 +1128,30 @@ class ALSServingModel(ServingModel):
                 # direct callers) still attribute FLOPs instead of reading
                 # zero forever
                 snap.cost_keys_attempted.add(cost_key)
-                compilecache.aot_compile(
-                    _top_k_dot_batch, snap.score_mat, qs, None, excl, k,
-                    cost_key=cost_key,
-                )
-            vals, idx = _top_k_dot_batch(snap.score_mat, qs, None, excl, k)
-        else:
-            # per-query LSH candidate masks: (B, num_buckets) lookup table
-            # indexed by item bucket on device
-            k = min(snap.n, _round_up_pow2(max(2 * how_many, 64)))
-            lut = jnp.asarray(self._build_lut(qs_host))
-            if (cost_key not in snap.cost_keys_attempted
-                    and metrics_mod.default_registry().enabled):
-                snap.cost_keys_attempted.add(cost_key)
-                compilecache.aot_compile(
-                    _top_k_dot_batch_masked, snap.score_mat, qs, lut,
-                    snap.buckets, excl, k, cost_key=cost_key,
-                )
-            vals, idx = _top_k_dot_batch_masked(
-                snap.score_mat, qs, lut, snap.buckets, excl, k
-            )
-        cost_reg.record(cost_key)
-        vals, idx = np.asarray(vals), np.asarray(idx)
-        if not filtering:
-            ids = snap.ids
-            vb, ib = vals[:, :how_many], idx[:, :how_many]
-            return [
-                [(ids[int(i)], float(v)) for v, i in zip(vb[b], ib[b]) if np.isfinite(v)]
-                for b in range(len(query_vecs))
-            ]
-        out = []
-        for b in range(len(query_vecs)):
-            allowed = alloweds[b] if alloweds else None
-            got = self._collect(snap, vals[b], idx[b], how_many, allowed, None)[:how_many]
-            if len(got) < how_many and k < snap.n:
-                # heavy filtering consumed this query's candidates — fall back
-                # to the widening single-query path
-                got = self.top_n(
-                    qs_host[b], how_many, 0, allowed, None,
-                    excluded=excluded[b] if excluded else None,
-                )
-            out.append(got)
-        return out
+                compilecache.aot_compile(fn, *args, cost_key=cost_key)
+            vals, idx = fn(*args)
+            profiling.costs().record(cost_key)
+        with spans.stage("topn.wait_download"):
+            # the program's run and the copy back: the first conversion
+            # blocks until the device is done
+            vals, idx = np.asarray(vals), np.asarray(idx)
+        with spans.stage("topn.ids"):
+            if not filtering:
+                return _id_lists(snap.ids, vals, idx, how_many)
+            out = []
+            for b in range(len(query_vecs)):
+                allowed = alloweds[b] if alloweds else None
+                got = self._collect(
+                    snap, vals[b], idx[b], how_many, allowed, None)[:how_many]
+                if len(got) < how_many and k < snap.n:
+                    # heavy filtering consumed this query's candidates —
+                    # fall back to the widening single-query path
+                    got = self.top_n(
+                        qs_host[b], how_many, 0, allowed, None,
+                        excluded=excluded[b] if excluded else None,
+                    )
+                out.append(got)
+            return out
 
     def _quant_top_n_batch(
         self, snap: _QuantSnapshot, qs_host: np.ndarray, how_many: int,
@@ -1168,27 +1180,23 @@ class ALSServingModel(ServingModel):
         vals, idx = self._quant_scan(
             snap, qs_host, r, excl, lut=lut, register_cost=cost_key
         )
-        if not filtering:
-            ids = snap.ids
-            vb, ib = vals[:, :how_many], idx[:, :how_many]
-            return [
-                [(ids[int(i_)], float(v_)) for v_, i_ in zip(vb[b], ib[b])
-                 if np.isfinite(v_)]
-                for b in range(len(qs_host))
-            ]
-        out = []
-        for b in range(len(qs_host)):
-            allowed = alloweds[b] if alloweds else None
-            got = self._collect(snap, vals[b], idx[b], how_many, allowed, None)[:how_many]
-            if len(got) < how_many and r < snap.n:
-                # heavy filtering consumed this query's candidates — fall
-                # back to the widening single-query quant path
-                got = self._quant_top_n(
-                    snap, qs_host[b], how_many, 0, allowed, None,
-                    excluded[b] if excluded else None,
-                )
-            out.append(got)
-        return out
+        with spans.stage("topn.ids"):
+            if not filtering:
+                return _id_lists(snap.ids, vals, idx, how_many)
+            out = []
+            for b in range(len(qs_host)):
+                allowed = alloweds[b] if alloweds else None
+                got = self._collect(
+                    snap, vals[b], idx[b], how_many, allowed, None)[:how_many]
+                if len(got) < how_many and r < snap.n:
+                    # heavy filtering consumed this query's candidates —
+                    # fall back to the widening single-query quant path
+                    got = self._quant_top_n(
+                        snap, qs_host[b], how_many, 0, allowed, None,
+                        excluded[b] if excluded else None,
+                    )
+                out.append(got)
+            return out
 
     def warm_bucket(self, batch_size: int, how_many: int = 10) -> None:
         """Pre-compile the batched top-N program for ONE pow2 batch size

@@ -154,9 +154,7 @@ class TopNCoalescer:
         self._flusher: asyncio.TimerHandle | None = None
         self._deadline_timer: asyncio.TimerHandle | None = None
         self._inflight = 0
-        self.deadline_flushes = 0  # observability + tests
-        self.shed_requests = 0
-        self.degraded_requests = 0
+        self.deadline_flushes = 0  # tests/test_batcher.py reads it
 
     def admit(self) -> bool:
         """Breaker admission for the coalesced path: False while the
@@ -165,7 +163,6 @@ class TopNCoalescer:
         breaker's probe quota so a recovered device closes it again."""
         if self.breaker is None or self.breaker.allow():
             return True
-        self.degraded_requests += 1
         _DEGRADED.inc()
         return False
 
@@ -177,7 +174,6 @@ class TopNCoalescer:
             # shed NOW, before queueing: a 503 in microseconds beats a 200
             # after a timeout-sized queue wait, and the client's retry lands
             # on a drained queue (or another replica)
-            self.shed_requests += 1
             _SHED.inc()
             # one throttled flight-recorder event per shed burst (the
             # ``suppressed`` count carries the storm's size) — an overload
@@ -305,6 +301,14 @@ class TopNCoalescer:
                     ),
                 },
             )
+            # the flush's stages (children of the call span) sit in the FIRST
+            # waiter's trace only; ``call`` — the call span's id, on it and
+            # on every stage — is what joins them to the other waiters
+            call_span.set_attribute("call", call_span.span_id)
+            handoff = spans.start_span(
+                "coalescer.handoff", parent=call_span,
+                attributes={"call": call_span.span_id},
+            )
             for p in group:
                 p.wait_span.set_attribute(
                     "queue_wait_ms", round((now - p.enq_t) * 1000.0, 3)
@@ -312,7 +316,7 @@ class TopNCoalescer:
                 spans.finish_span(p.wait_span)
             try:
                 loop.run_in_executor(None, self._execute, loop, model, group,
-                                     call_span)
+                                     call_span, handoff)
             except Exception as e:  # noqa: BLE001 — executor/loop torn down
                 # dispatch itself failed (executor shut down mid-close): the
                 # slot was taken but _execute will never run, so _done will
@@ -321,6 +325,7 @@ class TopNCoalescer:
                 # pending request behind the leaked slot) to hang until
                 # client timeout
                 self._inflight -= 1
+                spans.finish_span(handoff)
                 call_span.record_exception(e)
                 spans.finish_span(call_span)
                 log.exception(
@@ -343,7 +348,8 @@ class TopNCoalescer:
             # timer here would idle the device for window_ms per cycle
             self._flush(loop)
 
-    def _execute(self, loop, model, group: list[_Pending], call_span) -> None:
+    def _execute(self, loop, model, group: list[_Pending], call_span,
+                 handoff) -> None:
         """Executor thread: ONE batched device call for the whole group.
 
         The device call is a FAN-IN: ``call_span`` (opened at dispatch on
@@ -352,64 +358,71 @@ class TopNCoalescer:
         find the shared call — and its batch-size/pad-waste attributes —
         that answered it.
 
+        Its stages are its children, end to end with no hole between them:
+        ``coalescer.handoff`` (``handoff``, opened on the loop with the call
+        span, closed by this function's first line), ``coalescer.assemble``
+        (here, up to the call of ``top_n_batch``), the model's ``topn.*``,
+        and ``coalescer.wakeup`` — from the call span's close until the
+        LAST waiter's future has been resolved on the loop.
+
         Resilience (docs/robustness.md): requests whose per-request
         Deadline expired while queued are answered 504 here WITHOUT
         spending device time on them; a failed batch reports to the
         device-call circuit breaker and each of its requests retries as an
         uncoalesced per-request scan (degraded mode) before any client
         sees an error."""
-        live: list[_Pending] = []
-        for p in group:
-            if p.deadline is not None and p.deadline.expired():
-                _DEADLINE_DROPS.inc()
-                loop.call_soon_threadsafe(
-                    _set_exception, p.future,
-                    resilience.DeadlineExceeded(
-                        "deadline expired in the coalescer queue"
-                    ),
-                )
-            else:
-                live.append(p)
-        if len(live) < len(group):
-            call_span.set_attribute("deadline.dropped", len(group) - len(live))
-        group = live
-        if not group:
-            spans.finish_span(call_span)
-            loop.call_soon_threadsafe(self._done, loop)
-            return
+        spans.finish_span(handoff)
         span_finished = False
         try:
             with spans.activate(call_span):
-                faults.maybe_fail("serving.device_call")
-                qs = np.stack([p.vec for p in group])
-                want = max(p.want for p in group)
-                alloweds = (
-                    [p.allowed for p in group]
-                    if any(p.allowed is not None for p in group)
-                    else None
-                )
-                excluded = (
-                    [p.excluded for p in group]
-                    if any(p.excluded for p in group)
-                    else None
-                )
-                # pad the batch to a power of two: coalesced batch sizes vary
-                # per flush, and every distinct size would otherwise be a fresh
-                # XLA trace/compile of the batched top-N program — seconds
-                # of compile on the hot path
-                n_real = len(group)
-                n_pad = 1 << max(0, n_real - 1).bit_length()
-                call_span.set_attribute("batch.padded", n_pad)
-                call_span.set_attribute("pad.waste_rows", n_pad - n_real)
-                if n_pad > n_real:
-                    _PAD_WASTE.inc(n_pad - n_real)
-                    qs = np.concatenate(
-                        [qs, np.repeat(qs[:1], n_pad - n_real, axis=0)]
+                with spans.stage("coalescer.assemble"):
+                    live: list[_Pending] = []
+                    for p in group:
+                        if p.deadline is not None and p.deadline.expired():
+                            _DEADLINE_DROPS.inc()
+                            loop.call_soon_threadsafe(
+                                _set_exception, p.future,
+                                resilience.DeadlineExceeded(
+                                    "deadline expired in the coalescer queue"
+                                ),
+                            )
+                        else:
+                            live.append(p)
+                    group = live
+                    if not group:
+                        # ``finally`` closes the call span, frees the slot
+                        return
+                    qs = np.stack([p.vec for p in group])
+                    want = max(p.want for p in group)
+                    alloweds = (
+                        [p.allowed for p in group]
+                        if any(p.allowed is not None for p in group)
+                        else None
                     )
-                    if alloweds is not None:
-                        alloweds = alloweds + [None] * (n_pad - n_real)
-                    if excluded is not None:
-                        excluded = list(excluded) + [None] * (n_pad - n_real)
+                    excluded = (
+                        [p.excluded for p in group]
+                        if any(p.excluded for p in group)
+                        else None
+                    )
+                    # pad the batch to a power of two: coalesced batch sizes
+                    # vary per flush, and every distinct size would otherwise
+                    # be a fresh XLA trace/compile of the batched top-N
+                    # program — seconds of compile on the hot path
+                    n_real = len(group)
+                    n_pad = 1 << max(0, n_real - 1).bit_length()
+                    call_span.set_attribute("batch.padded", n_pad)
+                    call_span.set_attribute("pad.waste_rows", n_pad - n_real)
+                    if n_pad > n_real:
+                        _PAD_WASTE.inc(n_pad - n_real)
+                        qs = np.concatenate(
+                            [qs, np.repeat(qs[:1], n_pad - n_real, axis=0)]
+                        )
+                        if alloweds is not None:
+                            alloweds = alloweds + [None] * (n_pad - n_real)
+                        if excluded is not None:
+                            excluded = (list(excluded)
+                                        + [None] * (n_pad - n_real))
+                faults.maybe_fail("serving.device_call")
                 results = model.top_n_batch(qs, want, alloweds, excluded)
             if self.breaker is not None:
                 self.breaker.record_success()
@@ -421,9 +434,18 @@ class TopNCoalescer:
             # observe it)
             span_finished = True
             spans.finish_span(call_span)
-            for p, res in zip(group, results):
+            wakeup = spans.start_span(
+                "coalescer.wakeup", parent=call_span,
+                attributes={"call": call_span.span_id},
+            )
+            last = len(group) - 1
+            for n, (p, res) in enumerate(zip(group, results)):
                 out = res[p.offset:p.offset + p.how_many]
-                loop.call_soon_threadsafe(_set_result, p.future, out)
+                if n == last:
+                    loop.call_soon_threadsafe(_set_last_result, p.future, out,
+                                              wakeup)
+                else:
+                    loop.call_soon_threadsafe(_set_result, p.future, out)
         except Exception as e:  # noqa: BLE001 — fail the batch, not the loop
             if self.breaker is not None:
                 self.breaker.record_failure()
@@ -476,6 +498,13 @@ class TopNCoalescer:
 def _set_result(future: asyncio.Future, value) -> None:
     if not future.done():
         future.set_result(value)
+
+
+def _set_last_result(future: asyncio.Future, value, wakeup_span) -> None:
+    """The flush's last waiter: its result, then the end of the flush's
+    ``coalescer.wakeup`` — every waiter's future is resolved by now."""
+    _set_result(future, value)
+    spans.finish_span(wakeup_span)
 
 
 def _set_exception(future: asyncio.Future, exc: BaseException) -> None:

@@ -114,18 +114,22 @@ def _to_csv_row(item: Any) -> str:
 
 
 def render(request: web.Request, payload: Any, status: int = 200) -> web.Response:
-    accept = request.headers.get("Accept", "")
-    if "text/csv" in accept:
-        if payload is None:
-            body = ""
-        elif isinstance(payload, (list, tuple)):
-            body = "\n".join(_to_csv_row(i) for i in payload)
-            if body:
-                body += "\n"
-        else:
-            body = _to_csv_row(payload) + "\n"
-        return web.Response(text=body, status=status, content_type="text/csv")
-    return web.json_response(payload, status=status)
+    """The response body, under a ``serving.render`` span (child of the
+    request's ingress span): every endpoint's answer passes through here."""
+    with spans.span("serving.render", annotate=True):
+        accept = request.headers.get("Accept", "")
+        if "text/csv" in accept:
+            if payload is None:
+                body = ""
+            elif isinstance(payload, (list, tuple)):
+                body = "\n".join(_to_csv_row(i) for i in payload)
+                if body:
+                    body += "\n"
+            else:
+                body = _to_csv_row(payload) + "\n"
+            return web.Response(text=body, status=status,
+                                content_type="text/csv")
+        return web.json_response(payload, status=status)
 
 
 def id_value(id_: str, value: float) -> dict:
