@@ -242,26 +242,34 @@ def check_kernels(features: tuple, on_tpu: bool) -> dict:
         owners = np.sort(rng.choice(block, 96) // 3 * 3)
         srow = np.concatenate([owners, np.full(8, block)]).astype(np.int32)
         s = len(srow)
-        slens = np.where(srow < block, rng.integers(1, t + 1, s), 0)
-        mask = (np.arange(t)[None, :] < slens[:, None]).astype(np.float32)
-        scols = np.sort(rng.integers(0, n_opp, (s, t)), axis=1).astype(np.int32)
+        slens = np.where(srow < block, rng.integers(1, t + 1, s),
+                         0).astype(np.int32)
+        valid = np.arange(t)[None, :] < slens[:, None]
+        mask = valid.astype(np.float32)
+        # entries past a slot's length name a row of NaN: the kernel issues
+        # no copy for them, and one that did would poison the Gramian
+        scols = np.where(
+            valid, np.sort(rng.integers(0, n_opp, (s, t)), axis=1), n_opp,
+        ).astype(np.int32)
         w = rng.random((s, t), dtype=np.float32) * 2.0 * mask
         coef = rng.standard_normal((s, t)).astype(np.float32) * mask
-        y = rng.standard_normal((n_opp, k)).astype(np.float32)
+        y = rng.standard_normal((n_opp + 1, k)).astype(np.float32)
+        y[n_opp] = np.nan
         gg = jax.jit(lambda *a: pk.gather_gramian_accumulate(
             *a, block=block, interpret=interpret))
         for dtype, tol in ((jnp.float32, GG_TOL_F32), (jnp.bfloat16, GG_TOL_BF16)):
             yj = jnp.asarray(y).astype(dtype)
             # reference on the operands the MXU is handed, in float64
-            y_ref = np.asarray(yj.astype(jnp.float32), dtype=np.float64)
+            y_ref = np.nan_to_num(
+                np.asarray(yj.astype(jnp.float32), dtype=np.float64))
             yg = y_ref[scols]
             ra = np.zeros((block + 1, k, k))
             rb = np.zeros((block + 1, k))
             np.add.at(ra, srow, np.einsum("st,sti,stj->sij", w, yg, yg))
             np.add.at(rb, srow, np.einsum("st,sti->si", coef, yg))
             big_a, big_b = (np.asarray(o) for o in gg(
-                yj, jnp.asarray(srow), jnp.asarray(scols), jnp.asarray(w),
-                jnp.asarray(coef)))
+                yj, jnp.asarray(srow), jnp.asarray(slens), jnp.asarray(scols),
+                jnp.asarray(w), jnp.asarray(coef)))
             if not (np.isfinite(big_a).all() and np.isfinite(big_b).all()):
                 raise SmokeFailure(f"gather_gramian k={k}: non-finite output")
             scale = max(np.abs(ra).max(), np.abs(rb).max())

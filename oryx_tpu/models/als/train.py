@@ -39,6 +39,7 @@ reused across iterations.
 from __future__ import annotations
 
 import functools
+import logging
 import math
 import os
 from dataclasses import dataclass
@@ -99,10 +100,29 @@ class _BlockedSide:
     # incremental delta instead of the whole batch. Never mutated in place:
     # the delta path copies before writing (jnp.asarray may alias on CPU).
     np_slabs: "tuple | None" = None
+    # what the pack counted: the interactions it placed, and the slots that
+    # hold them (every other slot of the (n_blocks, S) grid is block padding)
+    entries: int = 0
+    real_slots: int = 0
 
     @property
     def padded_rows(self) -> int:
         return self.n_blocks * self.block
+
+    def gather_rows(self, fused: bool) -> int:
+        """Factor rows one half-iteration's gather moves. The fused kernel
+        copies each slot to its own length — one row an entry; the einsum
+        formulation gathers every cell of every slot, pad slots included."""
+        return self.entries if fused else int(self.scols.size)
+
+    def gather_rows_per_entry(self, fused: bool) -> "tuple[float, float]":
+        """(copying every real slot to its width T, as the kernel did before
+        it was handed the slots' lengths; as issued now) over the entries:
+        the share of the gather that copying to length removes is
+        1 − now ÷ before."""
+        n = max(1, self.entries)
+        return (self.real_slots * self.slot_width / n,
+                self.gather_rows(fused) / n)
 
 
 def _pack_workers(workers: "int | None", nnz: int) -> int:
@@ -234,8 +254,6 @@ def make_blocked_side(
     if len(r) and n_blocks > 1:
         pad_ratio = s_len * t * n_blocks / max(1, len(r))
         if pad_ratio > 6.0:
-            import logging
-
             logging.getLogger(__name__).warning(
                 "slotted COO padding ratio %.1fx (T=%d, S=%d x %d blocks vs "
                 "%d nnz): row-skewed data; consider a smaller block size",
@@ -285,6 +303,7 @@ def make_blocked_side(
         jnp.asarray(srows), jnp.asarray(scols), jnp.asarray(svals),
         jnp.asarray(slens), n_rows, block, n_blocks, t, slot_chunk,
         np_slabs=(srows, scols, svals, slens) if keep_np else None,
+        entries=len(r), real_slots=total_slots,
     )
 
 
@@ -402,6 +421,7 @@ def _delta_blocked_side(
         jnp.asarray(srows), jnp.asarray(scols), jnp.asarray(svals),
         jnp.asarray(slens), n_rows, block, n_blocks, t, chunk,
         np_slabs=(srows, scols, svals, slens),
+        entries=len(rows), real_slots=total_slots,
     )
 
 
@@ -575,7 +595,8 @@ def _solve_block(y, srow, scols, svals, slens, *, block, features, lam, alpha,
     if fused_gramian:
         w, coef = _entry_weights(svals, slens, alpha, implicit, t)
         big_a, big_b = pk.gather_gramian_accumulate(
-            y, srow, scols, w, coef, block=block, interpret=kernel_interpret,
+            y, srow, slens, scols, w, coef, block=block,
+            interpret=kernel_interpret,
         )
         # interaction counts are k²-free — a plain (S,) segment-sum costs
         # nothing next to the Gramians and keeps the kernel surface small
@@ -681,8 +702,6 @@ def _resolve_fused(fused_gramian: "bool | None", on_tpu: bool,
     says so, because on a TPU the difference is large."""
     want = on_tpu if fused_gramian is None else bool(fused_gramian)
     if want and not pk.gather_gramian_supported(features, slots):
-        import logging
-
         logging.getLogger(__name__).warning(
             "fused gather-Gramian kernel not used: features=%d, %d slots "
             "per block is past its VMEM/SMEM gates; using the einsum "
@@ -853,6 +872,11 @@ def prepare_blocked(
         sides = pack_user(), pack_item()
     if cache is not None:
         cache.store_batch(batch.rows, batch.cols, batch.vals)
+    on_tpu = pk.on_tpu(sides[0].scols)
+    for name, side in zip(("user", "item"), sides):
+        fused = on_tpu and pk.gather_gramian_supported(
+            features, side.srows.shape[1])
+        _log_gather_rows(name, side, fused)
     return sides
 
 
@@ -872,25 +896,43 @@ def init_item_factors(item_side: _BlockedSide, n_items: int, features: int,
     return _init_factors(item_side.padded_rows, n_items, features, key)
 
 
-def _register_half_cost(key: str, side: _BlockedSide, nnz: int,
-                        features: int, dtype: str) -> None:
+def _log_gather_rows(name: str, side: _BlockedSide, fused: bool) -> None:
+    before, now = side.gather_rows_per_entry(fused)
+    logging.getLogger(__name__).info(
+        "slotted COO %s side: %d entries in %d slots of T=%d (+%d of block "
+        "padding); the gather moves %.3f factor rows an entry (%s), %.3f if "
+        "every slot were copied to its width",
+        name, side.entries, side.real_slots, side.slot_width,
+        int(side.srows.size) - side.real_slots, now,
+        "fused kernel: each slot to its own length" if fused
+        else "einsum: every cell of every slot", before,
+    )
+
+
+def _register_half_cost(key: str, side: _BlockedSide, features: int,
+                        dtype: str, fused: bool) -> None:
     """Analytic per-half-iteration device cost for the trainer's cost
     accounting (common/profiling.py): the same useful-FLOP model the batch
     bench's MFU derives from (2·nnz·k² Gramian + 2·nnz·k RHS +
     rows·(k³/3 + 2k²) solve), with bytes as the dominant HBM terms — the
-    slot-cell gather at the compute dtype plus the per-row Gramian and
-    factor writes. The blocked solver is a scan of sub-programs rather than
-    one compiled executable, so the trainer registers analytically where
-    serving registers from ``cost_analysis()``; either way the label is one
-    program signature multiplied by recorded calls."""
+    factor rows the gather ISSUES (``side.gather_rows``: one an entry under
+    the fused kernel, whose copies are 32-bit whatever the compute dtype;
+    every slot cell at the compute dtype under the einsum formulation) plus
+    the per-row Gramian and factor writes. The blocked solver is a scan of
+    sub-programs rather than one compiled executable, so the trainer
+    registers analytically where serving registers from
+    ``cost_analysis()``; either way the label is one program signature
+    multiplied by recorded calls."""
     k = features
+    nnz = side.entries
     rows = side.padded_rows
     flops = (2.0 * nnz * k * k + 2.0 * nnz * k
              + rows * (k ** 3 / 3.0 + 2.0 * k * k))
-    gather_itemsize = 2.0 if dtype == "bfloat16" else 4.0
-    bytes_ = (float(side.scols.size) * k * gather_itemsize
+    gather_itemsize = 2.0 if dtype == "bfloat16" and not fused else 4.0
+    bytes_ = (float(side.gather_rows(fused)) * k * gather_itemsize
               + rows * k * (k + 1) * 4.0)
     profiling.costs().register(key, flops, bytes_)
+    _log_gather_rows(key, side, fused)
 
 
 def _recorded_half(key: str, fn):
@@ -1014,7 +1056,9 @@ def als_train(
         side = item_fut.result()
         wait_s = time.perf_counter() - t1
         pool.shutdown(wait=False)
-        _register_half_cost("als.train.item_half", side, batch.nnz, k, dtype)
+        fused["item"] = resolve_fused(side)
+        _register_half_cost("als.train.item_half", side, k, dtype,
+                            fused["item"])
         if layout_cache is not None:
             layout_cache.store_batch(batch.rows, batch.cols, batch.vals)
         if timings is not None:
@@ -1037,8 +1081,6 @@ def als_train(
         user_side = pack_user()
         pack_user_s = time.perf_counter() - t0
         chunk_u = user_side.slot_chunk
-        _register_half_cost("als.train.user_half", user_side, batch.nnz, k,
-                            dtype)
 
         if key is None:
             key = rand.get_key()
@@ -1060,8 +1102,6 @@ def als_train(
                     start_iter = min(int(ck.step), iterations)
                     checkpointer.mark_resumed(start_iter)
                 else:
-                    import logging
-
                     logging.getLogger(__name__).warning(
                         "checkpoint %s does not match the current factor "
                         "shapes; training from scratch", ck.path,
@@ -1103,7 +1143,22 @@ def als_train(
             y = _init_factors(_padded_rows_for(n_items, block_i, ndev),
                               n_items, k, key)
 
-        if mesh is not None and row_axis is not None:
+        # the formulation each side runs is resolved ONCE, here, from the
+        # devices that will hold the factors: the cost accounting counts the
+        # rows that formulation's gather issues, and the solvers below are
+        # handed the same answer
+        sharded_mode = mesh is not None and row_axis is not None
+        on_tpu = pk.on_tpu(mesh=mesh) if sharded_mode else pk.on_tpu(y)
+
+        def resolve_fused(side: _BlockedSide) -> bool:
+            return _resolve_fused(fused_gramian, on_tpu, k,
+                                  side.srows.shape[1])
+
+        fused = {"user": resolve_fused(user_side)}
+        _register_half_cost("als.train.user_half", user_side, k, dtype,
+                            fused["user"])
+
+        if sharded_mode:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             row_shard = NamedSharding(mesh, P(row_axis, None))
@@ -1133,23 +1188,19 @@ def als_train(
                 _finish_ckpt()
                 return x, y
             u_arrays = put_side(user_side)
-            on_tpu = pk.on_tpu(mesh=mesh)
 
-            def sharded(side, blk):
+            def sharded(name, side, blk):
                 return _sharded_solver(
                     mesh, row_axis, blk, k, implicit, side.slot_chunk, dtype,
-                    on_tpu,
-                    _resolve_fused(fused_gramian, on_tpu, k,
-                                   side.srows.shape[1]),
-                    not on_tpu)
+                    on_tpu, fused[name], not on_tpu)
 
             solve_u = _recorded_half("als.train.user_half",
-                                     sharded(user_side, block_u))
+                                     sharded("user", user_side, block_u))
             x = solve_u(y, *u_arrays, lam, alpha)  # device busy; host packs
             item_side, _ = finish_item_pack()
             i_arrays = put_side(item_side)
             solve_i = _recorded_half("als.train.item_half",
-                                     sharded(item_side, block_i))
+                                     sharded("item", item_side, block_i))
             y = solve_i(x, *i_arrays, lam, alpha)
             completed = start_iter + 1
             _maybe_ckpt(completed, x, y)
@@ -1162,14 +1213,12 @@ def als_train(
             return x, y
 
         def solve(side, opp, blk, ck):
-            profiling.costs().record(
-                "als.train.user_half" if side is user_side
-                else "als.train.item_half"
-            )
+            name = "user" if side is user_side else "item"
+            profiling.costs().record(f"als.train.{name}_half")
             return solve_side_blocked(
                 opp, side.srows, side.scols, side.svals, side.slens, lam,
                 alpha, block=blk, features=k, implicit=implicit,
-                slot_chunk=ck, dtype=dtype, fused_gramian=fused_gramian,
+                slot_chunk=ck, dtype=dtype, fused_gramian=fused[name],
             )
 
         if start_iter >= iterations:

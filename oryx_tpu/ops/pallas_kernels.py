@@ -26,23 +26,33 @@ three HBM round-trips per scan chunk while the MXU idles (measured MFU
 0.15%: the loop is gather-bandwidth-bound, and bf16 inputs buy only 17%).
 Here each factor row crosses HBM exactly once:
 
-  grid = slots; per step:  DMA-gather the slot's T factor rows → VMEM
-                           (rows are column-sorted within the slot, so the
-                           gather walks HBM in address order; ring of
-                           ``_GG_BUFS`` in-flight copies)
+  grid = slots; per step:  DMA-gather the slot's factor rows → VMEM: one
+                           copy an ENTRY, to the slot's own length (a
+                           scalar-prefetched ``slens``), none for the
+                           padding of its last cells; all of them started
+                           before the first wait, on one semaphore (rows
+                           are column-sorted within the slot, so the gather
+                           walks HBM in address order)
                            Gramian (k,T)·(T,k) + RHS (1,T)·(T,k)   (MXU)
                            accumulate into the slot's OWNER ROW's
                            (1, k, k)/(1, 1, k) output block in VMEM
+
+Scratch rows past a slot's length keep an earlier slot's rows (zeros before
+the first) and meet weight 0 in the matmuls. What paces the gather, measured
+on a v5e at the Netflix-shaped cell (PERF.md, PR 25): with 4 copies in
+flight one HBM round trip, 84 ns a copy; with a whole slot in flight the
+copy engine and the scalar core's issue loop, 9–17 ns a copy — and no
+copy at all for the 5–44% of a side's slot cells that are padding.
 
 Mosaic slices a DMA only along untiled (leading) dims, and a 16-bit row
 shares its 32-bit sublane word with its neighbour — so the wrapper hands the
 kernel the factors as float32 ``(R, 1, pad128(k))``: one row = one (1, 128)-
 tiled slab, addressable by a leading-dim index. A bfloat16 compute dtype
 still feeds the MXU bf16 (the kernel casts after the gather); what it no
-longer buys is a halved gather, which at one ~200 B row per descriptor was
-descriptor-bound anyway. Every blocked operand is 3-D so that the block's
-last two dims EQUAL the array's — the TPU lowering refuses a ``(1, t)``
-block over an ``(S, T)`` array.
+longer buys is a halved gather: a copy's cost is its descriptor's, not its
+200 B–1 KB. Every blocked operand is 3-D so that the block's last two dims
+EQUAL the array's — the TPU lowering refuses a ``(1, t)`` block over an
+``(S, T)`` array.
 
 Slots arrive row-sorted (the pack guarantees it), so the per-row output
 block — selected by a scalar-prefetched ``srow`` index map — is revisited
@@ -222,10 +232,13 @@ def spd_solve_batched(a, b, *, interpret: bool):
     return x[:n]
 
 
-# in-flight DMA ring depth for the per-slot factor-row gather: deep enough
-# to hide one row's HBM latency behind the previous rows' copies, shallow
-# enough that the semaphore array stays trivially within hardware limits
-_GG_BUFS = 4
+# Starts (or waits) per trip of the kernel's two copy loops. A slot's length
+# is only known at run time, so the loops are unrolled by hand: at 1 the
+# scalar core's loop overhead paces the copies (3.25 / 3.32 s a user / item
+# half of the Netflix cell), at 4 2.49 / 2.56, at 8 2.36 / 2.31, at 16
+# 2.31 / 2.29 (PERF.md, PR 25: the sweep on the chip). 8 keeps a slot of
+# the narrowest pack (T = 8) one trip.
+_GG_UNROLL = 8
 # The pack's slot width T is a power of two in [8, 512] (train.py
 # _auto_slot_width) — the kernel's resident budget is evaluated at the cap.
 _GG_SLOT_WIDTH_MAX = 512
@@ -240,11 +253,16 @@ _GG_SLOT_WIDTH_MAX = 512
 # tests/test_kernel_differential.py so the constant can never silently
 # drift from the kernel it guards.
 _GG_MAX_FEATURES = 256
-# The per-slot owner rows ride whole in SMEM (scalar prefetch), which the
-# compiler caps at 1 MiB per program: 4 B × 196,608 slots leaves a quarter
-# of it for the double-buffered index blocks. The pack stays far below this
-# unless a block's rows average more than ~11,000 interactions each.
-_GG_MAX_SLOTS = 3 << 16
+# The per-slot owner rows AND valid lengths ride whole in SMEM (two
+# scalar-prefetched vectors), which the compiler caps at 1 MiB per program:
+# 2 × 4 B × 98,304 slots is three quarters of it, the rest is left to the
+# double-buffered index blocks and the compiler's own scalars. The static
+# kernel model derives the same number from the parsed call
+# (kernelmodel.SMEM_PREFETCH_BUDGET_BYTES; tests/test_kernel_differential.py
+# pins the two together). The pack stays far below this unless a block's
+# rows average more than ~6,000 interactions each (the Netflix item side:
+# 5,654 a row in blocks of 5,924 rows, 72,594 slots).
+_GG_MAX_SLOTS = 3 << 15
 
 
 def gather_gramian_supported(features: int, slots: int) -> bool:
@@ -253,12 +271,40 @@ def gather_gramian_supported(features: int, slots: int) -> bool:
     return features <= _GG_MAX_FEATURES and slots <= _GG_MAX_SLOTS
 
 
-def _make_gather_gramian_kernel(t: int, k: int, kp: int, block: int, cd):
-    def kernel(srow_ref, scols_ref, wc_ref, y_ref, a0_ref, b0_ref,
-               a_ref, b_ref, yg, sems):
+def _unrolled(n, body):
+    """``body(tt)`` for tt in [0, n), ``n`` a run-time scalar: trips of
+    ``_GG_UNROLL`` calls with no test between them, then the remainder one
+    by one."""
+    trips = n // _GG_UNROLL
+
+    def trip(c, carry):
+        for u in range(_GG_UNROLL):
+            body(c * _GG_UNROLL + u)
+        return carry
+
+    jax.lax.fori_loop(0, trips, trip, 0)
+
+    def one(tt, carry):
+        body(tt)
+        return carry
+
+    jax.lax.fori_loop(trips * _GG_UNROLL, n, one, 0)
+
+
+def _make_gather_gramian_kernel(t: int, k: int, kp: int, cd):
+    def kernel(srow_ref, slen_ref, scols_ref, wc_ref, y_ref, a0_ref, b0_ref,
+               a_ref, b_ref, yg, sem):
         i = pl.program_id(0)
         row = srow_ref[i]
         prev_row = srow_ref[jnp.maximum(i - 1, 0)]
+        n = slen_ref[i]  # the slot's valid entries: as many copies, no more
+
+        # rows of the scratch past a slot's length keep whatever an earlier
+        # slot gathered there and meet weight 0 in the matmuls: they must
+        # hold finite numbers from the start (0 × NaN is NaN)
+        @pl.when(i == 0)
+        def _():
+            yg[...] = jnp.zeros_like(yg)
 
         # first slot of a new output row: the (1, k, k)/(1, 1, k) blocks
         # just rotated in (their VMEM content is undefined) — zero before
@@ -270,40 +316,30 @@ def _make_gather_gramian_kernel(t: int, k: int, kp: int, block: int, cd):
             a_ref[...] = jnp.zeros_like(a_ref)
             b_ref[...] = jnp.zeros_like(b_ref)
 
-        # pad slots skip the gather AND the matmuls: their owner is the
-        # spill row, initialized above and sliced off by the caller —
-        # issuing T DMAs of row 0 for them would only burn bandwidth
-        @pl.when(row < block)
+        # an empty slot (the pack's pad slots, whose owner is the spill row)
+        # skips the gather AND the matmuls
+        @pl.when(n > 0)
         def _():
-            def dma(tt):
+            def start(tt):
                 # one factor row per copy, selected on y's LEADING dim (the
                 # only dim Mosaic lets a DMA slice below tile size); within
                 # a slot the column indices are ascending (pack sorts by
                 # (row, col)), so consecutive copies walk y in HBM address
                 # order
-                return pltpu.make_async_copy(
-                    y_ref.at[scols_ref[0, 0, tt]], yg.at[tt],
-                    sems.at[tt % _GG_BUFS],
-                )
+                pltpu.make_async_copy(
+                    y_ref.at[scols_ref[0, 0, tt]], yg.at[tt], sem.at[0],
+                ).start()
 
-            for tt in range(min(_GG_BUFS, t)):
-                dma(tt).start()
+            def wait(tt):
+                # a wait names no copy of its own: the one semaphore counts
+                # bytes, and every copy's are one row's
+                pltpu.make_async_copy(y_ref.at[0], yg.at[0], sem.at[0]).wait()
 
-            def body(tt, carry):
-                # wait BEFORE reusing the slot's semaphore: copy tt+BUFS
-                # signals sems[tt % BUFS] too, and a counting semaphore
-                # can't tell whose bytes released the wait — issuing it
-                # first would let a faster tt+BUFS copy satisfy this wait
-                # while row tt is still in flight
-                dma(tt).wait()
-
-                @pl.when(tt + _GG_BUFS < t)
-                def _():
-                    dma(tt + _GG_BUFS).start()
-
-                return carry
-
-            jax.lax.fori_loop(0, t, body, 0)
+            # ALL of the slot's copies are in flight before the first wait:
+            # nothing reads the scratch until the last one, so the pace is
+            # the copy engine's, not one HBM round trip per few rows
+            _unrolled(n, start)
+            _unrolled(n, wait)
 
             ygv = yg[...].reshape(t, kp)[:, :k]  # (T, k) f32
             wc = wc_ref[0]  # (2, T): Gramian weights, RHS coefficients
@@ -331,7 +367,7 @@ def _make_gather_gramian_kernel(t: int, k: int, kp: int, block: int, cd):
     return kernel
 
 
-def gather_gramian_accumulate(y, srow, scols, w, coef, *, block: int,
+def gather_gramian_accumulate(y, srow, slens, scols, w, coef, *, block: int,
                               interpret: bool):
     """Fused gather → per-slot Gramian → per-row accumulate for one block.
 
@@ -340,10 +376,13 @@ def gather_gramian_accumulate(y, srow, scols, w, coef, *, block: int,
         input precision; the gather itself always moves 32-bit rows).
       srow: (S,) int32 block-local owner row per slot, SORTED ascending,
         pad = ``block`` (the spill row).
+      slens: (S,) int32 valid entries per slot, 0 on pad slots: the kernel
+        copies a slot's first ``slens`` rows and no others.
       scols: (S, T) int32 gather indices into ``y`` (column-ascending
         within each slot).
-      w / coef: (S, T) f32 per-entry Gramian / RHS weights, zero on padding
-        entries (the mask and confidence algebra are applied by the caller).
+      w / coef: (S, T) f32 per-entry Gramian / RHS weights, zero from
+        ``slens`` on (the mask and confidence algebra are applied by the
+        caller).
       block: rows per block; outputs carry the extra spill row.
 
     Returns (big_a (block+1, k, k) f32, big_b (block+1, k) f32). Rows with
@@ -358,31 +397,32 @@ def gather_gramian_accumulate(y, srow, scols, w, coef, *, block: int,
     a0 = jnp.zeros((block + 1, k, k), jnp.float32)
     b0 = jnp.zeros((block + 1, 1, k), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # srow drives the output index maps
+        # srow drives the output index maps, slens the copy loops
+        num_scalar_prefetch=2,
         grid=(s,),
         in_specs=[
             # gather indices are DMA addresses: SMEM, one slot per step
-            pl.BlockSpec((1, 1, t), lambda i, sr: (i, 0, 0),
+            pl.BlockSpec((1, 1, t), lambda i, sr, sl: (i, 0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 2, t), lambda i, sr: (i, 0, 0),
+            pl.BlockSpec((1, 2, t), lambda i, sr, sl: (i, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pl.ANY),  # y stays in HBM
             pl.BlockSpec(memory_space=pl.ANY),  # big_a zero donor
             pl.BlockSpec(memory_space=pl.ANY),  # big_b zero donor
         ],
         out_specs=[
-            pl.BlockSpec((1, k, k), lambda i, sr: (sr[i], 0, 0),
+            pl.BlockSpec((1, k, k), lambda i, sr, sl: (sr[i], 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, k), lambda i, sr: (sr[i], 0, 0),
+            pl.BlockSpec((1, 1, k), lambda i, sr, sl: (sr[i], 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         scratch_shapes=[
             pltpu.VMEM((t, 1, kp), jnp.float32),  # gathered factor rows
-            pltpu.SemaphoreType.DMA((_GG_BUFS,)),
+            pltpu.SemaphoreType.DMA((1,)),
         ],
     )
     big_a, big_b = pl.pallas_call(
-        _make_gather_gramian_kernel(t, k, kp, block, y.dtype),
+        _make_gather_gramian_kernel(t, k, kp, y.dtype),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((block + 1, k, k), jnp.float32),
@@ -390,9 +430,10 @@ def gather_gramian_accumulate(y, srow, scols, w, coef, *, block: int,
         ],
         # zero donors alias the outputs: rows no slot ever visits keep
         # exact zeros — deterministic on hardware AND under interpret
-        input_output_aliases={4: 0, 5: 1},
+        input_output_aliases={5: 0, 6: 1},
         interpret=interpret,
-    )(srow, scols.reshape(s, 1, t), wc, y3, a0, b0)
+    )(srow.reshape(s), slens.reshape(s), scols.reshape(s, 1, t), wc, y3,
+      a0, b0)
     return big_a, big_b[:, 0, :]
 
 

@@ -39,6 +39,7 @@ analyze substrate.
 from __future__ import annotations
 
 import ast
+import math
 import re
 
 from oryx_tpu.tools.analyze.core import scope_nodes
@@ -73,6 +74,16 @@ SCOPED_BUDGET_BYTES = 3 << 20
 #: ``_GG_MAX_FEATURES = 256`` gate exactly (see docs/static_analysis.md
 #: "Pallas kernel family" for the evaluated math).
 RESIDENT_BUDGET_BYTES = 1536 << 10
+#: The SMEM one program may use (1 MiB on a v5e, from the compiler's own
+#: "Used 1.00M of 1.00M smem" message), and the share of it that
+#: scalar-prefetched operands — which ride there WHOLE, whatever the grid —
+#: may take: three quarters, the rest left to the double-buffered SMEM
+#: blocks and the compiler's own scalars. ``_GG_MAX_SLOTS`` is this budget
+#: over the gather-Gramian call's two prefetched words a slot. Compiled for
+#: a described v5e the call fits to 130,048 slots and overruns by 5.1 KB at
+#: 131,072 (T = 512): the quarter is margin, not need.
+SMEM_LIMIT_BYTES = 1 << 20
+SMEM_PREFETCH_BUDGET_BYTES = 768 << 10
 
 
 def budgets(config=None) -> dict:
@@ -298,7 +309,8 @@ class KernelModel:
 
     __slots__ = ("fctx", "call", "name", "enclosing", "grid", "inputs",
                  "outputs", "scratch", "operands", "out_shapes", "aliases",
-                 "interpret", "kernel_fn", "num_prefetch", "senv")
+                 "interpret", "kernel_fn", "num_prefetch", "senv",
+                 "prefetch_shapes")
 
     def __init__(self, fctx, call, name, enclosing):
         self.fctx = fctx
@@ -316,6 +328,9 @@ class KernelModel:
         self.kernel_fn = None  # FunctionDef of the kernel body, if resolved
         self.num_prefetch = 0
         self.senv: dict = {}
+        # whole-array shapes of the scalar-prefetched operands, where the
+        # call site shows them (``srow.reshape(s)``); None where it does not
+        self.prefetch_shapes: list = []
 
     # -- byte math ----------------------------------------------------------
 
@@ -347,6 +362,36 @@ class KernelModel:
                 return None
             best = max(best, size)
         return best
+
+    def prefetch_smem_bytes(self, bindings: dict) -> "float | None":
+        """SMEM taken by the scalar-prefetched operands: each rides there
+        whole, one 32-bit word an element. None when a shape is not visible
+        at the call site or does not resolve."""
+        total = 0.0
+        for shape in self.prefetch_shapes:
+            if shape is None:
+                return None
+            dims = [_dim_value(d, bindings) for d in shape]
+            if any(d is None for d in dims):
+                return None
+            total += 4.0 * math.prod(max(1, d) for d in dims)
+        return total
+
+    def smem_bytes(self, bindings: dict) -> "float | None":
+        """The program's SMEM footprint under ``bindings``: the prefetched
+        operands, the SMEM blocks (padded; ×2 when pipelined) and SMEM
+        scratch. Semaphores live in their own space and are not counted."""
+        total = self.prefetch_smem_bytes(bindings)
+        if total is None:
+            return None
+        for b in self.buffers():
+            if b.space != "smem":
+                continue
+            size = b.padded_bytes(bindings)
+            if size is None:
+                return None
+            total += size * (2.0 if b.pipelined else 1.0)
+        return total
 
     def vmem_poly(self) -> Poly:
         """Unpadded symbolic footprint (pipelined ×2) for display; evaluate
@@ -487,8 +532,10 @@ def _parse_scratch(fctx, node) -> "KernelBuffer | None":
             _resolve_dims(fctx, dims), dtype, None, node,
         )
     if "SemaphoreType" in resolved or tail == "DMA":
-        return KernelBuffer("scratch", 0, "semaphores", "sem", None, None,
-                            None, node)
+        # ``SemaphoreType.DMA((n,))``: the shape is the count of semaphores
+        dims = _tuple_dims(node.args[0]) if node.args else None
+        return KernelBuffer("scratch", 0, "semaphores", "sem",
+                            _resolve_dims(fctx, dims), None, None, node)
     return None
 
 
@@ -692,6 +739,12 @@ def _fill_model(fctx, fn_node, model: KernelModel) -> None:
             if isinstance(n, ast.Call) and n.func is call:
                 model.operands = list(n.args)
                 break
+
+    shape_of = model.senv.get("__shape_of__")
+    model.prefetch_shapes = [
+        _resolve_dims(fctx, shape_of(op)) if shape_of else None
+        for op in model.operands[:num_prefetch]
+    ]
 
     # the kernel function body (through one factory hop)
     if call.args:

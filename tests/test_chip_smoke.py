@@ -142,14 +142,16 @@ def test_every_kernel_and_the_tpu_half_iteration_lower_for_tpu(
 
     f32 = jnp.float32
     for dtype in (jnp.float32, jnp.bfloat16):
-        for t in (8, 128, 512):
+        # the pack's narrowest slot, one below the copy loops' trip, the
+        # Netflix cell's two widths
+        for t in (8, 128, 256, 512):
             s, block = 64, 32
             text = _lower_tpu(
-                lambda y, sr, sc, w, c: pk.gather_gramian_accumulate(
-                    y, sr, sc, w, c, block=block, interpret=False),
+                lambda y, sr, sl, sc, w, c: pk.gather_gramian_accumulate(
+                    y, sr, sl, sc, w, c, block=block, interpret=False),
                 jnp.zeros((1000, k), dtype), jnp.zeros((s,), jnp.int32),
-                jnp.zeros((s, t), jnp.int32), jnp.zeros((s, t), f32),
-                jnp.zeros((s, t), f32))
+                jnp.zeros((s,), jnp.int32), jnp.zeros((s, t), jnp.int32),
+                jnp.zeros((s, t), f32), jnp.zeros((s, t), f32))
             assert text.count("tpu_custom_call") == 1, (k, dtype, t)
 
     text = _lower_tpu(
@@ -213,6 +215,16 @@ def half(k, n_blocks, block, s, t, dtype):
 # enough that the SPD kernel's scoped VMEM is what production allocates
 half(50, 13, 7693, 10240, 32, "float32")
 half(256, 2, 1000, 2048, 512, "bfloat16")  # both kernels' last supported width
+# the Netflix cell's two sides as the pack shapes them: the item side's
+# 72,594 slots a block of T=512 (owner rows AND slot lengths whole in SMEM),
+# the user side's T=256; then 250 features (a 1 KB row a copy) at both
+# widths, and the slot gate itself at the widest slot
+half(50, 3, 5924, 72594, 512, "float32")
+half(50, 2, 8139, 11790, 256, "float32")
+half(250, 2, 1046, 12288, 512, "float32")
+half(250, 2, 1072, 2048, 256, "bfloat16")
+from oryx_tpu.ops import pallas_kernels as pk
+half(50, 1, 5924, pk._GG_MAX_SLOTS, 512, "float32")
 print("COMPILED")
 """
 

@@ -92,14 +92,18 @@ def test_kernel_direct_against_numpy_reference():
     mask = np.arange(t)[None, :] < slens[:, None]
     w *= mask
     coef *= mask
-    y = rng.standard_normal((n_opp, k)).astype(np.float32)
+    # padding entries name a row of NaN: the kernel copies a slot's first
+    # ``slens`` rows and no others, and clears its scratch before the first
+    scols = np.where(mask, scols, n_opp).astype(np.int32)
+    y = rng.standard_normal((n_opp + 1, k)).astype(np.float32)
+    y[n_opp] = np.nan
 
     big_a, big_b = jax.jit(
         lambda *a: gather_gramian_accumulate(*a, block=block, interpret=True)
-    )(jnp.asarray(y), jnp.asarray(srow), jnp.asarray(scols), jnp.asarray(w),
-      jnp.asarray(coef))
+    )(jnp.asarray(y), jnp.asarray(srow), jnp.asarray(slens),
+      jnp.asarray(scols), jnp.asarray(w), jnp.asarray(coef))
 
-    yg = y[scols]  # (S, T, k)
+    yg = np.nan_to_num(y)[scols]  # (S, T, k)
     ra = np.zeros((block + 1, k, k), np.float32)
     rb = np.zeros((block + 1, k), np.float32)
     np.add.at(ra, srow, np.einsum("st,sti,stj->sij", w, yg, yg))
@@ -117,7 +121,10 @@ def test_kernel_direct_against_numpy_reference():
 def test_supported_gate():
     assert gather_gramian_supported(50, 4096)
     assert not gather_gramian_supported(512, 4096)
-    # the owner rows ride whole in the compiler's 1 MiB of SMEM
+    # owner rows and slot lengths ride whole in the compiler's 1 MiB of
+    # SMEM: the Netflix item side's 72,594 slots a block fit, 100,000 do not
+    assert gather_gramian_supported(50, 72_594)
+    assert not gather_gramian_supported(50, 100_000)
     assert not gather_gramian_supported(50, 1 << 18)
     # above the gate, the platform default must fall back, not fail
     batch, _ = _skewed_batch(5)
@@ -126,6 +133,36 @@ def test_supported_gate():
                              jax.random.PRNGKey(0))
     out = _half(side, y, 300, implicit=True, dtype="float32", fused=None)
     assert np.isfinite(out).all()
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_gather_rows_counted_as_issued(fused):
+    """The pack counts its entries and the slots that hold them; the
+    trainer's cost accounting charges the gather for the rows the chosen
+    formulation ISSUES — one an entry under the fused kernel (it copies a
+    slot to its own length), every cell of every slot under the einsum —
+    and both sides report what copying every slot to its width would be."""
+    from oryx_tpu.common import profiling
+
+    batch, k = _skewed_batch(7)
+    nnz = len(batch.rows)
+    for side in tr.prepare_blocked(batch, k, block=64):
+        slens = np.asarray(side.slens)
+        assert side.entries == nnz == int(slens.sum())
+        assert side.real_slots == int((slens > 0).sum())
+        before, now = side.gather_rows_per_entry(fused)
+        assert before == side.real_slots * side.slot_width / nnz > 1.0
+        assert now == (1.0 if fused else side.scols.size / nnz)
+        assert side.gather_rows(fused) == (nnz if fused
+                                           else side.scols.size)
+    tr.als_train(batch, k, 0.01, 1.0, True, iterations=1,
+                 key=jax.random.PRNGKey(2), block=64, fused_gramian=fused)
+    u_side, i_side = tr.prepare_blocked(batch, k, block=64)
+    for key, side in (("als.train.user_half", u_side),
+                      ("als.train.item_half", i_side)):
+        _, nbytes = profiling.costs().cost(key)
+        writes = side.padded_rows * k * (k + 1) * 4.0
+        assert nbytes == side.gather_rows(fused) * k * 4.0 + writes
 
 
 # ---------------------------------------------------------------------------
@@ -137,8 +174,10 @@ def _sides_equal(a, b) -> bool:
     return all(
         np.array_equal(np.asarray(getattr(a, f)), np.asarray(getattr(b, f)))
         for f in ("srows", "scols", "svals", "slens")
-    ) and (a.block, a.n_blocks, a.slot_width, a.slot_chunk, a.n_rows) == (
-        b.block, b.n_blocks, b.slot_width, b.slot_chunk, b.n_rows
+    ) and (a.block, a.n_blocks, a.slot_width, a.slot_chunk, a.n_rows,
+           a.entries, a.real_slots) == (
+        b.block, b.n_blocks, b.slot_width, b.slot_chunk, b.n_rows,
+        b.entries, b.real_slots
     )
 
 

@@ -9,12 +9,14 @@ harness proves NUMBERS, two ways:
     ``interpret=True`` against plain numpy references, across the edge
     shapes that bite on chip: single-row batches, batch sizes straddling
     the pad tile, k at the VMEM budget boundary, empty rows, pad slots,
-    single-slot grids, skewed slot fill, bf16 inputs. Zero-input regions
+    single-slot grids, skewed slot fill, slots filled to 0, 1, T−1 and T
+    entries with every padding column naming a row of NaN, slot widths
+    around the copy loops' trip, bf16 inputs. Zero-input regions
     must come back BITWISE zero (the donated-alias contract); everything
     else within accumulation tolerance.
 
-  * **budget consistency** — the runtime gates (``_GG_MAX_FEATURES``, the
-    ``spd_tile_b`` batch-tile formula) are recomputed from the PARSED
+  * **budget consistency** — the runtime gates (``_GG_MAX_FEATURES``,
+    ``_GG_MAX_SLOTS``, the ``spd_tile_b`` batch-tile formula) are recomputed from the PARSED
     kernel models (tools/analyze/kernelmodel.py) under the registered
     ``oryx.analyze.kernel.*`` budgets and asserted EQUAL. The hand-derived
     constants in ops/pallas_kernels.py can no longer silently drift from
@@ -104,6 +106,26 @@ def _gg_layout(rng, block, t, n_slots, n_pad_slots, skew):
     return srow, slens
 
 
+def _gg_fill_layout(rng, block, fills):
+    """Slots of exactly the given fills, in order, over ascending rows.
+    ``None`` is a pad slot (owner = spill row, length 0) and always ends its
+    row: the pack writes pad slots only at a block's end, but the kernel's
+    contract is wider — a change of owner flushes a block and zeroes the
+    next, so a pad slot BETWEEN two rows must cost nothing but its step."""
+    srow, slens, row = [], [], 0
+    for f in fills:
+        if f is None:
+            srow.append(block)
+            slens.append(0)
+            row += 1
+        else:
+            srow.append(row)
+            slens.append(f)
+            row += rng.choice((0, 0, 1, 2))
+    assert row < block
+    return np.array(srow, np.int32), np.array(slens, np.int32)
+
+
 def _gg_reference(y, srow, scols, w, coef, block):
     yg = y[scols]  # (S, T, k)
     ra = np.zeros((block + 1, y.shape[1], y.shape[1]), np.float32)
@@ -114,37 +136,65 @@ def _gg_reference(y, srow, scols, w, coef, block):
 
 
 def _gg_cases():
+    """(k, t, block, layout, case_seed): ``layout`` is either
+    (n_slots, n_pad, skew) for the random fill or a list of exact fills."""
+    u = pk._GG_UNROLL
     rng = random.Random(SEED + 7)
     cases = []
-    for k, t, block, n_slots, n_pad, skew in (
-        (4, 1, 8, 3, 2, False),     # T=1: one entry per slot
-        (8, 4, 16, 1, 0, False),    # single-slot grid
-        (8, 8, 32, 12, 4, True),    # skewed fill, pad slots
-        (13, 7, 8, 5, 3, True),     # nothing tile-round anywhere
-        (50, 8, 64, 20, 4, False),  # the production k
-        (256, 4, 2, 3, 1, False),   # k AT the resident-budget boundary
+    for k, t, block, layout in (
+        (4, 1, 8, (3, 2, False)),     # T=1: one entry per slot
+        (8, 4, 16, (1, 0, False)),    # single-slot grid
+        (8, 8, 32, (12, 4, True)),    # skewed fill, pad slots
+        (13, 7, 8, (5, 3, True)),     # nothing tile-round anywhere
+        (50, 8, 64, (20, 4, False)),  # the production k
+        (256, 4, 2, (3, 1, False)),   # k AT the resident-budget boundary
+        # slots filled to 0, 1, T-1 and T entries; the FIRST slot of the
+        # grid partly filled (its tail reads the scratch as the kernel's
+        # first step left it); a pad slot between two rows
+        (8, 8, 32, [3, 8, 0, 1, 7, None, 8, 5, 0, None, None]),
+        # T smaller than, equal to and larger than the copy loops' trip
+        (8, u // 2, 16, [1, u // 2, 0, u // 2 - 1, None]),
+        (8, u, 16, [u - 1, u, 1, 0, u, None]),
+        (8, 3 * u + 5, 16, [2, 3 * u + 5, u, u + 1, 2 * u - 1, 3 * u, None,
+                            3 * u + 4, 0, 1]),
+        # a first slot with nothing in it, then a row
+        (12, 16, 8, [0, 16, 9]),
     ):
-        cases.append((k, t, block, n_slots, n_pad, skew,
-                      rng.randrange(1 << 16)))
+        cases.append((k, t, block, layout, rng.randrange(1 << 16)))
     return cases
 
 
-@pytest.mark.parametrize("k,t,block,n_slots,n_pad,skew,case_seed", _gg_cases())
+def _gg_case_id(case):
+    k, t, _, layout, _ = case
+    kind = "fills" if isinstance(layout, list) else "fuzz"
+    return f"k{k}-t{t}-{kind}{len(layout) if kind == 'fills' else layout[0]}"
+
+
+@pytest.mark.parametrize("k,t,block,layout,case_seed", _gg_cases(),
+                         ids=[_gg_case_id(c) for c in _gg_cases()])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_gg_differential_matches_numpy(k, t, block, n_slots, n_pad, skew,
-                                       case_seed, dtype):
+def test_gg_differential_matches_numpy(k, t, block, layout, case_seed, dtype):
     if dtype == "bfloat16" and k == 256:
         pytest.skip("one boundary run is enough; bf16 covered at small k")
     rng = random.Random(case_seed)
     nrng = np.random.default_rng(case_seed)
-    srow, slens = _gg_layout(rng, block, t, n_slots, n_pad, skew)
+    if isinstance(layout, list):
+        srow, slens = _gg_fill_layout(rng, block, layout)
+    else:
+        srow, slens = _gg_layout(rng, block, t, *layout)
     s = len(srow)
     n_opp = max(2 * k, 16)
-    scols = np.sort(nrng.integers(0, n_opp, (s, t)), axis=1).astype(np.int32)
-    mask = (np.arange(t)[None, :] < slens[:, None]).astype(np.float32)
+    valid = np.arange(t)[None, :] < slens[:, None]
+    mask = valid.astype(np.float32)
+    # every entry past a slot's length names a row of NaN: one copy issued
+    # for padding, or a scratch row that was never cleared, and the
+    # Gramian is NaN (0 × NaN)
+    scols = np.where(valid, np.sort(nrng.integers(0, n_opp, (s, t)), axis=1),
+                     n_opp).astype(np.int32)
     w = (nrng.standard_normal((s, t)).astype(np.float32) * mask)
     coef = (nrng.standard_normal((s, t)).astype(np.float32) * mask)
-    y = nrng.standard_normal((n_opp, k)).astype(np.float32)
+    y = nrng.standard_normal((n_opp + 1, k)).astype(np.float32)
+    y[n_opp] = np.nan
 
     yj = jnp.asarray(y)
     if dtype == "bfloat16":
@@ -159,13 +209,15 @@ def test_gg_differential_matches_numpy(k, t, block, n_slots, n_pad, skew,
         tol = 2e-2
     else:
         y_ref, w_ref, coef_ref, tol = y, w, coef, 1e-4
+    y_ref = np.nan_to_num(y_ref)  # the reference never meets the NaN row
 
     big_a, big_b = jax.jit(
         lambda *args: pk.gather_gramian_accumulate(
             *args, block=block, interpret=True)
-    )(yj, jnp.asarray(srow), jnp.asarray(scols), jnp.asarray(w),
-      jnp.asarray(coef))
+    )(yj, jnp.asarray(srow), jnp.asarray(slens), jnp.asarray(scols),
+      jnp.asarray(w), jnp.asarray(coef))
     big_a, big_b = np.asarray(big_a), np.asarray(big_b)
+    assert np.isfinite(big_a).all() and np.isfinite(big_b).all()
 
     ra, rb = _gg_reference(y_ref, srow, scols, w_ref, coef_ref, block)
     scale = max(1e-9, np.abs(ra).max(), np.abs(rb).max())
@@ -186,6 +238,9 @@ def test_gg_supported_gate_spans_the_fuzz_matrix():
     ks = [c[0] for c in _gg_cases()]
     assert all(pk.gather_gramian_supported(k, 64) for k in ks)
     assert max(ks) == pk._GG_MAX_FEATURES
+    # and the matrix's slot widths straddle the copy loops' trip
+    ts = {c[1] for c in _gg_cases()}
+    assert min(ts) < pk._GG_UNROLL < max(ts) and pk._GG_UNROLL in ts
 
 
 # ---------------------------------------------------------------------------
@@ -227,6 +282,42 @@ def test_gg_max_features_equals_modeled_budget(ops_kernel_models):
     assert modeled_max == pk._GG_MAX_FEATURES
     for k in (1, 7, 8, 50, 200, 249, 255, 256, 257, 264, 300, 511, 512):
         assert pk.gather_gramian_supported(k, 64) == fits(k), k
+
+
+def test_gg_max_slots_equals_modeled_smem_budget(ops_kernel_models):
+    """``_GG_MAX_SLOTS`` is the model's number, not a hand-derived island:
+    the parsed call's scalar-prefetched operands (owner rows AND slot
+    lengths, one word a slot each) against the share of SMEM they may take.
+    A third prefetched vector, or a wider one, moves the model and fails
+    here until the gate is re-derived. The whole SMEM footprint at the gate
+    and the widest slot stays inside the compiler's limit, and the gate
+    admits the Netflix cell's item side (72,594 slots a block)."""
+    from oryx_tpu.tools.analyze.kernelmodel import (
+        SMEM_LIMIT_BYTES,
+        SMEM_PREFETCH_BUDGET_BYTES,
+    )
+
+    gg = ops_kernel_models["gather_gramian_accumulate"]
+    assert gg.num_prefetch == 2
+    assert gg.prefetch_shapes == [("s",), ("s",)]
+
+    def fits(slots: int) -> bool:
+        nbytes = gg.prefetch_smem_bytes({"s": slots})
+        assert nbytes is not None, "gg prefetch no longer evaluates — reparse"
+        return nbytes <= SMEM_PREFETCH_BUDGET_BYTES
+
+    step = 1 << 10
+    modeled_max = max(n for n in range(step, (1 << 18) + 1, step) if fits(n))
+    assert modeled_max == pk._GG_MAX_SLOTS
+    for slots in (1, 72_594, pk._GG_MAX_SLOTS, pk._GG_MAX_SLOTS + 1,
+                  3 << 16, 1 << 18):
+        assert pk.gather_gramian_supported(50, slots) == fits(slots), slots
+    assert pk.gather_gramian_supported(50, 72_594)
+    total = gg.smem_bytes({"s": pk._GG_MAX_SLOTS,
+                           "t": pk._GG_SLOT_WIDTH_MAX})
+    # the prefetched words + the double-buffered (1, 1, T) index block
+    assert total == 2 * 4 * pk._GG_MAX_SLOTS + 2 * 4 * pk._GG_SLOT_WIDTH_MAX
+    assert total <= SMEM_LIMIT_BYTES
 
 
 def test_spd_tile_formula_equals_modeled_budget(ops_kernel_models):

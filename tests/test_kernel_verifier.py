@@ -551,11 +551,17 @@ def test_real_kernels_parse_with_expected_structure(kernels_project):
     assert spd.interpret == ("param", "interpret")
 
     gg = models["gather_gramian_accumulate"]
-    assert gg.num_prefetch == 1
+    # owner rows and slot lengths, each a whole (S,) vector in SMEM
+    assert gg.num_prefetch == 2
+    assert gg.prefetch_shapes == [("s",), ("s",)]
     assert [b.space for b in gg.inputs] == [
         "smem", "vmem", "any", "any", "any",
     ]
-    assert gg.aliases == {4: 0, 5: 1}
+    assert gg.aliases == {5: 0, 6: 1}
+    # the gather scratch, and ONE semaphore for every copy of a slot
+    assert [(b.space, b.shape) for b in gg.scratch] == [
+        ("vmem", ("t", 1, "kp")), ("sem", (1,)),
+    ]
     # the scalar-prefetch-driven output maps are data-dependent: revisited
     assert all(b.revisits_across_grid(gg.grid) for b in gg.outputs)
     # and the kernel zero-initializes both refs on first visit
@@ -599,6 +605,33 @@ def test_gg_vmem_model_matches_hand_computed_budget(kernels_project):
     assert at(256) == expected_256 == 1_058_816
     budget = budgets()["resident_budget_bytes"]
     assert at(256) <= budget < at(264)
+
+
+def test_gg_smem_model_matches_what_the_compiler_said(kernels_project):
+    """The SMEM side of the model: two prefetched words a slot plus the
+    double-buffered (1, 1, T) index block. Compiled for a described v5e at
+    T = 512 the call fit at 130,048 slots and ran "out of memory in memory
+    space smem ... by 5.1K" at 131,072 (PR 25): the model says 4 KiB under
+    and 4 KiB over the same 1 MiB, the compiler's own scalars being the
+    rest. A kernel with no prefetched operand reads 0; one whose prefetched
+    shapes the call site does not show reads None, never a guess."""
+    from oryx_tpu.tools.analyze.kernelmodel import (
+        SMEM_LIMIT_BYTES, kernel_models,
+    )
+
+    models = {m.name: m for m in kernel_models(kernels_project)}
+    gg = models["gather_gramian_accumulate"]
+    at = lambda s: gg.smem_bytes({"s": s, "t": 512})
+    assert at(130_048) == SMEM_LIMIT_BYTES - 4096
+    assert at(131_072) == SMEM_LIMIT_BYTES + 4096
+    assert gg.prefetch_smem_bytes({"s": 1000}) == 8000
+    assert gg.smem_bytes({"t": 512}) is None  # unbound slots: no number
+    assert models["_spd_solve_call"].smem_bytes({}) == 0
+    gg.prefetch_shapes = [None, ("s",)]
+    try:
+        assert gg.smem_bytes({"s": 8, "t": 8}) is None
+    finally:
+        gg.prefetch_shapes = [("s",), ("s",)]
 
 
 def test_cli_cost_renders_kernel_rows(capsys):
