@@ -792,9 +792,10 @@ def _check_placement(batch, serving, size) -> dict:
     spread("train_x", x)
     spread("train_y", y)
     snap = serving.manager.get_model().y_snapshot()
-    if snap.sharded_mat is None:
+    if snap.mesh is None:
         raise SmokeFailure("the serving tier's Y is not on the sharded path")
-    spread("serving_y", snap.sharded_mat)
+    spread("serving_y", snap.score_mat)
+    spread("serving_y_f32", snap.mat)
     in_use = []
     for d in jax.local_devices():
         stats = d.memory_stats() or {}

@@ -286,8 +286,6 @@ class IVFSnapshot:
         # flat-snapshot duck type for serving's guards
         self.mat = None
         self.score_mat = None
-        self.sharded_mat = None
-        self.sharded_buckets = None
         self.mesh = None
         self.buckets = None
         if prev is not None and appended is not None:

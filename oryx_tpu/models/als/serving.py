@@ -16,6 +16,7 @@ only re-materialize lazily, so the query path never blocks on updates
 
 from __future__ import annotations
 
+import collections
 import functools
 import json
 import logging
@@ -42,6 +43,8 @@ from oryx_tpu.models.als import pmml_codec
 from oryx_tpu.models.als.lsh import LocalitySensitiveHash
 from oryx_tpu.models.als.rescorer import load_rescorer_providers
 from oryx_tpu.models.als.vectors import FeatureVectorStore
+from oryx_tpu.parallel.mesh import (put_row_sharded, replicated_sharding,
+                                    row_sharding)
 from oryx_tpu.common.lockutils import RateLimitCheck
 from oryx_tpu.ops.solver import SolverCache
 
@@ -189,61 +192,85 @@ def _top_k_dot_batch_masked(mat, qs, lut, buckets, excl, k: int):
     return jax.lax.approx_max_k(scores, k, recall_target=0.99)
 
 
-@functools.lru_cache(maxsize=32)
+@functools.lru_cache(maxsize=64)
 def _sharded_top_k_fn(mesh, axis: str, k: int, k_final: int, n_real: int,
                       use_lut: bool, use_excl: bool = True):
-    """Cross-shard top-N: Y's rows shard over ``axis``; each device scores
-    its block, masks (pad rows, per-query LSH lut, per-query excluded items)
-    and takes a local top-k; the (B, ndev·k) candidates merge with one more
-    top-k. This is the multi-chip scan of SURVEY §2.14 ("device-resident Y
-    shards; top-N via sharded matmul + lax.top_k + cross-shard merge") — the
-    framework's intra-request parallelism.
+    """Cross-shard top-N: Y's rows shard over ``axis``; each device runs the
+    ONE-CHIP scan over its own block — the same ``_score`` matmul and
+    ``_top_k_of_scores`` (approximate top-k at the same recall target, fused
+    behind the matmul: no ``(B, n_local)`` score matrix is left in HBM) —
+    with pad rows, the per-query LSH lut and per-query excluded items masked
+    as on one chip; the ``ndev`` candidate lists of ``(B, k)`` are gathered
+    across the mesh and merged with one more top-k. This is the multi-chip
+    scan of SURVEY §2.14 ("device-resident Y shards; top-N via sharded
+    matmul + lax.top_k + cross-shard merge") — the framework's
+    intra-request parallelism.
 
     Exclusion (known-item filtering, Recommend.java:84-106) is a device-side
     scatter: ``excl`` is (B, E) GLOBAL row indices, -1-padded; each shard
     rebases to local coordinates and drops out-of-range entries, so the mask
-    costs O(E) scatter per shard instead of a host round-trip."""
+    costs O(E) scatter per shard instead of a host round-trip.
+
+    Operands of the returned jitted program: ``(mat, qs[, excl][, lut,
+    buckets])`` — only what the flags say is used. Its name is stable
+    (``jit__sharded_top_k_dot_batch``): the device trace finds it by that,
+    and finds the merge's gather as the program's all-gather op."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    def local(mat_blk, qs_blk, excl_blk, lut_blk, buckets_blk):
+    def local(mat_blk, qs_blk, *rest):
+        rest = list(rest)
         n_local = mat_blk.shape[0]
         offset = jax.lax.axis_index(axis) * n_local
-        scores = _score(qs_blk, mat_blk)  # (B, n_local)
-        col_ids = offset + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        scores = jnp.where(col_ids < n_real, scores, -jnp.inf)
+        scores = _score(qs_blk, mat_blk)  # (B, n_local), fused into the top-k
+        if n_real < n_local * mesh.shape[axis]:
+            # zero rows past the last id (row count padded to the shards)
+            col_ids = offset + jax.lax.broadcasted_iota(
+                jnp.int32, scores.shape, 1)
+            scores = jnp.where(col_ids < n_real, scores, -jnp.inf)
+        if use_excl:
+            # per-query exclusions: global→local rebase; -1 pads and rows
+            # owned by other shards fall out of range and are remapped to
+            # the drop index (negative scatter indices would wrap, so
+            # _mask_excluded clamps explicitly)
+            scores = _mask_excluded(scores, rest.pop(0) - offset)
         if use_lut:
+            lut_blk, buckets_blk = rest
             valid = jnp.take_along_axis(
                 lut_blk, buckets_blk[None, :].astype(jnp.int32), axis=1
             )
             scores = jnp.where(valid, scores, -jnp.inf)
-        if use_excl:
-            # per-query exclusions: global→local rebase; -1 pads and rows
-            # owned by other shards are remapped to the drop index (negative
-            # scatter indices would wrap, so clamp explicitly)
-            local_excl = excl_blk - offset
-            scores = _mask_excluded(scores, local_excl)
-        vals, idx = jax.lax.top_k(scores, k)
-        return vals, idx + offset
+        vals, idx = _top_k_of_scores(scores, k)
+        # the merge: every shard's candidates to every device, then top-k.
+        # The gather is the program's only collective; nothing overlaps it
+        with jax.named_scope("topn_merge"):
+            vals = jax.lax.all_gather(vals, axis, axis=1, tiled=True)
+            idx = jax.lax.all_gather(idx + offset, axis, axis=1, tiled=True)
+            mvals, pos = jax.lax.top_k(vals, k_final)  # (B, ndev*k) → k_final
+            return mvals, jnp.take_along_axis(idx, pos, axis=1)
 
-    @jax.jit
-    def fn(mat, qs, excl, lut, buckets):
-        # the replicated P(None, None) operands here are BATCH-shaped
-        # (queries/exclusions/lut: B·k, B·E, B·buckets) — a deliberate
-        # small broadcast, which the replicated-collective checker keeps
-        # quiet on because none of them is data-gathered like a factor
-        # table; Y (the model-scaled operand) is the sharded one
-        vals, idx = shard_map(
-            local,
-            mesh=mesh,
-            in_specs=(P(axis, None), P(None, None), P(None, None),
-                      P(None, None), P(axis)),
-            out_specs=(P(None, axis), P(None, axis)),
-        )(mat, qs, excl, lut, buckets)
-        mvals, pos = jax.lax.top_k(vals, k_final)  # (B, ndev*k) → (B, k_final)
-        return mvals, jnp.take_along_axis(idx, pos, axis=1)
+    # the replicated P(None, None) operands here are BATCH-shaped
+    # (queries/exclusions/lut: B·k, B·E, B·buckets) — a deliberate small
+    # broadcast, which the replicated-collective checker keeps quiet on
+    # because none of them is data-gathered like a factor table; Y (the
+    # model-scaled operand) is the sharded one
+    in_specs = (P(axis, None), P(None, None))
+    if use_excl:
+        in_specs += (P(None, None),)
+    if use_lut:
+        in_specs += (P(None, None), P(axis))
 
-    return fn
+    def _sharded_top_k_dot_batch(mat, qs, *rest):
+        return shard_map(
+            local, mesh=mesh, in_specs=in_specs,
+            out_specs=(P(None, None), P(None, None)),
+            # every device ends with the same merged list (the gather makes
+            # the candidates equal everywhere); all_gather's result is typed
+            # as varying, so the static replication check cannot see it
+            check_vma=False,
+        )(mat, qs, *rest)
+
+    return jax.jit(_sharded_top_k_dot_batch)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -322,10 +349,37 @@ def _quant_cosine_candidates(qmat, qscale, norms, qs, q_norms, valid, k: int):
     return jax.lax.top_k(scores, k)
 
 
+@functools.lru_cache(maxsize=8)
+def _derive_sharded(row_sharding, mat_sharding, score_dtype):
+    """norms and the scoring copy of a row-sharded Y, each computed where its
+    rows live and left split the same way (``score_dtype`` None: no copy)."""
+
+    def derive(mat):
+        norms = jnp.linalg.norm(mat, axis=1)
+        return norms, (None if score_dtype is None else mat.astype(score_dtype))
+
+    return jax.jit(derive, out_shardings=(
+        row_sharding, None if score_dtype is None else mat_sharding))
+
+
+_Y_SHARD_BYTES = metrics_mod.default_registry().gauge(
+    "oryx_serving_y_shard_bytes",
+    "Bytes of the newest Y snapshot resident on each device (factors, "
+    "scoring copy, norms, buckets)",
+    ("device",),
+)
+
+
 class _YSnapshot:
-    """Immutable device view of Y: ids, matrix, norms, LSH buckets. With a
-    mesh, the scoring copy is row-sharded over ``shard_axis`` (rows padded to
-    the shard count) so Y may exceed a single device's memory.
+    """Immutable device view of Y: ids, matrix, norms, LSH buckets.
+
+    With a mesh EVERY per-row array (the float32 factors ``mat``, the scoring
+    copy ``score_mat``, ``norms``, ``buckets``) is split by rows over
+    ``shard_axis`` as the store materialized it: each device holds its own
+    block of ``n_rows / shards`` rows and derives its norms and scoring rows
+    locally, so Y may exceed a single device's memory — no array with all of
+    Y's rows is ever placed on one device. ``n_rows`` is then ``n`` padded to
+    the shard count; rows past ``n`` are zero and masked in every scan.
 
     ``prev`` + ``delta`` ((changed base-row indices, appended-row count) from
     FeatureVectorStore.delta_since) build the snapshot INCREMENTALLY after a
@@ -348,7 +402,7 @@ class _YSnapshot:
     ):
         self.ids = ids
         self.device_dtype = device_dtype
-        self.mat = mat  # jax (n, k) or None, float32
+        self.mat = mat  # jax (n_rows, k) or None, float32
         # lazy cost-registration marks (see _top_n_batch): per GENERATION so
         # a model swap re-registers against the new shapes, but carried
         # across same-shape incremental snapshots (point-update microbatches
@@ -371,83 +425,95 @@ class _YSnapshot:
                 self.id_to_idx[ids[i]] = i
         else:
             self.id_to_idx = {s: i for i, s in enumerate(ids)}
-        self.mesh = mesh
+        self.mesh = mesh if mat is not None else None
         self.shard_axis = shard_axis
-        self.sharded_mat = None
-        self.sharded_buckets = None
-        if mat is not None:
-            self.norms = jnp.linalg.norm(mat, axis=1)
-            # scoring copy: bf16 on TPU halves HBM traffic per scan; exact
-            # dots/norms keep the f32 matrix. An explicit
-            # oryx.serving.device-dtype overrides the backend heuristic
-            # (int8 never reaches this class — see _QuantSnapshot)
-            if device_dtype == "float32":
-                self.score_mat = mat
-            elif device_dtype == "bfloat16":
-                self.score_mat = mat.astype(jnp.bfloat16)
-            else:  # auto
-                self.score_mat = (
-                    mat.astype(jnp.bfloat16)
-                    if jax.default_backend() == "tpu" else mat
-                )
-            if lsh and lsh.num_hashes:
-                if prev is not None and delta is not None and prev.buckets is not None:
-                    # rehash only the delta: pull changed/new rows (not the
-                    # whole matrix) to host for bucket assignment
-                    buckets = prev.buckets
-                    ch, n_new = delta
-                    if len(ch):
-                        ch_j = jnp.asarray(ch, dtype=jnp.int32)
-                        new_b = jnp.asarray(
-                            lsh.assign_buckets(np.asarray(mat[ch_j]))
-                        )
-                        buckets = buckets.at[ch_j].set(new_b)
-                    if n_new:
-                        tail = np.asarray(mat[len(prev.ids):])
-                        buckets = jnp.concatenate(
-                            [buckets, jnp.asarray(lsh.assign_buckets(tail))]
-                        )
-                    self.buckets = buckets
-                else:
-                    self.buckets = jnp.asarray(lsh.assign_buckets(np.asarray(mat)))
-            else:
-                self.buckets = None
-            if mesh is not None:
-                n_shards = mesh.shape[shard_axis]
-                pad = (-mat.shape[0]) % n_shards
-                padded = (
-                    jnp.concatenate(
-                        [self.score_mat,
-                         jnp.zeros((pad, mat.shape[1]), self.score_mat.dtype)]
-                    )
-                    if pad
-                    else self.score_mat
-                )
-                sharding = jax.sharding.NamedSharding(
-                    mesh, jax.sharding.PartitionSpec(shard_axis, None)
-                )
-                self.sharded_mat = jax.device_put(padded, sharding)
-                # bucket array rides the same sharding (zeros when no LSH so
-                # the shard_map signature stays fixed)
-                b = (
-                    np.asarray(self.buckets, dtype=np.int32)
-                    if self.buckets is not None
-                    else np.zeros(mat.shape[0], dtype=np.int32)
-                )
-                if pad:
-                    b = np.concatenate([b, np.zeros(pad, dtype=np.int32)])
-                bshard = jax.sharding.NamedSharding(
-                    mesh, jax.sharding.PartitionSpec(shard_axis)
-                )
-                self.sharded_buckets = jax.device_put(b, bshard)
-        else:
+        if mat is None:
             self.norms = None
             self.score_mat = None
             self.buckets = None
+            return
+        # scoring copy: bf16 on TPU halves HBM traffic per scan; exact
+        # dots/norms keep the f32 matrix. An explicit
+        # oryx.serving.device-dtype overrides the backend heuristic
+        # (int8 never reaches this class — see _QuantSnapshot)
+        bf16 = device_dtype == "bfloat16" or (
+            device_dtype == "auto" and jax.default_backend() == "tpu")
+        if mesh is None:
+            self.norms = jnp.linalg.norm(mat, axis=1)
+            self.score_mat = mat.astype(jnp.bfloat16) if bf16 else mat
+        else:
+            self.norms, score = _derive_sharded(
+                row_sharding(mesh, shard_axis), mat.sharding,
+                jnp.bfloat16 if bf16 else None,
+            )(mat)
+            self.score_mat = mat if score is None else score
+        self.buckets = self._assign_buckets(lsh, prev, delta)
+        if mesh is not None:
+            self._publish_shard_bytes()
+
+    def _assign_buckets(self, lsh, prev, delta):
+        """(n_rows,) LSH bucket of every row, or None without LSH; under a
+        mesh split like the rows (padding rows in bucket 0, masked anyway)."""
+        if not (lsh and lsh.num_hashes):
+            return None
+        mat, n = self.mat, self.n
+        if prev is None or delta is None or prev.buckets is None:
+            host = lsh.assign_buckets(np.asarray(mat)[:n])
+            return self._place_buckets(host)
+        # rehash only the delta: pull changed/new rows (not the whole
+        # matrix) to host for bucket assignment
+        ch, n_new = delta
+        if self.mesh is not None:
+            # the bucket vector (4 B a row) makes the round trip; Y does not
+            host = np.zeros(n, dtype=np.int32)
+            host[: prev.n] = np.asarray(prev.buckets)[: prev.n]
+            if len(ch):
+                host[ch] = lsh.assign_buckets(np.asarray(mat[jnp.asarray(ch)]))
+            if n_new:
+                host[prev.n:] = lsh.assign_buckets(np.asarray(mat[prev.n:n]))
+            return self._place_buckets(host)
+        buckets = prev.buckets
+        if len(ch):
+            ch_j = jnp.asarray(ch, dtype=jnp.int32)
+            new_b = jnp.asarray(lsh.assign_buckets(np.asarray(mat[ch_j])))
+            buckets = buckets.at[ch_j].set(new_b)
+        if n_new:
+            tail = np.asarray(mat[len(prev.ids):])
+            buckets = jnp.concatenate(
+                [buckets, jnp.asarray(lsh.assign_buckets(tail))]
+            )
+        return buckets
+
+    def _place_buckets(self, host: np.ndarray):
+        if self.mesh is None:
+            return jnp.asarray(host)
+        return put_row_sharded(
+            np.asarray(host, dtype=np.int32), self.mesh, self.shard_axis)
+
+    def device_arrays(self) -> list:
+        """The distinct arrays this snapshot holds on device (the scoring
+        copy only where it is not the float32 matrix itself)."""
+        arrays = [self.mat, self.norms, self.buckets]
+        if self.score_mat is not self.mat:
+            arrays.append(self.score_mat)
+        return [a for a in arrays if a is not None]
+
+    def _publish_shard_bytes(self) -> None:
+        per_device: collections.Counter = collections.Counter()
+        for arr in self.device_arrays():
+            for sh in arr.addressable_shards:
+                per_device[sh.device.id] += int(sh.data.nbytes)
+        for dev, nbytes in per_device.items():
+            _Y_SHARD_BYTES.labels(str(dev)).set(nbytes)
 
     @property
     def n(self) -> int:
         return len(self.ids)
+
+    @property
+    def n_rows(self) -> int:
+        """Rows of the device arrays: ``n``, padded to the shard count."""
+        return self.n if self.mat is None else int(self.mat.shape[0])
 
 
 #: Host-side quantization chunk: bounds the transient f32 gather while
@@ -489,8 +555,6 @@ class _QuantSnapshot:
         self.slab_rows = slab_rows  # (n,) slab row per snapshot position
         self.mat = None         # no f32 device matrix in this mode
         self.score_mat = None
-        self.sharded_mat = None
-        self.sharded_buckets = None
         self.mesh = None
         if prev is not None and appended is not None:
             # id→idx append-only sharing, exactly like _YSnapshot
@@ -647,7 +711,9 @@ class ALSServingModel(ServingModel):
         self.mesh = mesh
         self.shard_axis = shard_axis
         self.x = FeatureVectorStore()
-        self.y = FeatureVectorStore()
+        # under a mesh the store hands every device its own row block of Y
+        # (int8 / IVF snapshots never use the store's device matrix)
+        self.y = FeatureVectorStore(mesh=mesh, shard_axis=shard_axis)
         self.lsh = LocalitySensitiveHash(sample_rate, features) if sample_rate < 1.0 else None
         self.known_items: dict[str, set[str]] = {}
         self._known_lock = threading.Lock()
@@ -906,39 +972,54 @@ class ALSServingModel(ServingModel):
         query — fully vectorized over the batch (lsh.get_candidate_lut)."""
         return self.lsh.get_candidate_lut(qs_host)
 
-    def _sharded_query(self, snap: _YSnapshot, qs_host: np.ndarray, want: int, excluded):
-        """Multi-device scan: per-shard matmul + local top-k + cross-shard
+    def _sharded_query(self, snap: _YSnapshot, qs_host: np.ndarray, want: int,
+                       excluded, cost_key: "str | None" = None):
+        """Multi-device scan: the one-chip scan on every shard + cross-shard
         merge, with LSH lut and per-query known-item exclusion applied
-        device-side (no host fallback for filtered traffic)."""
+        device-side (no host fallback for filtered traffic). Returns
+        ``(vals, idx)`` on the host, at least ``min(want, n)`` wide."""
         B = qs_host.shape[0]
-        ndev = snap.mesh.shape[snap.shard_axis]
-        n_local = snap.sharded_mat.shape[0] // ndev
-        want = min(want, snap.n)
-        k = min(n_local, _round_up_pow2(max(want, 16)))
-        k_final = min(ndev * k, _round_up_pow2(max(want, 16)))
         use_lut = self.lsh is not None and snap.buckets is not None
+        use_excl = excluded is not None and any(e for e in excluded)
         with spans.stage("topn.upload"):
-            lut_j = (
-                jnp.asarray(self._build_lut(qs_host))
-                if use_lut
-                else jnp.zeros((B, 1), dtype=bool)
-            )
-            use_excl = excluded is not None and any(e for e in excluded)
-            excl = jnp.asarray(
-                self._excluded_indices(snap, excluded, B)
-                if use_excl
-                else np.full((B, 1), -1, dtype=np.int32)  # fixed shard_map arity
-            )
-            qs = jnp.asarray(qs_host)
+            # batch-shaped operands go from the host to every device at once
+            # (not to one device and on from there inside the dispatch)
+            everywhere = replicated_sharding(snap.mesh)
+            args = [snap.score_mat, jax.device_put(qs_host, everywhere)]
+            if use_excl:
+                args.append(jax.device_put(
+                    self._excluded_indices(snap, excluded, B), everywhere))
+            if use_lut:
+                args += [jax.device_put(self._build_lut(qs_host), everywhere),
+                         snap.buckets]
         with spans.stage("topn.dispatch"):
-            fn = _sharded_top_k_fn(
-                snap.mesh, snap.shard_axis, k, k_final, snap.n, use_lut,
-                use_excl,
-            )
-            vals, idx = fn(snap.sharded_mat, qs, excl, lut_j,
-                           snap.sharded_buckets)
+            fn = self._sharded_program(snap, want, use_lut, use_excl)
+            if (cost_key is not None
+                    and cost_key not in snap.cost_keys_attempted
+                    and metrics_mod.default_registry().enabled):
+                # as on one chip: the first use of a signature shares its
+                # compile with the cost registration
+                snap.cost_keys_attempted.add(cost_key)
+                compilecache.aot_compile(fn, *args, cost_key=cost_key)
+            vals, idx = fn(*args)
+            if cost_key is not None:
+                profiling.costs().record(cost_key)
         with spans.stage("topn.wait_download"):
             return np.asarray(vals), np.asarray(idx)
+
+    def _sharded_program(self, snap: _YSnapshot, want: int, use_lut: bool,
+                         use_excl: bool):
+        """The jitted mesh scan for ``want`` results a query: each shard
+        keeps ``k`` candidates (a pow2 ≥ 16, as the one-chip program's
+        width), the merge ``k_final``."""
+        ndev = snap.mesh.shape[snap.shard_axis]
+        n_local = snap.n_rows // ndev
+        width = _round_up_pow2(max(min(want, snap.n), 16))
+        k = min(n_local, width)
+        return _sharded_top_k_fn(
+            snap.mesh, snap.shard_axis, k, min(ndev * k, width), snap.n,
+            use_lut, use_excl,
+        )
 
     def top_n(
         self,
@@ -968,7 +1049,7 @@ class ALSServingModel(ServingModel):
                 snap, q_host, how_many, offset, allowed, rescore, excluded
             )
         want = how_many + offset
-        if snap.sharded_mat is not None:
+        if snap.mesh is not None:
             k = want if allowed is None and rescore is None else max(4 * want, 64)
             while True:
                 vals, idx = self._sharded_query(
@@ -1080,22 +1161,47 @@ class ALSServingModel(ServingModel):
             return self._quant_top_n_batch(
                 snap, qs_host, how_many, alloweds, excluded, filtering
             )
-        if snap.sharded_mat is not None and not filtering:
-            # sharded scan: calls are attributed (cost accounting counts
-            # them) but no per-call cost is registered for the multi-shard
-            # program — the calls-without-flops gap stays visible
-            profiling.costs().record(
-                f"als.top_n_batch/b{len(qs_host)}+sharded"
-            )
-            vals, idx = self._sharded_query(snap, qs_host, how_many, excluded)
-            with spans.stage("topn.ids"):
-                return _id_lists(snap.ids, vals, idx, how_many)
-        # the stages of one call, end to end (docs/observability.md): what
-        # the coalescer's device-call span is made of on this side
+        use_excl = excluded is not None and any(e for e in excluded)
         masked = self.lsh is not None and snap.buckets is not None
+        if snap.mesh is not None:
+            # the mesh scan has the stages of the one-chip call and a cost
+            # key of its own (its per-call cost is a shard's, plus the merge)
+            k = min(snap.n, _round_up_pow2(
+                max(2 * how_many, 64) if filtering else max(how_many, 16)))
+            vals, idx = self._sharded_query(
+                snap, qs_host, k, excluded,
+                cost_key=_topn_cost_key(len(qs_host), use_excl) + "+sharded",
+            )
+        else:
+            vals, idx, k = self._one_device_scan(
+                snap, qs_host, how_many, excluded, use_excl, masked,
+                filtering)
+        with spans.stage("topn.ids"):
+            if not filtering:
+                return _id_lists(snap.ids, vals, idx, how_many)
+            out = []
+            for b in range(len(query_vecs)):
+                allowed = alloweds[b] if alloweds else None
+                got = self._collect(
+                    snap, vals[b], idx[b], how_many, allowed, None)[:how_many]
+                if len(got) < how_many and k < snap.n:
+                    # heavy filtering consumed this query's candidates —
+                    # fall back to the widening single-query path
+                    got = self.top_n(
+                        qs_host[b], how_many, 0, allowed, None,
+                        excluded=excluded[b] if excluded else None,
+                    )
+                out.append(got)
+            return out
+
+    def _one_device_scan(self, snap: _YSnapshot, qs_host: np.ndarray,
+                         how_many: int, excluded, use_excl: bool,
+                         masked: bool, filtering: bool):
+        """The stages of one call on one device, end to end
+        (docs/observability.md): what the coalescer's device-call span is
+        made of on this side. Returns ``(vals, idx, k)`` on the host."""
         with spans.stage("topn.upload"):
             qs = jnp.asarray(qs_host)
-            use_excl = excluded is not None and any(e for e in excluded)
             excl = (
                 jnp.asarray(
                     self._excluded_indices(snap, excluded, len(qs_host)))
@@ -1134,24 +1240,7 @@ class ALSServingModel(ServingModel):
         with spans.stage("topn.wait_download"):
             # the program's run and the copy back: the first conversion
             # blocks until the device is done
-            vals, idx = np.asarray(vals), np.asarray(idx)
-        with spans.stage("topn.ids"):
-            if not filtering:
-                return _id_lists(snap.ids, vals, idx, how_many)
-            out = []
-            for b in range(len(query_vecs)):
-                allowed = alloweds[b] if alloweds else None
-                got = self._collect(
-                    snap, vals[b], idx[b], how_many, allowed, None)[:how_many]
-                if len(got) < how_many and k < snap.n:
-                    # heavy filtering consumed this query's candidates —
-                    # fall back to the widening single-query path
-                    got = self.top_n(
-                        qs_host[b], how_many, 0, allowed, None,
-                        excluded=excluded[b] if excluded else None,
-                    )
-                out.append(got)
-            return out
+            return np.asarray(vals), np.asarray(idx), k
 
     def _quant_top_n_batch(
         self, snap: _QuantSnapshot, qs_host: np.ndarray, how_many: int,
@@ -1267,10 +1356,28 @@ class ALSServingModel(ServingModel):
                     cost_key=keys[1],
                 )
             snap.cost_keys_attempted.update(keys)
-        elif snap.sharded_mat is not None:
-            # the sharded scan builds its program through the lru-cached
-            # _sharded_top_k_fn; the executions below compile it off-path
-            pass
+        elif snap.mesh is not None:
+            # the mesh scan's ladder: the same two families under the
+            # sharded cost keys, compiled against Y's shards as they lie
+            use_lut = self.lsh is not None and snap.buckets is not None
+            everywhere = replicated_sharding(snap.mesh)
+
+            def struct(like):
+                return jax.ShapeDtypeStruct(
+                    like.shape, like.dtype, sharding=everywhere)
+
+            lut = ((jax.ShapeDtypeStruct(
+                (batch_size, self.lsh.num_buckets), jnp.bool_,
+                sharding=everywhere), snap.buckets) if use_lut else ())
+            for use_excl in (False, True):
+                key = _topn_cost_key(batch_size, use_excl) + "+sharded"
+                compilecache.aot_compile(
+                    self._sharded_program(snap, how_many, use_lut, use_excl),
+                    snap.score_mat, struct(qs_struct),
+                    *((struct(excl_struct),) if use_excl else ()), *lut,
+                    cost_key=key,
+                )
+                snap.cost_keys_attempted.add(key)
         elif self.lsh is None or snap.buckets is None:
             k = min(snap.n, _round_up_pow2(max(how_many, 16)))
             compilecache.aot_compile(
@@ -1297,7 +1404,7 @@ class ALSServingModel(ServingModel):
                 lut_struct, snap.buckets, excl_struct, k,
                 cost_key=_topn_cost_key(batch_size, True),
             )
-        if snap.sharded_mat is None and not isinstance(
+        if snap.mesh is None and not isinstance(
                 snap, (_QuantSnapshot, ivf_mod.IVFSnapshot)):
             # mark both signatures attempted: the lazy first-use
             # registration in _top_n_batch would otherwise re-lower and
@@ -1371,12 +1478,17 @@ class ALSServingModel(ServingModel):
             k = min(snap.n, k * 2)
 
     def _candidate_mask(self, snap: _YSnapshot, query_vec: np.ndarray):
+        """(n_rows,) bool: the rows a query may be answered from. The zero
+        rows that pad a sharded Y past its last id are never candidates."""
+        n_rows = getattr(snap, "n_rows", snap.n)
+        real = None if n_rows == snap.n else jnp.arange(n_rows) < snap.n
         if self.lsh is None or snap.buckets is None:
-            return jnp.ones(snap.n, dtype=bool)
+            return jnp.ones(snap.n, dtype=bool) if real is None else real
         candidates = self.lsh.get_candidate_indices(query_vec)
         lut = np.zeros(self.lsh.num_buckets, dtype=bool)
         lut[candidates] = True
-        return jnp.asarray(lut)[snap.buckets]
+        valid = jnp.asarray(lut)[snap.buckets]
+        return valid if real is None else valid & real
 
     @staticmethod
     def _collect(snap, vals, idx, want, allowed, rescore) -> list[tuple[str, float]]:
@@ -1399,18 +1511,16 @@ class ALSServingModel(ServingModel):
 
     def device_factor_bytes(self) -> int:
         """Bytes the current Y snapshot holds on device (f32 matrix +
-        scoring copy + norms + buckets, or the int8 slab + scales) — the
-        HBM side of the bench memory section's f32-vs-int8 comparison."""
+        scoring copy + norms + buckets, or the int8 slab + scales; summed
+        over the devices of a mesh) — the HBM side of the bench memory
+        section's f32-vs-int8 comparison."""
         snap = self.y_snapshot()
         if isinstance(snap, ivf_mod.IVFSnapshot):
             return snap.device_nbytes()
         arrays = (
             (snap.qmat, snap.qscale, snap.norms, snap.buckets)
             if isinstance(snap, _QuantSnapshot)
-            else (snap.mat,
-                  snap.score_mat if snap.score_mat is not snap.mat else None,
-                  snap.norms, snap.buckets, snap.sharded_mat,
-                  snap.sharded_buckets)
+            else snap.device_arrays()
         )
         return int(sum(
             int(getattr(a, "nbytes", 0) or 0) for a in arrays if a is not None

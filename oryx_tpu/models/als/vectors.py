@@ -33,6 +33,9 @@ tier never pins a device matrix just for fold-in solvers.
 from __future__ import annotations
 
 import collections
+import concurrent.futures
+import functools
+import os
 import threading
 import weakref
 
@@ -63,11 +66,49 @@ def configure(config) -> None:
     )
 
 
+#: Row-chunked host copies: at reference scale (20M × 250f = 20 GB) a copy
+#: into fresh memory is bound by the first touch of its pages, which a few
+#: threads take side by side (numpy drops the GIL inside each chunk's copy).
+_COPY_CHUNK_ROWS = 1 << 18
+_COPY_MIN_BYTES = 64 << 20
+_COPY_WORKERS = min(8, os.cpu_count() or 1)
+
+
+def _copy_rows(dst: np.ndarray, src: np.ndarray, rows=None) -> None:
+    """``dst[i] = src[rows[i]]`` (``src[i]`` with no ``rows``) for every row
+    of ``dst``; large copies go by chunks of rows on a few threads."""
+    n = dst.shape[0]
+
+    def fill(a, b):
+        if rows is None:
+            dst[a:b] = src[a:b]
+        else:
+            # "clip": the rows are the store's own, and numpy would buffer
+            # ``out`` whole under the default "raise"
+            np.take(src, rows[a:b], axis=0, out=dst[a:b], mode="clip")
+
+    if dst.nbytes < _COPY_MIN_BYTES or _COPY_WORKERS < 2:
+        fill(0, n)
+        return
+    with concurrent.futures.ThreadPoolExecutor(_COPY_WORKERS) as pool:
+        list(pool.map(lambda a: fill(a, min(n, a + _COPY_CHUNK_ROWS)),
+                      range(0, n, _COPY_CHUNK_ROWS)))
+
+
 def _host_gather(slab: np.ndarray, rows) -> np.ndarray:
     """One C-level gather of slab rows about to cross the host→device
     boundary — THE seam tests monkeypatch to count upload traffic (a full
-    rebuild gathers every live row; a point-update batch only its delta)."""
-    return slab[np.asarray(rows, dtype=np.int64)]
+    rebuild gathers every live row; a point-update batch only its delta).
+    A run of consecutive rows (a bulk handoff's whole order) is a plain
+    copy."""
+    rows = np.asarray(rows, dtype=np.int64)
+    out = np.empty((rows.shape[0],) + slab.shape[1:], dtype=slab.dtype)
+    if rows.size and rows[-1] - rows[0] + 1 == rows.size and (
+            rows.size == 1 or bool(np.all(np.diff(rows) == 1))):
+        _copy_rows(out, slab[rows[0]: rows[-1] + 1])
+    else:
+        _copy_rows(out, slab, rows)
+    return out
 
 
 class _IdIndex:
@@ -176,6 +217,54 @@ class _IdIndex:
         self._table[slot] = row
         self._used += 1
 
+    def add_many(self, ids, first_row: int) -> None:
+        """Bind NEW, mutually distinct ids to rows ``first_row, first_row+1,
+        …`` in one pass (caller guarantees absence and distinctness): what
+        :meth:`add` does an id at a time, with the encode, the hash and the
+        probe-table insert each done for the whole handoff at once — a
+        20M-row MODEL handoff spends seconds here, not minutes."""
+        n = len(ids)
+        if not n:
+            return
+        encs = [s.encode() for s in ids]
+        lens = np.fromiter(map(len, encs), dtype=np.int32, count=n)
+        hashes = np.fromiter(map(hash, encs), dtype=np.int64, count=n)
+        self._grow_rows(first_row + n)
+        rows = slice(first_row, first_row + n)
+        self._starts[rows] = (len(self._blob)
+                              + np.cumsum(lens, dtype=np.int64) - lens)
+        self._lens[rows] = lens
+        self._hashes[rows] = hashes & 0x7FFFFFFFFFFFFFFF
+        self._blob.extend(b"".join(encs))
+        size = self._table.shape[0]
+        while (self._used + self._tombstones + n) * 3 > size * 2:
+            size *= 2
+        placing = np.arange(first_row, first_row + n, dtype=np.int32)
+        if size != self._table.shape[0]:
+            placing = np.concatenate(
+                [self._table[self._table >= 0], placing])
+            self._table = np.full(size, -1, dtype=np.int32)
+            self._tombstones = 0
+        self._place(placing)
+        self._used += n
+
+    def _place(self, rows: np.ndarray) -> None:
+        """Linear-probe insert of ``rows`` (none in the table yet), all at
+        once: each round every unplaced row writes itself into its slot if
+        the slot is EMPTY — of several claimants one write stays — and the
+        rows that do not find themselves there move one slot on. A row only
+        ever moves past an occupied slot, so every probe path stays
+        unbroken, as :meth:`_probe` needs."""
+        table = self._table
+        mask = table.shape[0] - 1
+        slots = self._hashes[rows] & mask
+        while rows.size:
+            free = table[slots] == -1
+            table[slots[free]] = rows[free]
+            left = table[slots] != rows
+            rows = rows[left]
+            slots = (slots[left] + 1) & mask
+
     def delete(self, id_: str) -> int:
         """Unbind ``id_``; returns its row or −1. Blob bytes stay until a
         structural compaction rebuilds the index."""
@@ -205,13 +294,16 @@ class Transition:
     buffers in HBM — once every consumer drops a generation, the chain
     through it simply breaks and the consumer falls back to a full rebuild."""
 
-    __slots__ = ("prev_ref", "new_ref", "changed_idx", "n_new")
+    __slots__ = ("prev_ref", "new_ref", "changed_idx", "n_new", "n_prev")
 
-    def __init__(self, prev_mat, new_mat, changed_idx: np.ndarray, n_new: int):
+    def __init__(self, prev_mat, new_mat, changed_idx: np.ndarray, n_new: int,
+                 n_prev: int):
         self.prev_ref = weakref.ref(prev_mat)
         self.new_ref = weakref.ref(new_mat)
         self.changed_idx = changed_idx
         self.n_new = n_new
+        # ids in prev_mat: its row count, less the padding of a sharded one
+        self.n_prev = n_prev
 
 
 class HostDelta:
@@ -237,9 +329,38 @@ class HostDelta:
         # place and every row-moving change is structural)
 
 
+@functools.lru_cache(maxsize=8)
+def _sharded_row_ops(sharding):
+    """(scatter, append) jitted under ``sharding`` (rows split over the
+    mesh): the incremental path's two device steps, each returning an array
+    split exactly like its operand. ``append`` rebuilds the row blocks for a
+    new padded row count — the partitioner moves rows between neighbours;
+    ``n_old`` (the real rows kept) is static."""
+    import jax
+    import jax.numpy as jnp
+
+    @functools.partial(jax.jit, out_shardings=sharding)
+    def scatter(mat, idx, vals):
+        return mat.at[idx].set(vals)
+
+    @functools.partial(jax.jit, out_shardings=sharding,
+                       static_argnames=("n_old",))
+    def append(mat, tail, n_old: int):
+        return jnp.concatenate([mat[:n_old], tail])
+
+    return scatter, append
+
+
 class FeatureVectorStore:
-    def __init__(self, initial_rows: "int | None" = None):
+    def __init__(self, initial_rows: "int | None" = None, mesh=None,
+                 shard_axis: str = "model"):
         self._initial_rows = initial_rows or _DEFAULT_INITIAL_ROWS
+        # device materialization split by rows over ``shard_axis`` of
+        # ``mesh``: every device holds only its own row block (the matrix
+        # then has mesh.padded_rows(n) rows, zero past the last id). None
+        # keeps the one-device materialization exactly as it was.
+        self._mesh = mesh
+        self._shard_axis = shard_axis
         self._lock = AutoReadWriteLock()
         # -- the arena ------------------------------------------------------
         self._slab: "np.ndarray | None" = None  # (capacity, k) float32
@@ -444,10 +565,9 @@ class FeatureVectorStore:
                 k = matrix.shape[1]
                 cap = max(self._initial_rows, self._reserve_rows, len(ids), 1)
                 self._slab = np.zeros((cap, k), dtype=np.float32)
-                self._slab[: len(ids)] = matrix
+                _copy_rows(self._slab[: len(ids)], matrix)
                 self._ids = _IdIndex(cap)
-                for i, id_ in enumerate(ids):
-                    self._ids.add(id_, i)
+                self._ids.add_many(ids, 0)
                 self._rowmap = np.arange(len(ids), dtype=np.int32)
                 self._pos_of_row = np.zeros(cap, dtype=np.int32)
                 self._pos_of_row[: len(ids)] = np.arange(len(ids))
@@ -651,18 +771,24 @@ class FeatureVectorStore:
                 new_vecs = vals[len(changed_rows):]
                 new_ids = self._decode_ids(new_rows)
                 prev_mat = self._cached_matrix
-                mat = prev_mat
-                if changed_idx:
-                    mat = mat.at[jnp.asarray(changed_idx, dtype=jnp.int32)].set(
-                        jnp.asarray(changed_vals)
-                    )
-                if new_ids:
-                    mat = jnp.concatenate([mat, jnp.asarray(new_vecs)])
+                if self._mesh is None:
+                    mat = prev_mat
+                    if changed_idx:
+                        mat = mat.at[
+                            jnp.asarray(changed_idx, dtype=jnp.int32)
+                        ].set(jnp.asarray(changed_vals))
+                    if new_ids:
+                        mat = jnp.concatenate([mat, jnp.asarray(new_vecs)])
+                else:
+                    mat = self._sharded_step(
+                        prev_mat, cached_len, changed_idx, changed_vals,
+                        new_vecs)
                 # new list: snapshots holding the previous ids list stay valid
                 ids = self._cached_ids + new_ids
                 self._transitions.append(Transition(
                     prev_mat, mat,
                     np.asarray(changed_idx, dtype=np.int64), len(new_ids),
+                    cached_len,
                 ))
                 self._cached_ids = ids
                 self._cached_matrix = mat
@@ -685,7 +811,7 @@ class FeatureVectorStore:
             )
         dec = index.decode
         ids = [dec(int(r)) for r in rows]
-        mat = jnp.asarray(host) if host.size else None
+        mat = self._to_device(host) if host.size else None
         with self._cache_lock:
             if version > self._cached_version:
                 self._cached_ids = ids
@@ -693,6 +819,55 @@ class FeatureVectorStore:
                 self._cached_version = version
                 self._transitions.clear()
             return self._cached_ids, self._cached_matrix
+
+    def _to_device(self, host: np.ndarray):
+        """The full rebuild's upload. One device: one array, as ever. With a
+        mesh: each device receives only its own row block from ``host``."""
+        import jax.numpy as jnp
+
+        if self._mesh is None:
+            return jnp.asarray(host)
+        from oryx_tpu.common import spans
+        from oryx_tpu.parallel.mesh import put_row_sharded
+
+        with spans.span(
+            "snapshot.shard_upload",
+            attributes={"bytes": int(host.nbytes),
+                        "devices": int(self._mesh.shape[self._shard_axis])},
+        ):
+            mat = put_row_sharded(host, self._mesh, self._shard_axis)
+            mat.block_until_ready()  # the span times the copies, not the enqueue
+        return mat
+
+    def _sharded_step(self, prev_mat, n_old: int, changed_idx, changed_vals,
+                      new_vecs):
+        """The incremental step under the mesh's row sharding: changed rows
+        scattered, new rows written into the zero padding while it lasts
+        (the shape, and every program compiled for it, stays) or appended
+        under a new padded row count. Never leaves the sharding."""
+        import jax.numpy as jnp
+
+        from oryx_tpu.parallel.mesh import padded_rows, row_sharding
+
+        scatter, append = _sharded_row_ops(
+            row_sharding(self._mesh, self._shard_axis))
+        idx = list(changed_idx)
+        vals = changed_vals
+        n_new = len(new_vecs)
+        mat = prev_mat
+        if n_new and n_old + n_new <= prev_mat.shape[0]:
+            idx = idx + list(range(n_old, n_old + n_new))
+            vals = np.concatenate([changed_vals, new_vecs])
+            n_new = 0
+        if idx:
+            mat = scatter(mat, jnp.asarray(idx, dtype=jnp.int32),
+                          jnp.asarray(vals))
+        if n_new:
+            rows = padded_rows(n_old + n_new, self._mesh, self._shard_axis)
+            tail = np.zeros((rows - n_old, new_vecs.shape[1]), np.float32)
+            tail[:n_new] = new_vecs
+            mat = append(mat, jnp.asarray(tail), n_old=n_old)
+        return mat
 
     def delta_since(self, from_mat, to_mat) -> "tuple[np.ndarray, int] | None":
         """Compose the recorded incremental steps from ``from_mat`` up to
@@ -713,7 +888,7 @@ class FeatureVectorStore:
         # previous step's output, and a full rebuild clears the log), so
         # intermediate generations need no liveness check — only the two
         # endpoints, which the caller holds alive, anchor the walk
-        n_base = from_mat.shape[0]
+        n_base = chain[start].n_prev
         changed: set = set()
         n_new = 0
         for t in chain[start:]:

@@ -68,3 +68,46 @@ def make_mesh(n_devices: int | None = None, axes: tuple[str, ...] = ("data",), s
         shape = (n_devices,) + (1,) * (len(axes) - 1)
     dev_array = np.asarray(devices[: int(np.prod(shape))]).reshape(shape)
     return jax.sharding.Mesh(dev_array, axes)
+
+
+def row_sharding(mesh, axis: str = "model"):
+    """NamedSharding that splits an array's rows (dim 0) over ``axis``."""
+    import jax
+
+    return jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(axis))
+
+
+def replicated_sharding(mesh):
+    """NamedSharding that puts an array whole on every device of ``mesh``."""
+    import jax
+
+    return jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+
+
+def padded_rows(n_rows: int, mesh, axis: str = "model") -> int:
+    """``n_rows`` rounded up to a multiple of the shard count of ``axis``."""
+    shards = mesh.shape[axis]
+    return -(-n_rows // shards) * shards
+
+
+def put_row_sharded(host: np.ndarray, mesh, axis: str = "model"):
+    """Row-split a host array over ``axis`` WITHOUT staging it on any one
+    device: each device is handed its own row block straight from ``host``
+    (rows past the end, up to the shard count, are zero padding made on the
+    host side of the copy). The global array has :func:`padded_rows` rows."""
+    import jax
+
+    n = host.shape[0]
+    shape = (padded_rows(n, mesh, axis),) + host.shape[1:]
+
+    def block(index):
+        rows = index[0]
+        lo, hi = rows.start or 0, shape[0] if rows.stop is None else rows.stop
+        if hi <= n:
+            return host[lo:hi]
+        out = np.zeros((hi - lo,) + host.shape[1:], dtype=host.dtype)
+        if lo < n:
+            out[: n - lo] = host[lo:n]
+        return out
+
+    return jax.make_array_from_callback(shape, row_sharding(mesh, axis), block)

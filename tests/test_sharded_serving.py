@@ -100,7 +100,7 @@ def test_sharded_lsh_masks_on_device():
     got = model.top_n_batch(queries, 6)
     assert model.lsh is not None and model.lsh.num_hashes > 0
     snap = model.y_snapshot()
-    assert snap.sharded_mat is not None  # really took the sharded path
+    assert snap.mesh is not None  # really took the sharded path
     buckets = np.asarray(snap.buckets)
     for b, res in enumerate(got):
         assert res, "LSH-masked sharded scan returned nothing"
@@ -137,7 +137,7 @@ def test_sharded_snapshot_tracks_point_updates():
     got = sharded.top_n(q, 3)
     assert got[0][0] == "i300", (base, got)
     snap = sharded.y_snapshot()
-    assert snap.sharded_mat is not None  # still the multi-device scan
+    assert snap.mesh is not None  # still the multi-device scan
     # appended NEW item also lands in the sharded scan
     sharded.set_item_vector("fresh", (winner_vec * 2).astype(np.float32))
     got2 = sharded.top_n(q, 3)
