@@ -388,10 +388,11 @@ oryx = {
       # the most that stays inside the limit above with margin.
       scoped-budget-bytes = 3145728
       # Resident-state budget for accumulator kernels whose output blocks
-      # stay VMEM-resident across grid steps (the gather-Gramian shape);
-      # 1.5 MB ratifies _GG_MAX_FEATURES = 256 exactly
-      # (docs/static_analysis.md "Pallas kernel family").
-      resident-budget-bytes = 1572864
+      # stay VMEM-resident across grid steps (the gather-Gramian shape):
+      # the kernel's own footprint at _GG_MAX_FEATURES = 256 and T = 512,
+      # two gather buffers included (docs/static_analysis.md "Pallas
+      # kernel family").
+      resident-budget-bytes = 1583104
     }
   }
 

@@ -104,6 +104,10 @@ class _BlockedSide:
     # hold them (every other slot of the (n_blocks, S) grid is block padding)
     entries: int = 0
     real_slots: int = 0
+    # ... and those of them whose rows the fused kernel fetches under the
+    # slot before's matmuls: all but the first of each block's call, which
+    # is the pipeline's prologue
+    prefetched_slots: int = 0
 
     @property
     def padded_rows(self) -> int:
@@ -304,7 +308,16 @@ def make_blocked_side(
         jnp.asarray(slens), n_rows, block, n_blocks, t, slot_chunk,
         np_slabs=(srows, scols, svals, slens) if keep_np else None,
         entries=len(r), real_slots=total_slots,
+        prefetched_slots=_prefetched_slots(slens),
     )
+
+
+def _prefetched_slots(slens: np.ndarray) -> int:
+    """Non-empty slots less the first non-empty slot of each block: the
+    fused kernel starts a slot's row copies one grid step early, so every
+    slot but the first of a call gathers under its predecessor's matmuls."""
+    full = slens > 0
+    return int(full.sum()) - int(full.any(axis=1).sum())
 
 
 def _entry_weights(svals, slens, alpha, implicit, t):
@@ -422,6 +435,7 @@ def _delta_blocked_side(
         jnp.asarray(slens), n_rows, block, n_blocks, t, chunk,
         np_slabs=(srows, scols, svals, slens),
         entries=len(rows), real_slots=total_slots,
+        prefetched_slots=_prefetched_slots(slens),
     )
 
 
@@ -901,11 +915,13 @@ def _log_gather_rows(name: str, side: _BlockedSide, fused: bool) -> None:
     logging.getLogger(__name__).info(
         "slotted COO %s side: %d entries in %d slots of T=%d (+%d of block "
         "padding); the gather moves %.3f factor rows an entry (%s), %.3f if "
-        "every slot were copied to its width",
+        "every slot were copied to its width; %.3f%% of the slots are "
+        "fetched under the slot before (the rest open a block's call)",
         name, side.entries, side.real_slots, side.slot_width,
         int(side.srows.size) - side.real_slots, now,
         "fused kernel: each slot to its own length" if fused
         else "einsum: every cell of every slot", before,
+        100.0 * side.prefetched_slots / max(1, side.real_slots),
     )
 
 

@@ -26,23 +26,41 @@ three HBM round-trips per scan chunk while the MXU idles (measured MFU
 0.15%: the loop is gather-bandwidth-bound, and bf16 inputs buy only 17%).
 Here each factor row crosses HBM exactly once:
 
-  grid = slots; per step:  DMA-gather the slot's factor rows → VMEM: one
-                           copy an ENTRY, to the slot's own length (a
-                           scalar-prefetched ``slens``), none for the
-                           padding of its last cells; all of them started
-                           before the first wait, on one semaphore (rows
-                           are column-sorted within the slot, so the gather
-                           walks HBM in address order)
-                           Gramian (k,T)·(T,k) + RHS (1,T)·(T,k)   (MXU)
-                           accumulate into the slot's OWNER ROW's
-                           (1, k, k)/(1, 1, k) output block in VMEM
+  grid = slots; step i:  START the row copies of slot i+1 → the OTHER of
+                         two VMEM gather buffers, on that buffer's own
+                         semaphore: one copy an ENTRY, to the slot's own
+                         length (a scalar-prefetched ``slens``), none for
+                         the padding of its last cells, all of them started
+                         before any wait (rows are column-sorted within the
+                         slot, so the gather walks HBM in address order);
+                         step 0 starts slot 0's as well, the last step
+                         starts none
+                         WAIT for slot i's copies (started at step i−1)
+                         Gramian (k,T)·(T,k) + RHS (1,T)·(T,k)   (MXU)
+                         accumulate into the slot's OWNER ROW's
+                         (1, k, k)/(1, 1, k) output block in VMEM
 
-Scratch rows past a slot's length keep an earlier slot's rows (zeros before
-the first) and meet weight 0 in the matmuls. What paces the gather, measured
-on a v5e at the Netflix-shaped cell (PERF.md, PR 25): with 4 copies in
-flight one HBM round trip, 84 ns a copy; with a whole slot in flight the
-copy engine and the scalar core's issue loop, 9–17 ns a copy — and no
-copy at all for the 5–44% of a side's slot cells that are padding.
+A software pipeline over the slots: slot i+1's copies are in the DMA unit
+while slot i's matmuls run, where the two took turns. Slot i uses buffer and
+semaphore i % 2. Two semaphores, because a wait names no copy — a semaphore
+counts bytes — so on a shared one the rows of slot i+1 landing first would
+satisfy slot i's waits and its matmul would read rows that have not arrived.
+Slot i+1's gather indices reach step i as a second view of ``scols`` (index
+map ``min(i + 1, S − 1)``). Buffer rows past a slot's length keep an earlier
+slot's rows (zeros before the first) and meet weight 0 in the matmuls; an
+empty slot neither starts, waits nor multiplies.
+
+What paces the gather, measured on a v5e at the Netflix-shaped cell
+(PERF.md): with 4 copies in flight one HBM round trip, 84 ns a copy; with a
+whole slot in flight 2.2 ns a copy against the 9 MB item table and 15.8 ns
+against the 246 MB user table — and no copy at all for the 5–44% of a
+side's slot cells that are padding (PR 25). The pipeline hides almost none
+of that (PR 27): a half costs its copies PLUS its matmuls whichever step
+the copies are started in, to ±2% (item half 2.30 → 2.18 s, user half 2.34
+→ 2.38 s). Copies started a step early make no progress while the core
+computes — at most one round trip a slot (0.4 µs) is saved — so a start
+seems to hold the one instruction stream until the DMA unit takes the
+descriptor. Fewer copies, not better-placed ones, is what is left.
 
 Mosaic slices a DMA only along untiled (leading) dims, and a 16-bit row
 shares its 32-bit sublane word with its neighbour — so the wrapper hands the
@@ -243,15 +261,19 @@ _GG_UNROLL = 8
 # _auto_slot_width) — the kernel's resident budget is evaluated at the cap.
 _GG_SLOT_WIDTH_MAX = 512
 # Features past this would push the kernel's resident VMEM state — the
-# double-buffered (1, k, k)/(1, 1, k) accumulator blocks, the
-# (T, 1, pad128(k)) gather scratch, and the (1, 2, T) weight block — past
+# double-buffered (1, k, k)/(1, 1, k) accumulator blocks, the two
+# (T, 1, pad128(k)) gather buffers, and the (1, 2, T) weight block — past
 # the resident-state budget
-# (oryx.analyze.kernel.resident-budget-bytes, 1.5 MB); callers fall back to
-# the einsum formulation (same numerics, more HBM traffic). The value is
-# the max k whose padded footprint at T = _GG_SLOT_WIDTH_MAX fits that
-# budget, pinned against the static kernel model by
-# tests/test_kernel_differential.py so the constant can never silently
-# drift from the kernel it guards.
+# (oryx.analyze.kernel.resident-budget-bytes: 1,583,104 B, which IS that
+# footprint at k = 256, T = 512); callers fall back to the einsum
+# formulation (same numerics, more HBM traffic). The value is the max k
+# whose padded footprint at T = _GG_SLOT_WIDTH_MAX fits that budget, pinned
+# against the static kernel model by tests/test_kernel_differential.py so
+# the constant can never silently drift from the kernel it guards. The
+# budget is a discipline, not the hardware's limit: the v5e compiler takes
+# the call to k = 768 at T = 512 (tests/test_chip_smoke.py compiles the
+# gate itself), and a block at k = 250, T = 512 ran on the chip (PERF.md,
+# PR 27).
 _GG_MAX_FEATURES = 256
 # The per-slot owner rows AND valid lengths ride whole in SMEM (two
 # scalar-prefetched vectors), which the compiler caps at 1 MiB per program:
@@ -292,19 +314,39 @@ def _unrolled(n, body):
 
 
 def _make_gather_gramian_kernel(t: int, k: int, kp: int, cd):
-    def kernel(srow_ref, slen_ref, scols_ref, wc_ref, y_ref, a0_ref, b0_ref,
-               a_ref, b_ref, yg, sem):
+    def kernel(srow_ref, slen_ref, scols_ref, scols_next_ref, wc_ref, y_ref,
+               a0_ref, b0_ref, a_ref, b_ref, yg, sem):
         i = pl.program_id(0)
+        last = pl.num_programs(0) - 1
         row = srow_ref[i]
         prev_row = srow_ref[jnp.maximum(i - 1, 0)]
         n = slen_ref[i]  # the slot's valid entries: as many copies, no more
+        # the next slot's, whose copies start at THIS step; none after the
+        # last, so no copy is in flight when the call returns
+        n_next = jnp.where(i < last, slen_ref[jnp.minimum(i + 1, last)], 0)
+        buf = i % 2  # slot i gathers into buffer i % 2, on semaphore i % 2
 
-        # rows of the scratch past a slot's length keep whatever an earlier
+        def start_slot(cols_ref, count, b):
+            def start(tt):
+                # one factor row per copy, selected on y's LEADING dim (the
+                # only dim Mosaic lets a DMA slice below tile size); within
+                # a slot the column indices are ascending (pack sorts by
+                # (row, col)), so consecutive copies walk y in HBM address
+                # order
+                pltpu.make_async_copy(
+                    y_ref.at[cols_ref[0, 0, tt]], yg.at[b, tt], sem.at[b],
+                ).start()
+
+            _unrolled(count, start)
+
+        # rows of a buffer past a slot's length keep whatever an earlier
         # slot gathered there and meet weight 0 in the matmuls: they must
-        # hold finite numbers from the start (0 × NaN is NaN)
+        # hold finite numbers from the start (0 × NaN is NaN). The first
+        # slot has no step before it: its copies start here
         @pl.when(i == 0)
         def _():
             yg[...] = jnp.zeros_like(yg)
+            start_slot(scols_ref, n, 0)
 
         # first slot of a new output row: the (1, k, k)/(1, 1, k) blocks
         # just rotated in (their VMEM content is undefined) — zero before
@@ -316,32 +358,27 @@ def _make_gather_gramian_kernel(t: int, k: int, kp: int, cd):
             a_ref[...] = jnp.zeros_like(a_ref)
             b_ref[...] = jnp.zeros_like(b_ref)
 
-        # an empty slot (the pack's pad slots, whose owner is the spill row)
-        # skips the gather AND the matmuls
+        # the pipeline: slot i's copies were started a step ago, slot i+1's
+        # start NOW into the other buffer, before this slot's waits — the
+        # copy engine works on them for the whole of this slot's matmuls. An
+        # empty slot (the pack's pad slots, whose owner is the spill row)
+        # starts nothing, waits for nothing and skips the matmuls, whichever
+        # side of a pair it is on.
+        start_slot(scols_next_ref, n_next, 1 - buf)
+
         @pl.when(n > 0)
         def _():
-            def start(tt):
-                # one factor row per copy, selected on y's LEADING dim (the
-                # only dim Mosaic lets a DMA slice below tile size); within
-                # a slot the column indices are ascending (pack sorts by
-                # (row, col)), so consecutive copies walk y in HBM address
-                # order
-                pltpu.make_async_copy(
-                    y_ref.at[scols_ref[0, 0, tt]], yg.at[tt], sem.at[0],
-                ).start()
-
             def wait(tt):
-                # a wait names no copy of its own: the one semaphore counts
-                # bytes, and every copy's are one row's
-                pltpu.make_async_copy(y_ref.at[0], yg.at[0], sem.at[0]).wait()
+                # a wait names no copy of its own: a semaphore counts bytes,
+                # and every copy's are one row's. Hence one semaphore a
+                # BUFFER: on a shared one, rows of slot i+1 landing first
+                # would satisfy slot i's waits
+                pltpu.make_async_copy(
+                    y_ref.at[0], yg.at[buf, 0], sem.at[buf]).wait()
 
-            # ALL of the slot's copies are in flight before the first wait:
-            # nothing reads the scratch until the last one, so the pace is
-            # the copy engine's, not one HBM round trip per few rows
-            _unrolled(n, start)
             _unrolled(n, wait)
 
-            ygv = yg[...].reshape(t, kp)[:, :k]  # (T, k) f32
+            ygv = yg[buf].reshape(t, kp)[:, :k]  # (T, k) f32
             wc = wc_ref[0]  # (2, T): Gramian weights, RHS coefficients
             # the Gramian weights are a lane-major row and must scale the
             # gathered rows along SUBLANES: mask the broadcast row to the
@@ -394,6 +431,7 @@ def gather_gramian_accumulate(y, srow, slens, scols, w, coef, *, block: int,
     y3 = jnp.pad(y.astype(jnp.float32), ((0, 0), (0, kp - k))).reshape(
         -1, 1, kp)
     wc = jnp.stack([w, coef], axis=1)  # (S, 2, T)
+    scols3 = scols.reshape(s, 1, t)
     a0 = jnp.zeros((block + 1, k, k), jnp.float32)
     b0 = jnp.zeros((block + 1, 1, k), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -403,6 +441,10 @@ def gather_gramian_accumulate(y, srow, slens, scols, w, coef, *, block: int,
         in_specs=[
             # gather indices are DMA addresses: SMEM, one slot per step
             pl.BlockSpec((1, 1, t), lambda i, sr, sl: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            # ... and the NEXT slot's, whose copies start a step early
+            pl.BlockSpec((1, 1, t),
+                         lambda i, sr, sl: (jnp.minimum(i + 1, s - 1), 0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 2, t), lambda i, sr, sl: (i, 0, 0),
                          memory_space=pltpu.VMEM),
@@ -417,8 +459,9 @@ def gather_gramian_accumulate(y, srow, slens, scols, w, coef, *, block: int,
                          memory_space=pltpu.VMEM),
         ],
         scratch_shapes=[
-            pltpu.VMEM((t, 1, kp), jnp.float32),  # gathered factor rows
-            pltpu.SemaphoreType.DMA((1,)),
+            # gathered factor rows: this slot's and the next slot's
+            pltpu.VMEM((2, t, 1, kp), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),  # one a buffer
         ],
     )
     big_a, big_b = pl.pallas_call(
@@ -430,10 +473,9 @@ def gather_gramian_accumulate(y, srow, slens, scols, w, coef, *, block: int,
         ],
         # zero donors alias the outputs: rows no slot ever visits keep
         # exact zeros — deterministic on hardware AND under interpret
-        input_output_aliases={5: 0, 6: 1},
+        input_output_aliases={6: 0, 7: 1},
         interpret=interpret,
-    )(srow.reshape(s), slens.reshape(s), scols.reshape(s, 1, t), wc, y3,
-      a0, b0)
+    )(srow.reshape(s), slens.reshape(s), scols3, scols3, wc, y3, a0, b0)
     return big_a, big_b[:, 0, :]
 
 

@@ -69,19 +69,25 @@ VMEM_LIMIT_BYTES = 16 << 20
 SCOPED_BUDGET_BYTES = 3 << 20
 #: Resident-state budget for accumulator kernels whose output blocks stay
 #: VMEM-resident across grid steps (the gather-Gramian shape): double-
-#: buffered (k, k) accumulators + the gather scratch must leave the bulk of
-#: VMEM to the pipeline. 1.5 MB ratifies the hand-derived
-#: ``_GG_MAX_FEATURES = 256`` gate exactly (see docs/static_analysis.md
-#: "Pallas kernel family" for the evaluated math).
-RESIDENT_BUDGET_BYTES = 1536 << 10
+#: buffered (k, k) accumulators + the TWO gather buffers (this slot's rows
+#: and the next slot's, fetched under this slot's matmuls) must leave the
+#: bulk of VMEM to the pipeline. The value IS the kernel's footprint at the
+#: ``_GG_MAX_FEATURES = 256`` gate and the widest slot (T = 512): 1,583,104
+#: B, so anything added to the kernel fails the drift gate until both sides
+#: are re-derived. Not a hardware limit: compiled for a described v5e the
+#: call is accepted to k = 768 at T = 512 and refused at 1,024 (17.47 MiB
+#: of the 16 MiB scoped limit; PERF.md, PR 27). See docs/static_analysis.md
+#: "Pallas kernel family" for the evaluated math.
+RESIDENT_BUDGET_BYTES = 1_583_104
 #: The SMEM one program may use (1 MiB on a v5e, from the compiler's own
 #: "Used 1.00M of 1.00M smem" message), and the share of it that
 #: scalar-prefetched operands — which ride there WHOLE, whatever the grid —
 #: may take: three quarters, the rest left to the double-buffered SMEM
 #: blocks and the compiler's own scalars. ``_GG_MAX_SLOTS`` is this budget
 #: over the gather-Gramian call's two prefetched words a slot. Compiled for
-#: a described v5e the call fits to 130,048 slots and overruns by 5.1 KB at
-#: 131,072 (T = 512): the quarter is margin, not need.
+#: a described v5e the call fits at 129,024 slots and overruns by 1.1 KB at
+#: 130,048 and 9.1 KB at 131,072 (T = 512, two double-buffered index
+#: blocks: PR 27): the quarter is margin, not need.
 SMEM_LIMIT_BYTES = 1 << 20
 SMEM_PREFETCH_BUDGET_BYTES = 768 << 10
 

@@ -11,9 +11,9 @@ of that proof that need no chip:
     nothing is published, every layer exits clean: exactly the failure a
     first chip run would otherwise not show);
   * every Pallas kernel and the TPU-default trainer half-iteration LOWER for
-    ``("tpu",)`` at k=50 and k=250, so a block shape the TPU lowering
-    refuses — what stopped the trainer at the parent of this PR — fails
-    here without a chip.
+    ``("tpu",)`` at k=50, k=250 and the gather kernel's gate k=256, so a
+    block shape the TPU lowering refuses — what stopped the trainer at the
+    parent of this PR — fails here without a chip.
 
 Each smoke run is a fresh process with ``JAX_ENABLE_X64`` removed: the
 program runs with it off and the chip has no float64, whatever conftest
@@ -134,7 +134,7 @@ def _program_dtypes():
         yield
 
 
-@pytest.mark.parametrize("k", [50, 250])
+@pytest.mark.parametrize("k", [50, 250, 256])
 def test_every_kernel_and_the_tpu_half_iteration_lower_for_tpu(
         k, _program_dtypes):
     from oryx_tpu.models.als import train as tr
@@ -143,7 +143,8 @@ def test_every_kernel_and_the_tpu_half_iteration_lower_for_tpu(
     f32 = jnp.float32
     for dtype in (jnp.float32, jnp.bfloat16):
         # the pack's narrowest slot, one below the copy loops' trip, the
-        # Netflix cell's two widths
+        # Netflix cell's two widths; k = 256 at T = 512 is the gate itself,
+        # where the two gather buffers are largest
         for t in (8, 128, 256, 512):
             s, block = 64, 32
             text = _lower_tpu(
@@ -214,7 +215,11 @@ def half(k, n_blocks, block, s, t, dtype):
 # the smoke's own user side (13 blocks of 7693 rows, T=32): a batch large
 # enough that the SPD kernel's scoped VMEM is what production allocates
 half(50, 13, 7693, 10240, 32, "float32")
-half(256, 2, 1000, 2048, 512, "bfloat16")  # both kernels' last supported width
+# both kernels' last supported width, at the widest slot: the gather
+# kernel's two (512, 1, 256) buffers and both accumulator blocks — the
+# footprint the resident budget is ratified at
+half(256, 2, 1000, 2048, 512, "bfloat16")
+half(256, 2, 1000, 2048, 512, "float32")
 # the Netflix cell's two sides as the pack shapes them: the item side's
 # 72,594 slots a block of T=512 (owner rows AND slot lengths whole in SMEM),
 # the user side's T=256; then 250 features (a 1 KB row a copy) at both

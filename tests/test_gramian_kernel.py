@@ -135,6 +135,150 @@ def test_supported_gate():
     assert np.isfinite(out).all()
 
 
+# ---------------------------------------------------------------------------
+# the software pipeline: slot i+1's rows are fetched under slot i's matmuls
+# ---------------------------------------------------------------------------
+
+# (T, [(owner row or None = the spill row, valid length), ...]): packs chosen
+# for the pipeline's edges. Every slot gathers its own, disjoint rows of y.
+_PIPELINE_CASES = {
+    "one-slot": (8, [(0, 5)]),
+    "two-slots": (8, [(0, 8), (0, 3)]),
+    "only-empty-slots": (8, [(None, 0), (None, 0)]),
+    "empty-first": (8, [(0, 0), (1, 8), (1, 2)]),
+    "empty-last": (8, [(0, 8), (1, 4), (None, 0)]),
+    "empty-between-rows": (8, [(0, 8), (None, 0), (2, 8)]),
+    "empty-between-same-row": (8, [(0, 8), (0, 0), (0, 5)]),
+    "two-empty-between": (8, [(0, 3), (None, 0), (None, 0), (4, 8)]),
+    "owner-changes-every-slot": (8, [(0, 8), (1, 8), (2, 8), (5, 8)]),
+    "length-1-beside-length-T": (16, [(0, 1), (0, 16), (1, 16), (2, 1),
+                                      (3, 1)]),
+    # THE hazard: a long slot's copies in flight with the short next slot's.
+    # On one shared semaphore the short slot's row landing first satisfies a
+    # wait of the long slot, whose matmul then reads rows that have not
+    # arrived; reading the other buffer gives the neighbour's rows
+    "hazard-long-then-short": (16, [(0, 16), (1, 1), (2, 16), (3, 1),
+                                    (3, 16)]),
+}
+
+
+def _pipeline_pack(case: str, k: int = 8):
+    """Integer-valued operands (every product and sum exact in float32, so
+    bit-equality holds whatever order a matmul sums in); padding columns
+    name a row of NaN; slot i gathers rows [i·T, i·T + n) of y."""
+    t, slots = _PIPELINE_CASES[case]
+    block = 8
+    rng = np.random.default_rng(sorted(_PIPELINE_CASES).index(case))
+    s = len(slots)
+    srow = np.array([block if r is None else r for r, _ in slots], np.int32)
+    slens = np.array([n for _, n in slots], np.int32)
+    n_opp = s * t
+    valid = np.arange(t)[None, :] < slens[:, None]
+    scols = np.where(valid, np.arange(n_opp).reshape(s, t), n_opp).astype(
+        np.int32)
+    w = (rng.integers(1, 4, (s, t)) * valid).astype(np.float32)
+    coef = (rng.integers(-3, 4, (s, t)) * valid).astype(np.float32)
+    y = rng.integers(-4, 5, (n_opp + 1, k)).astype(np.float32)
+    y[n_opp] = np.nan
+    return block, t, y, srow, slens, scols, w, coef
+
+
+def _slot_by_slot(block, t, y, srow, slens, scols, w, coef):
+    """Plain numpy, in the kernel's order: two gather buffers zeroed once,
+    slot i copies its first ``slens[i]`` rows into buffer i % 2 (rows past
+    them keep what slot i − 2 left and meet weight 0), then accumulates
+    into its owner row; an empty slot does nothing."""
+    k = y.shape[1]
+    bufs = np.zeros((2, t, k), np.float32)
+    big_a = np.zeros((block + 1, k, k), np.float32)
+    big_b = np.zeros((block + 1, k), np.float32)
+    for i, n in enumerate(slens):
+        if n == 0:
+            continue
+        buf = bufs[i % 2]
+        buf[:n] = y[scols[i, :n]]
+        big_a[srow[i]] += (buf * w[i][:, None]).T @ buf
+        big_b[srow[i]] += coef[i] @ buf
+    return big_a, big_b
+
+
+def _dma_on_wait():
+    """The TPU interpreter with copies executed only when a wait needs them,
+    LATEST started first: a wait that another slot's copies can satisfy
+    leaves this slot's rows unwritten, as the chip may."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                 detect_races=True)
+
+
+def _run_kernel(fn, pack, interpret):
+    block, _, y, srow, slens, scols, w, coef = pack
+    big_a, big_b = jax.jit(
+        lambda *a: fn(*a, block=block, interpret=interpret)
+    )(jnp.asarray(y), jnp.asarray(srow), jnp.asarray(slens),
+      jnp.asarray(scols), jnp.asarray(w), jnp.asarray(coef))
+    return np.asarray(big_a), np.asarray(big_b)
+
+
+@pytest.mark.parametrize("mode", ["interpret", "dma-on-wait"])
+@pytest.mark.parametrize("case", list(_PIPELINE_CASES))
+def test_pipeline_edges_bit_equal_to_slot_by_slot_numpy(case, mode):
+    """A copy is started in one grid step and waited for in the next: the
+    two buffers and both semaphores carry over, the first step starts two
+    slots' copies, the last starts none, an empty slot neither starts nor
+    waits — and every output is the slot-by-slot accumulation's to the
+    bit, under the plain interpreter and under the TPU interpreter's
+    late, out-of-order copies (which also reports no race)."""
+    pack = _pipeline_pack(case)
+    ra, rb = _slot_by_slot(*pack)
+    interpret = True if mode == "interpret" else _dma_on_wait()
+    big_a, big_b = _run_kernel(gather_gramian_accumulate, pack, interpret)
+    np.testing.assert_array_equal(big_a, ra)
+    np.testing.assert_array_equal(big_b, rb)
+    if mode == "dma-on-wait":
+        from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+
+        assert not interpret_pallas_call.races.races_found
+
+
+_PLANTED_FAULTS = {
+    # every copy of both buffers on semaphore 0, as the single-buffer kernel
+    # had it: shows only where copies run late and out of order
+    "one-semaphore": ([("sem.at[b],", "sem.at[0],"),
+                       ("sem.at[buf]).wait()", "sem.at[0]).wait()")], True),
+    # the matmul reads the buffer the NEXT slot is landing in
+    "wrong-buffer": ([("ygv = yg[buf]", "ygv = yg[1 - buf]")], False),
+}
+
+
+@pytest.mark.parametrize("fault", list(_PLANTED_FAULTS))
+def test_pipeline_hazard_case_catches_a_planted_fault(fault):
+    """The hazard case has teeth: the kernel's own source with the fault
+    planted gives ANOTHER answer on it. (A wait on the other buffer's
+    semaphore alone never returns under the TPU interpreter — nothing
+    signals it — so it is the shared semaphore that is planted.)"""
+    import inspect
+    import types
+
+    from oryx_tpu.ops import pallas_kernels as pk
+
+    edits, late_copies = _PLANTED_FAULTS[fault]
+    source = inspect.getsource(pk)
+    for old, new in edits:
+        assert source.count(old) == 1, "the kernel moved: re-aim the fault"
+        source = source.replace(old, new)
+    faulty = types.ModuleType("pallas_kernels_faulty")
+    exec(compile(source, "pallas_kernels_faulty.py", "exec"),
+         faulty.__dict__)
+    pack = _pipeline_pack("hazard-long-then-short")
+    ra, rb = _slot_by_slot(*pack)
+    big_a, big_b = _run_kernel(faulty.gather_gramian_accumulate, pack,
+                               _dma_on_wait() if late_copies else True)
+    assert not (np.array_equal(big_a, ra, equal_nan=True)
+                and np.array_equal(big_b, rb, equal_nan=True))
+
+
 @pytest.mark.parametrize("fused", [True, False])
 def test_gather_rows_counted_as_issued(fused):
     """The pack counts its entries and the slots that hold them; the
@@ -150,6 +294,12 @@ def test_gather_rows_counted_as_issued(fused):
         slens = np.asarray(side.slens)
         assert side.entries == nnz == int(slens.sum())
         assert side.real_slots == int((slens > 0).sum())
+        # every slot but the first of each block's call is fetched under
+        # the slot before it; blocks of 64 rows: a short pipeline, and the
+        # share says so
+        assert side.prefetched_slots == side.real_slots - int(
+            (slens > 0).any(axis=1).sum())
+        assert 0 < side.prefetched_slots < side.real_slots
         before, now = side.gather_rows_per_entry(fused)
         assert before == side.real_slots * side.slot_width / nnz > 1.0
         assert now == (1.0 if fused else side.scols.size / nnz)
@@ -165,6 +315,25 @@ def test_gather_rows_counted_as_issued(fused):
         assert nbytes == side.gather_rows(fused) * k * 4.0 + writes
 
 
+def test_pack_logs_the_share_of_slots_fetched_under_a_predecessor(caplog):
+    """What tells a reader of a small-block deployment that its pipeline is
+    mostly prologue: each side's log line carries prefetched ÷ real slots.
+    One block a side here, so all but one slot of each."""
+    import logging
+
+    batch, k = _skewed_batch(9)
+    with caplog.at_level(logging.INFO, logger="oryx_tpu.models.als.train"):
+        sides = tr.prepare_blocked(batch, k, block=512)
+    lines = [r.getMessage() for r in caplog.records
+             if "slotted COO" in r.getMessage()]
+    assert len(lines) == 2
+    for side, line in zip(sides, lines):
+        assert side.n_blocks == 1
+        assert side.prefetched_slots == side.real_slots - 1
+        share = 100.0 * side.prefetched_slots / side.real_slots
+        assert f"{share:.3f}% of the slots are fetched under" in line
+
+
 # ---------------------------------------------------------------------------
 # layout cache + pack/compute overlap
 # ---------------------------------------------------------------------------
@@ -175,9 +344,9 @@ def _sides_equal(a, b) -> bool:
         np.array_equal(np.asarray(getattr(a, f)), np.asarray(getattr(b, f)))
         for f in ("srows", "scols", "svals", "slens")
     ) and (a.block, a.n_blocks, a.slot_width, a.slot_chunk, a.n_rows,
-           a.entries, a.real_slots) == (
+           a.entries, a.real_slots, a.prefetched_slots) == (
         b.block, b.n_blocks, b.slot_width, b.slot_chunk, b.n_rows,
-        b.entries, b.real_slots
+        b.entries, b.real_slots, b.prefetched_slots
     )
 
 

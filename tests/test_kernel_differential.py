@@ -315,8 +315,10 @@ def test_gg_max_slots_equals_modeled_smem_budget(ops_kernel_models):
     assert pk.gather_gramian_supported(50, 72_594)
     total = gg.smem_bytes({"s": pk._GG_MAX_SLOTS,
                            "t": pk._GG_SLOT_WIDTH_MAX})
-    # the prefetched words + the double-buffered (1, 1, T) index block
-    assert total == 2 * 4 * pk._GG_MAX_SLOTS + 2 * 4 * pk._GG_SLOT_WIDTH_MAX
+    # the prefetched words + the two double-buffered (1, 1, T) index
+    # blocks: this slot's and the next slot's
+    assert total == (2 * 4 * pk._GG_MAX_SLOTS
+                     + 2 * 2 * 4 * pk._GG_SLOT_WIDTH_MAX)
     assert total <= SMEM_LIMIT_BYTES
 
 
@@ -358,4 +360,4 @@ def test_budget_knobs_registered_and_defaults_agree():
     assert conf.get_int("oryx.analyze.kernel.scoped-budget-bytes") \
         == b["scoped_budget_bytes"] == pk._SPD_SCOPED_BUDGET_BYTES
     assert conf.get_int("oryx.analyze.kernel.resident-budget-bytes") \
-        == b["resident_budget_bytes"] == 1536 << 10
+        == b["resident_budget_bytes"] == 1_583_104

@@ -554,13 +554,15 @@ def test_real_kernels_parse_with_expected_structure(kernels_project):
     # owner rows and slot lengths, each a whole (S,) vector in SMEM
     assert gg.num_prefetch == 2
     assert gg.prefetch_shapes == [("s",), ("s",)]
+    # the gather indices twice: this slot's block and the next slot's
     assert [b.space for b in gg.inputs] == [
-        "smem", "vmem", "any", "any", "any",
+        "smem", "smem", "vmem", "any", "any", "any",
     ]
-    assert gg.aliases == {5: 0, 6: 1}
-    # the gather scratch, and ONE semaphore for every copy of a slot
+    assert gg.aliases == {6: 0, 7: 1}
+    # two gather buffers (this slot's rows, the next slot's in flight) and
+    # one semaphore a buffer
     assert [(b.space, b.shape) for b in gg.scratch] == [
-        ("vmem", ("t", 1, "kp")), ("sem", (1,)),
+        ("vmem", (2, "t", 1, "kp")), ("sem", (2,)),
     ]
     # the scalar-prefetch-driven output maps are data-dependent: revisited
     assert all(b.revisits_across_grid(gg.grid) for b in gg.outputs)
@@ -585,10 +587,12 @@ def test_real_kernels_parse_with_expected_structure(kernels_project):
 def test_gg_vmem_model_matches_hand_computed_budget(kernels_project):
     """The acceptance numbers: the gather-Gramian resident footprint at
     (k=256, T=512) — double-buffered (1,k,k)/(1,1,k) accumulators, the
-    (1,2,T) weight block, the (T,1,pad128(k)) gather scratch, each under
-    the tiling Mosaic infers for it ((1,128) and (2,128) for the 1- and
-    2-row tails, not 8 rows) — is exactly 1,058,816 B, inside the 1.5 MiB
-    resident budget; the next k tile (264) overflows it."""
+    (1,2,T) weight block, the two (T,1,pad128(k)) gather buffers, each
+    under the tiling Mosaic infers for it ((1,128) and (2,128) for the 1-
+    and 2-row tails, not 8 rows) — is exactly 1,583,104 B, which IS the
+    resident budget (re-ratified with the second buffer: PERF.md, PR 27);
+    the next k tile (264) overflows it, and 250 features — a row of the
+    same 256 lanes — read the same bytes."""
     from oryx_tpu.tools.analyze.kernelmodel import (
         budgets, kernel_models, pad_up,
     )
@@ -600,20 +604,21 @@ def test_gg_vmem_model_matches_hand_computed_budget(kernels_project):
         2 * 256 * 256 * 4       # (1,256,256) f32 out block, double-buffered
         + 2 * 1 * 256 * 4       # (1,1,256) out block: one (1,128)-tiled row
         + 2 * 2 * 512 * 4       # (1,2,512) f32 weight block, (2,128)-tiled
-        + 512 * 1 * 256 * 4     # (512,1,256) gather scratch, row per tile
+        + 2 * 512 * 1 * 256 * 4  # (2,512,1,256) gather buffers, row per tile
     )
-    assert at(256) == expected_256 == 1_058_816
+    assert at(256) == at(250) == expected_256 == 1_583_104
     budget = budgets()["resident_budget_bytes"]
-    assert at(256) <= budget < at(264)
+    assert at(256) == budget < at(264) == 2_395_136
 
 
 def test_gg_smem_model_matches_what_the_compiler_said(kernels_project):
-    """The SMEM side of the model: two prefetched words a slot plus the
-    double-buffered (1, 1, T) index block. Compiled for a described v5e at
-    T = 512 the call fit at 130,048 slots and ran "out of memory in memory
-    space smem ... by 5.1K" at 131,072 (PR 25): the model says 4 KiB under
-    and 4 KiB over the same 1 MiB, the compiler's own scalars being the
-    rest. A kernel with no prefetched operand reads 0; one whose prefetched
+    """The SMEM side of the model: two prefetched words a slot plus the two
+    double-buffered (1, 1, T) index blocks (this slot's and the next
+    slot's). Compiled for a described v5e at T = 512 the call fit at
+    129,024 slots and ran "out of memory in memory space smem ... by 1.1K"
+    at 130,048 and "by 9.1K" at 131,072 (PR 27): the model says 8 KiB
+    under, exactly full and 8 KiB over the same 1 MiB, the compiler's own
+    scalars being the 1.1 KB. A kernel with no prefetched operand reads 0; one whose prefetched
     shapes the call site does not show reads None, never a guess."""
     from oryx_tpu.tools.analyze.kernelmodel import (
         SMEM_LIMIT_BYTES, kernel_models,
@@ -622,8 +627,9 @@ def test_gg_smem_model_matches_what_the_compiler_said(kernels_project):
     models = {m.name: m for m in kernel_models(kernels_project)}
     gg = models["gather_gramian_accumulate"]
     at = lambda s: gg.smem_bytes({"s": s, "t": 512})
-    assert at(130_048) == SMEM_LIMIT_BYTES - 4096
-    assert at(131_072) == SMEM_LIMIT_BYTES + 4096
+    assert at(129_024) == SMEM_LIMIT_BYTES - 8192
+    assert at(130_048) == SMEM_LIMIT_BYTES
+    assert at(131_072) == SMEM_LIMIT_BYTES + 8192
     assert gg.prefetch_smem_bytes({"s": 1000}) == 8000
     assert gg.smem_bytes({"t": 512}) is None  # unbound slots: no number
     assert models["_spd_solve_call"].smem_bytes({}) == 0
