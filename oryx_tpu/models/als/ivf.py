@@ -48,10 +48,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from oryx_tpu.common import compilecache
 from oryx_tpu.common import metrics as metrics_mod
-from oryx_tpu.common import profiling
-from oryx_tpu.common import spans
+from oryx_tpu.models.als.topn import (_ArenaSnapshot, _Fed,
+                                      _quantize_chunked, _quantize_rows,
+                                      _round_up_pow2)
 
 log = logging.getLogger(__name__)
 
@@ -84,10 +84,6 @@ _TRAIN_PER_CELL = 64
 _ASSIGN_CHUNK = 1 << 16
 
 _KMEANS_SEED = 0x0f1e
-
-
-def _round_up_pow2(n: int) -> int:
-    return 1 << max(0, (n - 1).bit_length())
 
 
 def auto_cells(n: int) -> int:
@@ -232,29 +228,29 @@ def _assign_cells(rows, centroids):
 # -- snapshot ----------------------------------------------------------------
 
 
-class IVFSnapshot:
+class IVFSnapshot(_ArenaSnapshot):
     """Immutable device view of Y as an inverted-file index (int8 cells +
     f32 centroids), plus the host-side mirrors (flat quantized rows, the
     assignment, the cell tables) that make incremental maintenance a
     per-affected-cell device scatter instead of a rebuild.
 
-    Shares the flat int8 snapshot's duck type where serving touches it:
-    ``ids`` / ``id_to_idx`` / ``n`` / ``version`` / ``gather_rows`` (the
-    pinned arena-slab rescore view) / ``cost_keys_attempted``; ``mat`` /
-    ``score_mat`` stay None — no flat factor copy of any dtype lands in
-    HBM in this mode."""
+    The fourth scan backend (models/als/topn.py:_Snapshot): exclusion
+    padding, LSH luts, the exact rescore and host collection are the flat
+    int8 view's, so it differs ONLY in how candidates are generated — a
+    probe program ahead of the scan, and a width of two parts
+    ``(probes, cut)``. No flat factor copy of any dtype lands in HBM in
+    this mode."""
 
     def __init__(self, ids, version: int, *, centroids_np=None, assign=None,
                  q_np=None, scale_np=None, norms_np=None, buckets_np=None,
                  cell_pos_np=None, cell_len=None, cell_width: int = 0,
                  probes: int = 8, skew_bound: float = 4.0,
+                 rescore_factor: float = 4.0, lsh=None,
                  centroids=None, cell_pos=None, cell_q=None,
                  cell_scale=None, cell_norms=None, cell_buckets=None,
                  slab=None, slab_rows=None,
                  prev: "IVFSnapshot | None" = None,
-                 appended: "list[str] | None" = None):
-        self.ids = ids
-        self.version = version
+                 incremental: bool = False):
         # host mirrors (maintenance only — the request path never reads them)
         self.centroids_np = centroids_np   # (C, k) f32
         self.assign = assign               # (n,) i32 snapshot position → cell
@@ -279,34 +275,15 @@ class IVFSnapshot:
         self.cell_scale = cell_scale       # (C, L) f32
         self.cell_norms = cell_norms       # (C, L) f32
         self.cell_buckets = cell_buckets   # (C, L) i32 or None
-        # pinned exact-rescore view (same contract as the flat int8
-        # snapshot: the slab object + row indices captured in `ids` order)
-        self.slab = slab
-        self.slab_rows = slab_rows
-        # flat-snapshot duck type for serving's guards
-        self.mat = None
-        self.score_mat = None
-        self.mesh = None
-        self.buckets = None
-        if prev is not None and appended is not None:
-            self.id_to_idx = prev.id_to_idx
-            for i in range(len(prev.ids), len(ids)):
-                self.id_to_idx[ids[i]] = i
-        else:
-            self.id_to_idx = {s: i for i, s in enumerate(ids)}
-        if (prev is not None
-                and getattr(prev.cell_q, "shape", None)
-                == getattr(cell_q, "shape", None)):
-            self.cost_keys_attempted = prev.cost_keys_attempted
-        else:
-            self.cost_keys_attempted: set = set()
-        profiling.register_quantized(self)
+        super().__init__(ids, version, cell_q,
+                         lsh if buckets_np is not None else None, slab,
+                         slab_rows, rescore_factor, prev, incremental)
         if cell_len is not None and len(ids):
             _INDEX_SKEW.set(self.skew())
 
     @property
-    def n(self) -> int:
-        return len(self.ids)
+    def scanned(self):
+        return self.cell_q
 
     @property
     def n_cells(self) -> int:
@@ -326,18 +303,10 @@ class IVFSnapshot:
             total += int(getattr(arr, "nbytes", 0) or 0)
         return total
 
-    def device_nbytes(self) -> int:
-        """All device bytes the index holds (device_factor_bytes)."""
-        total = 0
-        for arr in (self.centroids, self.cell_pos, self.cell_q,
-                    self.cell_scale, self.cell_norms, self.cell_buckets):
-            total += int(getattr(arr, "nbytes", 0) or 0)
-        return total
-
-    def gather_rows(self, positions: np.ndarray) -> np.ndarray:
-        """Exact f32 rows for snapshot positions, off the PINNED slab."""
-        pos = np.clip(np.asarray(positions, dtype=np.int64), 0, self.n - 1)
-        return self.slab[self.slab_rows[pos]]
+    def device_arrays(self) -> list:
+        arrays = (self.centroids, self.cell_pos, self.cell_q,
+                  self.cell_scale, self.cell_norms, self.cell_buckets)
+        return [a for a in arrays if a is not None]
 
     # -- construction --------------------------------------------------------
 
@@ -345,27 +314,18 @@ class IVFSnapshot:
     def build(cls, ids, host: np.ndarray, version: int, lsh,
               row_view: tuple, prev: "IVFSnapshot | None" = None, *,
               cells: int = 0, probes: int = 8, skew_bound: float = 4.0,
+              rescore_factor: float = 4.0,
               centroids: "np.ndarray | None" = None, cell_width: int = 0):
         """Full index build from one host matrix: quantize (chunked),
         cluster (deterministic-seeded k-means on a bounded subsample unless
         ``centroids`` are given), assign every row, lay the cells out
         sorted-ascending and pow2-padded, and land the device arrays."""
-        from oryx_tpu.models.als.serving import _quantize_rows
-
         n = len(ids)
         slab, slab_rows = row_view
         if n == 0 or host.size == 0:
             return cls(list(ids), version, probes=probes,
-                       skew_bound=skew_bound)
-        k = host.shape[1]
-        q = np.empty((n, k), dtype=np.int8)
-        scale = np.empty(n, dtype=np.float32)
-        norms = np.empty(n, dtype=np.float32)
-        chunk = 1 << 16
-        for a in range(0, n, chunk):
-            b = min(n, a + chunk)
-            q[a:b], scale[a:b] = _quantize_rows(host[a:b])
-            norms[a:b] = np.linalg.norm(host[a:b], axis=1)
+                       skew_bound=skew_bound, rescore_factor=rescore_factor)
+        q, scale, norms = _quantize_chunked(host)
         buckets_np = None
         if lsh and lsh.num_hashes:
             # np.array (not asarray): device-backed results come back
@@ -425,7 +385,7 @@ class IVFSnapshot:
             q_np=q, scale_np=scale, norms_np=norms, buckets_np=buckets_np,
             cell_pos_np=cell_pos_np, cell_len=cell_len, cell_width=width,
             probes=max(1, min(_round_up_pow2(probes), c)),
-            skew_bound=skew_bound,
+            skew_bound=skew_bound, rescore_factor=rescore_factor, lsh=lsh,
             centroids=jnp.asarray(centroids),
             slab=slab, slab_rows=slab_rows, prev=prev,
         )
@@ -475,7 +435,7 @@ class IVFSnapshot:
             self.cell_buckets = self.cell_buckets.at[ix].set(jnp.asarray(cb))
 
     @classmethod
-    def from_delta(cls, prev: "IVFSnapshot", delta, lsh):
+    def from_delta(cls, prev: "IVFSnapshot", delta):
         """Incremental step off one composed arena delta: requantize and
         reassign ONLY the touched rows, splice them through the host cell
         tables (sorted-ascending order preserved), and rewrite only the
@@ -483,12 +443,7 @@ class IVFSnapshot:
         overflow its padded width or the post-update balance drifts past
         ``skew_bound`` — the caller re-clusters (full rebuild, fresh
         centroids)."""
-        from oryx_tpu.models.als.serving import _quantize_rows
-
-        n_prev = prev.n
-        n_new = n_prev + len(delta.appended_ids)
-        if prev.cell_q is None or prev.centroids_np is None:
-            return None
+        n_prev, lsh = prev.n, prev.lsh
         # flat host mirrors: changed rows update in place (prev never reads
         # them again — the request path only touches device arrays and the
         # pinned slab), appends extend by copy
@@ -551,22 +506,18 @@ class IVFSnapshot:
                                n_prev + off, width):
                     return None
                 affected.add(int(nc))
-        ids = prev.ids + delta.appended_ids
-        slab_rows = (
-            np.concatenate([prev.slab_rows,
-                            np.asarray(delta.appended_rows, dtype=np.int64)])
-            if len(delta.appended_ids) else prev.slab_rows
-        )
+        ids, slab_rows = prev.appended(delta)
         snap = cls(
             ids, delta.version, centroids_np=prev.centroids_np,
             assign=assign, q_np=q_np, scale_np=scale_np, norms_np=norms_np,
             buckets_np=buckets_np, cell_pos_np=cell_pos_np,
             cell_len=cell_len, cell_width=width, probes=prev.probes,
-            skew_bound=prev.skew_bound, centroids=prev.centroids,
+            skew_bound=prev.skew_bound, rescore_factor=prev.rescore_factor,
+            lsh=lsh, centroids=prev.centroids,
             cell_pos=prev.cell_pos, cell_q=prev.cell_q,
             cell_scale=prev.cell_scale, cell_norms=prev.cell_norms,
             cell_buckets=prev.cell_buckets, slab=delta.slab,
-            slab_rows=slab_rows, prev=prev, appended=delta.appended_ids,
+            slab_rows=slab_rows, prev=prev, incremental=True,
         )
         snap.base_skew = prev.base_skew
         if snap.skew() > max(snap.skew_bound, prev.base_skew * 1.25):
@@ -579,6 +530,92 @@ class IVFSnapshot:
             snap._land_cells(np.fromiter(sorted(affected), dtype=np.int64))
         _INDEX_SKEW.set(snap.skew())
         return snap
+
+    # -- the scan backend (topn.py:_Snapshot) --------------------------------
+
+    def batch_width(self, how_many: int, filtering: bool):
+        """``(probes, cut)``: the default probe width, and ``rescore-factor
+        x how_many`` candidates rounded up to a pow2 (signature stability),
+        capped by what the probed cells can actually surface."""
+        cap = min(self.n, self.probes * self.cell_width)
+        return self.probes, max(1, min(cap, self.rescore_width(how_many)))
+
+    def _widths(self, want: int):
+        """The widening policy: the cut doubles first (more candidates from
+        the same probes), then the probe width doubles (pow2 signatures),
+        until the scan covers the whole catalog (probes == cells is the
+        flat scan, cell-shaped)."""
+        probes, r = self.probes, self.rescore_width(want)
+        while True:
+            cap = min(self.n, probes * self.cell_width)
+            r_eff = min(r, cap)
+            yield probes, r_eff
+            if probes >= self.n_cells and r_eff >= self.n:
+                return
+            if r_eff < cap:
+                r = r_eff * 2  # widen the cut over the same probed cells
+            else:
+                probes = min(self.n_cells, probes * 2)  # widen the probe set
+                r = min(self.n, r * 2)
+
+    def plan(self, qs, excl, lut, width):
+        """One probe matmul, then one probed-cell scan for the whole batch,
+        whose ``cells`` operand is the probe's result, still on the device.
+        Each under a cost key of its own, so attribution separates
+        candidate generation from the scan and the exact rescore."""
+        probes, r = width
+        b, c = qs.shape[0], self.n_cells
+        cells = _Fed((b, probes), jnp.int32)
+        key = scan_cost_key(b, c, probes, excl is not None, lut is not None)
+        if lut is not None:
+            scan = (_ivf_candidates_masked,
+                    (self.cell_pos, self.cell_q, self.cell_scale,
+                     self.cell_buckets, lut, qs, cells, excl, r), key)
+        else:
+            scan = (_ivf_candidates,
+                    (self.cell_pos, self.cell_q, self.cell_scale, qs, cells,
+                     excl, r), key)
+        return ((_probe_cells, (self.centroids, qs, probes),
+                 probe_cost_key(b, c, probes)), scan)
+
+    def dispatched(self, batch: int, width) -> None:
+        probes, r = width
+        _INDEX_PROBED.inc(batch * probes)
+        _INDEX_CANDIDATES.inc(batch * r)
+
+    def candidates(self, scan, q_host: np.ndarray, want: int, excluded,
+                   hooks: bool):
+        # exclusions that name no row of this view leave the plain program
+        known = excluded and any(e in self.id_to_idx for e in excluded)
+        for width in self._widths(want):
+            v, i = scan(self, q_host[None, :],
+                        [excluded] if known else None, width,
+                        register=False)
+            vals, idx = self.rescore(q_host[None, :], v, i)
+            yield vals[0], idx[0]
+
+    def cosine_candidates(self, qs_host: np.ndarray, want: int):
+        """Mean-cosine candidates for one request's query-vector set: probes
+        rank by the MEAN query direction, candidates rescore exact from the
+        slab (cosine), widening as :meth:`candidates`."""
+        qs = jnp.asarray(qs_host)
+        q_norms = jnp.asarray(np.linalg.norm(qs_host, axis=1))
+        lut_union = (jnp.asarray(self.bucket_union(qs_host))
+                     if self.lsh is not None else None)
+        probe_vec = jnp.asarray(np.mean(qs_host, axis=0, keepdims=True))
+        for probes, r in self._widths(want):
+            cells = _probe_cells(self.centroids, probe_vec, probes)
+            v, i = _ivf_cosine_candidates(
+                self.cell_pos, self.cell_q, self.cell_scale, self.cell_norms,
+                lut_union, self.cell_buckets, qs, q_norms, cells[0], r,
+            )
+            _INDEX_PROBED.inc(probes)
+            _INDEX_CANDIDATES.inc(r)
+            vals, idx = self.rescore(
+                qs_host, np.asarray(v)[None, :], np.asarray(i)[None, :],
+                cosine=True,
+            )
+            yield vals[0], idx[0]
 
 
 def _splice(cell_pos_np, cell_len, old_cell: int, new_cell: int,
@@ -605,243 +642,3 @@ def _insert(cell_pos_np, cell_len, cell: int, pos: int, width: int) -> bool:
     row[i] = pos
     cell_len[cell] = ln + 1
     return True
-
-
-# -- serving drivers ---------------------------------------------------------
-# Called from ALSServingModel (models/als/serving.py) with the model as the
-# first argument: exclusion padding, LSH luts, the exact rescore and host
-# collection all reuse the model's flat-path helpers, so the IVF path
-# differs ONLY in how candidates are generated.
-
-
-def _candidate_width(model, snap: IVFSnapshot, probes: int,
-                     want: int) -> int:
-    """Rescore width for one scan: ``rescore-factor x want`` rounded up to
-    a pow2 (signature stability), capped by what the probed cells can
-    actually surface."""
-    cap = min(snap.n, probes * snap.cell_width)
-    return max(1, min(cap, _round_up_pow2(
-        max(int(model.rescore_factor * want), 16)
-    )))
-
-
-def _scan(model, snap: IVFSnapshot, qs_host: np.ndarray, probes: int,
-          r: int, excl, lut, register: bool):
-    """One probe + candidate scan: (vals, idx) of width ``r`` in snapshot
-    positions, quantized scores. Registers/records the probe and scan
-    programs under their own cost keys so attribution separates candidate
-    generation from the exact rescore."""
-    with spans.stage("topn.upload"):
-        qs = jnp.asarray(qs_host)
-    b = qs_host.shape[0]
-    c = snap.n_cells
-    pk = probe_cost_key(b, c, probes)
-    sk = scan_cost_key(b, c, probes, excl is not None, lut is not None)
-
-    def scan_args(cells):
-        if lut is not None:
-            return (_ivf_candidates_masked,
-                    (snap.cell_pos, snap.cell_q, snap.cell_scale,
-                     snap.cell_buckets, lut, qs, cells, excl))
-        return (_ivf_candidates,
-                (snap.cell_pos, snap.cell_q, snap.cell_scale, qs, cells,
-                 excl))
-
-    with spans.stage("topn.dispatch"):
-        if register and metrics_mod.default_registry().enabled:
-            if pk not in snap.cost_keys_attempted:
-                snap.cost_keys_attempted.add(pk)
-                compilecache.aot_compile(
-                    _probe_cells, snap.centroids, qs, probes, cost_key=pk
-                )
-            if sk not in snap.cost_keys_attempted:
-                snap.cost_keys_attempted.add(sk)
-                fn, a = scan_args(
-                    jax.ShapeDtypeStruct((b, probes), jnp.int32)
-                )
-                compilecache.aot_compile(fn, *a, r, cost_key=sk)
-        cells = _probe_cells(snap.centroids, qs, probes)
-        fn, a = scan_args(cells)
-        vals, idx = fn(*a, r)
-        if register:
-            profiling.costs().record(pk)
-            profiling.costs().record(sk)
-        _INDEX_PROBED.inc(b * probes)
-        _INDEX_CANDIDATES.inc(b * r)
-    with spans.stage("topn.wait_download"):
-        return np.asarray(vals), np.asarray(idx)
-
-
-def top_n(model, snap: IVFSnapshot, q_host: np.ndarray, how_many: int,
-          offset: int, allowed, rescore, excluded) -> list:
-    """Single-query IVF top-N with widening: rescore width doubles first
-    (more candidates from the same probes), then the probe width doubles
-    (pow2 signatures) until the request is satisfied or the scan covers
-    the whole catalog (probes == cells is the flat scan, cell-shaped)."""
-    want = how_many + offset
-    excl = None
-    if excluded:
-        padded = model._excluded_indices(snap, [excluded], 1)
-        if (padded >= 0).any():
-            excl = jnp.asarray(padded)
-    lut = (
-        jnp.asarray(model._build_lut(q_host[None, :]))
-        if model.lsh is not None and snap.cell_buckets is not None
-        else None
-    )
-    probes = snap.probes
-    r = _round_up_pow2(max(int(model.rescore_factor * want), 16))
-    while True:
-        cap = min(snap.n, probes * snap.cell_width)
-        r_eff = min(r, cap)
-        v, i = _scan(model, snap, q_host[None, :], probes, r_eff, excl,
-                     lut, register=False)
-        vals, idx = model._rescore_exact(snap, q_host[None, :], v, i)
-        out = model._collect(snap, vals[0], idx[0], want, allowed, rescore)
-        if len(out) >= want or (probes >= snap.n_cells
-                                and r_eff >= snap.n):
-            return out[offset:offset + how_many]
-        if r_eff < cap:
-            r = r_eff * 2  # widen the cut over the same probed cells
-        else:
-            probes = min(snap.n_cells, probes * 2)  # widen the probe set
-            r = min(snap.n, r * 2)
-
-
-def top_n_batch(model, snap: IVFSnapshot, qs_host: np.ndarray,
-                how_many: int, alloweds, excluded,
-                filtering: bool) -> list:
-    """Batched IVF top-N: one probe matmul + one probed-cell scan for the
-    whole batch, exact-f32-rescored from the arena slab before the final
-    cut. Per-query widening (heavy host filtering) falls back to the
-    single-query path, exactly like the flat int8 batch driver."""
-    b = len(qs_host)
-    use_excl = excluded is not None and any(e for e in excluded)
-    excl = (
-        jnp.asarray(model._excluded_indices(snap, excluded, b))
-        if use_excl else None
-    )
-    lut = (
-        jnp.asarray(model._build_lut(qs_host))
-        if model.lsh is not None and snap.cell_buckets is not None
-        else None
-    )
-    r = _candidate_width(model, snap, snap.probes, how_many)
-    v, i = _scan(model, snap, qs_host, snap.probes, r, excl, lut,
-                 register=True)
-    with spans.stage("topn.rescore"):
-        vals, idx = model._rescore_exact(snap, qs_host, v, i)
-    with spans.stage("topn.ids"):
-        if not filtering:
-            from oryx_tpu.models.als.serving import _id_lists
-
-            return _id_lists(snap.ids, vals, idx, how_many)
-        out = []
-        for q in range(b):
-            allowed = alloweds[q] if alloweds else None
-            got = model._collect(
-                snap, vals[q], idx[q], how_many, allowed, None
-            )[:how_many]
-            if len(got) < how_many and r < snap.n:
-                got = top_n(
-                    model, snap, qs_host[q], how_many, 0, allowed, None,
-                    excluded[q] if excluded else None,
-                )
-            out.append(got)
-        return out
-
-
-def top_n_cosine(model, snap: IVFSnapshot, qs_host: np.ndarray,
-                 q_norms_host: np.ndarray, how_many: int, offset: int,
-                 allowed, rescore) -> list:
-    """Mean-cosine IVF top-N for one request's query-vector set: probes
-    rank by the MEAN query direction, candidates rescore exact from the
-    slab (cosine), widening mirrors :func:`top_n`."""
-    want = how_many + offset
-    qs = jnp.asarray(qs_host)
-    q_norms = jnp.asarray(q_norms_host)
-    lut_union = None
-    if model.lsh is not None and snap.cell_buckets is not None:
-        lu = np.zeros(model.lsh.num_buckets, dtype=bool)
-        for qv in qs_host:
-            lu[model.lsh.get_candidate_indices(qv)] = True
-        lut_union = jnp.asarray(lu)
-    probe_vec = np.mean(qs_host, axis=0, keepdims=True)
-    probes = snap.probes
-    r = _round_up_pow2(max(int(model.rescore_factor * want), 16))
-    while True:
-        cap = min(snap.n, probes * snap.cell_width)
-        r_eff = min(r, cap)
-        cells = _probe_cells(snap.centroids, jnp.asarray(probe_vec), probes)
-        v, i = _ivf_cosine_candidates(
-            snap.cell_pos, snap.cell_q, snap.cell_scale, snap.cell_norms,
-            lut_union, snap.cell_buckets, qs, q_norms, cells[0], r_eff,
-        )
-        _INDEX_PROBED.inc(probes)
-        _INDEX_CANDIDATES.inc(r_eff)
-        vals, idx = model._rescore_exact(
-            snap, qs_host, np.asarray(v)[None, :], np.asarray(i)[None, :],
-            cosine=True,
-        )
-        out = model._collect(snap, vals[0], idx[0], want, allowed, rescore)
-        if len(out) >= want or (probes >= snap.n_cells
-                                and r_eff >= snap.n):
-            return out[offset:offset + how_many]
-        if r_eff < cap:
-            r = r_eff * 2
-        else:
-            probes = min(snap.n_cells, probes * 2)
-            r = min(snap.n, r * 2)
-
-
-def warm_bucket(model, snap: IVFSnapshot, batch_size: int,
-                how_many: int) -> None:
-    """AOT-compile the IVF probe + scan signatures for one pow2 bucket —
-    the per-bucket unit of the serving warm ladder, under the IVF cost
-    keys. Both exclusion families warm (the default /recommend path always
-    sends known-item exclusions at the floored pad width); the shared
-    zero-batch executions in ALSServingModel.warm_bucket then populate the
-    jit dispatch caches these programs actually serve from."""
-    from oryx_tpu.models.als.serving import _EXCL_PAD_MIN
-
-    probes = snap.probes
-    c = snap.n_cells
-    r = _candidate_width(model, snap, probes, how_many)
-    qs_struct = jax.ShapeDtypeStruct(
-        (batch_size, model.features), jnp.float32
-    )
-    excl_struct = jax.ShapeDtypeStruct(
-        (batch_size, _EXCL_PAD_MIN), jnp.int32
-    )
-    cells_struct = jax.ShapeDtypeStruct((batch_size, probes), jnp.int32)
-    pk = probe_cost_key(batch_size, c, probes)
-    compilecache.aot_compile(
-        _probe_cells, snap.centroids, qs_struct, probes, cost_key=pk
-    )
-    use_lsh = model.lsh is not None and snap.cell_buckets is not None
-    keys = (scan_cost_key(batch_size, c, probes, False, use_lsh),
-            scan_cost_key(batch_size, c, probes, True, use_lsh))
-    if use_lsh:
-        lut_struct = jax.ShapeDtypeStruct(
-            (batch_size, model.lsh.num_buckets), jnp.bool_
-        )
-        compilecache.aot_compile(
-            _ivf_candidates_masked, snap.cell_pos, snap.cell_q,
-            snap.cell_scale, snap.cell_buckets, lut_struct, qs_struct,
-            cells_struct, None, r, cost_key=keys[0],
-        )
-        compilecache.aot_compile(
-            _ivf_candidates_masked, snap.cell_pos, snap.cell_q,
-            snap.cell_scale, snap.cell_buckets, lut_struct, qs_struct,
-            cells_struct, excl_struct, r, cost_key=keys[1],
-        )
-    else:
-        compilecache.aot_compile(
-            _ivf_candidates, snap.cell_pos, snap.cell_q, snap.cell_scale,
-            qs_struct, cells_struct, None, r, cost_key=keys[0],
-        )
-        compilecache.aot_compile(
-            _ivf_candidates, snap.cell_pos, snap.cell_q, snap.cell_scale,
-            qs_struct, cells_struct, excl_struct, r, cost_key=keys[1],
-        )
-    snap.cost_keys_attempted.update({pk, *keys})

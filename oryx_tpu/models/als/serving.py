@@ -20,7 +20,6 @@ import collections
 import functools
 import json
 import logging
-import math
 import threading
 import time
 import weakref
@@ -42,6 +41,10 @@ from oryx_tpu.models.als import ivf as ivf_mod
 from oryx_tpu.models.als import pmml_codec
 from oryx_tpu.models.als.lsh import LocalitySensitiveHash
 from oryx_tpu.models.als.rescorer import load_rescorer_providers
+from oryx_tpu.models.als.topn import (_EXCL_PAD_MIN, _ArenaSnapshot, _Snapshot,
+                                      _collect, _excluded_indices, _id_lists,
+                                      _operands, _quantize_chunked,
+                                      _quantize_rows, _round_up_pow2)
 from oryx_tpu.models.als.vectors import FeatureVectorStore
 from oryx_tpu.parallel.mesh import (put_row_sharded, replicated_sharding,
                                     row_sharding)
@@ -85,21 +88,6 @@ def _load_fraction_fn(manager_ref):
     return fn
 
 
-def _round_up_pow2(n: int) -> int:
-    return 1 << max(0, (n - 1).bit_length())
-
-
-#: Floor of the pow2-bucketed exclusion-mask width. Known-item exclusion is
-#: what the DEFAULT /recommend path sends (considerKnownItems=false), so its
-#: jit signature must be shape-stable enough to PRE-warm: flooring the width
-#: means every request with ≤ this many known items — the overwhelming
-#: common case — lands on ONE compiled program, which the batch warmer
-#: compiles off-path (warm_bucket). Users past the floor bucket up by pow2
-#: and pay one compile per bucket per process (persistent-cache-served
-#: afterwards), exactly like unusual howMany values.
-_EXCL_PAD_MIN = 8
-
-
 #: Valid values of ``oryx.serving.device-dtype``: "auto" keeps the historic
 #: behavior (bf16 scoring copy on TPU, f32 elsewhere); explicit f32/bf16
 #: force the scoring dtype; "int8" holds ONLY a per-row-scaled int8 slab on
@@ -118,17 +106,6 @@ def _topn_cost_key(batch_size: int, excl: bool, quant: bool = False) -> str:
     rescale multiply) differs from the f32/bf16 scan's."""
     return (f"als.top_n_batch/b{batch_size}"
             + ("+excl" if excl else "") + ("+int8" if quant else ""))
-
-
-def _id_lists(ids, vals: np.ndarray, idx: np.ndarray, how_many: int) -> list:
-    """(B, >= how_many) scores and row indices, best first -> per query its
-    ``(id, score)`` list; masked candidates (-inf from the scan) left out."""
-    vb, ib = vals[:, :how_many], idx[:, :how_many]
-    return [
-        [(ids[int(i)], float(v)) for v, i in zip(vb[b], ib[b])
-         if np.isfinite(v)]
-        for b in range(len(vb))
-    ]
 
 
 def _score(qs, mat):
@@ -290,18 +267,6 @@ def _top_k_cosine_sum(mat, norms, qs, q_norms, valid, k: int):
 # so recall, not precision, is the only quantization exposure.
 
 
-def _quantize_rows(mat: np.ndarray) -> "tuple[np.ndarray, np.ndarray]":
-    """Per-row symmetric int8 quantization: scale_i = max|row_i| / 127.
-    Zero rows get scale 1 (their dots are exactly 0 either way)."""
-    if mat.size == 0:
-        return (np.zeros(mat.shape, dtype=np.int8),
-                np.ones(mat.shape[0], dtype=np.float32))
-    amax = np.max(np.abs(mat), axis=1)
-    scale = np.where(amax > 0, amax / 127.0, 1.0).astype(np.float32)
-    q = np.clip(np.rint(mat / scale[:, None]), -127, 127).astype(np.int8)
-    return q, scale
-
-
 @jax.jit
 def _quant_masked_scores(qmat, qscale, qs, valid, excl):
     """(B, n) approximate scores off the int8 slab: the convert rides the
@@ -362,6 +327,105 @@ def _derive_sharded(row_sharding, mat_sharding, score_dtype):
         row_sharding, None if score_dtype is None else mat_sharding))
 
 
+# -- the scan, whichever view of Y it runs over -----------------------------
+# A snapshot (models/als/topn.py:_Snapshot) is the scan backend: it owns its
+# program, its width rule and its way of placing operands. What follows is
+# written once for all of them.
+
+
+def _scan(snap, qs_host: np.ndarray, excluded, width, register: bool):
+    """The device side of one call, end to end (docs/observability.md):
+    what the coalescer's device-call span is made of on this side, for
+    every backend. ``width`` is the backend's static width
+    (``snap.batch_width`` for a batch); ``register`` attributes the call to
+    the program's cost key. Returns ``(vals, idx)`` on the host."""
+    use_excl = excluded is not None and any(e for e in excluded)
+    with spans.stage("topn.upload"):
+        # batch-shaped operands go from the host straight to where the view
+        # lies (on a mesh to every device at once, not to one device and on
+        # from there inside the dispatch)
+        qs = snap.place(qs_host)
+        excl = (
+            snap.place(_excluded_indices(snap, excluded, len(qs_host)))
+            if use_excl
+            else None
+        )
+        # per-query LSH candidate masks: (B, num_buckets) lookup table
+        # indexed by item bucket on device, fully vectorized over the batch
+        lut = (snap.place(snap.lsh.get_candidate_lut(qs_host))
+               if snap.lsh is not None else None)
+    with spans.stage("topn.dispatch"):
+        out = None
+        for fn, args, cost_key in snap.plan(qs, excl, lut, width):
+            if out is not None:
+                args = _operands(args, out)
+            if (register and cost_key not in snap.cost_keys_attempted
+                    and metrics_mod.default_registry().enabled):
+                # first use of this signature this generation: the dispatch
+                # below pays the XLA compile anyway — the sanctioned AOT
+                # route shares that compile AND yields the executable's
+                # cost_analysis, so unwarmed signatures (odd batch sizes,
+                # direct callers) still attribute FLOPs instead of reading
+                # zero forever
+                snap.cost_keys_attempted.add(cost_key)
+                compilecache.aot_compile(fn, *args, cost_key=cost_key)
+            out = fn(*args)
+            if register:
+                profiling.costs().record(cost_key)
+        snap.dispatched(len(qs_host), width)
+        vals, idx = out
+    with spans.stage("topn.wait_download"):
+        # the program's run and the copy back: the first conversion
+        # blocks until the device is done
+        return np.asarray(vals), np.asarray(idx)
+
+
+def _first_enough(snap, candidates, how_many: int, offset: int, allowed,
+                  rescore) -> list[tuple[str, float]]:
+    """The widening loop of a single query: ``allowed``/``rescore`` host
+    hooks (rescorer SPI) consume candidates, so take the backend's next
+    wider list until enough survive or it has none wider."""
+    want = how_many + offset
+    out: list[tuple[str, float]] = []
+    for vals, idx in candidates:
+        out = _collect(snap, vals, idx, want, allowed, rescore)
+        if len(out) >= want:
+            break
+    return out[offset:offset + how_many]
+
+
+def _doubling(k: int, n: int):
+    """The widths a widening tries: ``k``, doubled until it reaches ``n``."""
+    k = min(n, k)
+    while k < n:
+        yield k
+        k = min(n, k * 2)
+    yield k
+
+
+def _widen_top_k(snap, scores, k: int, q_host: np.ndarray):
+    """The flat and int8 widening policy: the scan ran ONCE and ``scores``
+    is its (1, n) result; a wider list is only another top-k over it —
+    never another full-bandwidth pass over Y."""
+    for k in _doubling(k, snap.n):
+        vals, idx = _top_k_of_scores(scores, k)
+        vals, idx = np.asarray(vals), np.asarray(idx)
+        if snap.rescore is not None:
+            vals, idx = snap.rescore(q_host[None, :], vals, idx)
+        yield vals[0], idx[0]
+
+
+def _candidate_mask(snap, query_vecs: np.ndarray, n_rows: int):
+    """(n_rows,) bool: the rows the query vectors may be answered from —
+    those in the union of their LSH candidate buckets. The zero rows that
+    pad a sharded Y past its last id are never candidates."""
+    real = None if n_rows == snap.n else jnp.arange(n_rows) < snap.n
+    if snap.lsh is None:
+        return jnp.ones(snap.n, dtype=bool) if real is None else real
+    valid = jnp.asarray(snap.bucket_union(query_vecs))[snap.buckets]
+    return valid if real is None else valid & real
+
+
 _Y_SHARD_BYTES = metrics_mod.default_registry().gauge(
     "oryx_serving_y_shard_bytes",
     "Bytes of the newest Y snapshot resident on each device (factors, "
@@ -370,16 +434,9 @@ _Y_SHARD_BYTES = metrics_mod.default_registry().gauge(
 )
 
 
-class _YSnapshot:
-    """Immutable device view of Y: ids, matrix, norms, LSH buckets.
-
-    With a mesh EVERY per-row array (the float32 factors ``mat``, the scoring
-    copy ``score_mat``, ``norms``, ``buckets``) is split by rows over
-    ``shard_axis`` as the store materialized it: each device holds its own
-    block of ``n_rows / shards`` rows and derives its norms and scoring rows
-    locally, so Y may exceed a single device's memory — no array with all of
-    Y's rows is ever placed on one device. ``n_rows`` is then ``n`` padded to
-    the shard count; rows past ``n`` are zero and masked in every scan.
+class _YSnapshot(_Snapshot):
+    """Immutable device view of Y on one device: ids, matrix, norms, LSH
+    buckets.
 
     ``prev`` + ``delta`` ((changed base-row indices, appended-row count) from
     FeatureVectorStore.delta_since) build the snapshot INCREMENTALLY after a
@@ -394,39 +451,13 @@ class _YSnapshot:
         ids: list[str],
         mat,
         lsh: LocalitySensitiveHash | None,
-        mesh=None,
-        shard_axis: str = "model",
         prev: "_YSnapshot | None" = None,
         delta: "tuple[np.ndarray, int] | None" = None,
         device_dtype: str = "auto",
     ):
-        self.ids = ids
+        super().__init__(ids, mat, prev=prev, incremental=delta is not None)
         self.device_dtype = device_dtype
         self.mat = mat  # jax (n_rows, k) or None, float32
-        # lazy cost-registration marks (see _top_n_batch): per GENERATION so
-        # a model swap re-registers against the new shapes, but carried
-        # across same-shape incremental snapshots (point-update microbatches
-        # whose dispatch signatures — and therefore per-call costs — are
-        # unchanged). Marked even when registration fails, so a backend
-        # without usable cost_analysis never re-pays lower+compile per call.
-        if (prev is not None
-                and getattr(prev.mat, "shape", None)
-                == getattr(mat, "shape", None)):
-            self.cost_keys_attempted = prev.cost_keys_attempted
-        else:
-            self.cost_keys_attempted: set = set()
-        if prev is not None and delta is not None:
-            # id→idx is append-only across incremental generations; sharing
-            # the dict avoids an O(n) rebuild per microbatch (extra entries
-            # in the older snapshot only affect exclusion masks, which drop
-            # out-of-range rows on device)
-            self.id_to_idx = prev.id_to_idx
-            for i in range(len(prev.ids), len(ids)):
-                self.id_to_idx[ids[i]] = i
-        else:
-            self.id_to_idx = {s: i for i, s in enumerate(ids)}
-        self.mesh = mesh if mat is not None else None
-        self.shard_axis = shard_axis
         if mat is None:
             self.norms = None
             self.score_mat = None
@@ -438,41 +469,51 @@ class _YSnapshot:
         # (int8 never reaches this class — see _QuantSnapshot)
         bf16 = device_dtype == "bfloat16" or (
             device_dtype == "auto" and jax.default_backend() == "tpu")
-        if mesh is None:
-            self.norms = jnp.linalg.norm(mat, axis=1)
-            self.score_mat = mat.astype(jnp.bfloat16) if bf16 else mat
-        else:
-            self.norms, score = _derive_sharded(
-                row_sharding(mesh, shard_axis), mat.sharding,
-                jnp.bfloat16 if bf16 else None,
-            )(mat)
-            self.score_mat = mat if score is None else score
-        self.buckets = self._assign_buckets(lsh, prev, delta)
-        if mesh is not None:
-            self._publish_shard_bytes()
+        self.norms, self.score_mat = self._derive(
+            mat, jnp.bfloat16 if bf16 else None)
+        self.buckets = None
+        if lsh and lsh.num_hashes:
+            self.lsh = lsh
+            self.buckets = self._assign_buckets(prev, delta)
 
-    def _assign_buckets(self, lsh, prev, delta):
-        """(n_rows,) LSH bucket of every row, or None without LSH; under a
-        mesh split like the rows (padding rows in bucket 0, masked anyway)."""
-        if not (lsh and lsh.num_hashes):
-            return None
-        mat, n = self.mat, self.n
+    @classmethod
+    def source(cls, store):
+        # outside the snapshot lock: the store has a lock of its own, and a
+        # first materialization uploads all of Y
+        return store.materialize()
+
+    @classmethod
+    def current(cls, store, lsh, prev, source, **options):
+        """The view of the store's device matrix (``source``) as it stands:
+        ``prev`` when the matrix is the one it was built on, else a new one
+        — incremental when the store can say what changed since."""
+        ids, mat = source
+        if prev is not None and prev.mat is mat:
+            return prev
+        delta = None
+        if prev is not None and prev.mat is not None and mat is not None:
+            # catch up across any number of incremental generations
+            # (e.g. get_vtv consumed pending batches in between)
+            delta = store.delta_since(prev.mat, mat)
+        return cls(ids, mat, lsh, prev=prev if delta is not None else None,
+                   delta=delta, **options)
+
+    def _derive(self, mat, score_dtype):
+        return (jnp.linalg.norm(mat, axis=1),
+                mat if score_dtype is None else mat.astype(score_dtype))
+
+    def _assign_buckets(self, prev, delta):
+        """(n_rows,) LSH bucket of every row."""
+        mat, lsh = self.mat, self.lsh
         if prev is None or delta is None or prev.buckets is None:
-            host = lsh.assign_buckets(np.asarray(mat)[:n])
-            return self._place_buckets(host)
+            return self._place_buckets(
+                lsh.assign_buckets(np.asarray(mat)[:self.n]))
         # rehash only the delta: pull changed/new rows (not the whole
         # matrix) to host for bucket assignment
-        ch, n_new = delta
-        if self.mesh is not None:
-            # the bucket vector (4 B a row) makes the round trip; Y does not
-            host = np.zeros(n, dtype=np.int32)
-            host[: prev.n] = np.asarray(prev.buckets)[: prev.n]
-            if len(ch):
-                host[ch] = lsh.assign_buckets(np.asarray(mat[jnp.asarray(ch)]))
-            if n_new:
-                host[prev.n:] = lsh.assign_buckets(np.asarray(mat[prev.n:n]))
-            return self._place_buckets(host)
-        buckets = prev.buckets
+        return self._rehash(prev, *delta)
+
+    def _rehash(self, prev, ch, n_new):
+        mat, lsh, buckets = self.mat, self.lsh, prev.buckets
         if len(ch):
             ch_j = jnp.asarray(ch, dtype=jnp.int32)
             new_b = jnp.asarray(lsh.assign_buckets(np.asarray(mat[ch_j])))
@@ -485,10 +526,7 @@ class _YSnapshot:
         return buckets
 
     def _place_buckets(self, host: np.ndarray):
-        if self.mesh is None:
-            return jnp.asarray(host)
-        return put_row_sharded(
-            np.asarray(host, dtype=np.int32), self.mesh, self.shard_axis)
+        return jnp.asarray(host)
 
     def device_arrays(self) -> list:
         """The distinct arrays this snapshot holds on device (the scoring
@@ -498,6 +536,92 @@ class _YSnapshot:
             arrays.append(self.score_mat)
         return [a for a in arrays if a is not None]
 
+    @property
+    def scanned(self):
+        return self.mat
+
+    @property
+    def n_rows(self) -> int:
+        """Rows of the device arrays: ``n``, padded to the shard count."""
+        return self.n if self.mat is None else int(self.mat.shape[0])
+
+    def batch_width(self, how_many: int, filtering: bool) -> int:
+        # host filters and LSH masks consume candidates: ask for more
+        wide = filtering or self.lsh is not None
+        return min(self.n, _round_up_pow2(
+            max(2 * how_many, 64) if wide else max(how_many, 16)))
+
+    def plan(self, qs, excl, lut, k: int):
+        key = _topn_cost_key(qs.shape[0], excl is not None)
+        if lut is not None:
+            return ((_top_k_dot_batch_masked,
+                     (self.score_mat, qs, lut, self.buckets, excl, k), key),)
+        return ((_top_k_dot_batch, (self.score_mat, qs, None, excl, k), key),)
+
+    def candidates(self, scan, q_host: np.ndarray, want: int, excluded,
+                   hooks: bool):
+        # score once; widenings re-run only the top-k over the cached
+        # scores. The unfiltered hot path stays exactly matmul + top_k:
+        # masks are None (static) unless LSH or exclusions actually apply
+        valid = (_candidate_mask(self, q_host[None, :], self.n_rows)
+                 if self.lsh is not None else None)
+        scores = _masked_scores(
+            self.score_mat, jnp.asarray(q_host[None, :]), valid,
+            self.one_excluded(excluded))
+        return _widen_top_k(
+            self, scores, _round_up_pow2(max(4 * want, 64)), q_host)
+
+    def cosine_candidates(self, qs_host: np.ndarray, want: int):
+        qs = jnp.asarray(qs_host)
+        q_norms = jnp.linalg.norm(qs, axis=1)
+        valid = _candidate_mask(self, qs_host, self.n_rows)
+        for k in _doubling(_round_up_pow2(max(4 * want, 64)), self.n):
+            vals, idx = _top_k_cosine_sum(
+                self.mat, self.norms, qs, q_norms, valid, k)
+            yield np.asarray(vals), np.asarray(idx)
+
+
+class _ShardedYSnapshot(_YSnapshot):
+    """The float view with its rows split over a mesh: EVERY per-row array
+    (the float32 factors ``mat``, the scoring copy ``score_mat``, ``norms``,
+    ``buckets``) is split by rows over ``shard_axis`` as the store
+    materialized it: each device holds its own block of ``n_rows / shards``
+    rows and derives its norms and scoring rows locally, so Y may exceed a
+    single device's memory — no array with all of Y's rows is ever placed on
+    one device. ``n_rows`` is ``n`` padded to the shard count; rows past
+    ``n`` are zero and masked in every scan."""
+
+    def __init__(self, ids, mat, lsh, mesh, shard_axis: str = "model", **kw):
+        self.mesh = mesh if mat is not None else None
+        self.shard_axis = shard_axis
+        self._everywhere = replicated_sharding(mesh)
+        super().__init__(ids, mat, lsh, **kw)
+        if mat is not None:
+            self._publish_shard_bytes()
+
+    def _derive(self, mat, score_dtype):
+        norms, score = _derive_sharded(
+            row_sharding(self.mesh, self.shard_axis), mat.sharding,
+            score_dtype,
+        )(mat)
+        return norms, mat if score is None else score
+
+    def _rehash(self, prev, ch, n_new):
+        # the bucket vector (4 B a row) makes the round trip; Y does not
+        mat, lsh, n = self.mat, self.lsh, self.n
+        host = np.zeros(n, dtype=np.int32)
+        host[: prev.n] = np.asarray(prev.buckets)[: prev.n]
+        if len(ch):
+            host[ch] = lsh.assign_buckets(np.asarray(mat[jnp.asarray(ch)]))
+        if n_new:
+            host[prev.n:] = lsh.assign_buckets(np.asarray(mat[prev.n:n]))
+        return self._place_buckets(host)
+
+    def _place_buckets(self, host: np.ndarray):
+        # split like the rows (padding rows in bucket 0, masked anyway)
+        return put_row_sharded(
+            np.asarray(host, dtype=np.int32), self.mesh, self.shard_axis)
+
     def _publish_shard_bytes(self) -> None:
         per_device: collections.Counter = collections.Counter()
         for arr in self.device_arrays():
@@ -506,76 +630,76 @@ class _YSnapshot:
         for dev, nbytes in per_device.items():
             _Y_SHARD_BYTES.labels(str(dev)).set(nbytes)
 
-    @property
-    def n(self) -> int:
-        return len(self.ids)
+    def place(self, host: np.ndarray):
+        return jax.device_put(host, self._everywhere)
 
-    @property
-    def n_rows(self) -> int:
-        """Rows of the device arrays: ``n``, padded to the shard count."""
-        return self.n if self.mat is None else int(self.mat.shape[0])
+    def struct(self, shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=self._everywhere)
+
+    def batch_width(self, how_many: int, filtering: bool) -> int:
+        return min(self.n, _round_up_pow2(
+            max(2 * how_many, 64) if filtering else max(how_many, 16)))
+
+    def plan(self, qs, excl, lut, k: int):
+        """The jitted mesh scan for ``k`` results a query — the one-chip
+        scan on every shard + cross-shard merge, with LSH lut and per-query
+        known-item exclusion applied device-side (no host fallback for
+        filtered traffic), at least ``min(k, n)`` wide: each shard keeps
+        ``k_shard`` candidates (a pow2 ≥ 16, as the one-chip program's
+        width), the merge ``min(ndev * k_shard, width)``. It has a cost key
+        of its own (its per-call cost is a shard's, plus the merge)."""
+        ndev = self.mesh.shape[self.shard_axis]
+        width = _round_up_pow2(max(min(k, self.n), 16))
+        k_shard = min(self.n_rows // ndev, width)
+        fn = _sharded_top_k_fn(
+            self.mesh, self.shard_axis, k_shard, min(ndev * k_shard, width),
+            self.n, lut is not None, excl is not None,
+        )
+        args = (self.score_mat, qs)
+        if excl is not None:
+            args += (excl,)
+        if lut is not None:
+            args += (lut, self.buckets)
+        return ((fn, args, _topn_cost_key(qs.shape[0], excl is not None)
+                 + "+sharded"),)
+
+    def candidates(self, scan, q_host: np.ndarray, want: int, excluded,
+                   hooks: bool):
+        # every widening is another scan of the shards, asked for more
+        for k in _doubling(max(4 * want, 64) if hooks else want, self.n):
+            vals, idx = scan(self, q_host[None, :],
+                             [excluded] if excluded else None, k,
+                             register=False)
+            yield vals[0], idx[0]
 
 
-#: Host-side quantization chunk: bounds the transient f32 gather while
-#: building a full quantized snapshot (2^16 rows × 50f ≈ 13 MB per chunk
-#: instead of one n×k f32 copy next to the arena slab).
-_QUANT_CHUNK = 1 << 16
-
-
-class _QuantSnapshot:
+class _QuantSnapshot(_ArenaSnapshot):
     """Immutable int8 device view of Y (``oryx.serving.device-dtype = int8``):
     per-row-scaled int8 factors + exact f32 norms + optional LSH buckets.
     No f32 (or bf16) copy of Y ever lands in HBM — the whole point of the
     mode is fitting a 21M × 50f item side per chip with headroom.
 
-    Built from the factor arena's HOST snapshot (``host_matrix``) and kept
-    current with composed host deltas (``delta_info``): a speed microbatch
-    of point updates requantizes only the changed/appended rows and lands
-    them as row-index scatters, mirroring the f32 path's incremental
-    device maintenance. ``version`` anchors the next delta."""
+    A speed microbatch of point updates requantizes only the
+    changed/appended rows and lands them as row-index scatters, mirroring
+    the f32 path's incremental device maintenance. Its programs (and so
+    its cost keys, ``+int8``) are distinct from the f32/bf16 scan's: the
+    attribution and the warm ladder see them as their own signatures."""
 
     def __init__(self, ids, version: int, qmat, qscale, norms, buckets,
-                 prev: "_QuantSnapshot | None" = None,
-                 appended: "list[str] | None" = None,
-                 slab=None, slab_rows=None):
-        self.ids = ids
-        self.version = version
+                 lsh=None, prev: "_QuantSnapshot | None" = None,
+                 incremental: bool = False, slab=None, slab_rows=None,
+                 rescore_factor: float = 4.0):
         self.qmat = qmat        # (n, k) int8 device
         self.qscale = qscale    # (n,) f32 device
         self.norms = norms      # (n,) f32 device, exact
         self.buckets = buckets  # (n,) int32 device or None
-        # pinned exact-rescore view: THIS snapshot's slab object + its row
-        # indices, captured by the store in the same order epoch as `ids`.
-        # Structural store changes (GC, compaction) replace the live
-        # slab/rowmap and never disturb this pair, so a rescore can never
-        # crash on, or misalign against, a concurrently mutated store. A
-        # point update rewriting a captured row in place is visible here —
-        # the rescore ranks with fresher factors than the scan, benign.
-        self.slab = slab
-        self.slab_rows = slab_rows  # (n,) slab row per snapshot position
-        self.mat = None         # no f32 device matrix in this mode
-        self.score_mat = None
-        self.mesh = None
-        if prev is not None and appended is not None:
-            # id→idx append-only sharing, exactly like _YSnapshot
-            self.id_to_idx = prev.id_to_idx
-            for i in range(len(prev.ids), len(ids)):
-                self.id_to_idx[ids[i]] = i
-        else:
-            self.id_to_idx = {s: i for i, s in enumerate(ids)}
-        # lazy cost-registration marks: per generation, carried across
-        # same-shape incremental snapshots (see _YSnapshot)
-        if (prev is not None
-                and getattr(prev.qmat, "shape", None)
-                == getattr(qmat, "shape", None)):
-            self.cost_keys_attempted = prev.cost_keys_attempted
-        else:
-            self.cost_keys_attempted: set = set()
-        profiling.register_quantized(self)
+        super().__init__(ids, version, qmat,
+                         lsh if buckets is not None else None, slab,
+                         slab_rows, rescore_factor, prev, incremental)
 
     @property
-    def n(self) -> int:
-        return len(self.ids)
+    def scanned(self):
+        return self.qmat
 
     def quantized_nbytes(self) -> int:
         """Device bytes held by the quantized factors (the
@@ -585,46 +709,38 @@ class _QuantSnapshot:
             total += int(getattr(arr, "nbytes", 0) or 0)
         return total
 
-    def gather_rows(self, positions: np.ndarray) -> np.ndarray:
-        """Exact f32 factor rows for snapshot ``positions``, gathered from
-        the PINNED slab view (see __init__) — one fancy index."""
-        pos = np.clip(np.asarray(positions, dtype=np.int64), 0, self.n - 1)
-        return self.slab[self.slab_rows[pos]]
+    def device_arrays(self) -> list:
+        return [a for a in (self.qmat, self.qscale, self.norms, self.buckets)
+                if a is not None]
 
     @classmethod
     def build(cls, ids, host: np.ndarray, version: int,
               lsh: "LocalitySensitiveHash | None",
               row_view: tuple,
-              prev: "_QuantSnapshot | None" = None):
-        """Full quantized build from one host matrix, chunked so the
-        transient stays bounded at reference scale."""
-        n = len(ids)
+              prev: "_QuantSnapshot | None" = None,
+              rescore_factor: float = 4.0):
+        """Full quantized build from one host matrix."""
         slab, slab_rows = row_view
-        if n == 0 or host.size == 0:
-            return cls(list(ids), version, None, None, None, None)
-        k = host.shape[1]
-        q = np.empty((n, k), dtype=np.int8)
-        scale = np.empty(n, dtype=np.float32)
-        norms = np.empty(n, dtype=np.float32)
-        for a in range(0, n, _QUANT_CHUNK):
-            b = min(n, a + _QUANT_CHUNK)
-            q[a:b], scale[a:b] = _quantize_rows(host[a:b])
-            norms[a:b] = np.linalg.norm(host[a:b], axis=1)
+        if len(ids) == 0 or host.size == 0:
+            return cls(list(ids), version, None, None, None, None,
+                       rescore_factor=rescore_factor)
+        q, scale, norms = _quantize_chunked(host)
         buckets = None
         if lsh and lsh.num_hashes:
             buckets = jnp.asarray(lsh.assign_buckets(host))
         return cls(list(ids), version, jnp.asarray(q), jnp.asarray(scale),
-                   jnp.asarray(norms), buckets, prev=prev,
-                   slab=slab, slab_rows=slab_rows)
+                   jnp.asarray(norms), buckets, lsh, prev=prev,
+                   slab=slab, slab_rows=slab_rows,
+                   rescore_factor=rescore_factor)
 
     @classmethod
-    def from_delta(cls, prev: "_QuantSnapshot", delta,
-                   lsh: "LocalitySensitiveHash | None"):
+    def from_delta(cls, prev: "_QuantSnapshot", delta):
         """Incremental step: requantize only the changed/appended rows and
         land them as device row scatters / one append."""
         qmat, qscale, norms, buckets = (
             prev.qmat, prev.qscale, prev.norms, prev.buckets
         )
+        lsh = prev.lsh
         changed_pos = [prev.id_to_idx[i] for i in delta.changed_ids
                        if i in prev.id_to_idx]
         if changed_pos:
@@ -648,18 +764,50 @@ class _QuantSnapshot:
             if buckets is not None:
                 buckets = jnp.concatenate([buckets, jnp.asarray(
                     lsh.assign_buckets(delta.appended_vals))])
-        ids = prev.ids + delta.appended_ids
-        # extend the pinned rescore view: delta.slab is the CURRENT slab
-        # (a non-structural grow copies rows in place, so prev's indices
-        # stay valid in it) and the appended ids bring their own rows
-        slab_rows = (
-            np.concatenate([prev.slab_rows,
-                            np.asarray(delta.appended_rows, dtype=np.int64)])
-            if len(delta.appended_ids) else prev.slab_rows
-        )
-        return cls(ids, delta.version, qmat, qscale, norms, buckets,
-                   prev=prev, appended=delta.appended_ids,
-                   slab=delta.slab, slab_rows=slab_rows)
+        ids, slab_rows = prev.appended(delta)
+        return cls(ids, delta.version, qmat, qscale, norms, buckets, lsh,
+                   prev=prev, incremental=True, slab=delta.slab,
+                   slab_rows=slab_rows, rescore_factor=prev.rescore_factor)
+
+    def batch_width(self, how_many: int, filtering: bool) -> int:
+        return min(self.n, self.rescore_width(how_many))
+
+    def plan(self, qs, excl, lut, r: int):
+        """Top-``r`` CANDIDATES (approximate scores) for the exact rescore:
+        ONE quantized scan over the whole query batch (¼ the f32 HBM per
+        pass); the per-query lut selects the masked program."""
+        key = _topn_cost_key(qs.shape[0], excl is not None, quant=True)
+        if lut is not None:
+            return ((_quant_candidates_masked,
+                     (self.qmat, self.qscale, qs, lut, self.buckets, excl, r),
+                     key),)
+        return ((_quant_candidates,
+                 (self.qmat, self.qscale, qs, None, excl, r), key),)
+
+    def candidates(self, scan, q_host: np.ndarray, want: int, excluded,
+                   hooks: bool):
+        # the quantized matmul runs ONCE, exactly like the f32 path; each
+        # widening rescores its candidates exactly from the arena
+        valid = (_candidate_mask(self, q_host[None, :], self.n)
+                 if self.lsh is not None else None)
+        scores = _quant_masked_scores(
+            self.qmat, self.qscale, jnp.asarray(q_host[None, :]), valid,
+            self.one_excluded(excluded))
+        return _widen_top_k(self, scores, self.rescore_width(want), q_host)
+
+    def cosine_candidates(self, qs_host: np.ndarray, want: int):
+        # quantized candidates (norms are exact f32), exact mean-cosine
+        # rescore from the arena slab before the final cut
+        qs = jnp.asarray(qs_host)
+        q_norms = jnp.linalg.norm(qs, axis=1)
+        valid = _candidate_mask(self, qs_host, self.n)
+        for r in _doubling(self.rescore_width(want), self.n):
+            v, i = _quant_cosine_candidates(
+                self.qmat, self.qscale, self.norms, qs, q_norms, valid, r)
+            vals, idx = self.rescore(
+                qs_host, np.asarray(v)[None, :], np.asarray(i)[None, :],
+                cosine=True)
+            yield vals[0], idx[0]
 
 
 class ALSServingModel(ServingModel):
@@ -720,8 +868,25 @@ class ALSServingModel(ServingModel):
         self.expected_user_ids: set[str] = set()
         self.expected_item_ids: set[str] = set()
         self.yty_cache = SolverCache(self.y.get_vtv)
-        self._snapshot: _YSnapshot | None = None
-        self._snapshot_src = None
+        # the scan backend, chosen here where the options are resolved and
+        # never asked about again: every snapshot it builds owns its
+        # program, its width and its warm signatures (topn.py:_Snapshot)
+        if self.index_enabled:
+            backend, options = ivf_mod.IVFSnapshot, dict(
+                cells=self.index_cells, probes=self.index_probes,
+                skew_bound=self.index_skew,
+                rescore_factor=self.rescore_factor)
+        elif device_dtype == "int8":
+            backend, options = _QuantSnapshot, dict(
+                rescore_factor=self.rescore_factor)
+        elif mesh is not None:
+            backend, options = _ShardedYSnapshot, dict(
+                mesh=mesh, shard_axis=shard_axis, device_dtype=device_dtype)
+        else:
+            backend, options = _YSnapshot, dict(device_dtype=device_dtype)
+        self._source = backend.source
+        self._current = functools.partial(backend.current, **options)
+        self._snapshot: _Snapshot | None = None
         self._snap_lock = threading.Lock()
 
     # -- vector + known-item bookkeeping ------------------------------------
@@ -811,216 +976,16 @@ class ALSServingModel(ServingModel):
 
     # -- device snapshot ----------------------------------------------------
     def y_snapshot(self):
-        if self.device_dtype == "int8":
-            if self.index_enabled:
-                return self._ivf_snapshot()
-            return self._quant_snapshot()
-        ids, mat = self.y.materialize()
+        """The backend's current view of Y (``.n``, ``.ids``, ``.mesh``):
+        the one it last built while the store has not moved, else the next —
+        incremental where the backend can take the step."""
+        source = self._source(self.y)
         with self._snap_lock:
-            if self._snapshot is None or self._snapshot_src is not mat:
-                prev, delta = None, None
-                if self._snapshot is not None and self._snapshot.mat is not None \
-                        and mat is not None:
-                    # catch up across any number of incremental generations
-                    # (e.g. get_vtv consumed pending batches in between)
-                    delta = self.y.delta_since(self._snapshot.mat, mat)
-                    if delta is not None:
-                        prev = self._snapshot
-                self._snapshot = _YSnapshot(
-                    ids, mat, self.lsh, self.mesh, self.shard_axis,
-                    prev=prev, delta=delta, device_dtype=self.device_dtype,
-                )
-                self._snapshot_src = mat
+            self._snapshot = self._current(
+                self.y, self.lsh, self._snapshot, source)
             return self._snapshot
-
-    def _quant_snapshot(self) -> _QuantSnapshot:
-        """Current int8 device view: incremental (requantize + scatter only
-        the rows a speed microbatch touched) when the arena's write log
-        covers the gap, full chunked rebuild otherwise. The store's f32
-        device-materialization cache is never engaged in this mode — the
-        arena slab itself is the exact-f32 source of truth (the rescore
-        gathers straight from it)."""
-        with self._snap_lock:
-            prev = self._snapshot if isinstance(self._snapshot, _QuantSnapshot) else None
-            if prev is not None and prev.qmat is not None:
-                delta = self.y.delta_info(prev.version, len(prev.ids))
-                if delta is not None:
-                    if not delta.changed_ids and not delta.appended_ids:
-                        return prev
-                    self._snapshot = _QuantSnapshot.from_delta(
-                        prev, delta, self.lsh
-                    )
-                    return self._snapshot
-            ids, host, version, row_view = self.y.host_matrix()
-            self._snapshot = _QuantSnapshot.build(
-                ids, host, version, self.lsh, row_view, prev=prev
-            )
-            return self._snapshot
-
-    def _ivf_snapshot(self) -> "ivf_mod.IVFSnapshot":
-        """Current IVF device view: incremental (requantize + reassign only
-        the rows a speed microbatch touched, rewrite only the affected
-        cells) when the arena's write log covers the gap AND the update
-        neither overflows a cell nor drifts the balance past the skew
-        bound; full re-cluster rebuild otherwise."""
-        with self._snap_lock:
-            prev = (self._snapshot
-                    if isinstance(self._snapshot, ivf_mod.IVFSnapshot)
-                    else None)
-            if prev is not None and prev.cell_q is not None:
-                delta = self.y.delta_info(prev.version, len(prev.ids))
-                if delta is not None:
-                    if not delta.changed_ids and not delta.appended_ids:
-                        return prev
-                    nxt = ivf_mod.IVFSnapshot.from_delta(
-                        prev, delta, self.lsh
-                    )
-                    if nxt is not None:
-                        self._snapshot = nxt
-                        return nxt
-            ids, host, version, row_view = self.y.host_matrix()
-            self._snapshot = ivf_mod.IVFSnapshot.build(
-                ids, host, version, self.lsh, row_view, prev=prev,
-                cells=self.index_cells, probes=self.index_probes,
-                skew_bound=self.index_skew,
-            )
-            return self._snapshot
-
-    def _rescore_exact(self, snap: _QuantSnapshot, qs_host: np.ndarray,
-                       vals: np.ndarray, idx: np.ndarray,
-                       cosine: bool = False) -> "tuple[np.ndarray, np.ndarray]":
-        """Exact f32 rescore of the quantized scan's candidates: gather the
-        candidate rows from the snapshot's PINNED arena-slab view (one
-        fancy index — the slab is what makes this cheap), recompute exact
-        scores, and return the candidates re-ranked by exact score. Masked
-        candidates (-inf from the scan) stay -inf. For ``cosine`` the batch
-        dimension is the query-vector set of ONE request (mean cosine)."""
-        B, R = idx.shape
-        rows = snap.gather_rows(idx.reshape(-1)).reshape(B, R, -1)
-        if cosine:
-            # one request, many query vectors: qs_host (Q, k); rows (1, R, k)
-            r = rows[0]
-            rn = np.linalg.norm(r, axis=1)
-            qn = np.linalg.norm(qs_host, axis=1)
-            sims = (r @ qs_host.T) / np.maximum(
-                rn[:, None] * qn[None, :], 1e-12
-            )
-            exact = np.mean(sims, axis=1, dtype=np.float32)[None, :]
-        else:
-            exact = np.einsum("bk,brk->br", qs_host, rows).astype(np.float32)
-        exact = np.where(np.isfinite(vals), exact, -np.inf)
-        order = np.argsort(-exact, axis=1, kind="stable")
-        return (np.take_along_axis(exact, order, axis=1),
-                np.take_along_axis(idx, order, axis=1))
-
-    def _quant_scan(self, snap: _QuantSnapshot, qs_host: np.ndarray,
-                    r: int, excl, valid=None, lut=None,
-                    register_cost: "str | None" = None):
-        """One quantized candidate scan + exact rescore: (vals, idx) of
-        width ``r``, exact-f32-ranked. ``excl`` is the padded (B, E) index
-        array or None; ``valid`` an optional (n,) candidate mask; ``lut``
-        a per-query (B, num_buckets) LSH lookup table (selects the masked
-        program). One registration/record/rescore sequence serves every
-        variant."""
-        with spans.stage("topn.upload"):
-            qs = jnp.asarray(qs_host)
-        with spans.stage("topn.dispatch"):
-            if lut is not None:
-                fn = _quant_candidates_masked
-                args = (snap.qmat, snap.qscale, qs, lut, snap.buckets, excl, r)
-            else:
-                fn = _quant_candidates
-                args = (snap.qmat, snap.qscale, qs, valid, excl, r)
-            if register_cost is not None and (
-                    register_cost not in snap.cost_keys_attempted
-                    and metrics_mod.default_registry().enabled):
-                snap.cost_keys_attempted.add(register_cost)
-                compilecache.aot_compile(fn, *args, cost_key=register_cost)
-            vals, idx = fn(*args)
-            if register_cost is not None:
-                profiling.costs().record(register_cost)
-        with spans.stage("topn.wait_download"):
-            vals, idx = np.asarray(vals), np.asarray(idx)
-        with spans.stage("topn.rescore"):
-            return self._rescore_exact(snap, qs_host, vals, idx)
 
     # -- query primitives ----------------------------------------------------
-    @staticmethod
-    def _excluded_indices(snap: _YSnapshot, excluded, batch: int) -> np.ndarray:
-        """(B, E) int32 of global Y rows to mask out, -1-padded, E a pow2
-        FLOORED at ``_EXCL_PAD_MIN`` so the common exclusion widths all
-        share one jit signature — the one the batch warmer precompiles."""
-        idx_lists: list[list[int]] = []
-        max_e = 1
-        for b in range(batch):
-            ids = excluded[b] if excluded is not None else None
-            ix = (
-                [snap.id_to_idx[i] for i in ids if i in snap.id_to_idx]
-                if ids
-                else []
-            )
-            idx_lists.append(ix)
-            max_e = max(max_e, len(ix))
-        width = max(_EXCL_PAD_MIN, _round_up_pow2(max_e))
-        out = np.full((batch, width), -1, dtype=np.int32)
-        for b, ix in enumerate(idx_lists):
-            out[b, : len(ix)] = ix
-        return out
-
-    def _build_lut(self, qs_host: np.ndarray) -> np.ndarray:
-        """(B, num_buckets) bool LSH candidate lookup table, one row per
-        query — fully vectorized over the batch (lsh.get_candidate_lut)."""
-        return self.lsh.get_candidate_lut(qs_host)
-
-    def _sharded_query(self, snap: _YSnapshot, qs_host: np.ndarray, want: int,
-                       excluded, cost_key: "str | None" = None):
-        """Multi-device scan: the one-chip scan on every shard + cross-shard
-        merge, with LSH lut and per-query known-item exclusion applied
-        device-side (no host fallback for filtered traffic). Returns
-        ``(vals, idx)`` on the host, at least ``min(want, n)`` wide."""
-        B = qs_host.shape[0]
-        use_lut = self.lsh is not None and snap.buckets is not None
-        use_excl = excluded is not None and any(e for e in excluded)
-        with spans.stage("topn.upload"):
-            # batch-shaped operands go from the host to every device at once
-            # (not to one device and on from there inside the dispatch)
-            everywhere = replicated_sharding(snap.mesh)
-            args = [snap.score_mat, jax.device_put(qs_host, everywhere)]
-            if use_excl:
-                args.append(jax.device_put(
-                    self._excluded_indices(snap, excluded, B), everywhere))
-            if use_lut:
-                args += [jax.device_put(self._build_lut(qs_host), everywhere),
-                         snap.buckets]
-        with spans.stage("topn.dispatch"):
-            fn = self._sharded_program(snap, want, use_lut, use_excl)
-            if (cost_key is not None
-                    and cost_key not in snap.cost_keys_attempted
-                    and metrics_mod.default_registry().enabled):
-                # as on one chip: the first use of a signature shares its
-                # compile with the cost registration
-                snap.cost_keys_attempted.add(cost_key)
-                compilecache.aot_compile(fn, *args, cost_key=cost_key)
-            vals, idx = fn(*args)
-            if cost_key is not None:
-                profiling.costs().record(cost_key)
-        with spans.stage("topn.wait_download"):
-            return np.asarray(vals), np.asarray(idx)
-
-    def _sharded_program(self, snap: _YSnapshot, want: int, use_lut: bool,
-                         use_excl: bool):
-        """The jitted mesh scan for ``want`` results a query: each shard
-        keeps ``k`` candidates (a pow2 ≥ 16, as the one-chip program's
-        width), the merge ``k_final``."""
-        ndev = snap.mesh.shape[snap.shard_axis]
-        n_local = snap.n_rows // ndev
-        width = _round_up_pow2(max(min(want, snap.n), 16))
-        k = min(n_local, width)
-        return _sharded_top_k_fn(
-            snap.mesh, snap.shard_axis, k, min(ndev * k, width), snap.n,
-            use_lut, use_excl,
-        )
-
     def top_n(
         self,
         query_vec: np.ndarray,
@@ -1035,86 +1000,19 @@ class ALSServingModel(ServingModel):
         are masked on device; ``allowed``/``rescore`` host hooks (rescorer SPI)
         filter the candidate stream with widening retry."""
         snap = self.y_snapshot()
-        if snap.n == 0 or (snap.mat is None and not isinstance(
-                snap, (_QuantSnapshot, ivf_mod.IVFSnapshot))):
+        if not snap.servable:
             return []
-        q_host = np.asarray(query_vec, dtype=np.float32)
-        if isinstance(snap, ivf_mod.IVFSnapshot):
-            return ivf_mod.top_n(
-                self, snap, q_host, how_many, offset, allowed, rescore,
-                excluded,
-            )
-        if isinstance(snap, _QuantSnapshot):
-            return self._quant_top_n(
-                snap, q_host, how_many, offset, allowed, rescore, excluded
-            )
-        want = how_many + offset
-        if snap.mesh is not None:
-            k = want if allowed is None and rescore is None else max(4 * want, 64)
-            while True:
-                vals, idx = self._sharded_query(
-                    snap, q_host[None, :], k, [excluded] if excluded else None
-                )
-                out = self._collect(snap, vals[0], idx[0], want, allowed, rescore)
-                if len(out) >= want or k >= snap.n:
-                    return out[offset:offset + how_many]
-                k = min(snap.n, k * 2)  # widen: host filter consumed candidates
-        q = jnp.asarray(q_host)
-        # unfiltered hot path stays exactly matmul + top_k: masks are None
-        # (static) unless LSH or exclusions actually apply
-        has_lsh = self.lsh is not None and snap.buckets is not None
-        valid = self._candidate_mask(snap, q_host) if has_lsh else None
-        excl = None
-        if excluded:
-            # pow2-padded with -1 fill (the batch helper at batch=1) so jit
-            # signatures stay stable: every distinct known-item count would
-            # otherwise trigger a fresh compile on the serving hot path
-            padded = self._excluded_indices(snap, [excluded], 1)
-            if (padded >= 0).any():
-                excl = jnp.asarray(padded)
-        # score once; widenings re-run only the top-k over the cached scores
-        scores = _masked_scores(snap.score_mat, q[None, :], valid, excl)
-        k = min(snap.n, _round_up_pow2(max(4 * want, 64)))
-        while True:
-            vals, idx = _top_k_of_scores(scores, k)
-            out = self._collect(
-                snap, np.asarray(vals)[0], np.asarray(idx)[0], want, allowed, rescore
-            )
-            if len(out) >= want or k >= snap.n:
-                return out[offset:offset + how_many]
-            k = min(snap.n, k * 2)  # widen if filtering consumed candidates
+        return self._top_n(snap, np.asarray(query_vec, dtype=np.float32),
+                           how_many, offset, allowed, rescore, excluded)
 
-    def _quant_top_n(
-        self, snap: _QuantSnapshot, q_host: np.ndarray, how_many: int,
-        offset: int, allowed, rescore, excluded,
-    ) -> list[tuple[str, float]]:
-        """Single-query top-N on the int8 path: quantized candidate scan →
-        exact f32 rescore from the arena → host filtering. The quantized
-        matmul runs ONCE; widenings (``allowed``/``rescore`` hooks consuming
-        candidates) re-run only the top-k over the cached score matrix,
-        exactly like the f32 path — never another full-bandwidth pass
-        over the int8 slab."""
-        want = how_many + offset
-        excl = None
-        if excluded:
-            padded = self._excluded_indices(snap, [excluded], 1)
-            if (padded >= 0).any():
-                excl = jnp.asarray(padded)
-        has_lsh = self.lsh is not None and snap.buckets is not None
-        valid = self._candidate_mask(snap, q_host) if has_lsh else None
-        scores = _quant_masked_scores(
-            snap.qmat, snap.qscale, jnp.asarray(q_host[None, :]), valid, excl
-        )
-        r = min(snap.n, _round_up_pow2(max(int(self.rescore_factor * want), 16)))
-        while True:
-            v, i = _top_k_of_scores(scores, r)
-            vals, idx = self._rescore_exact(
-                snap, q_host[None, :], np.asarray(v), np.asarray(i)
-            )
-            out = self._collect(snap, vals[0], idx[0], want, allowed, rescore)
-            if len(out) >= want or r >= snap.n:
-                return out[offset:offset + how_many]
-            r = min(snap.n, r * 2)  # widen: host filter consumed candidates
+    @staticmethod
+    def _top_n(snap, q_host: np.ndarray, how_many: int, offset: int, allowed,
+               rescore, excluded) -> list[tuple[str, float]]:
+        return _first_enough(
+            snap,
+            snap.candidates(_scan, q_host, how_many + offset, excluded,
+                            allowed is not None or rescore is not None),
+            how_many, offset, allowed, rescore)
 
     def top_n_batch(
         self,
@@ -1148,139 +1046,31 @@ class ALSServingModel(ServingModel):
         excluded: "Sequence[Sequence[str] | None] | None" = None,
     ) -> list[list[tuple[str, float]]]:
         snap = self.y_snapshot()
-        if snap.n == 0 or (snap.mat is None and not isinstance(
-                snap, (_QuantSnapshot, ivf_mod.IVFSnapshot))):
+        if not snap.servable:
             return [[] for _ in range(len(query_vecs))]
         qs_host = np.asarray(query_vecs, dtype=np.float32)
         filtering = alloweds is not None and any(a is not None for a in alloweds)
-        if isinstance(snap, ivf_mod.IVFSnapshot):
-            return ivf_mod.top_n_batch(
-                self, snap, qs_host, how_many, alloweds, excluded, filtering
-            )
-        if isinstance(snap, _QuantSnapshot):
-            return self._quant_top_n_batch(
-                snap, qs_host, how_many, alloweds, excluded, filtering
-            )
-        use_excl = excluded is not None and any(e for e in excluded)
-        masked = self.lsh is not None and snap.buckets is not None
-        if snap.mesh is not None:
-            # the mesh scan has the stages of the one-chip call and a cost
-            # key of its own (its per-call cost is a shard's, plus the merge)
-            k = min(snap.n, _round_up_pow2(
-                max(2 * how_many, 64) if filtering else max(how_many, 16)))
-            vals, idx = self._sharded_query(
-                snap, qs_host, k, excluded,
-                cost_key=_topn_cost_key(len(qs_host), use_excl) + "+sharded",
-            )
-        else:
-            vals, idx, k = self._one_device_scan(
-                snap, qs_host, how_many, excluded, use_excl, masked,
-                filtering)
-        with spans.stage("topn.ids"):
-            if not filtering:
-                return _id_lists(snap.ids, vals, idx, how_many)
-            out = []
-            for b in range(len(query_vecs)):
-                allowed = alloweds[b] if alloweds else None
-                got = self._collect(
-                    snap, vals[b], idx[b], how_many, allowed, None)[:how_many]
-                if len(got) < how_many and k < snap.n:
-                    # heavy filtering consumed this query's candidates —
-                    # fall back to the widening single-query path
-                    got = self.top_n(
-                        qs_host[b], how_many, 0, allowed, None,
-                        excluded=excluded[b] if excluded else None,
-                    )
-                out.append(got)
-            return out
-
-    def _one_device_scan(self, snap: _YSnapshot, qs_host: np.ndarray,
-                         how_many: int, excluded, use_excl: bool,
-                         masked: bool, filtering: bool):
-        """The stages of one call on one device, end to end
-        (docs/observability.md): what the coalescer's device-call span is
-        made of on this side. Returns ``(vals, idx, k)`` on the host."""
-        with spans.stage("topn.upload"):
-            qs = jnp.asarray(qs_host)
-            excl = (
-                jnp.asarray(
-                    self._excluded_indices(snap, excluded, len(qs_host)))
-                if use_excl
-                else None
-            )
-            # per-query LSH candidate masks: (B, num_buckets) lookup table
-            # indexed by item bucket on device
-            lut = jnp.asarray(self._build_lut(qs_host)) if masked else None
-        with spans.stage("topn.dispatch"):
-            cost_key = _topn_cost_key(len(qs_host), use_excl)
-            if masked:
-                k = min(snap.n, _round_up_pow2(max(2 * how_many, 64)))
-                fn = _top_k_dot_batch_masked
-                args = (snap.score_mat, qs, lut, snap.buckets, excl, k)
-            else:
-                k = min(
-                    snap.n,
-                    _round_up_pow2(max(2 * how_many, 64) if filtering
-                                   else max(how_many, 16)),
-                )
-                fn = _top_k_dot_batch
-                args = (snap.score_mat, qs, None, excl, k)
-            if (cost_key not in snap.cost_keys_attempted
-                    and metrics_mod.default_registry().enabled):
-                # first use of this signature this generation: the dispatch
-                # below pays the XLA compile anyway — the sanctioned AOT
-                # route shares that compile AND yields the executable's
-                # cost_analysis, so unwarmed signatures (odd batch sizes,
-                # direct callers) still attribute FLOPs instead of reading
-                # zero forever
-                snap.cost_keys_attempted.add(cost_key)
-                compilecache.aot_compile(fn, *args, cost_key=cost_key)
-            vals, idx = fn(*args)
-            profiling.costs().record(cost_key)
-        with spans.stage("topn.wait_download"):
-            # the program's run and the copy back: the first conversion
-            # blocks until the device is done
-            return np.asarray(vals), np.asarray(idx), k
-
-    def _quant_top_n_batch(
-        self, snap: _QuantSnapshot, qs_host: np.ndarray, how_many: int,
-        alloweds, excluded, filtering: bool,
-    ) -> list[list[tuple[str, float]]]:
-        """Batched top-N on the int8 path: ONE quantized device scan over
-        the whole query batch (¼ the f32 HBM per pass) returning
-        ``rescore-factor × how_many`` candidates each, exact-f32-rescored
-        from the arena slab before the final cut. Cost keys carry ``+int8``
-        so the attribution (and the warm ladder) see the quantized programs
-        as their own signatures."""
-        use_excl = excluded is not None and any(e for e in excluded)
-        excl = (
-            jnp.asarray(self._excluded_indices(snap, excluded, len(qs_host)))
-            if use_excl
-            else None
-        )
-        cost_key = _topn_cost_key(len(qs_host), use_excl, quant=True)
-        r = min(snap.n,
-                _round_up_pow2(max(int(self.rescore_factor * how_many), 16)))
-        lut = (
-            jnp.asarray(self._build_lut(qs_host))
-            if self.lsh is not None and snap.buckets is not None
-            else None
-        )
-        vals, idx = self._quant_scan(
-            snap, qs_host, r, excl, lut=lut, register_cost=cost_key
-        )
+        vals, idx = _scan(snap, qs_host, excluded,
+                          snap.batch_width(how_many, filtering),
+                          register=True)
+        if snap.rescore is not None:
+            # an approximate backend's candidates, exact-f32-rescored from
+            # the arena slab before the final cut
+            with spans.stage("topn.rescore"):
+                vals, idx = snap.rescore(qs_host, vals, idx)
         with spans.stage("topn.ids"):
             if not filtering:
                 return _id_lists(snap.ids, vals, idx, how_many)
             out = []
             for b in range(len(qs_host)):
                 allowed = alloweds[b] if alloweds else None
-                got = self._collect(
+                got = _collect(
                     snap, vals[b], idx[b], how_many, allowed, None)[:how_many]
-                if len(got) < how_many and r < snap.n:
-                    # heavy filtering consumed this query's candidates —
-                    # fall back to the widening single-query quant path
-                    got = self._quant_top_n(
+                if len(got) < how_many and vals.shape[1] < snap.n:
+                    # heavy filtering consumed this query's candidates and
+                    # the scan was narrower than Y — fall back to the
+                    # widening single-query path
+                    got = self._top_n(
                         snap, qs_host[b], how_many, 0, allowed, None,
                         excluded[b] if excluded else None,
                     )
@@ -1301,119 +1091,38 @@ class ALSServingModel(ServingModel):
         the device-resident factor snapshot. Raises when the model has no
         items yet (the warmer retries later).
 
+        What is compiled is what the flush dispatches: the snapshot's own
+        ``plan``, asked with shapes where ``_scan`` asks with arrays, under
+        the same cost keys — so a handoff warms exactly the signatures its
+        traffic runs, whichever backend serves it.
+
         BOTH signature families warm: exclusion-free AND exclusion-carrying
         — the default ``/recommend`` path (considerKnownItems=false) always
         sends known-item exclusions, and ``_excluded_indices`` pads them to
         the shape-stable ``_EXCL_PAD_MIN`` width this warms, so the first
         client burst after a MODEL handoff pays no compile on the endpoint
         it actually calls."""
-        import jax
-
         snap = self.y_snapshot()
-        if snap.n == 0 or (snap.mat is None and not isinstance(
-                snap, (_QuantSnapshot, ivf_mod.IVFSnapshot))):
+        if not snap.servable:
             raise ValueError("no item factors to warm against yet")
-        qs_struct = jax.ShapeDtypeStruct(
-            (batch_size, self.features), jnp.float32
-        )
-        excl_struct = jax.ShapeDtypeStruct(
-            (batch_size, _EXCL_PAD_MIN), jnp.int32
-        )
-        if isinstance(snap, ivf_mod.IVFSnapshot):
-            # the IVF ladder: pow2 (batch, probes) probe + scan signatures
-            # under their own cost keys; the shared zero-batch executions
-            # below then populate the exact dispatch caches requests hit
-            ivf_mod.warm_bucket(self, snap, batch_size, how_many)
-        elif isinstance(snap, _QuantSnapshot):
-            # the quantized ladder: its programs (and so its AOT cost keys)
-            # are distinct from the f32/bf16 scan's — a quantized-model
-            # handoff warms exactly the signatures its traffic dispatches
-            r = min(snap.n,
-                    _round_up_pow2(max(int(self.rescore_factor * how_many), 16)))
-            keys = (_topn_cost_key(batch_size, False, quant=True),
-                    _topn_cost_key(batch_size, True, quant=True))
-            if self.lsh is None or snap.buckets is None:
+        width = snap.batch_width(how_many, False)
+        qs = snap.struct((batch_size, self.features), jnp.float32)
+        lut = (snap.struct((batch_size, snap.lsh.num_buckets), jnp.bool_)
+               if snap.lsh is not None else None)
+        compiled = set()
+        for excl in (None,
+                     snap.struct((batch_size, _EXCL_PAD_MIN), jnp.int32)):
+            for fn, args, cost_key in snap.plan(qs, excl, lut, width):
+                if cost_key in compiled:
+                    continue  # a step both families share
+                compiled.add(cost_key)
                 compilecache.aot_compile(
-                    _quant_candidates, snap.qmat, snap.qscale, qs_struct,
-                    None, None, r, cost_key=keys[0],
-                )
-                compilecache.aot_compile(
-                    _quant_candidates, snap.qmat, snap.qscale, qs_struct,
-                    None, excl_struct, r, cost_key=keys[1],
-                )
-            else:
-                lut_struct = jax.ShapeDtypeStruct(
-                    (batch_size, self.lsh.num_buckets), jnp.bool_
-                )
-                compilecache.aot_compile(
-                    _quant_candidates_masked, snap.qmat, snap.qscale,
-                    qs_struct, lut_struct, snap.buckets, None, r,
-                    cost_key=keys[0],
-                )
-                compilecache.aot_compile(
-                    _quant_candidates_masked, snap.qmat, snap.qscale,
-                    qs_struct, lut_struct, snap.buckets, excl_struct, r,
-                    cost_key=keys[1],
-                )
-            snap.cost_keys_attempted.update(keys)
-        elif snap.mesh is not None:
-            # the mesh scan's ladder: the same two families under the
-            # sharded cost keys, compiled against Y's shards as they lie
-            use_lut = self.lsh is not None and snap.buckets is not None
-            everywhere = replicated_sharding(snap.mesh)
-
-            def struct(like):
-                return jax.ShapeDtypeStruct(
-                    like.shape, like.dtype, sharding=everywhere)
-
-            lut = ((jax.ShapeDtypeStruct(
-                (batch_size, self.lsh.num_buckets), jnp.bool_,
-                sharding=everywhere), snap.buckets) if use_lut else ())
-            for use_excl in (False, True):
-                key = _topn_cost_key(batch_size, use_excl) + "+sharded"
-                compilecache.aot_compile(
-                    self._sharded_program(snap, how_many, use_lut, use_excl),
-                    snap.score_mat, struct(qs_struct),
-                    *((struct(excl_struct),) if use_excl else ()), *lut,
-                    cost_key=key,
-                )
-                snap.cost_keys_attempted.add(key)
-        elif self.lsh is None or snap.buckets is None:
-            k = min(snap.n, _round_up_pow2(max(how_many, 16)))
-            compilecache.aot_compile(
-                _top_k_dot_batch, snap.score_mat, qs_struct, None, None, k,
-                cost_key=_topn_cost_key(batch_size, False),
-            )
-            compilecache.aot_compile(
-                _top_k_dot_batch, snap.score_mat, qs_struct, None,
-                excl_struct, k,
-                cost_key=_topn_cost_key(batch_size, True),
-            )
-        else:
-            k = min(snap.n, _round_up_pow2(max(2 * how_many, 64)))
-            lut_struct = jax.ShapeDtypeStruct(
-                (batch_size, self.lsh.num_buckets), jnp.bool_
-            )
-            compilecache.aot_compile(
-                _top_k_dot_batch_masked, snap.score_mat, qs_struct,
-                lut_struct, snap.buckets, None, k,
-                cost_key=_topn_cost_key(batch_size, False),
-            )
-            compilecache.aot_compile(
-                _top_k_dot_batch_masked, snap.score_mat, qs_struct,
-                lut_struct, snap.buckets, excl_struct, k,
-                cost_key=_topn_cost_key(batch_size, True),
-            )
-        if snap.mesh is None and not isinstance(
-                snap, (_QuantSnapshot, ivf_mod.IVFSnapshot)):
-            # mark both signatures attempted: the lazy first-use
-            # registration in _top_n_batch would otherwise re-lower and
-            # re-compile each one the ladder just registered — once per
-            # signature per generation, during the handoff warm window
-            snap.cost_keys_attempted.update({
-                _topn_cost_key(batch_size, False),
-                _topn_cost_key(batch_size, True),
-            })
+                    fn, *_operands(args), cost_key=cost_key)
+        # marked attempted: the lazy first-use registration in _scan would
+        # otherwise re-lower and re-compile each signature the ladder just
+        # registered — once per signature per generation, during the
+        # handoff warm window
+        snap.cost_keys_attempted.update(compiled)
         zeros = np.zeros((batch_size, self.features), dtype=np.float32)
         self.top_n_batch(zeros, how_many)
         # one real exclusion-carrying execution: an id no snapshot contains
@@ -1434,96 +1143,21 @@ class ALSServingModel(ServingModel):
     ) -> list[tuple[str, float]]:
         """Mean-cosine top-N for /similarity (CosineAverageFunction.java:67)."""
         snap = self.y_snapshot()
-        if snap.n == 0 or (snap.mat is None and not isinstance(
-                snap, (_QuantSnapshot, ivf_mod.IVFSnapshot))):
+        if not snap.servable:
             return []
         qs_host = np.atleast_2d(np.asarray(query_vecs, dtype=np.float32))
-        if isinstance(snap, ivf_mod.IVFSnapshot):
-            return ivf_mod.top_n_cosine(
-                self, snap, qs_host,
-                np.linalg.norm(qs_host, axis=1), how_many, offset,
-                allowed, rescore,
-            )
-        qs = jnp.asarray(qs_host)
-        q_norms = jnp.linalg.norm(qs, axis=1)
-        # union of candidate buckets across ALL query vectors, mirroring the
-        # reference's per-partition candidate scan
-        valid = self._candidate_mask(snap, qs_host[0])
-        for extra in qs_host[1:]:
-            valid = valid | self._candidate_mask(snap, extra)
-        want = how_many + offset
-        if isinstance(snap, _QuantSnapshot):
-            # quantized candidates (norms are exact f32), exact mean-cosine
-            # rescore from the arena slab before the final cut
-            r = min(snap.n,
-                    _round_up_pow2(max(int(self.rescore_factor * want), 16)))
-            while True:
-                v, i = _quant_cosine_candidates(
-                    snap.qmat, snap.qscale, snap.norms, qs, q_norms, valid, r
-                )
-                vals, idx = self._rescore_exact(
-                    snap, qs_host, np.asarray(v)[None, :],
-                    np.asarray(i)[None, :], cosine=True,
-                )
-                out = self._collect(snap, vals[0], idx[0], want, allowed, rescore)
-                if len(out) >= want or r >= snap.n:
-                    return out[offset:offset + how_many]
-                r = min(snap.n, r * 2)
-        k = min(snap.n, _round_up_pow2(max(4 * want, 64)))
-        while True:
-            vals, idx = _top_k_cosine_sum(snap.mat, snap.norms, qs, q_norms, valid, k)
-            out = self._collect(snap, np.asarray(vals), np.asarray(idx), want, allowed, rescore)
-            if len(out) >= want or k >= snap.n:
-                return out[offset:offset + how_many]
-            k = min(snap.n, k * 2)
-
-    def _candidate_mask(self, snap: _YSnapshot, query_vec: np.ndarray):
-        """(n_rows,) bool: the rows a query may be answered from. The zero
-        rows that pad a sharded Y past its last id are never candidates."""
-        n_rows = getattr(snap, "n_rows", snap.n)
-        real = None if n_rows == snap.n else jnp.arange(n_rows) < snap.n
-        if self.lsh is None or snap.buckets is None:
-            return jnp.ones(snap.n, dtype=bool) if real is None else real
-        candidates = self.lsh.get_candidate_indices(query_vec)
-        lut = np.zeros(self.lsh.num_buckets, dtype=bool)
-        lut[candidates] = True
-        valid = jnp.asarray(lut)[snap.buckets]
-        return valid if real is None else valid & real
-
-    @staticmethod
-    def _collect(snap, vals, idx, want, allowed, rescore) -> list[tuple[str, float]]:
-        out: list[tuple[str, float]] = []
-        for v, i in zip(vals, idx):
-            if not np.isfinite(v):
-                break
-            id_ = snap.ids[int(i)]
-            if allowed is not None and not allowed(id_):
-                continue
-            score = float(v)
-            if rescore is not None:
-                score = rescore(id_, score)
-                if math.isnan(score):
-                    continue
-            out.append((id_, score))
-        if rescore is not None:
-            out.sort(key=lambda t: -t[1])
-        return out
+        return _first_enough(
+            snap, snap.cosine_candidates(qs_host, how_many + offset),
+            how_many, offset, allowed, rescore)
 
     def device_factor_bytes(self) -> int:
         """Bytes the current Y snapshot holds on device (f32 matrix +
-        scoring copy + norms + buckets, or the int8 slab + scales; summed
-        over the devices of a mesh) — the HBM side of the bench memory
-        section's f32-vs-int8 comparison."""
-        snap = self.y_snapshot()
-        if isinstance(snap, ivf_mod.IVFSnapshot):
-            return snap.device_nbytes()
-        arrays = (
-            (snap.qmat, snap.qscale, snap.norms, snap.buckets)
-            if isinstance(snap, _QuantSnapshot)
-            else snap.device_arrays()
-        )
+        scoring copy + norms + buckets, or the int8 slab + scales, or the
+        index's cells; summed over the devices of a mesh) — the HBM side of
+        the bench memory section's f32-vs-int8 comparison."""
         return int(sum(
-            int(getattr(a, "nbytes", 0) or 0) for a in arrays if a is not None
+            int(getattr(a, "nbytes", 0) or 0)
+            for a in self.y_snapshot().device_arrays()
         ))
 
     def dot_with_items(self, query_vec: np.ndarray, item_ids: Sequence[str]) -> list[float]:

@@ -1,0 +1,337 @@
+"""What every top-N scan backend shares, below both ``serving.py`` (the flat
+float, mesh-split and int8 views of Y, and the flush that drives them) and
+``ivf.py`` (the inverted-file view): the seam itself (:class:`_Snapshot` —
+a device view of Y that owns its program, its width and its warm
+signatures), the arena-backed exact rescore of the two approximate views
+(:class:`_ArenaSnapshot`), and the host-side pieces of a query that do not
+depend on the view (exclusion padding, candidate collection).
+
+Nothing here imports ``serving`` or ``ivf``: the arrows point one way.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from oryx_tpu.common import profiling
+
+
+def _round_up_pow2(n: int) -> int:
+    return 1 << max(0, (n - 1).bit_length())
+
+
+#: Floor of the pow2-bucketed exclusion-mask width. Known-item exclusion is
+#: what the DEFAULT /recommend path sends (considerKnownItems=false), so its
+#: jit signature must be shape-stable enough to PRE-warm: flooring the width
+#: means every request with ≤ this many known items — the overwhelming
+#: common case — lands on ONE compiled program, which the batch warmer
+#: compiles off-path (warm_bucket). Users past the floor bucket up by pow2
+#: and pay one compile per bucket per process (persistent-cache-served
+#: afterwards), exactly like unusual howMany values.
+_EXCL_PAD_MIN = 8
+
+#: Host-side quantization chunk: bounds the transient f32 gather while
+#: building a full quantized snapshot (2^16 rows × 50f ≈ 13 MB per chunk
+#: instead of one n×k f32 copy next to the arena slab).
+_QUANT_CHUNK = 1 << 16
+
+
+def _id_lists(ids, vals: np.ndarray, idx: np.ndarray, how_many: int) -> list:
+    """(B, >= how_many) scores and row indices, best first -> per query its
+    ``(id, score)`` list; masked candidates (-inf from the scan) left out."""
+    vb, ib = vals[:, :how_many], idx[:, :how_many]
+    return [
+        [(ids[int(i)], float(v)) for v, i in zip(vb[b], ib[b])
+         if np.isfinite(v)]
+        for b in range(len(vb))
+    ]
+
+
+def _collect(snap, vals, idx, want, allowed, rescore) -> list[tuple[str, float]]:
+    out: list[tuple[str, float]] = []
+    for v, i in zip(vals, idx):
+        if not np.isfinite(v):
+            break
+        id_ = snap.ids[int(i)]
+        if allowed is not None and not allowed(id_):
+            continue
+        score = float(v)
+        if rescore is not None:
+            score = rescore(id_, score)
+            if math.isnan(score):
+                continue
+        out.append((id_, score))
+    if rescore is not None:
+        out.sort(key=lambda t: -t[1])
+    return out
+
+
+def _excluded_indices(snap, excluded, batch: int) -> np.ndarray:
+    """(B, E) int32 of global Y rows to mask out, -1-padded, E a pow2
+    FLOORED at ``_EXCL_PAD_MIN`` so the common exclusion widths all
+    share one jit signature — the one the batch warmer precompiles."""
+    idx_lists: list[list[int]] = []
+    max_e = 1
+    for b in range(batch):
+        ids = excluded[b] if excluded is not None else None
+        ix = (
+            [snap.id_to_idx[i] for i in ids if i in snap.id_to_idx]
+            if ids
+            else []
+        )
+        idx_lists.append(ix)
+        max_e = max(max_e, len(ix))
+    width = max(_EXCL_PAD_MIN, _round_up_pow2(max_e))
+    out = np.full((batch, width), -1, dtype=np.int32)
+    for b, ix in enumerate(idx_lists):
+        out[b, : len(ix)] = ix
+    return out
+
+
+def _quantize_rows(mat: np.ndarray) -> "tuple[np.ndarray, np.ndarray]":
+    """Per-row symmetric int8 quantization: scale_i = max|row_i| / 127.
+    Zero rows get scale 1 (their dots are exactly 0 either way)."""
+    if mat.size == 0:
+        return (np.zeros(mat.shape, dtype=np.int8),
+                np.ones(mat.shape[0], dtype=np.float32))
+    amax = np.max(np.abs(mat), axis=1)
+    scale = np.where(amax > 0, amax / 127.0, 1.0).astype(np.float32)
+    q = np.clip(np.rint(mat / scale[:, None]), -127, 127).astype(np.int8)
+    return q, scale
+
+
+def _quantize_chunked(host: np.ndarray):
+    """``(q, scale, norms)`` of a whole host matrix, ``_QUANT_CHUNK`` rows
+    at a time so the transient stays bounded at reference scale."""
+    n, k = host.shape
+    q = np.empty((n, k), dtype=np.int8)
+    scale = np.empty(n, dtype=np.float32)
+    norms = np.empty(n, dtype=np.float32)
+    for a in range(0, n, _QUANT_CHUNK):
+        b = min(n, a + _QUANT_CHUNK)
+        q[a:b], scale[a:b] = _quantize_rows(host[a:b])
+        norms[a:b] = np.linalg.norm(host[a:b], axis=1)
+    return q, scale, norms
+
+
+class _Fed:
+    """In a plan's step after the first, the operand that is the result of
+    the step before: the flush puts that result in its place, the warm
+    ladder its shape."""
+
+    def __init__(self, shape, dtype):
+        self.struct = jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _operands(args: tuple, fed=None) -> tuple:
+    """A step's operands with the step before's result ``fed`` (or, for
+    ``None``, that result's shape) where they hold a :class:`_Fed`."""
+    return tuple(
+        (a.struct if fed is None else fed) if type(a) is _Fed else a
+        for a in args)
+
+
+class _Snapshot:
+    """An immutable device view of Y, and so the scan backend:
+    ``ALSServingModel`` holds whichever kind its options resolved to and
+    drives every one through these operations, never asking which it is.
+
+    * ``source(store)`` then ``current(store, lsh, prev, source, **options)``
+      (classmethods): the view of the store as it stands — ``prev`` itself,
+      an incremental step from it, or a rebuild. ``current`` is called under
+      the model's snapshot lock; ``source`` just before it, outside the
+      lock, for what a backend reads of the store that may take long.
+    * ``batch_width(how_many, filtering)``: the static width of a batch's
+      program — the width rule, once per backend.
+    * ``plan(qs, excl, lut, width)`` → steps ``(fn, args, cost_key)``, in
+      order: each a jitted program, its operands (static width last) and
+      its cost key, for a query batch ``qs``, optional ``(B, E)`` exclusions
+      and, where the view is LSH-masked, the per-query ``lut``. The three
+      may be arrays (the flush runs the steps) or ``jax.ShapeDtypeStruct``s
+      (the warm ladder compiles them): the ladder compiles exactly what the
+      flush dispatches. A later step takes the result of the one before it
+      where its operands hold a :class:`_Fed`.
+    * ``dispatched(batch, width)``: called after a scan's last step is on
+      its way — a backend's own counters.
+    * ``place(host)`` / ``struct(shape, dtype)``: a batch-shaped operand on
+      the device(s), as an array or as a shape.
+    * ``rescore(qs_host, vals, idx)``: exact f32 re-ranking of approximate
+      candidates; ``None`` where the scan's scores are final.
+    * ``candidates(scan, q_host, want, excluded, hooks)``: one query's
+      ``(vals, idx)`` candidate rows, then wider ones while any remain — the
+      backend's widening policy. ``scan`` is the flush's device side, for
+      the backends that widen by querying again.
+    * ``cosine_candidates(qs_host, want)``: the same for mean cosine.
+    * ``device_arrays()``: what it holds on the device; ``scanned``: the
+      one of them every batch program reads, whose shape keys the jit
+      signatures (``__init__`` takes it as it is at construction).
+    """
+
+    mesh = None     # set only by the view whose rows are split over one
+    rescore = None
+
+    @classmethod
+    def source(cls, store):
+        return None
+
+    def dispatched(self, batch: int, width) -> None:
+        pass
+
+    def __init__(self, ids, scanned, lsh=None, prev=None,
+                 incremental: bool = False):
+        self.ids = ids
+        # the LSH whose buckets this view carries (None: nothing is masked)
+        self.lsh = lsh
+        if prev is not None and incremental:
+            # id→idx is append-only across incremental generations; sharing
+            # the dict avoids an O(n) rebuild per microbatch (extra entries
+            # in the older snapshot only affect exclusion masks, which drop
+            # out-of-range rows on device)
+            self.id_to_idx = prev.id_to_idx
+            for i in range(len(prev.ids), len(ids)):
+                self.id_to_idx[ids[i]] = i
+        else:
+            self.id_to_idx = {s: i for i, s in enumerate(ids)}
+        # lazy cost-registration marks (see serving._scan): per GENERATION
+        # so a model swap re-registers against the new shapes, but carried
+        # across same-shape incremental snapshots (point-update microbatches
+        # whose dispatch signatures — and therefore per-call costs — are
+        # unchanged). Marked even when registration fails, so a backend
+        # without usable cost_analysis never re-pays lower+compile per call.
+        if prev is not None and (getattr(prev.scanned, "shape", None)
+                                 == getattr(scanned, "shape", None)):
+            self.cost_keys_attempted = prev.cost_keys_attempted
+        else:
+            self.cost_keys_attempted: set = set()
+
+    @property
+    def n(self) -> int:
+        return len(self.ids)
+
+    @property
+    def servable(self) -> bool:
+        """There are item rows on the device to answer from."""
+        return bool(self.ids) and self.scanned is not None
+
+    def place(self, host: np.ndarray):
+        return jnp.asarray(host)
+
+    def struct(self, shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def bucket_union(self, query_vecs: np.ndarray) -> np.ndarray:
+        """(num_buckets,) bool: the union of the query vectors' LSH candidate
+        buckets, mirroring the reference's per-partition candidate scan."""
+        lut = np.zeros(self.lsh.num_buckets, dtype=bool)
+        for query_vec in query_vecs:
+            lut[self.lsh.get_candidate_indices(query_vec)] = True
+        return lut
+
+    def one_excluded(self, excluded):
+        """A single query's padded exclusions on the device, or None when
+        none of them is a row of this view. pow2-padded with -1 fill so jit
+        signatures stay stable: every distinct known-item count would
+        otherwise trigger a fresh compile on the serving hot path."""
+        if excluded:
+            padded = _excluded_indices(self, [excluded], 1)
+            if (padded >= 0).any():
+                return jnp.asarray(padded)
+        return None
+
+
+class _ArenaSnapshot(_Snapshot):
+    """A view whose device arrays only CHOOSE candidates (int8 rows, flat or
+    in IVF cells): the final ranking is an exact f32 rescore of the top
+    ``rescore-factor × how_many`` rows, gathered from the host factor arena.
+
+    Built from the arena's HOST snapshot (``host_matrix``) and kept current
+    with composed host deltas (``delta_info``); ``version`` anchors the
+    next delta. A subclass supplies ``build`` (full) and ``from_delta``
+    (incremental, or None when only a rebuild will do)."""
+
+    def __init__(self, ids, version: int, scanned, lsh, slab, slab_rows,
+                 rescore_factor: float, prev=None, incremental: bool = False):
+        super().__init__(ids, scanned, lsh, prev, incremental)
+        self.version = version
+        self.rescore_factor = rescore_factor
+        # pinned exact-rescore view: THIS snapshot's slab object + its row
+        # indices, captured by the store in the same order epoch as `ids`.
+        # Structural store changes (GC, compaction) replace the live
+        # slab/rowmap and never disturb this pair, so a rescore can never
+        # crash on, or misalign against, a concurrently mutated store. A
+        # point update rewriting a captured row in place is visible here —
+        # the rescore ranks with fresher factors than the scan, benign.
+        self.slab = slab
+        self.slab_rows = slab_rows  # (n,) slab row per snapshot position
+        profiling.register_quantized(self)
+
+    @classmethod
+    def current(cls, store, lsh, prev, source, **build_options):
+        """Incremental (requantize only the rows a speed microbatch touched)
+        when the arena's write log covers the gap and the subclass can take
+        the step; full rebuild otherwise. The store's f32
+        device-materialization cache is never engaged — the arena slab
+        itself is the exact-f32 source of truth."""
+        if prev is not None and prev.servable:
+            delta = store.delta_info(prev.version, len(prev.ids))
+            if delta is not None:
+                if not delta.changed_ids and not delta.appended_ids:
+                    return prev
+                nxt = cls.from_delta(prev, delta)
+                if nxt is not None:
+                    return nxt
+        ids, host, version, row_view = store.host_matrix()
+        return cls.build(ids, host, version, lsh, row_view, prev=prev,
+                         **build_options)
+
+    def appended(self, delta):
+        """``(ids, slab_rows)`` of the step after this one: ``delta.slab`` is
+        the CURRENT slab (a non-structural grow copies rows in place, so
+        this view's indices stay valid in it) and the appended ids bring
+        their own rows."""
+        ids = self.ids + delta.appended_ids
+        if not len(delta.appended_ids):
+            return ids, self.slab_rows
+        return ids, np.concatenate(
+            [self.slab_rows, np.asarray(delta.appended_rows, dtype=np.int64)])
+
+    def rescore_width(self, want: int) -> int:
+        """Candidates to rescore for ``want`` results: a pow2, so the
+        program's signature is stable."""
+        return _round_up_pow2(max(int(self.rescore_factor * want), 16))
+
+    def gather_rows(self, positions: np.ndarray) -> np.ndarray:
+        """Exact f32 factor rows for snapshot ``positions``, gathered from
+        the PINNED slab view (see __init__) — one fancy index."""
+        pos = np.clip(np.asarray(positions, dtype=np.int64), 0, self.n - 1)
+        return self.slab[self.slab_rows[pos]]
+
+    def rescore(self, qs_host: np.ndarray, vals: np.ndarray, idx: np.ndarray,
+                cosine: bool = False) -> "tuple[np.ndarray, np.ndarray]":
+        """Exact f32 rescore of the quantized scan's candidates: gather the
+        candidate rows from the PINNED arena-slab view (the slab is what
+        makes this cheap), recompute exact scores, and return the candidates
+        re-ranked by exact score. Masked candidates (-inf from the scan)
+        stay -inf. For ``cosine`` the batch dimension is the query-vector
+        set of ONE request (mean cosine)."""
+        B, R = idx.shape
+        rows = self.gather_rows(idx.reshape(-1)).reshape(B, R, -1)
+        if cosine:
+            # one request, many query vectors: qs_host (Q, k); rows (1, R, k)
+            r = rows[0]
+            rn = np.linalg.norm(r, axis=1)
+            qn = np.linalg.norm(qs_host, axis=1)
+            sims = (r @ qs_host.T) / np.maximum(
+                rn[:, None] * qn[None, :], 1e-12
+            )
+            exact = np.mean(sims, axis=1, dtype=np.float32)[None, :]
+        else:
+            exact = np.einsum("bk,brk->br", qs_host, rows).astype(np.float32)
+        exact = np.where(np.isfinite(vals), exact, -np.inf)
+        order = np.argsort(-exact, axis=1, kind="stable")
+        return (np.take_along_axis(exact, order, axis=1),
+                np.take_along_axis(idx, order, axis=1))

@@ -42,6 +42,24 @@ def _model(y, mesh, **kw):
     return model
 
 
+#: Every scan backend a model can resolve to (ISSUE 28), over the same
+#: factors: what each answers is a float32 brute force's. The index probes
+#: all of its cells — on factors without cluster structure that is what makes
+#: it exact (its recall under fewer probes is test_ivf.py's to hold).
+BACKENDS = {
+    "mesh": lambda: {"mesh": _mesh()},
+    "flat": dict,
+    "int8": lambda: {"device_dtype": "int8"},
+    "ivf": lambda: {"device_dtype": "int8", "index_enabled": True,
+                    "index_cells": 16, "index_probes": 16},
+}
+
+
+def _served(y, backend):
+    options = BACKENDS[backend]()
+    return _model(y, options.pop("mesh", None), **options)
+
+
 def _names(answers):
     return [[i for i, _ in a] for a in answers]
 
@@ -50,13 +68,14 @@ def _ids(answers):
     return [[int(i[1:]) for i in a] for a in _names(answers)]
 
 
+@pytest.mark.parametrize("backend", list(BACKENDS))
 @pytest.mark.parametrize("excluding", [False, True], ids=["plain", "excluding"])
 @pytest.mark.parametrize("batch", [1, 3, 64])
-def test_mesh_answers_are_the_references_and_the_one_device_paths(batch,
-                                                                  excluding):
+def test_mesh_answers_are_the_references_and_the_one_device_paths(
+        batch, excluding, backend):
     y, qs = _factors(seed=batch)
     qs = qs[:batch]
-    sharded, single = _model(y, _mesh()), _model(y, None)
+    sharded, single = _served(y, backend), _model(y, None)
     excluded = None
     if excluding:
         # each query's own best three are taken out of its answer
@@ -156,17 +175,41 @@ def test_a_point_update_and_an_append_keep_the_split_and_the_answers():
                                rtol=1e-4)
 
 
-def test_host_filters_and_cosine_run_on_the_split_arrays():
+@pytest.mark.parametrize("backend", list(BACKENDS))
+def test_host_filters_and_cosine_run_on_the_split_arrays(backend):
     y, qs = _factors(seed=13)
-    sharded, single = _model(y, _mesh()), _model(y, None)
+    sharded, single = _served(y, backend), _model(y, None)
     banned = {"i3", "i500", "i1002"}
     allow = [lambda i: i not in banned] * 4
     got = sharded.top_n_batch(qs[:4], 6, alloweds=allow)
     want = single.top_n_batch(qs[:4], 6, alloweds=allow)
     assert _names(got) == _names(want)
-    cos_got = sharded.top_n_cosine(y[[5, 9]], 7)
-    cos_want = single.top_n_cosine(y[[5, 9]], 7)
+    # a filter one row in fifty passes: the batch's candidates run out and
+    # every query falls back to the widening single-query path; the best of
+    # the rows that pass is excluded as well. Against a float32 brute force
+    passing = np.arange(7, N_ITEMS, 50)
+    rare = [lambda i: int(i[1:]) % 50 == 7] * 4
+    left, out = passing[1:], [[f"i{passing[0]}"]] * 4
+    got = sharded.top_n_batch(qs[:4], 6, alloweds=rare, excluded=out)
+    brute = y[left] @ qs[:4].T
+    order = np.argsort(-brute, axis=0)[:6].T
+    assert _ids(got) == left[order].tolist()
+    np.testing.assert_allclose(
+        [[v for _, v in a] for a in got],
+        np.take_along_axis(brute.T, order, axis=1), rtol=2e-5, atol=1e-5)
+    one = sharded.top_n(qs[5], 4, offset=2, allowed=rare[0], excluded=out[0])
+    order = np.argsort(-(y[left] @ qs[5]))[2:6]
+    assert _ids([one]) == [left[order].tolist()]
+    # past the two rows themselves, which tie at the head of the list
+    cos_got = sharded.top_n_cosine(y[[5, 9]], 7, offset=2)
+    cos_want = single.top_n_cosine(y[[5, 9]], 7, offset=2)
     assert [i for i, _ in cos_got] == [i for i, _ in cos_want]
+    unit = y / np.linalg.norm(y, axis=1, keepdims=True)
+    brute = (unit @ unit[[5, 9]].T).mean(axis=1)
+    order = np.argsort(-brute)[2:9]
+    assert _ids([cos_got]) == [order.tolist()]
+    np.testing.assert_allclose(
+        [v for _, v in cos_got], brute[order], rtol=2e-5, atol=1e-5)
 
 
 def test_after_the_warm_ladder_no_bucket_compiles():

@@ -1,0 +1,113 @@
+"""The warm ladder is the dispatch (ISSUE 28): whichever scan backend a model
+resolves to, ``warm_bucket`` compiles what its snapshot's ``plan`` returns for
+shapes and a flush dispatches what the same ``plan`` returns for arrays — so
+after the ladder a batch of that size, with or without exclusions, registers
+no cost key and compiles nothing. The benchmark's ``compiles_in_window`` limit
+is 0 in every serving cell; this is what holds it for every backend at once."""
+
+import threading
+
+import numpy as np
+import pytest
+
+from oryx_tpu.common import compilecache
+from oryx_tpu.models.als.serving import ALSServingModel
+from oryx_tpu.parallel.mesh import make_mesh
+from oryx_tpu.tools import sanitize
+
+FEATURES = 16
+N_ITEMS = 603  # not a multiple of the four shards
+
+BACKENDS = {
+    "flat": {},
+    "flat+lsh": {"sample_rate": 0.3},
+    "int8": {"device_dtype": "int8"},
+    "int8+lsh": {"device_dtype": "int8", "sample_rate": 0.3},
+    "ivf": {"device_dtype": "int8", "index_enabled": True},
+    "mesh": {"mesh": True},
+}
+
+
+def _model(backend: str) -> ALSServingModel:
+    options = dict(BACKENDS[backend])
+    if options.pop("mesh", False):
+        options["mesh"] = make_mesh(4, axes=("model",))
+    model = ALSServingModel(FEATURES, implicit=True, **options)
+    y = np.random.default_rng(28).standard_normal(
+        (N_ITEMS, FEATURES), dtype=np.float32)
+    model.bulk_load_items([f"i{j}" for j in range(N_ITEMS)], y)
+    return model
+
+
+def _signature(fn, args):
+    """A program and the abstract values of its operands (an array or a
+    ``ShapeDtypeStruct`` by shape and dtype, a static by its value)."""
+    return fn, tuple(
+        (tuple(a.shape), str(a.dtype)) if hasattr(a, "shape") else a
+        for a in args)
+
+
+@pytest.mark.parametrize("bucket", [4, 32])
+@pytest.mark.parametrize("backend", list(BACKENDS))
+def test_after_warm_bucket_a_batch_registers_and_compiles_nothing(
+        backend, bucket, monkeypatch):
+    model = _model(backend)
+    compiled: dict = {}
+    aot_compile = compilecache.aot_compile
+
+    def spy(fn, *args, cost_key=None):
+        compiled.setdefault(cost_key, []).append(_signature(fn, args))
+        return aot_compile(fn, *args, cost_key=cost_key)
+
+    monkeypatch.setattr(compilecache, "aot_compile", spy)
+    compilecache.install_compile_listener()
+    cold = compilecache.compiles_total()
+    model.warm_bucket(bucket, 10)
+    assert compilecache.compiles_total() - cold >= 2
+    snap = model.y_snapshot()
+    assert (model.lsh is not None) == backend.endswith("+lsh")
+    warmed = set(snap.cost_keys_attempted)
+    assert len(warmed) >= 2 and all(f"/b{bucket}" in k for k in warmed)
+    assert set(compiled) == warmed
+    ladder = {key: sigs[0] for key, sigs in compiled.items()}
+
+    qs = np.random.default_rng(bucket).standard_normal(
+        (bucket, FEATURES), dtype=np.float32)
+    excluded = [["i1", "i2", "i3"]] + [None] * (bucket - 1)
+    before = compilecache.compiles_total()
+    plain = model.top_n_batch(qs, 10)
+    excluding = model.top_n_batch(qs, 10, excluded=excluded)
+    assert len(plain) == len(excluding) == bucket
+    assert not {"i1", "i2", "i3"} & {i for i, _ in excluding[0]}
+    assert model.y_snapshot() is snap
+    assert set(snap.cost_keys_attempted) == warmed
+    assert compilecache.compiles_total() - before == 0
+
+    # and what a flush dispatches IS what the ladder compiled: a flush's
+    # first-use registration hands aot_compile the very program and operands
+    # it then calls, so forget the marks and let the two batches register
+    compiled.clear()
+    snap.cost_keys_attempted.clear()
+    model.top_n_batch(qs, 10)
+    model.top_n_batch(qs, 10, excluded=excluded)
+    assert {key: sigs[0] for key, sigs in compiled.items()} == ladder
+
+
+@pytest.mark.parametrize("backend", ["flat", "mesh"])
+def test_a_float_view_flush_takes_no_lock_under_the_snapshot_lock(backend):
+    """The store is materialized BEFORE the model's snapshot lock, as it
+    always was: with its lock taken under that one, the lock sanitizer
+    formats a stack at every new thread's first flush (its first sight of
+    the order), 0.3 ms between ``coalescer.assemble`` and ``topn.upload``,
+    which grew past ``test_stage_spans``'s 1 ms beside five other workers."""
+    if not sanitize.enabled("locks"):
+        pytest.skip("lock sanitizer not installed (ORYX_SANITIZE=off)")
+    model = _model(backend)
+    qs = np.zeros((4, FEATURES), dtype=np.float32)
+    model.top_n_batch(qs, 10)
+    with sanitize.isolated() as (graph, _watch):
+        flush = threading.Thread(target=model.top_n_batch, args=(qs, 10))
+        flush.start()
+        flush.join()
+        nested = [edge for edge in graph.edges() if "als/serving.py" in edge[0]]
+    assert not nested
