@@ -142,9 +142,11 @@ def run_batch_bench(
     cols = rng.integers(0, n_items, nnz).astype(np.int32)
     vals = np.ones(nnz, dtype=np.float32)
     record["gen_s"] = round(time.perf_counter() - t0, 2)
-    # fused Pallas gather-Gramian kernel: the platform default on TPU; on
-    # a CPU it would run interpret-emulated (minutes per block), so a CPU
-    # run measures the einsum formulation only and the parity suite
+    # fused Pallas gather-Gramian kernel: FORCED on both sides on a TPU (the
+    # trainer itself picks a side's formulation from the opposite table,
+    # train._choose_formulation; the unfused loop below is the other one
+    # forced); on a CPU it would run interpret-emulated (minutes per block),
+    # so a CPU run measures the einsum formulation only and the parity suite
     # (tests/test_gramian_kernel.py) covers the kernel path
     fused_default = backend == "tpu"
     record["fused_gramian"] = fused_default
@@ -771,13 +773,15 @@ def run_mesh_bench(features: int = FEATURES) -> dict:
     from oryx_tpu.ops.pallas_kernels import on_tpu as mesh_on_tpu
 
     on_tpu = mesh_on_tpu(mesh=mesh)
-    solver = lambda side: tr._sharded_solver(
+    solver = lambda side, opposite: tr._sharded_solver(
         mesh, "model", side.block, features, True, side.slot_chunk,
         "float32", on_tpu,
-        tr._resolve_fused(None, on_tpu, features, side.srows.shape[1]),
+        tr._resolve_fused(None, on_tpu, features, side.srows.shape[1],
+                          opposite.padded_rows),
         not on_tpu,
     )
-    solve_u, solve_i = solver(user_side), solver(item_side)
+    solve_u = solver(user_side, item_side)
+    solve_i = solver(item_side, user_side)
     y = jax.device_put(
         tr.init_item_factors(item_side, n_items, features,
                              jax.random.PRNGKey(0)),

@@ -697,24 +697,40 @@ def _check_against_reference(size, config, users, items, x_pub, y_pub, x_row,
                            f"max |x| > {HALF_ITER_TOL}")
     findings.check()
 
-    # (3) what als_train picks on a TPU, lowered for a TPU
+    # (3) what als_train picks on a TPU, lowered for a TPU: the SPD kernel
+    # on both sides, and a side the gather-Gramian formulation the trainer's
+    # one gate answers for the opposite table that side gathers from — at
+    # this smoke's size both are small tables of narrow rows, so the rule
+    # says einsum; the count of kernel calls in each program must be what
+    # the gate said
     y_dev = next(iter(y0.devices()))
     if on_tpu and not pk.on_tpu(y0):
         raise SmokeFailure(f"the trainer's operands live on {y_dev}, not a TPU")
-    slots = user_side.srows.shape[1]
-    fused = tr._resolve_fused(None, True, k, slots)
-    lowered = tr._solve_side_blocked_jit.trace(
-        y0, user_side.srows, user_side.scols, user_side.svals,
-        user_side.slens, lam, alpha, block=user_side.block, features=k,
-        implicit=True, slot_chunk=user_side.slot_chunk, dtype="float32",
-        spd_kernel=True, fused_gramian=fused, kernel_interpret=False,
-    ).lower(lowering_platforms=("tpu",)).as_text()
-    out["tpu_custom_calls"] = lowered.count("tpu_custom_call")
-    if not fused or out["tpu_custom_calls"] != 2:
-        raise SmokeFailure(
-            f"the TPU-default half-iteration lowers to "
-            f"{out['tpu_custom_calls']} tpu_custom_call(s) (fused={fused}); "
-            "both kernels are TPU defaults")
+    out["formulation"] = {}
+    for name, side, opp_rows in (("user", user_side, item_side.padded_rows),
+                                 ("item", item_side, user_side.padded_rows)):
+        fused, why = tr._choose_formulation(None, True, k,
+                                            side.srows.shape[1], opp_rows)
+        lowered = tr._solve_side_blocked_jit.trace(
+            jax.ShapeDtypeStruct((opp_rows, k), jnp.float32), side.srows,
+            side.scols, side.svals, side.slens, lam, alpha, block=side.block,
+            features=k, implicit=True, slot_chunk=side.slot_chunk,
+            dtype="float32", spd_kernel=True, fused_gramian=fused,
+            kernel_interpret=False,
+        ).lower(lowering_platforms=("tpu",)).as_text()
+        calls = lowered.count("tpu_custom_call")
+        out["formulation"][name] = {
+            "runs": tr._FORMULATION_NAMES[fused], "why": why,
+            "tpu_custom_calls": calls}
+        print(f"chip_smoke: the {name} half runs the "
+              f"{tr._FORMULATION_NAMES[fused]} ({why}); "
+              f"{calls} tpu_custom_call(s)", file=sys.stderr)
+        if calls != 1 + fused:
+            raise SmokeFailure(
+                f"the TPU-default {name} half-iteration lowers to {calls} "
+                f"tpu_custom_call(s) where the gate chose the "
+                f"{tr._FORMULATION_NAMES[fused]}: the SPD kernel is a TPU "
+                "default, the gather-Gramian kernel is the gate's to pick")
 
     # (2) train the reference formulation to the same iteration count
     xr, yy = x_ref, y0

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from oryx_tpu.common import metrics as metrics_mod
 from oryx_tpu.common import profiling
 from oryx_tpu.models.als.data import RatingBatch
 from oryx_tpu.ops import pallas_kernels as pk
@@ -707,22 +708,113 @@ def _solve_side_blocked_jit(y, srows, scols, svals, slens, lam, alpha, *,
     return out.reshape(-1, features)
 
 
-def _resolve_fused(fused_gramian: "bool | None", on_tpu: bool,
-                   features: int, slots: int) -> bool:
-    """One gate for every path that selects the fused gather-Gramian kernel
-    (single-device, mesh, benches): None = platform default. Past the
-    kernel's gates (VMEM at ``features``, SMEM at ``slots`` per block) the
-    einsum formulation runs instead of a program that cannot compile — and
-    says so, because on a TPU the difference is large."""
-    want = on_tpu if fused_gramian is None else bool(fused_gramian)
-    if want and not pk.gather_gramian_supported(features, slots):
+_FORMULATION_NAMES = {True: "fused kernel", False: "einsum"}
+_HALF_FORMULATION = metrics_mod.default_registry().gauge(
+    "oryx_als_half_formulation",
+    "1 for the gather-Gramian formulation the trainer's last generation "
+    "resolved this side's half-iteration to, 0 for the other",
+    ("side", "formulation"),
+)
+
+# The gather-Gramian formulation is chosen a SIDE, from the opposite factor
+# table that side gathers from — its row width and its bytes. Placed from a
+# sweep on the chip (PERF.md §6, PR 29; one v5e, the Netflix cell's two packs
+# — T = 256 at 1.77 cells an entry, T = 512 at 1.05 — each against opposite
+# tables of 17,770 to 2M rows, seconds a half-iteration, einsum / kernel):
+#
+#   features  9 MB-table    ~0.5M rows    ~1-2M rows     who wins
+#      32     0.94 / 2.10   2.46 / 2.98   8.75 / 3.07    einsum, then kernel
+#      50     1.28 / 2.38   4.33 / 3.27   10.4 / 3.27    einsum, then kernel
+#      64     1.49 / 2.56   2.11 / 3.45   2.11 / 3.54    einsum
+#     100     2.33 / 3.41   2.84 / 4.32   2.85 / 4.42    einsum
+#     128     3.55 / 4.28   4.05 / 5.07   4.05 / 5.07    einsum
+#     250     12.0 / 11.3   12.6 / 13.3   12.6 / 17.7    kernel, then einsum
+#
+# (the T = 256 pack; the T = 512 pack orders the same way but for 250
+# features, where the two stay within 9% of each other at every size.) The
+# einsum's batched matmul over a materialised (Sc, T, k) gather beats the
+# kernel's slot-at-a-time contraction by 1.2-3.3x wherever XLA's gather holds
+# up, and it holds up at every size for rows of 64 features and more. For
+# NARROWER rows it collapses once the table outgrows something between 71 MB
+# (50 features x 355,400 rows: 1.75 / 3.20) and 96 MB (x 480,189 rows, the
+# Netflix user table: 2.34 / 2.18 on its own item side; 32 features x 480,189
+# = 61 MB still holds, 1.23 / 2.16) — there the kernel, whose copies cost 7.5
+# ns more an entry once the lane-padded table passes 128 MiB and no more
+# after that, is 1.07-3.2x faster. At 250 features the kernel's contraction
+# has caught up (ahead by 0.78 s and 0.22 s on the Netflix user and item
+# sides; interpolated linearly from 128 features' 0.73 and 0.71 s behind, a
+# whole iteration crosses at 200) — until its copies of sparse slots out of a
+# table past ~0.5-1 GB lose it again (480 MB: 0.92x / 1.05x the einsum's
+# seconds on the two packs; 960 MB: 0.97x / 1.17x; 1.9 GB: 1.01x / 1.40x).
+# Widths between the measured ones are unmeasured, and so is more than 2M rows.
+_GG_NARROW_FEATURES = 64  # rows under this: XLA's gather collapses when large
+_GG_NARROW_TABLE_BYTES = 80 << 20  # ... from here (between 71 and 96 MB)
+_GG_WIDE_FEATURES = 200  # rows from this: the kernel's contraction is ahead
+_GG_WIDE_TABLE_BYTES = 640 << 20  # ... up to here (between 480 and 960 MB)
+
+
+def _rule(features: int, table_rows: int) -> "tuple[bool, str]":
+    """The sweep above as a function of what a half-iteration can observe of
+    the table it gathers from: (kernel?, the sizes against the crossover)."""
+    nbytes = table_rows * features * 4
+    seen = (f"the opposite table's {table_rows} rows of {features} features "
+            f"are {nbytes / 1e6:.1f} MB")
+    if features < _GG_NARROW_FEATURES:
+        fused = nbytes >= _GG_NARROW_TABLE_BYTES
+        return fused, (
+            f"{seen}, {'at or over' if fused else 'under'} the "
+            f"{_GG_NARROW_TABLE_BYTES / 1e6:.1f} MB where XLA's gather of "
+            f"rows under {_GG_NARROW_FEATURES} features collapses")
+    if features >= _GG_WIDE_FEATURES:
+        fused = nbytes < _GG_WIDE_TABLE_BYTES
+        return fused, (
+            f"{seen}, {'under' if fused else 'at or over'} the "
+            f"{_GG_WIDE_TABLE_BYTES / 1e6:.1f} MB up to which the kernel is "
+            f"ahead at {_GG_WIDE_FEATURES} features and more")
+    return False, (f"{seen}: between {_GG_NARROW_FEATURES} and "
+                   f"{_GG_WIDE_FEATURES} features the einsum is ahead at "
+                   "every size")
+
+
+def _choose_formulation(fused_gramian: "bool | None", on_tpu: bool,
+                        features: int, slots: int,
+                        table_rows: int) -> "tuple[bool, str]":
+    """(run the fused gather-Gramian kernel?, why) for one side: the ONE
+    gate of every path that picks a formulation (single-device, mesh,
+    benches, the cost accounting and the pack's log line). ``slots`` is the
+    side's slots a block, ``table_rows`` the rows of the opposite factor
+    table it gathers from — under a mesh the whole all-gathered table, which
+    is what every shard reads.
+
+    An explicit ``True`` / ``False`` forces a formulation; ``None`` is the
+    rule: off a TPU the einsum; on one whichever :func:`_rule` measured
+    faster for a table of this width and size. The kernel's own gates (VMEM
+    at ``features``, SMEM at ``slots``) are tested first: past them the
+    einsum runs instead of a program that cannot compile — and says so,
+    because on a TPU the difference can be large."""
+    if fused_gramian is None:
+        if not on_tpu:
+            return False, "not on a TPU"
+    elif not fused_gramian:
+        return False, "asked for"
+    if not pk.gather_gramian_supported(features, slots):
         logging.getLogger(__name__).warning(
             "fused gather-Gramian kernel not used: features=%d, %d slots "
             "per block is past its VMEM/SMEM gates; using the einsum "
             "formulation", features, slots,
         )
-        return False
-    return want
+        return False, (f"features={features}, {slots} slots a block is past "
+                       "the kernel's gates")
+    if fused_gramian:
+        return True, "asked for"
+    return _rule(features, table_rows)
+
+
+def _resolve_fused(fused_gramian: "bool | None", on_tpu: bool,
+                   features: int, slots: int, table_rows: int) -> bool:
+    """:func:`_choose_formulation`'s answer without its reason."""
+    return _choose_formulation(fused_gramian, on_tpu, features, slots,
+                               table_rows)[0]
 
 
 def solve_side_blocked(y, srows, scols, svals, slens, lam, alpha, *, block,
@@ -731,19 +823,22 @@ def solve_side_blocked(y, srows, scols, svals, slens, lam, alpha, *, block,
                        fused_gramian: "bool | None" = None):
     """One half-iteration, single device: lax.map over row blocks.
 
-    ``spd_kernel=None`` / ``fused_gramian=None`` pick the Pallas kernels
-    (Gauss-Jordan solve; fused gather-Gramian accumulation) on TPU and the
-    XLA formulations elsewhere. Jit decisions are static, so the platform
-    is resolved here at call time, from the devices that hold ``y``
-    (``pallas_kernels.on_tpu``), never from the process default. The SAME
-    decision also sets the kernels' interpret mode: a caller that forces a
-    kernel on (tests) gets it emulated off-TPU, and no kernel can run in
-    interpret mode on the chip."""
+    ``spd_kernel=None`` picks the Pallas Gauss-Jordan solve on a TPU and
+    XLA's cholesky elsewhere. ``fused_gramian=None`` picks the gather-Gramian
+    formulation THIS side runs fastest (:func:`_choose_formulation`): off a
+    TPU the einsum; on one whichever the chip sweep measured faster for an
+    opposite table ``y`` of this width and size. Jit decisions are
+    static, so both are resolved here at call time, from the devices that
+    hold ``y`` (``pallas_kernels.on_tpu``) and from the operands' shapes,
+    never from the process default. The platform decision also sets the
+    kernels' interpret mode: a caller that forces a kernel on (tests) gets
+    it emulated off-TPU, and no kernel can run in interpret mode on the
+    chip."""
     on_tpu = pk.on_tpu(y)
     if spd_kernel is None:
         spd_kernel = on_tpu
     fused_gramian = _resolve_fused(fused_gramian, on_tpu, features,
-                                   srows.shape[1])
+                                   srows.shape[1], y.shape[0])
     return _solve_side_blocked_jit(
         y, srows, scols, svals, slens, lam, alpha, block=block,
         features=features, implicit=implicit, slot_chunk=slot_chunk,
@@ -887,10 +982,11 @@ def prepare_blocked(
     if cache is not None:
         cache.store_batch(batch.rows, batch.cols, batch.vals)
     on_tpu = pk.on_tpu(sides[0].scols)
-    for name, side in zip(("user", "item"), sides):
-        fused = on_tpu and pk.gather_gramian_supported(
-            features, side.srows.shape[1])
-        _log_gather_rows(name, side, fused)
+    for name, side, opposite in (("user", sides[0], sides[1]),
+                                 ("item", sides[1], sides[0])):
+        _log_gather_rows(name, side, *_choose_formulation(
+            None, on_tpu, features, side.srows.shape[1],
+            opposite.padded_rows))
     return sides
 
 
@@ -910,27 +1006,35 @@ def init_item_factors(item_side: _BlockedSide, n_items: int, features: int,
     return _init_factors(item_side.padded_rows, n_items, features, key)
 
 
-def _log_gather_rows(name: str, side: _BlockedSide, fused: bool) -> None:
+def _log_gather_rows(name: str, side: _BlockedSide, fused: bool,
+                     reason: str) -> None:
+    """One line a side: what the pack holds, the formulation the gate chose
+    for it and why, and the factor rows that formulation's gather issues."""
     before, now = side.gather_rows_per_entry(fused)
+    if fused:
+        issues = ("fused kernel: each slot to its own length; %.3f%% of the "
+                  "slots are fetched under the slot before, the rest open a "
+                  "block's call"
+                  % (100.0 * side.prefetched_slots / max(1, side.real_slots)))
+    else:
+        issues = "einsum: every cell of every slot"
     logging.getLogger(__name__).info(
         "slotted COO %s side: %d entries in %d slots of T=%d (+%d of block "
-        "padding); the gather moves %.3f factor rows an entry (%s), %.3f if "
-        "every slot were copied to its width; %.3f%% of the slots are "
-        "fetched under the slot before (the rest open a block's call)",
+        "padding); formulation: %s (%s); the gather moves %.3f factor rows "
+        "an entry (%s), %.3f if every slot were copied to its width",
         name, side.entries, side.real_slots, side.slot_width,
-        int(side.srows.size) - side.real_slots, now,
-        "fused kernel: each slot to its own length" if fused
-        else "einsum: every cell of every slot", before,
-        100.0 * side.prefetched_slots / max(1, side.real_slots),
+        int(side.srows.size) - side.real_slots,
+        _FORMULATION_NAMES[fused], reason, now, issues, before,
     )
 
 
-def _register_half_cost(key: str, side: _BlockedSide, features: int,
-                        dtype: str, fused: bool) -> None:
+def _register_half_cost(name: str, side: _BlockedSide, features: int,
+                        dtype: str, fused: bool, reason: str) -> None:
     """Analytic per-half-iteration device cost for the trainer's cost
-    accounting (common/profiling.py): the same useful-FLOP model the batch
-    bench's MFU derives from (2·nnz·k² Gramian + 2·nnz·k RHS +
-    rows·(k³/3 + 2k²) solve), with bytes as the dominant HBM terms — the
+    accounting (common/profiling.py), under ``als.train.<name>_half``: the
+    same useful-FLOP model the batch bench's MFU derives from (2·nnz·k²
+    Gramian + 2·nnz·k RHS + rows·(k³/3 + 2k²) solve), with bytes as the
+    dominant HBM terms — the
     factor rows the gather ISSUES (``side.gather_rows``: one an entry under
     the fused kernel, whose copies are 32-bit whatever the compute dtype;
     every slot cell at the compute dtype under the einsum formulation) plus
@@ -938,7 +1042,9 @@ def _register_half_cost(key: str, side: _BlockedSide, features: int,
     sub-programs rather than one compiled executable, so the trainer
     registers analytically where serving registers from
     ``cost_analysis()``; either way the label is one program signature
-    multiplied by recorded calls."""
+    multiplied by recorded calls. Which formulation the side resolved to is
+    a fact of the run: ``oryx_als_half_formulation{side, formulation}``
+    reads 1 for it and 0 for the other."""
     k = features
     nnz = side.entries
     rows = side.padded_rows
@@ -947,8 +1053,11 @@ def _register_half_cost(key: str, side: _BlockedSide, features: int,
     gather_itemsize = 2.0 if dtype == "bfloat16" and not fused else 4.0
     bytes_ = (float(side.gather_rows(fused)) * k * gather_itemsize
               + rows * k * (k + 1) * 4.0)
+    key = f"als.train.{name}_half"
     profiling.costs().register(key, flops, bytes_)
-    _log_gather_rows(key, side, fused)
+    for which, label in _FORMULATION_NAMES.items():
+        _HALF_FORMULATION.labels(name, label).set(float(which == fused))
+    _log_gather_rows(key, side, fused, reason)
 
 
 def _recorded_half(key: str, fn):
@@ -998,10 +1107,15 @@ def als_train(
     ``pack_user_s``/``pack_item_s`` (raw per-side work) and the cache
     modes.
 
-    ``fused_gramian=None`` selects the fused Pallas gather-Gramian kernel
-    on TPU (``ops/pallas_kernels.gather_gramian_accumulate``) and the
-    einsum+segment-sum formulation elsewhere; ``True`` forces the kernel
-    (interpret-emulated off-TPU — how the CPU suite tests the exact path).
+    ``fused_gramian=None`` picks the gather-Gramian formulation a SIDE
+    (:func:`_choose_formulation`): on a TPU the fused Pallas kernel
+    (``ops/pallas_kernels.gather_gramian_accumulate``) or the
+    einsum+segment-sum formulation, whichever the chip sweep measured
+    faster for the opposite factor table that side gathers from (at 50
+    features: the einsum under 80 MiB of factor rows, the kernel from
+    there), and the einsum everywhere off a TPU; ``True`` / ``False`` force
+    one on both sides (``True`` is interpret-emulated off-TPU — how the CPU
+    suite tests the exact path).
 
     **Preemption tolerance**: ``checkpointer`` (a
     ``common/checkpoint.TrainerCheckpointer``) restores the newest valid
@@ -1072,9 +1186,7 @@ def als_train(
         side = item_fut.result()
         wait_s = time.perf_counter() - t1
         pool.shutdown(wait=False)
-        fused["item"] = resolve_fused(side)
-        _register_half_cost("als.train.item_half", side, k, dtype,
-                            fused["item"])
+        fused["item"] = resolve("item", side, user_side.padded_rows)
         if layout_cache is not None:
             layout_cache.store_batch(batch.rows, batch.cols, batch.vals)
         if timings is not None:
@@ -1160,19 +1272,21 @@ def als_train(
                               n_items, k, key)
 
         # the formulation each side runs is resolved ONCE, here, from the
-        # devices that will hold the factors: the cost accounting counts the
-        # rows that formulation's gather issues, and the solvers below are
-        # handed the same answer
+        # devices that will hold the factors and the rows of the opposite
+        # table the side gathers from (padded, as the solver sees it; under
+        # a mesh the whole table, which every shard reads): the cost
+        # accounting counts the rows that formulation's gather issues, and
+        # the solvers below are handed the same answer
         sharded_mode = mesh is not None and row_axis is not None
         on_tpu = pk.on_tpu(mesh=mesh) if sharded_mode else pk.on_tpu(y)
 
-        def resolve_fused(side: _BlockedSide) -> bool:
-            return _resolve_fused(fused_gramian, on_tpu, k,
-                                  side.srows.shape[1])
+        def resolve(name: str, side: _BlockedSide, table_rows: int) -> bool:
+            choice = _choose_formulation(fused_gramian, on_tpu, k,
+                                         side.srows.shape[1], table_rows)
+            _register_half_cost(name, side, k, dtype, *choice)
+            return choice[0]
 
-        fused = {"user": resolve_fused(user_side)}
-        _register_half_cost("als.train.user_half", user_side, k, dtype,
-                            fused["user"])
+        fused = {"user": resolve("user", user_side, y.shape[0])}
 
         if sharded_mode:
             from jax.sharding import NamedSharding, PartitionSpec as P

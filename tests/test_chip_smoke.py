@@ -84,8 +84,12 @@ def test_tiny_mode_passes_every_check_and_names_the_cpu():
     # the coalescer really coalesced; the warm ladder really finished
     assert loop["coalescer"]["requests"] > loop["coalescer"]["flushes"] > 0
     assert loop["warmup"]["done"] == loop["warmup"]["total"] > 0
-    # the formulation a TPU trains with carries both kernels
-    assert loop["tpu_custom_calls"] == 2
+    # the programs a TPU trains with: the SPD kernel on both sides, and at
+    # this size (two tiny opposite tables) the gate's einsum on both
+    for side in ("user", "item"):
+        chosen = loop["formulation"][side]
+        assert chosen["runs"] == "einsum" and ", under the " in chosen["why"]
+        assert chosen["tpu_custom_calls"] == 1
     assert set(summary["kernels"]) == {
         "gather_gramian/float32", "gather_gramian/bfloat16", "spd_solve",
         "kmeans",
@@ -166,18 +170,23 @@ def test_every_kernel_and_the_tpu_half_iteration_lower_for_tpu(
         jnp.zeros((20, k), f32))
     assert text.count("tpu_custom_call") == 1
 
-    # what als_train picks on a TPU: both kernels, resolved by the trainer's
-    # own gate at this width and slot count
+    # what als_train picks on a TPU, resolved by the trainer's own gate at
+    # this width and slot count: the SPD kernel always; against the Netflix
+    # user table (480,201 rows) the gather-Gramian kernel at every one of
+    # these widths; against a 2,000-row table the einsum at 50 features (a
+    # small table of narrow rows) and the kernel at 250 and 256
     n_blocks, block, s, t = 2, 512, 1024, 32
-    assert tr._resolve_fused(None, True, k, s) is True
-    text = tr._solve_side_blocked_jit.trace(
-        jnp.zeros((2000, k), f32), jnp.zeros((n_blocks, s), jnp.int32),
-        jnp.zeros((n_blocks, s, t), jnp.int32), jnp.zeros((n_blocks, s, t), f32),
-        jnp.zeros((n_blocks, s), jnp.int32), 0.01, 1.0, block=block,
-        features=k, implicit=True, slot_chunk=s, dtype="float32",
-        spd_kernel=True, fused_gramian=True, kernel_interpret=False,
-    ).lower(lowering_platforms=("tpu",)).as_text()
-    assert text.count("tpu_custom_call") == 2
+    for table_rows, fused in ((480201, True), (2000, k >= 250)):
+        assert tr._resolve_fused(None, True, k, s, table_rows) is fused
+        text = tr._solve_side_blocked_jit.trace(
+            jnp.zeros((2000, k), f32), jnp.zeros((n_blocks, s), jnp.int32),
+            jnp.zeros((n_blocks, s, t), jnp.int32),
+            jnp.zeros((n_blocks, s, t), f32),
+            jnp.zeros((n_blocks, s), jnp.int32), 0.01, 1.0, block=block,
+            features=k, implicit=True, slot_chunk=s, dtype="float32",
+            spd_kernel=True, fused_gramian=fused, kernel_interpret=False,
+        ).lower(lowering_platforms=("tpu",)).as_text()
+        assert text.count("tpu_custom_call") == 1 + fused
 
 
 # ---------------------------------------------------------------------------
@@ -202,13 +211,14 @@ sharding = SingleDeviceSharding(topo.devices[0])
 def spec(shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
-def half(k, n_blocks, block, s, t, dtype):
+def half(k, n_blocks, block, s, t, dtype, table_rows=20000, fused=True,
+         chunk=None):
     fn = functools.partial(
         tr._solve_side_blocked_jit.__wrapped__, block=block, features=k,
-        implicit=True, slot_chunk=s, dtype=dtype, spd_kernel=True,
-        fused_gramian=True, kernel_interpret=False)
-    jax.jit(lambda y, a, b, c, d: fn(y, a, b, c, d, 0.01, 1.0)).lower(
-        spec((20000, k)), spec((n_blocks, s), jnp.int32),
+        implicit=True, slot_chunk=chunk or s, dtype=dtype, spd_kernel=True,
+        fused_gramian=fused, kernel_interpret=False)
+    return jax.jit(lambda y, a, b, c, d: fn(y, a, b, c, d, 0.01, 1.0)).lower(
+        spec((table_rows, k)), spec((n_blocks, s), jnp.int32),
         spec((n_blocks, s, t), jnp.int32), spec((n_blocks, s, t)),
         spec((n_blocks, s), jnp.int32)).compile()
 
@@ -220,11 +230,22 @@ half(50, 13, 7693, 10240, 32, "float32")
 # footprint the resident budget is ratified at
 half(256, 2, 1000, 2048, 512, "bfloat16")
 half(256, 2, 1000, 2048, 512, "float32")
-# the Netflix cell's two sides as the pack shapes them: the item side's
-# 72,594 slots a block of T=512 (owner rows AND slot lengths whole in SMEM),
-# the user side's T=256; then 250 features (a 1 KB row a copy) at both
-# widths, and the slot gate itself at the widest slot
-half(50, 3, 5924, 72594, 512, "float32")
+# the Netflix cell's two sides as the pack shapes them, each in the
+# formulation the trainer's gate picks for it from the opposite table it
+# gathers: the item side's 72,594 slots a block of T=512 against the user
+# table — the kernel (owner rows AND slot lengths whole in SMEM); the user
+# side's T=256 against the item table — the einsum, in ten chunks of 1,180
+# slots, and no gather-Gramian call in the program. Then the user side under
+# the kernel all the same (a forced formulation still has to compile), 250
+# features (a 1 KB row a copy) at both widths, and the slot gate itself at
+# the widest slot
+for s, table_rows, runs_kernel in ((72594, 59 * 8139, True),
+                                   (11800, 3 * 5924, False)):
+    assert tr._resolve_fused(None, True, 50, s, table_rows) is runs_kernel
+kernels = lambda compiled: compiled.as_text().count("tpu_custom_call")
+assert kernels(half(50, 3, 5924, 72594, 512, "float32", 59 * 8139, True)) == 2
+assert kernels(half(50, 2, 8139, 11800, 256, "float32", 3 * 5924, False,
+                    chunk=1180)) == 1  # the SPD solve's
 half(50, 2, 8139, 11790, 256, "float32")
 half(250, 2, 1046, 12288, 512, "float32")
 half(250, 2, 1072, 2048, 256, "bfloat16")

@@ -315,12 +315,19 @@ def test_gather_rows_counted_as_issued(fused):
         assert nbytes == side.gather_rows(fused) * k * 4.0 + writes
 
 
-def test_pack_logs_the_share_of_slots_fetched_under_a_predecessor(caplog):
+def test_pack_logs_the_share_of_slots_fetched_under_a_predecessor(
+        caplog, monkeypatch):
     """What tells a reader of a small-block deployment that its pipeline is
-    mostly prologue: each side's log line carries prefetched ÷ real slots.
-    One block a side here, so all but one slot of each."""
+    mostly prologue: a kernel side's log line carries prefetched ÷ real
+    slots (an einsum side has no pipeline: tests/test_als_formulation.py).
+    One block a side here, so all but one slot of each; the pack is asked as
+    on a TPU whose crossover every table is over."""
     import logging
 
+    from oryx_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "on_tpu", lambda operand=None, mesh=None: True)
+    monkeypatch.setattr(tr, "_GG_NARROW_TABLE_BYTES", 0)
     batch, k = _skewed_batch(9)
     with caplog.at_level(logging.INFO, logger="oryx_tpu.models.als.train"):
         sides = tr.prepare_blocked(batch, k, block=512)
