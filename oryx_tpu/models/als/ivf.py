@@ -100,10 +100,11 @@ def probe_cost_key(batch: int, cells: int, probes: int) -> str:
 
 
 def scan_cost_key(batch: int, cells: int, probes: int,
-                  excl: bool, lsh: bool) -> str:
-    """Cost-accounting signature of the probed-cell candidate scan."""
+                  room: int, lsh: bool) -> str:
+    """Cost-accounting signature of the probed-cell candidate scan, a batch
+    size, probe width and over-fetch room (topn._OVERFETCH_ROOM)."""
     return (f"als.ivf_scan/b{batch}/c{cells}/p{probes}"
-            + ("+excl" if excl else "") + ("+lsh" if lsh else ""))
+            + (f"+excl{room}" if room else "") + ("+lsh" if lsh else ""))
 
 
 # -- jitted programs ---------------------------------------------------------
@@ -122,13 +123,13 @@ def _probe_cells(centroids, qs, probes: int):
 
 
 @functools.partial(jax.jit, static_argnames=("r",))
-def _ivf_candidates(cell_pos, cell_q, cell_scale, qs, cells, excl, r: int):
+def _ivf_candidates(cell_pos, cell_q, cell_scale, qs, cells, r: int):
     """Quantized scores over the probed cells only. ``cells`` is (B, P);
     a ``lax.scan`` over the P probe columns bounds the gather transient at
     one (B, L, k) int8 block — the per-step gathers ARE the scan's HBM
     traffic (P·L·k bytes per query vs n·k for the flat slab). Padding
-    slots (cell_pos < 0) and per-query exclusions mask to -inf before the
-    exact top-k over the (B, P·L) candidate pool."""
+    slots (cell_pos < 0) mask to -inf before the exact top-k over the
+    (B, P·L) candidate pool."""
 
     def step(_, cell_col):  # cell_col: (B,) — one probe column
         pos = cell_pos[cell_col]       # (B, L) gather
@@ -139,9 +140,6 @@ def _ivf_candidates(cell_pos, cell_q, cell_scale, qs, cells, excl, r: int):
             preferred_element_type=jnp.float32,
         ) * sc
         s = jnp.where(pos >= 0, s, -jnp.inf)
-        if excl is not None:
-            hit = (pos[:, :, None] == excl[:, None, :]).any(axis=-1)
-            s = jnp.where(hit, -jnp.inf, s)
         return None, (s, pos)
 
     _, (scores, pos) = jax.lax.scan(step, None, cells.T)
@@ -154,7 +152,7 @@ def _ivf_candidates(cell_pos, cell_q, cell_scale, qs, cells, excl, r: int):
 
 @functools.partial(jax.jit, static_argnames=("r",))
 def _ivf_candidates_masked(cell_pos, cell_q, cell_scale, cell_buckets,
-                           lut, qs, cells, excl, r: int):
+                           lut, qs, cells, r: int):
     """Per-query-LUT (LSH) variant: the probed slots' buckets gather along
     with the factors and filter through the (B, num_buckets) table."""
 
@@ -169,9 +167,6 @@ def _ivf_candidates_masked(cell_pos, cell_q, cell_scale, cell_buckets,
         ) * sc
         valid = jnp.take_along_axis(lut, bk, axis=1)
         s = jnp.where(valid & (pos >= 0), s, -jnp.inf)
-        if excl is not None:
-            hit = (pos[:, :, None] == excl[:, None, :]).any(axis=-1)
-            s = jnp.where(hit, -jnp.inf, s)
         return None, (s, pos)
 
     _, (scores, pos) = jax.lax.scan(step, None, cells.T)
@@ -234,12 +229,12 @@ class IVFSnapshot(_ArenaSnapshot):
     assignment, the cell tables) that make incremental maintenance a
     per-affected-cell device scatter instead of a rebuild.
 
-    The fourth scan backend (models/als/topn.py:_Snapshot): exclusion
-    padding, LSH luts, the exact rescore and host collection are the flat
-    int8 view's, so it differs ONLY in how candidates are generated — a
-    probe program ahead of the scan, and a width of two parts
-    ``(probes, cut)``. No flat factor copy of any dtype lands in HBM in
-    this mode."""
+    The fourth scan backend (models/als/topn.py:_Snapshot): the over-fetch
+    room for exclusions, LSH luts, the exact rescore and host collection
+    are the flat int8 view's, so it differs ONLY in how candidates are
+    generated — a probe program ahead of the scan, and a width of
+    ``(probes, cut, room)``. No flat factor copy of any dtype lands in HBM
+    in this mode."""
 
     def __init__(self, ids, version: int, *, centroids_np=None, assign=None,
                  q_np=None, scale_np=None, norms_np=None, buckets_np=None,
@@ -533,12 +528,14 @@ class IVFSnapshot(_ArenaSnapshot):
 
     # -- the scan backend (topn.py:_Snapshot) --------------------------------
 
-    def batch_width(self, how_many: int, filtering: bool):
-        """``(probes, cut)``: the default probe width, and ``rescore-factor
-        x how_many`` candidates rounded up to a pow2 (signature stability),
-        capped by what the probed cells can actually surface."""
+    def batch_width(self, how_many: int, filtering: bool, room: int = 0):
+        """``(probes, cut, room)``: the default probe width, and
+        ``rescore-factor x how_many`` candidates plus the over-fetch
+        ``room`` rounded up to a pow2 (signature stability), capped by what
+        the probed cells can actually surface."""
         cap = min(self.n, self.probes * self.cell_width)
-        return self.probes, max(1, min(cap, self.rescore_width(how_many)))
+        cut = _round_up_pow2(self.rescore_width(how_many) + room)
+        return self.probes, max(1, min(cap, cut)), room
 
     def _widths(self, want: int):
         """The widening policy: the cut doubles first (more candidates from
@@ -549,7 +546,7 @@ class IVFSnapshot(_ArenaSnapshot):
         while True:
             cap = min(self.n, probes * self.cell_width)
             r_eff = min(r, cap)
-            yield probes, r_eff
+            yield probes, r_eff, 0
             if probes >= self.n_cells and r_eff >= self.n:
                 return
             if r_eff < cap:
@@ -558,39 +555,34 @@ class IVFSnapshot(_ArenaSnapshot):
                 probes = min(self.n_cells, probes * 2)  # widen the probe set
                 r = min(self.n, r * 2)
 
-    def plan(self, qs, excl, lut, width):
+    def plan(self, qs, lut, width):
         """One probe matmul, then one probed-cell scan for the whole batch,
         whose ``cells`` operand is the probe's result, still on the device.
         Each under a cost key of its own, so attribution separates
         candidate generation from the scan and the exact rescore."""
-        probes, r = width
+        probes, r, room = width
         b, c = qs.shape[0], self.n_cells
         cells = _Fed((b, probes), jnp.int32)
-        key = scan_cost_key(b, c, probes, excl is not None, lut is not None)
+        key = scan_cost_key(b, c, probes, room, lut is not None)
         if lut is not None:
             scan = (_ivf_candidates_masked,
                     (self.cell_pos, self.cell_q, self.cell_scale,
-                     self.cell_buckets, lut, qs, cells, excl, r), key)
+                     self.cell_buckets, lut, qs, cells, r), key)
         else:
             scan = (_ivf_candidates,
                     (self.cell_pos, self.cell_q, self.cell_scale, qs, cells,
-                     excl, r), key)
+                     r), key)
         return ((_probe_cells, (self.centroids, qs, probes),
                  probe_cost_key(b, c, probes)), scan)
 
     def dispatched(self, batch: int, width) -> None:
-        probes, r = width
+        probes, r, _ = width
         _INDEX_PROBED.inc(batch * probes)
         _INDEX_CANDIDATES.inc(batch * r)
 
-    def candidates(self, scan, q_host: np.ndarray, want: int, excluded,
-                   hooks: bool):
-        # exclusions that name no row of this view leave the plain program
-        known = excluded and any(e in self.id_to_idx for e in excluded)
+    def candidates(self, scan, q_host: np.ndarray, want: int, hooks: bool):
         for width in self._widths(want):
-            v, i = scan(self, q_host[None, :],
-                        [excluded] if known else None, width,
-                        register=False)
+            v, i = scan(self, q_host[None, :], width, register=False)
             vals, idx = self.rescore(q_host[None, :], v, i)
             yield vals[0], idx[0]
 
@@ -603,7 +595,7 @@ class IVFSnapshot(_ArenaSnapshot):
         lut_union = (jnp.asarray(self.bucket_union(qs_host))
                      if self.lsh is not None else None)
         probe_vec = jnp.asarray(np.mean(qs_host, axis=0, keepdims=True))
-        for probes, r in self._widths(want):
+        for probes, r, _ in self._widths(want):
             cells = _probe_cells(self.centroids, probe_vec, probes)
             v, i = _ivf_cosine_candidates(
                 self.cell_pos, self.cell_q, self.cell_scale, self.cell_norms,

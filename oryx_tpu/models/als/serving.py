@@ -41,10 +41,12 @@ from oryx_tpu.models.als import ivf as ivf_mod
 from oryx_tpu.models.als import pmml_codec
 from oryx_tpu.models.als.lsh import LocalitySensitiveHash
 from oryx_tpu.models.als.rescorer import load_rescorer_providers
-from oryx_tpu.models.als.topn import (_EXCL_PAD_MIN, _ArenaSnapshot, _Snapshot,
-                                      _collect, _excluded_indices, _id_lists,
-                                      _operands, _quantize_chunked,
-                                      _quantize_rows, _round_up_pow2)
+from oryx_tpu.models.als.known import KnownItems
+from oryx_tpu.models.als.topn import (_OVERFETCH_ROOM, _ArenaSnapshot,
+                                      _Snapshot, _collect, _drop_rows,
+                                      _id_lists, _operands, _quantize_chunked,
+                                      _quantize_rows, _room_for,
+                                      _round_up_pow2)
 from oryx_tpu.models.als.vectors import FeatureVectorStore
 from oryx_tpu.parallel.mesh import (put_row_sharded, replicated_sharding,
                                     row_sharding)
@@ -73,6 +75,16 @@ _DEADLINE_SWAPS = metrics_mod.default_registry().counter(
     "oryx_serving_swap_deadline_promotions_total",
     "Staged model generations promoted by the swap deadline, unwarmed",
 )
+_EXCLUDED_ENTRIES = metrics_mod.default_registry().counter(
+    "oryx_serving_excluded_entries_total",
+    "Rows handed to batched top-N flushes to be left out of their answers "
+    "(known items and a request's own exclusions)",
+)
+_EXCLUSION_OVERFLOW = metrics_mod.default_registry().counter(
+    "oryx_serving_exclusion_overflow_total",
+    "Queries whose exclusions were longer than the widest warmed over-fetch "
+    "room",
+)
 
 
 def _load_fraction_fn(manager_ref):
@@ -96,16 +108,16 @@ def _load_fraction_fn(manager_ref):
 _DEVICE_DTYPES = ("auto", "float32", "bfloat16", "int8")
 
 
-def _topn_cost_key(batch_size: int, excl: bool, quant: bool = False) -> str:
+def _topn_cost_key(batch_size: int, room: int, quant: bool = False) -> str:
     """Cost-accounting program signature for one batched top-N variant.
-    Keyed by (batch size, exclusion-carrying, quantized) — the axes the
-    coalescer's pow2 padding and the warm ladder actually produce; top-k
-    width drift (unusual howMany) folds into the same key, a documented
+    Keyed by (batch size, over-fetch room for exclusions, quantized) — the
+    axes the coalescer's pow2 padding and the warm ladder actually produce;
+    top-k width drift (unusual howMany) folds into the same key, a documented
     approximation (docs/observability.md "Device performance attribution").
     Quantized programs get their OWN keys: their per-call cost (int8 reads,
     rescale multiply) differs from the f32/bf16 scan's."""
     return (f"als.top_n_batch/b{batch_size}"
-            + ("+excl" if excl else "") + ("+int8" if quant else ""))
+            + (f"+excl{room}" if room else "") + ("+int8" if quant else ""))
 
 
 def _score(qs, mat):
@@ -117,40 +129,30 @@ def _score(qs, mat):
     )
 
 
-def _mask_excluded(scores, excl):
-    """Per-query exclusion scatter: ``excl`` is (B, E) row indices, -1-padded.
-    Out-of-range entries are remapped to n (a drop index): negative scatter
-    indices would WRAP from the end, so they must be clamped explicitly."""
-    n = scores.shape[1]
-    excl = jnp.where((excl >= 0) & (excl < n), excl, n)
-    return jax.vmap(lambda row, ix: row.at[ix].set(-jnp.inf, mode="drop"))(
-        scores, excl
-    )
-
-
 @functools.partial(jax.jit, static_argnames=("k",))
-def _top_k_dot_batch(mat, qs, valid, excl, k: int):
+def _top_k_dot_batch(mat, qs, valid, k: int):
     """One MXU matmul for the whole query batch + approx top-k (the masking
-    logic lives once in ``_masked_scores``). ``valid`` / ``excl`` are None on
-    the unfiltered hot path so it stays exactly matmul + top_k (None is a
-    static pytree — XLA never sees a dummy mask; the r1→r2 CPU regression was
-    unconditional masking here).
+    logic lives once in ``_masked_scores``). ``valid`` is None on the
+    unfiltered hot path so it stays exactly matmul + top_k (None is a static
+    pytree — XLA never sees a dummy mask; the r1→r2 CPU regression was
+    unconditional masking here). A flush's exclusions are no operand: the
+    flush asks for a wider ``k`` and drops their rows from the list
+    (``topn._OVERFETCH_ROOM``), so nothing stands between the matmul and the
+    top-k it is fused into and no ``(B, n)`` score matrix is written.
 
     approx_max_k is the TPU-native top-k (recall ≥ 0.99 beats LSH 0.3's own
     approximation); exact on backends without the TPU op."""
-    return _top_k_of_scores(_masked_scores(mat, qs, valid, excl), k)
+    return _top_k_of_scores(_masked_scores(mat, qs, valid), k)
 
 
 @jax.jit
-def _masked_scores(mat, qs, valid, excl):
+def _masked_scores(mat, qs, valid):
     """Masked score matrix only — lets the widening retry in ``top_n`` reuse
     one matmul's scores across successively larger top-k calls instead of
     re-scanning Y each widening."""
     scores = _score(qs, mat)
     if valid is not None:
         scores = jnp.where(valid[None, :], scores, -jnp.inf)
-    if excl is not None:
-        scores = _mask_excluded(scores, excl)
     return scores
 
 
@@ -160,43 +162,39 @@ def _top_k_of_scores(scores, k: int):
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
-def _top_k_dot_batch_masked(mat, qs, lut, buckets, excl, k: int):
+def _top_k_dot_batch_masked(mat, qs, lut, buckets, k: int):
     scores = _score(qs, mat)  # (B, n)
     valid = jnp.take_along_axis(lut, buckets[None, :], axis=1)  # (B, n)
     scores = jnp.where(valid, scores, -jnp.inf)
-    if excl is not None:
-        scores = _mask_excluded(scores, excl)
     return jax.lax.approx_max_k(scores, k, recall_target=0.99)
 
 
 @functools.lru_cache(maxsize=64)
 def _sharded_top_k_fn(mesh, axis: str, k: int, k_final: int, n_real: int,
-                      use_lut: bool, use_excl: bool = True):
+                      use_lut: bool):
     """Cross-shard top-N: Y's rows shard over ``axis``; each device runs the
     ONE-CHIP scan over its own block — the same ``_score`` matmul and
     ``_top_k_of_scores`` (approximate top-k at the same recall target, fused
     behind the matmul: no ``(B, n_local)`` score matrix is left in HBM) —
-    with pad rows, the per-query LSH lut and per-query excluded items masked
-    as on one chip; the ``ndev`` candidate lists of ``(B, k)`` are gathered
+    with pad rows and the per-query LSH lut masked as on one chip; the
+    ``ndev`` candidate lists of ``(B, k)`` are gathered
     across the mesh and merged with one more top-k. This is the multi-chip
     scan of SURVEY §2.14 ("device-resident Y shards; top-N via sharded
     matmul + lax.top_k + cross-shard merge") — the framework's
     intra-request parallelism.
 
-    Exclusion (known-item filtering, Recommend.java:84-106) is a device-side
-    scatter: ``excl`` is (B, E) GLOBAL row indices, -1-padded; each shard
-    rebases to local coordinates and drops out-of-range entries, so the mask
-    costs O(E) scatter per shard instead of a host round-trip.
+    Exclusion (known-item filtering, Recommend.java:84-106) is no operand,
+    as on one chip: the flush asks for wider ``k`` / ``k_final`` and drops
+    the excluded rows from the merged list.
 
-    Operands of the returned jitted program: ``(mat, qs[, excl][, lut,
-    buckets])`` — only what the flags say is used. Its name is stable
+    Operands of the returned jitted program: ``(mat, qs[, lut, buckets])``
+    — only what the flag says is used. Its name is stable
     (``jit__sharded_top_k_dot_batch``): the device trace finds it by that,
     and finds the merge's gather as the program's all-gather op."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(mat_blk, qs_blk, *rest):
-        rest = list(rest)
         n_local = mat_blk.shape[0]
         offset = jax.lax.axis_index(axis) * n_local
         scores = _score(qs_blk, mat_blk)  # (B, n_local), fused into the top-k
@@ -205,12 +203,6 @@ def _sharded_top_k_fn(mesh, axis: str, k: int, k_final: int, n_real: int,
             col_ids = offset + jax.lax.broadcasted_iota(
                 jnp.int32, scores.shape, 1)
             scores = jnp.where(col_ids < n_real, scores, -jnp.inf)
-        if use_excl:
-            # per-query exclusions: global→local rebase; -1 pads and rows
-            # owned by other shards fall out of range and are remapped to
-            # the drop index (negative scatter indices would wrap, so
-            # _mask_excluded clamps explicitly)
-            scores = _mask_excluded(scores, rest.pop(0) - offset)
         if use_lut:
             lut_blk, buckets_blk = rest
             valid = jnp.take_along_axis(
@@ -227,13 +219,11 @@ def _sharded_top_k_fn(mesh, axis: str, k: int, k_final: int, n_real: int,
             return mvals, jnp.take_along_axis(idx, pos, axis=1)
 
     # the replicated P(None, None) operands here are BATCH-shaped
-    # (queries/exclusions/lut: B·k, B·E, B·buckets) — a deliberate small
+    # (queries/lut: B·k, B·buckets) — a deliberate small
     # broadcast, which the replicated-collective checker keeps quiet on
     # because none of them is data-gathered like a factor table; Y (the
     # model-scaled operand) is the sharded one
     in_specs = (P(axis, None), P(None, None))
-    if use_excl:
-        in_specs += (P(None, None),)
     if use_lut:
         in_specs += (P(None, None), P(axis))
 
@@ -268,7 +258,7 @@ def _top_k_cosine_sum(mat, norms, qs, q_norms, valid, k: int):
 
 
 @jax.jit
-def _quant_masked_scores(qmat, qscale, qs, valid, excl):
+def _quant_masked_scores(qmat, qscale, qs, valid):
     """(B, n) approximate scores off the int8 slab: the convert rides the
     matmul operand (XLA fuses it — HBM traffic stays int8), accumulation is
     f32, and the per-row scale lands as one broadcast multiply."""
@@ -277,27 +267,23 @@ def _quant_masked_scores(qmat, qscale, qs, valid, excl):
     ) * qscale[None, :]
     if valid is not None:
         scores = jnp.where(valid[None, :], scores, -jnp.inf)
-    if excl is not None:
-        scores = _mask_excluded(scores, excl)
     return scores
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
-def _quant_candidates(qmat, qscale, qs, valid, excl, k: int):
+def _quant_candidates(qmat, qscale, qs, valid, k: int):
     """Top-k CANDIDATES (approximate scores) for the exact f32 rescore."""
-    return _top_k_of_scores(_quant_masked_scores(qmat, qscale, qs, valid, excl), k)
+    return _top_k_of_scores(_quant_masked_scores(qmat, qscale, qs, valid), k)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
-def _quant_candidates_masked(qmat, qscale, qs, lut, buckets, excl, k: int):
+def _quant_candidates_masked(qmat, qscale, qs, lut, buckets, k: int):
     """Per-query-LUT (LSH) variant of the quantized candidate scan."""
     scores = jnp.matmul(
         qs, qmat.T.astype(qs.dtype), preferred_element_type=jnp.float32
     ) * qscale[None, :]
     valid = jnp.take_along_axis(lut, buckets[None, :], axis=1)
     scores = jnp.where(valid, scores, -jnp.inf)
-    if excl is not None:
-        scores = _mask_excluded(scores, excl)
     return jax.lax.approx_max_k(scores, k, recall_target=0.99)
 
 
@@ -333,30 +319,26 @@ def _derive_sharded(row_sharding, mat_sharding, score_dtype):
 # written once for all of them.
 
 
-def _scan(snap, qs_host: np.ndarray, excluded, width, register: bool):
-    """The device side of one call, end to end (docs/observability.md):
-    what the coalescer's device-call span is made of on this side, for
-    every backend. ``width`` is the backend's static width
-    (``snap.batch_width`` for a batch); ``register`` attributes the call to
-    the program's cost key. Returns ``(vals, idx)`` on the host."""
-    use_excl = excluded is not None and any(e for e in excluded)
+def _dispatch(snap, qs_host: np.ndarray, width, register: bool):
+    """The device side of one call up to the program's launch
+    (docs/observability.md): what the coalescer's device-call span is made
+    of on this side, for every backend. ``width`` is the backend's static
+    width (``snap.batch_width`` for a batch, with the room its exclusions
+    need); ``register`` attributes the call to the program's cost key.
+    Returns ``(vals, idx)`` still on the device: what the host does before
+    :func:`_download` runs under the scan."""
     with spans.stage("topn.upload"):
         # batch-shaped operands go from the host straight to where the view
         # lies (on a mesh to every device at once, not to one device and on
         # from there inside the dispatch)
         qs = snap.place(qs_host)
-        excl = (
-            snap.place(_excluded_indices(snap, excluded, len(qs_host)))
-            if use_excl
-            else None
-        )
         # per-query LSH candidate masks: (B, num_buckets) lookup table
         # indexed by item bucket on device, fully vectorized over the batch
         lut = (snap.place(snap.lsh.get_candidate_lut(qs_host))
                if snap.lsh is not None else None)
     with spans.stage("topn.dispatch"):
         out = None
-        for fn, args, cost_key in snap.plan(qs, excl, lut, width):
+        for fn, args, cost_key in snap.plan(qs, lut, width):
             if out is not None:
                 args = _operands(args, out)
             if (register and cost_key not in snap.cost_keys_attempted
@@ -373,22 +355,33 @@ def _scan(snap, qs_host: np.ndarray, excluded, width, register: bool):
             if register:
                 profiling.costs().record(cost_key)
         snap.dispatched(len(qs_host), width)
-        vals, idx = out
+        return out
+
+
+def _download(out):
+    """``(vals, idx)`` of a dispatched call, on the host."""
     with spans.stage("topn.wait_download"):
         # the program's run and the copy back: the first conversion
         # blocks until the device is done
+        vals, idx = out
         return np.asarray(vals), np.asarray(idx)
 
 
+def _scan(snap, qs_host: np.ndarray, width, register: bool):
+    """One call's device side, end to end: ``(vals, idx)`` on the host."""
+    return _download(_dispatch(snap, qs_host, width, register))
+
+
 def _first_enough(snap, candidates, how_many: int, offset: int, allowed,
-                  rescore) -> list[tuple[str, float]]:
+                  rescore, dropped=None) -> list[tuple[str, float]]:
     """The widening loop of a single query: ``allowed``/``rescore`` host
-    hooks (rescorer SPI) consume candidates, so take the backend's next
-    wider list until enough survive or it has none wider."""
+    hooks (rescorer SPI) and the ``dropped`` rows (its exclusions) consume
+    candidates, so take the backend's next wider list until enough survive
+    or it has none wider."""
     want = how_many + offset
     out: list[tuple[str, float]] = []
     for vals, idx in candidates:
-        out = _collect(snap, vals, idx, want, allowed, rescore)
+        out = _collect(snap, vals, idx, want, allowed, rescore, dropped)
         if len(out) >= want:
             break
     return out[offset:offset + how_many]
@@ -545,29 +538,29 @@ class _YSnapshot(_Snapshot):
         """Rows of the device arrays: ``n``, padded to the shard count."""
         return self.n if self.mat is None else int(self.mat.shape[0])
 
-    def batch_width(self, how_many: int, filtering: bool) -> int:
+    def batch_width(self, how_many: int, filtering: bool, room: int = 0):
         # host filters and LSH masks consume candidates: ask for more
         wide = filtering or self.lsh is not None
         return min(self.n, _round_up_pow2(
-            max(2 * how_many, 64) if wide else max(how_many, 16)))
+            (max(2 * how_many, 64) if wide else max(how_many, 16)) + room)
+        ), room
 
-    def plan(self, qs, excl, lut, k: int):
-        key = _topn_cost_key(qs.shape[0], excl is not None)
+    def plan(self, qs, lut, width):
+        k, room = width
+        key = _topn_cost_key(qs.shape[0], room)
         if lut is not None:
             return ((_top_k_dot_batch_masked,
-                     (self.score_mat, qs, lut, self.buckets, excl, k), key),)
-        return ((_top_k_dot_batch, (self.score_mat, qs, None, excl, k), key),)
+                     (self.score_mat, qs, lut, self.buckets, k), key),)
+        return ((_top_k_dot_batch, (self.score_mat, qs, None, k), key),)
 
-    def candidates(self, scan, q_host: np.ndarray, want: int, excluded,
-                   hooks: bool):
+    def candidates(self, scan, q_host: np.ndarray, want: int, hooks: bool):
         # score once; widenings re-run only the top-k over the cached
         # scores. The unfiltered hot path stays exactly matmul + top_k:
-        # masks are None (static) unless LSH or exclusions actually apply
+        # the mask is None (static) unless LSH actually applies
         valid = (_candidate_mask(self, q_host[None, :], self.n_rows)
                  if self.lsh is not None else None)
         scores = _masked_scores(
-            self.score_mat, jnp.asarray(q_host[None, :]), valid,
-            self.one_excluded(excluded))
+            self.score_mat, jnp.asarray(q_host[None, :]), valid)
         return _widen_top_k(
             self, scores, _round_up_pow2(max(4 * want, 64)), q_host)
 
@@ -636,40 +629,35 @@ class _ShardedYSnapshot(_YSnapshot):
     def struct(self, shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=self._everywhere)
 
-    def batch_width(self, how_many: int, filtering: bool) -> int:
+    def batch_width(self, how_many: int, filtering: bool, room: int = 0):
         return min(self.n, _round_up_pow2(
-            max(2 * how_many, 64) if filtering else max(how_many, 16)))
+            (max(2 * how_many, 64) if filtering else max(how_many, 16))
+            + room)), room
 
-    def plan(self, qs, excl, lut, k: int):
+    def plan(self, qs, lut, width):
         """The jitted mesh scan for ``k`` results a query — the one-chip
-        scan on every shard + cross-shard merge, with LSH lut and per-query
-        known-item exclusion applied device-side (no host fallback for
-        filtered traffic), at least ``min(k, n)`` wide: each shard keeps
+        scan on every shard + cross-shard merge, with the LSH lut applied
+        device-side, at least ``min(k, n)`` wide: each shard keeps
         ``k_shard`` candidates (a pow2 ≥ 16, as the one-chip program's
         width), the merge ``min(ndev * k_shard, width)``. It has a cost key
         of its own (its per-call cost is a shard's, plus the merge)."""
+        k, room = width
         ndev = self.mesh.shape[self.shard_axis]
-        width = _round_up_pow2(max(min(k, self.n), 16))
-        k_shard = min(self.n_rows // ndev, width)
+        wide = _round_up_pow2(max(min(k, self.n), 16))
+        k_shard = min(self.n_rows // ndev, wide)
         fn = _sharded_top_k_fn(
-            self.mesh, self.shard_axis, k_shard, min(ndev * k_shard, width),
-            self.n, lut is not None, excl is not None,
+            self.mesh, self.shard_axis, k_shard, min(ndev * k_shard, wide),
+            self.n, lut is not None,
         )
         args = (self.score_mat, qs)
-        if excl is not None:
-            args += (excl,)
         if lut is not None:
             args += (lut, self.buckets)
-        return ((fn, args, _topn_cost_key(qs.shape[0], excl is not None)
-                 + "+sharded"),)
+        return ((fn, args, _topn_cost_key(qs.shape[0], room) + "+sharded"),)
 
-    def candidates(self, scan, q_host: np.ndarray, want: int, excluded,
-                   hooks: bool):
+    def candidates(self, scan, q_host: np.ndarray, want: int, hooks: bool):
         # every widening is another scan of the shards, asked for more
         for k in _doubling(max(4 * want, 64) if hooks else want, self.n):
-            vals, idx = scan(self, q_host[None, :],
-                             [excluded] if excluded else None, k,
-                             register=False)
+            vals, idx = scan(self, q_host[None, :], (k, 0), register=False)
             yield vals[0], idx[0]
 
 
@@ -769,30 +757,30 @@ class _QuantSnapshot(_ArenaSnapshot):
                    prev=prev, incremental=True, slab=delta.slab,
                    slab_rows=slab_rows, rescore_factor=prev.rescore_factor)
 
-    def batch_width(self, how_many: int, filtering: bool) -> int:
-        return min(self.n, self.rescore_width(how_many))
+    def batch_width(self, how_many: int, filtering: bool, room: int = 0):
+        return min(self.n, _round_up_pow2(
+            self.rescore_width(how_many) + room)), room
 
-    def plan(self, qs, excl, lut, r: int):
+    def plan(self, qs, lut, width):
         """Top-``r`` CANDIDATES (approximate scores) for the exact rescore:
         ONE quantized scan over the whole query batch (¼ the f32 HBM per
         pass); the per-query lut selects the masked program."""
-        key = _topn_cost_key(qs.shape[0], excl is not None, quant=True)
+        r, room = width
+        key = _topn_cost_key(qs.shape[0], room, quant=True)
         if lut is not None:
             return ((_quant_candidates_masked,
-                     (self.qmat, self.qscale, qs, lut, self.buckets, excl, r),
+                     (self.qmat, self.qscale, qs, lut, self.buckets, r),
                      key),)
         return ((_quant_candidates,
-                 (self.qmat, self.qscale, qs, None, excl, r), key),)
+                 (self.qmat, self.qscale, qs, None, r), key),)
 
-    def candidates(self, scan, q_host: np.ndarray, want: int, excluded,
-                   hooks: bool):
+    def candidates(self, scan, q_host: np.ndarray, want: int, hooks: bool):
         # the quantized matmul runs ONCE, exactly like the f32 path; each
         # widening rescores its candidates exactly from the arena
         valid = (_candidate_mask(self, q_host[None, :], self.n)
                  if self.lsh is not None else None)
         scores = _quant_masked_scores(
-            self.qmat, self.qscale, jnp.asarray(q_host[None, :]), valid,
-            self.one_excluded(excluded))
+            self.qmat, self.qscale, jnp.asarray(q_host[None, :]), valid)
         return _widen_top_k(self, scores, self.rescore_width(want), q_host)
 
     def cosine_candidates(self, qs_host: np.ndarray, want: int):
@@ -863,8 +851,7 @@ class ALSServingModel(ServingModel):
         # (int8 / IVF snapshots never use the store's device matrix)
         self.y = FeatureVectorStore(mesh=mesh, shard_axis=shard_axis)
         self.lsh = LocalitySensitiveHash(sample_rate, features) if sample_rate < 1.0 else None
-        self.known_items: dict[str, set[str]] = {}
-        self._known_lock = threading.Lock()
+        self.known = KnownItems()
         self.expected_user_ids: set[str] = set()
         self.expected_item_ids: set[str] = set()
         self.yty_cache = SolverCache(self.y.get_vtv)
@@ -916,13 +903,24 @@ class ALSServingModel(ServingModel):
     def get_item_vector(self, item: str):
         return self.y.get_vector(item)
 
+    def bulk_load_known_items(self, user_ids, offsets, items, item_ids) -> None:
+        """A generation's known items at once, beside ``bulk_load_users``:
+        user ``user_ids[u]`` knows ``item_ids[j]`` for every ``j`` of
+        ``items[offsets[u]:offsets[u + 1]]`` (models/als/known.py)."""
+        self.known.bulk_load(user_ids, offsets, items, item_ids)
+
     def add_known_items(self, user: str, items: Sequence[str]) -> None:
-        with self._known_lock:
-            self.known_items.setdefault(user, set()).update(items)
+        self.known.add(user, items)
 
     def get_known_items(self, user: str) -> set[str]:
-        with self._known_lock:
-            return set(self.known_items.get(user, ()))
+        return self.known.ids(user)
+
+    def known_item_codes(self, user: str) -> "np.ndarray | None":
+        """The user's known items as ``top_n_batch(excluded=...)`` takes them
+        from the default ``/recommend``: interned item codes, one dictionary
+        lookup a request; None where the user has none."""
+        codes = self.known.codes(user)
+        return codes if len(codes) else None
 
     def get_known_item_vectors_for_user(self, user: str) -> list[tuple[str, np.ndarray]]:
         """(ALSServingModel.getKnownItemVectorsForUser)"""
@@ -935,17 +933,11 @@ class ALSServingModel(ServingModel):
 
     def item_counts(self) -> dict[str, int]:
         """How many users know each item (ALSServingModel.getItemCounts)."""
-        counts: dict[str, int] = {}
-        with self._known_lock:
-            for items in self.known_items.values():
-                for i in items:
-                    counts[i] = counts.get(i, 0) + 1
-        return counts
+        return self.known.item_counts()
 
     def user_counts(self) -> dict[str, int]:
         """Known-item count per user (MostActiveUsers source)."""
-        with self._known_lock:
-            return {u: len(items) for u, items in self.known_items.items()}
+        return self.known.user_counts()
 
     def all_user_ids(self) -> list[str]:
         return self.x.ids()
@@ -961,11 +953,7 @@ class ALSServingModel(ServingModel):
         self.yty_cache.set_dirty()
 
     def retain_recent_and_known_items(self, users) -> None:
-        keep = set(users)
-        with self._known_lock:
-            for u in list(self.known_items):
-                if u not in keep:
-                    del self.known_items[u]
+        self.known.retain_users(users)
 
     def get_fraction_loaded(self) -> float:  # ALSServingModel.java:396
         total = len(self.expected_user_ids) + len(self.expected_item_ids)
@@ -986,6 +974,20 @@ class ALSServingModel(ServingModel):
             return self._snapshot
 
     # -- query primitives ----------------------------------------------------
+    def _excluded_rows(self, snap, excluded) -> "np.ndarray | None":
+        """One query's exclusions as rows of ``snap``: item codes (what
+        ``known_item_codes`` gives the default endpoint) by one fancy index
+        into the code → row table, item ids (a request's own) by a lookup
+        each. None where there are none."""
+        if excluded is None or not len(excluded):
+            return None
+        if isinstance(excluded, np.ndarray):
+            rows = self.known.rows_in(snap)[excluded]
+            return rows[rows >= 0]
+        return np.fromiter(
+            (r for r in map(snap.id_to_idx.get, excluded) if r is not None),
+            dtype=np.int32)
+
     def top_n(
         self,
         query_vec: np.ndarray,
@@ -993,40 +995,46 @@ class ALSServingModel(ServingModel):
         offset: int = 0,
         allowed: "Callable[[str], bool] | None" = None,
         rescore: "Callable[[str, float], float] | None" = None,
-        excluded: "Sequence[str] | None" = None,
+        excluded: "Sequence[str] | np.ndarray | None" = None,
     ) -> list[tuple[str, float]]:
         """Dot-product top-N over Y: one matmul + top_k (ALSServingModel.topN
-        :261-276, TopNConsumer:56-73). ``excluded`` ids (known-item filtering)
-        are masked on device; ``allowed``/``rescore`` host hooks (rescorer SPI)
-        filter the candidate stream with widening retry."""
+        :261-276, TopNConsumer:56-73). ``excluded`` (item ids, or the codes
+        of ``known_item_codes``: known-item filtering) are rows dropped from
+        an over-fetched list; ``allowed``/``rescore`` host hooks (rescorer
+        SPI) filter the candidate stream with widening retry."""
         snap = self.y_snapshot()
         if not snap.servable:
             return []
         return self._top_n(snap, np.asarray(query_vec, dtype=np.float32),
-                           how_many, offset, allowed, rescore, excluded)
+                           how_many, offset, allowed, rescore,
+                           self._excluded_rows(snap, excluded))
 
     @staticmethod
     def _top_n(snap, q_host: np.ndarray, how_many: int, offset: int, allowed,
-               rescore, excluded) -> list[tuple[str, float]]:
+               rescore, dropped) -> list[tuple[str, float]]:
+        room = 0 if dropped is None else len(dropped)
         return _first_enough(
             snap,
-            snap.candidates(_scan, q_host, how_many + offset, excluded,
+            snap.candidates(_scan, q_host, how_many + offset + room,
                             allowed is not None or rescore is not None),
-            how_many, offset, allowed, rescore)
+            how_many, offset, allowed, rescore, dropped)
 
     def top_n_batch(
         self,
         query_vecs: np.ndarray,
         how_many: int,
         alloweds: "Sequence[Callable[[str], bool] | None] | None" = None,
-        excluded: "Sequence[Sequence[str] | None] | None" = None,
+        excluded: "Sequence[Sequence[str] | np.ndarray | None] | None" = None,
     ) -> list[list[tuple[str, float]]]:
         """Micro-batched top-N: many queries in ONE matmul+top_k device call —
         the TPU-idiomatic serving pattern (amortizes per-call overhead that the
-        reference spends thread-fanning partition scans). ``excluded[b]`` ids
-        are masked device-side; ``alloweds`` host callables (rescorer SPI)
-        filter after the scan. One histogram observe + one counter add per
-        CALL (not per query) keeps the hot path inside the metrics budget."""
+        reference spends thread-fanning partition scans). ``excluded[b]``
+        (item ids or ``known_item_codes``) are left out by over-fetching:
+        the scan is asked for room enough for the longest of them and their
+        rows are dropped from its lists; ``alloweds`` host callables
+        (rescorer SPI) filter after the scan. One histogram observe + one
+        counter add per CALL (not per query) keeps the hot path inside the
+        metrics budget."""
         _TOPN_QUERIES.inc(len(query_vecs))
         t0 = time.perf_counter()
         try:
@@ -1038,47 +1046,87 @@ class ALSServingModel(ServingModel):
                 time.perf_counter() - t0, exemplar=spans.current_trace_id()
             )
 
+    def _exclusions(self, snap, excluded, batch: int, room: int) -> np.ndarray:
+        """A flush's exclusions as rows of ``snap``: ``(batch, E)`` int32, -1
+        where a query has fewer than the longest (or an item has no row).
+        Built while the device scans, dropped from its lists afterwards."""
+        with spans.stage("topn.exclude") as sp:
+            table = self.known.rows_in(snap)
+            lengths = [0 if e is None else len(e) for e in excluded]
+            rows = np.full((batch, max(lengths)), -1, dtype=np.int32)
+            for b, e in enumerate(excluded):
+                if not lengths[b]:
+                    continue
+                if isinstance(e, np.ndarray):  # item codes
+                    rows[b, :lengths[b]] = table[e]
+                else:
+                    known = self._excluded_rows(snap, e)
+                    rows[b, :len(known)] = known
+            entries = int((rows >= 0).sum())
+            overflowed = sum(1 for n in lengths if n > room)
+            _EXCLUDED_ENTRIES.inc(entries)
+            if overflowed:
+                _EXCLUSION_OVERFLOW.inc(overflowed)
+            sp.set_attribute("entries", entries)
+            sp.set_attribute("width", room)
+            sp.set_attribute("overflowed", overflowed)
+        return rows
+
     def _top_n_batch(
         self,
         query_vecs: np.ndarray,
         how_many: int,
         alloweds: "Sequence[Callable[[str], bool] | None] | None" = None,
-        excluded: "Sequence[Sequence[str] | None] | None" = None,
+        excluded: "Sequence[Sequence[str] | np.ndarray | None] | None" = None,
     ) -> list[list[tuple[str, float]]]:
         snap = self.y_snapshot()
         if not snap.servable:
             return [[] for _ in range(len(query_vecs))]
         qs_host = np.asarray(query_vecs, dtype=np.float32)
         filtering = alloweds is not None and any(a is not None for a in alloweds)
-        vals, idx = _scan(snap, qs_host, excluded,
-                          snap.batch_width(how_many, filtering),
-                          register=True)
+        # the room comes from the lengths as handed over (a code or id with
+        # no row only makes it generous); a request with nothing to leave
+        # out does no more than this test
+        room = 0 if excluded is None else _room_for(
+            max((len(e) for e in excluded if e is not None), default=0))
+        out = _dispatch(snap, qs_host,
+                        snap.batch_width(how_many, filtering, room),
+                        register=True)
+        # under the scan: which rows each query leaves out
+        rows = (self._exclusions(snap, excluded, len(qs_host), room)
+                if room else None)
+        vals, idx = _download(out)
         if snap.rescore is not None:
             # an approximate backend's candidates, exact-f32-rescored from
             # the arena slab before the final cut
             with spans.stage("topn.rescore"):
                 vals, idx = snap.rescore(qs_host, vals, idx)
         with spans.stage("topn.ids"):
+            dropped = None
+            if room:
+                vals, idx, dropped = _drop_rows(vals, idx, rows)
             if not filtering:
-                return _id_lists(snap.ids, vals, idx, how_many)
-            out = []
-            for b in range(len(qs_host)):
-                allowed = alloweds[b] if alloweds else None
-                got = _collect(
-                    snap, vals[b], idx[b], how_many, allowed, None)[:how_many]
-                if len(got) < how_many and vals.shape[1] < snap.n:
-                    # heavy filtering consumed this query's candidates and
-                    # the scan was narrower than Y — fall back to the
-                    # widening single-query path
-                    got = self._top_n(
-                        snap, qs_host[b], how_many, 0, allowed, None,
-                        excluded[b] if excluded else None,
-                    )
-                out.append(got)
+                out = _id_lists(snap.ids, vals, idx, how_many)
+            else:
+                out = [_collect(snap, vals[b], idx[b], how_many,
+                                alloweds[b] if alloweds else None,
+                                None)[:how_many]
+                       for b in range(len(qs_host))]
+            if (filtering or dropped is not None) and vals.shape[1] < snap.n:
+                for b, got in enumerate(out):
+                    if len(got) < how_many and (filtering or dropped[b]):
+                        # host filters, or more of this query's exclusions
+                        # among its best than there was room for, consumed
+                        # its candidates and the scan was narrower than Y —
+                        # fall back to the widening single-query path
+                        out[b] = self._top_n(
+                            snap, qs_host[b], how_many, 0,
+                            alloweds[b] if alloweds else None, None,
+                            None if rows is None else rows[b][rows[b] >= 0])
             return out
 
     def warm_bucket(self, batch_size: int, how_many: int = 10) -> None:
-        """Pre-compile the batched top-N program for ONE pow2 batch size
+        """Pre-compile the batched top-N programs for ONE pow2 batch size
         against the live factor shapes — the per-bucket unit of the serving
         warmup ladder (serving/app.py _BatchWarmer, smallest bucket first).
 
@@ -1092,46 +1140,48 @@ class ALSServingModel(ServingModel):
         items yet (the warmer retries later).
 
         What is compiled is what the flush dispatches: the snapshot's own
-        ``plan``, asked with shapes where ``_scan`` asks with arrays, under
+        ``plan``, asked with shapes where ``_dispatch`` asks with arrays, under
         the same cost keys — so a handoff warms exactly the signatures its
         traffic runs, whichever backend serves it.
 
-        BOTH signature families warm: exclusion-free AND exclusion-carrying
-        — the default ``/recommend`` path (considerKnownItems=false) always
-        sends known-item exclusions, and ``_excluded_indices`` pads them to
-        the shape-stable ``_EXCL_PAD_MIN`` width this warms, so the first
-        client burst after a MODEL handoff pays no compile on the endpoint
-        it actually calls."""
+        EVERY width a flush of this size can ask for warms: the plain one
+        and one for each over-fetch room (``topn._OVERFETCH_ROOM``) — the
+        default ``/recommend`` path (considerKnownItems=false) always hands
+        over its user's known items, and a flush takes the least room that
+        holds the longest history in it, the widest for any longer one. The
+        set is closed: no history length reaches a width this did not
+        compile, so the first client burst after a MODEL handoff pays no
+        compile on the endpoint it actually calls."""
         snap = self.y_snapshot()
         if not snap.servable:
             raise ValueError("no item factors to warm against yet")
-        width = snap.batch_width(how_many, False)
         qs = snap.struct((batch_size, self.features), jnp.float32)
         lut = (snap.struct((batch_size, snap.lsh.num_buckets), jnp.bool_)
                if snap.lsh is not None else None)
         compiled = set()
-        for excl in (None,
-                     snap.struct((batch_size, _EXCL_PAD_MIN), jnp.int32)):
-            for fn, args, cost_key in snap.plan(qs, excl, lut, width):
+        for room in _OVERFETCH_ROOM:
+            width = snap.batch_width(how_many, False, room)
+            for fn, args, cost_key in snap.plan(qs, lut, width):
                 if cost_key in compiled:
-                    continue  # a step both families share
+                    continue  # a step the widths share
                 compiled.add(cost_key)
                 compilecache.aot_compile(
                     fn, *_operands(args), cost_key=cost_key)
-        # marked attempted: the lazy first-use registration in _scan would
+        # marked attempted: the lazy first-use registration in _dispatch would
         # otherwise re-lower and re-compile each signature the ladder just
         # registered — once per signature per generation, during the
         # handoff warm window
         snap.cost_keys_attempted.update(compiled)
         zeros = np.zeros((batch_size, self.features), dtype=np.float32)
         self.top_n_batch(zeros, how_many)
-        # one real exclusion-carrying execution: an id no snapshot contains
-        # maps to an all(-1) mask of the floored width — the exact program
-        # the default endpoint's known-item exclusions dispatch to
-        self.top_n_batch(
-            zeros, how_many,
-            excluded=[("__warm__",)] + [None] * (batch_size - 1),
-        )
+        # one real execution at each over-fetched width: a first query that
+        # leaves out as many rows as the room holds (whichever rows: the
+        # queries are zero) dispatches the very program a history of that
+        # length does
+        for room in _OVERFETCH_ROOM[1:]:
+            self.top_n_batch(
+                zeros, how_many,
+                excluded=[snap.ids[:room]] + [None] * (batch_size - 1))
 
     def top_n_cosine(
         self,

@@ -4,7 +4,8 @@ float, mesh-split and int8 views of Y, and the flush that drives them) and
 a device view of Y that owns its program, its width and its warm
 signatures), the arena-backed exact rescore of the two approximate views
 (:class:`_ArenaSnapshot`), and the host-side pieces of a query that do not
-depend on the view (exclusion padding, candidate collection).
+depend on the view (the over-fetch room and the dropping of excluded rows,
+candidate collection).
 
 Nothing here imports ``serving`` or ``ivf``: the arrows point one way.
 """
@@ -24,15 +25,20 @@ def _round_up_pow2(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length())
 
 
-#: Floor of the pow2-bucketed exclusion-mask width. Known-item exclusion is
-#: what the DEFAULT /recommend path sends (considerKnownItems=false), so its
-#: jit signature must be shape-stable enough to PRE-warm: flooring the width
-#: means every request with ≤ this many known items — the overwhelming
-#: common case — lands on ONE compiled program, which the batch warmer
-#: compiles off-path (warm_bucket). Users past the floor bucket up by pow2
-#: and pay one compile per bucket per process (persistent-cache-served
-#: afterwards), exactly like unusual howMany values.
-_EXCL_PAD_MIN = 8
+#: Known-item exclusion is what the DEFAULT /recommend sends
+#: (considerKnownItems=false), and it is done by over-fetching: the scan —
+#: the same fused matmul + approximate top-k that answers a request with
+#: nothing to leave out — is asked for ``how_many`` plus room for the flush's
+#: longest history, and the known rows are dropped from the list on the host
+#: (the best ``k + E`` of everything, less at most ``E`` known rows, begins
+#: with the best ``k`` of the rest). The room is one of these, so a batch size
+#: has three programs, all of which the warm ladder compiles: none, the
+#: common histories, the long ones. With the default ``how_many`` of 10 the
+#: widths are 16, 64 and 256. A longer history is asked at the widest; only
+#: where what is left of a list then falls short of ``how_many`` (more of the
+#: user's own items among their best 256 than there was room for) is the
+#: query answered again, alone, by the widening single-query path.
+_OVERFETCH_ROOM = (0, 48, 240)
 
 #: Host-side quantization chunk: bounds the transient f32 gather while
 #: building a full quantized snapshot (2^16 rows × 50f ≈ 13 MB per chunk
@@ -51,7 +57,14 @@ def _id_lists(ids, vals: np.ndarray, idx: np.ndarray, how_many: int) -> list:
     ]
 
 
-def _collect(snap, vals, idx, want, allowed, rescore) -> list[tuple[str, float]]:
+def _collect(snap, vals, idx, want, allowed, rescore,
+             dropped=None) -> list[tuple[str, float]]:
+    """One query's candidates, best first, as ``(id, score)``: masked ones
+    (-inf) end the list, ``dropped`` rows (the query's exclusions) and ids
+    the hooks refuse are left out."""
+    if dropped is not None and len(dropped):
+        keep = ~np.isin(idx, dropped)
+        vals, idx = vals[keep], idx[keep]
     out: list[tuple[str, float]] = []
     for v, i in zip(vals, idx):
         if not np.isfinite(v):
@@ -70,26 +83,35 @@ def _collect(snap, vals, idx, want, allowed, rescore) -> list[tuple[str, float]]
     return out
 
 
-def _excluded_indices(snap, excluded, batch: int) -> np.ndarray:
-    """(B, E) int32 of global Y rows to mask out, -1-padded, E a pow2
-    FLOORED at ``_EXCL_PAD_MIN`` so the common exclusion widths all
-    share one jit signature — the one the batch warmer precompiles."""
-    idx_lists: list[list[int]] = []
-    max_e = 1
-    for b in range(batch):
-        ids = excluded[b] if excluded is not None else None
-        ix = (
-            [snap.id_to_idx[i] for i in ids if i in snap.id_to_idx]
-            if ids
-            else []
-        )
-        idx_lists.append(ix)
-        max_e = max(max_e, len(ix))
-    width = max(_EXCL_PAD_MIN, _round_up_pow2(max_e))
-    out = np.full((batch, width), -1, dtype=np.int32)
-    for b, ix in enumerate(idx_lists):
-        out[b, : len(ix)] = ix
-    return out
+def _room_for(longest: int) -> int:
+    """The least over-fetch room that holds a history of ``longest`` rows
+    (the widest where none does)."""
+    return next((r for r in _OVERFETCH_ROOM if r >= longest),
+                _OVERFETCH_ROOM[-1])
+
+
+#: Past this many comparisons (lists x their width x the longest history) a
+#: flush's rows are looked for a query at a time, not in one broadcast.
+_DROP_AT_ONCE = 1 << 21
+
+
+def _drop_rows(vals: np.ndarray, idx: np.ndarray, rows: np.ndarray):
+    """``(B, W)`` lists, best first, with each query's ``rows[b]`` (``(B,
+    E)`` row indices, -1 where a query has fewer) taken out: what is left
+    keeps its order and moves to the front, -inf fills the end. Returns the
+    lists and, a query, how many rows were taken out of its list. Most
+    flushes' lists hold none of their queries' rows: one comparison says so
+    and nothing is copied."""
+    if idx.size * rows.shape[1] <= _DROP_AT_ONCE:
+        hit = (idx[:, :, None] == rows[:, None, :]).any(axis=-1)
+    else:
+        hit = np.stack([np.isin(i, r) for i, r in zip(idx, rows)])
+    if not hit.any():
+        return vals, idx, np.zeros(len(idx), dtype=np.int64)
+    order = np.argsort(hit, axis=1, kind="stable")
+    gone = np.take_along_axis(hit, order, axis=1)
+    vals = np.where(gone, -np.inf, np.take_along_axis(vals, order, axis=1))
+    return vals, np.take_along_axis(idx, order, axis=1), hit.sum(axis=1)
 
 
 def _quantize_rows(mat: np.ndarray) -> "tuple[np.ndarray, np.ndarray]":
@@ -145,26 +167,29 @@ class _Snapshot:
       an incremental step from it, or a rebuild. ``current`` is called under
       the model's snapshot lock; ``source`` just before it, outside the
       lock, for what a backend reads of the store that may take long.
-    * ``batch_width(how_many, filtering)``: the static width of a batch's
-      program — the width rule, once per backend.
-    * ``plan(qs, excl, lut, width)`` → steps ``(fn, args, cost_key)``, in
-      order: each a jitted program, its operands (static width last) and
-      its cost key, for a query batch ``qs``, optional ``(B, E)`` exclusions
-      and, where the view is LSH-masked, the per-query ``lut``. The three
-      may be arrays (the flush runs the steps) or ``jax.ShapeDtypeStruct``s
-      (the warm ladder compiles them): the ladder compiles exactly what the
-      flush dispatches. A later step takes the result of the one before it
-      where its operands hold a :class:`_Fed`.
+    * ``batch_width(how_many, filtering, room)``: the static width of a
+      batch's program — the width rule, once per backend. ``room`` is how
+      many more candidates than ``how_many`` every query must get back: the
+      flush's exclusions are rows dropped from the list afterwards
+      (``_OVERFETCH_ROOM``), never an operand of the program.
+    * ``plan(qs, lut, width)`` → steps ``(fn, args, cost_key)``, in order:
+      each a jitted program, its operands (static width last) and its cost
+      key, for a query batch ``qs`` and, where the view is LSH-masked, the
+      per-query ``lut``. The two may be arrays (the flush runs the steps) or
+      ``jax.ShapeDtypeStruct``s (the warm ladder compiles them): the ladder
+      compiles exactly what the flush dispatches. A later step takes the
+      result of the one before it where its operands hold a :class:`_Fed`.
     * ``dispatched(batch, width)``: called after a scan's last step is on
       its way — a backend's own counters.
     * ``place(host)`` / ``struct(shape, dtype)``: a batch-shaped operand on
       the device(s), as an array or as a shape.
     * ``rescore(qs_host, vals, idx)``: exact f32 re-ranking of approximate
       candidates; ``None`` where the scan's scores are final.
-    * ``candidates(scan, q_host, want, excluded, hooks)``: one query's
-      ``(vals, idx)`` candidate rows, then wider ones while any remain — the
-      backend's widening policy. ``scan`` is the flush's device side, for
-      the backends that widen by querying again.
+    * ``candidates(scan, q_host, want, hooks)``: one query's ``(vals, idx)``
+      candidate rows, then wider ones while any remain — the backend's
+      widening policy (``want`` counts the rows the caller will drop).
+      ``scan`` is the flush's device side, for the backends that widen by
+      querying again.
     * ``cosine_candidates(qs_host, want)``: the same for mean cosine.
     * ``device_arrays()``: what it holds on the device; ``scanned``: the
       one of them every batch program reads, whose shape keys the jit
@@ -189,14 +214,13 @@ class _Snapshot:
         if prev is not None and incremental:
             # id→idx is append-only across incremental generations; sharing
             # the dict avoids an O(n) rebuild per microbatch (extra entries
-            # in the older snapshot only affect exclusion masks, which drop
-            # out-of-range rows on device)
+            # in the older snapshot only name rows its lists never hold)
             self.id_to_idx = prev.id_to_idx
             for i in range(len(prev.ids), len(ids)):
                 self.id_to_idx[ids[i]] = i
         else:
             self.id_to_idx = {s: i for i, s in enumerate(ids)}
-        # lazy cost-registration marks (see serving._scan): per GENERATION
+        # lazy cost-registration marks (see serving._dispatch): per GENERATION
         # so a model swap re-registers against the new shapes, but carried
         # across same-shape incremental snapshots (point-update microbatches
         # whose dispatch signatures — and therefore per-call costs — are
@@ -230,17 +254,6 @@ class _Snapshot:
         for query_vec in query_vecs:
             lut[self.lsh.get_candidate_indices(query_vec)] = True
         return lut
-
-    def one_excluded(self, excluded):
-        """A single query's padded exclusions on the device, or None when
-        none of them is a row of this view. pow2-padded with -1 fill so jit
-        signatures stay stable: every distinct known-item count would
-        otherwise trigger a fresh compile on the serving hot path."""
-        if excluded:
-            padded = _excluded_indices(self, [excluded], 1)
-            if (padded >= 0).any():
-                return jnp.asarray(padded)
-        return None
 
 
 class _ArenaSnapshot(_Snapshot):

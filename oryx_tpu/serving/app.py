@@ -650,12 +650,12 @@ class _BatchWarmer(threading.Thread):
     the warmer warms a staged generation before the serving one, then
     promotes it atomically, so an update-topic model push never causes a
     request-visible compile storm. Models without a batched top-N (k-means,
-    RDF) mark warmup trivially complete. Each bucket warms BOTH signature
-    families — exclusion-free and exclusion-carrying (the default
-    ``/recommend`` path always sends known-item exclusions, padded to a
-    shape-stable floored width precisely so this ladder can cover it);
-    only unusual howMany values and oversized exclusion sets still compile
-    on first use."""
+    RDF) mark warmup trivially complete. Each bucket warms EVERY width a
+    flush of its size can ask for — the exclusion-free one and the two
+    over-fetched ones (the default ``/recommend`` path always hands over
+    its user's known items; a flush asks for room for the longest history
+    in it, from a closed set, precisely so this ladder can cover it); only
+    unusual howMany values still compile on first use."""
 
     # the reference API's default howMany — warms the top-k width the
     # common request hits; larger howMany values still compile on first use

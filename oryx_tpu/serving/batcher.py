@@ -196,8 +196,9 @@ class TopNCoalescer:
         )
         self._pending.append((model, _Pending(
             np.asarray(query_vec, dtype=np.float32), how_many, offset,
-            allowed, excluded, fut, loop.time(), wait_span,
-            resilience.current_deadline(),
+            allowed,
+            excluded if excluded is not None and len(excluded) else None,
+            fut, loop.time(), wait_span, resilience.current_deadline(),
         )))
         self._maybe_flush(loop)
         return await fut
@@ -399,9 +400,11 @@ class TopNCoalescer:
                         if any(p.allowed is not None for p in group)
                         else None
                     )
+                    # ids, or a model's own codes for them (an array):
+                    # None where a request leaves nothing out
                     excluded = (
                         [p.excluded for p in group]
-                        if any(p.excluded for p in group)
+                        if any(p.excluded is not None for p in group)
                         else None
                     )
                     # pad the batch to a power of two: coalesced batch sizes

@@ -94,9 +94,10 @@ async def recommend(request: web.Request) -> web.Response:
     how_many, offset = get_how_many_offset(request)
     consider_known = request.query.get("considerKnownItems", "false") == "true"
     uv = check_exists(model.get_user_vector(user), user)
-    # known-item filtering rides the scan as a device-side mask (the sharded
-    # path needs no host fallback); rescorer hooks stay host-side callables
-    known = set() if consider_known else model.get_known_items(user)
+    # known-item filtering rides the scan: the model hands its codes for
+    # the user's items (no copy, no id at a time) and the flush over-fetches
+    # and drops their rows; rescorer hooks stay host-side callables
+    known = None if consider_known else model.known_item_codes(user)
     provider = _rescorer_provider(request)
     rescorer = (
         provider.get_recommend_rescorer([user], get_rescorer_params(request))

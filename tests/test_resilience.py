@@ -598,6 +598,9 @@ class _SlowALSModel:
     def get_known_items(self, user):
         return set()
 
+    def known_item_codes(self, user):
+        return None
+
     def top_n_batch(self, qs, how_many, alloweds=None, excluded=None):
         if self.delay_s:
             time.sleep(self.delay_s)

@@ -383,10 +383,11 @@ def test_precompile_batches_warms_pow2_ladder(tmp_path, monkeypatch):
             time.sleep(0.1)
         else:
             pytest.fail("warmer never warmed a model")
-        # each bucket executes TWICE — exclusion-free then exclusion-
-        # carrying (the default /recommend path's signature) — smallest
-        # bucket first so the replica turns ready incrementally
-        assert sizes[:10] == [1, 1, 2, 2, 4, 4, 8, 8, 16, 16], sizes
+        # each bucket executes THREE times — exclusion-free, then once at
+        # each over-fetched width the default /recommend path's known items
+        # can ask for (topn._OVERFETCH_ROOM) — smallest bucket first so the
+        # replica turns ready incrementally
+        assert sizes[:12] == [1, 1, 1, 2, 2, 2, 4, 4, 4, 8, 8, 8], sizes
         # the completed ladder marked the shared warmup state warm-ready
         from oryx_tpu.common import compilecache
 

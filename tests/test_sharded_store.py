@@ -220,10 +220,10 @@ def test_after_the_warm_ladder_no_bucket_compiles():
     cold = compilecache.compiles_total()
     for b in buckets:
         model.warm_bucket(b, 10)
-    assert compilecache.compiles_total() - cold >= 2 * len(buckets)
+    assert compilecache.compiles_total() - cold >= 3 * len(buckets)
     snap = model.y_snapshot()
     for b in buckets:
-        for excl in ("", "+excl"):
+        for excl in ("", "+excl48", "+excl240"):
             key = f"als.top_n_batch/b{b}{excl}+sharded"
             assert key in snap.cost_keys_attempted
             flops, bytes_ = profiling.costs().cost(key)

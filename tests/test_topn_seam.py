@@ -1,8 +1,9 @@
 """The warm ladder is the dispatch (ISSUE 28): whichever scan backend a model
 resolves to, ``warm_bucket`` compiles what its snapshot's ``plan`` returns for
 shapes and a flush dispatches what the same ``plan`` returns for arrays — so
-after the ladder a batch of that size, with or without exclusions, registers
-no cost key and compiles nothing. The benchmark's ``compiles_in_window`` limit
+after the ladder a batch of that size, with no exclusions or with histories of
+any length (ISSUE 30: a closed set of over-fetched widths), registers no cost
+key and compiles nothing. The benchmark's ``compiles_in_window`` limit
 is 0 in every serving cell; this is what holds it for every backend at once."""
 
 import threading
@@ -63,33 +64,40 @@ def test_after_warm_bucket_a_batch_registers_and_compiles_nothing(
     compilecache.install_compile_listener()
     cold = compilecache.compiles_total()
     model.warm_bucket(bucket, 10)
-    assert compilecache.compiles_total() - cold >= 2
+    assert compilecache.compiles_total() - cold >= 3
     snap = model.y_snapshot()
     assert (model.lsh is not None) == backend.endswith("+lsh")
     warmed = set(snap.cost_keys_attempted)
-    assert len(warmed) >= 2 and all(f"/b{bucket}" in k for k in warmed)
+    assert len(warmed) >= 3 and all(f"/b{bucket}" in k for k in warmed)
     assert set(compiled) == warmed
     ladder = {key: sigs[0] for key, sigs in compiled.items()}
 
     qs = np.random.default_rng(bucket).standard_normal(
         (bucket, FEATURES), dtype=np.float32)
-    excluded = [["i1", "i2", "i3"]] + [None] * (bucket - 1)
+    # a history a room (topn._OVERFETCH_ROOM), and one past the widest
+    histories = [[f"i{j}" for j in range(1, 1 + length)]
+                 for length in (3, 100, 500)]
     before = compilecache.compiles_total()
     plain = model.top_n_batch(qs, 10)
-    excluding = model.top_n_batch(qs, 10, excluded=excluded)
-    assert len(plain) == len(excluding) == bucket
-    assert not {"i1", "i2", "i3"} & {i for i, _ in excluding[0]}
+    assert len(plain) == bucket
+    for history in histories:
+        excluding = model.top_n_batch(
+            qs, 10, excluded=[history] + [None] * (bucket - 1))
+        assert len(excluding) == bucket and len(excluding[0]) == 10
+        assert not set(history) & {i for i, _ in excluding[0]}
+        assert excluding[1] == plain[1]
     assert model.y_snapshot() is snap
     assert set(snap.cost_keys_attempted) == warmed
     assert compilecache.compiles_total() - before == 0
 
     # and what a flush dispatches IS what the ladder compiled: a flush's
     # first-use registration hands aot_compile the very program and operands
-    # it then calls, so forget the marks and let the two batches register
+    # it then calls, so forget the marks and let the batches register
     compiled.clear()
     snap.cost_keys_attempted.clear()
     model.top_n_batch(qs, 10)
-    model.top_n_batch(qs, 10, excluded=excluded)
+    for history in histories:
+        model.top_n_batch(qs, 10, excluded=[history] + [None] * (bucket - 1))
     assert {key: sigs[0] for key, sigs in compiled.items()} == ladder
 
 
