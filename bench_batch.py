@@ -773,13 +773,13 @@ def run_mesh_bench(features: int = FEATURES) -> dict:
     from oryx_tpu.ops.pallas_kernels import on_tpu as mesh_on_tpu
 
     on_tpu = mesh_on_tpu(mesh=mesh)
-    solver = lambda side, opposite: tr._sharded_solver(
-        mesh, "model", side.block, features, True, side.slot_chunk,
-        "float32", on_tpu,
-        tr._resolve_fused(None, on_tpu, features, side.srows.shape[1],
-                          opposite.padded_rows),
-        not on_tpu,
-    )
+    def solver(side, opposite):
+        fused, gather_width = tr._resolve_fused(
+            None, on_tpu, features, side.srows.shape[1], opposite.padded_rows)
+        return tr._sharded_solver(
+            mesh, "model", side.block, features, True, side.slot_chunk,
+            "float32", on_tpu, fused, not on_tpu, gather_width)
+
     solve_u = solver(user_side, item_side)
     solve_i = solver(item_side, user_side)
     y = jax.device_put(

@@ -697,40 +697,59 @@ def _check_against_reference(size, config, users, items, x_pub, y_pub, x_row,
                            f"max |x| > {HALF_ITER_TOL}")
     findings.check()
 
+    # the gate's third answer, the einsum over the opposite table zero-padded
+    # to 64 columns (what a large table of narrow rows gets on a TPU), run
+    # here whatever the gate says of this size: it must agree with the
+    # reference like the others, on the device that is there
+    x_pad = tr._solve_side_blocked_jit(
+        y0, user_side.srows, user_side.scols, user_side.svals,
+        user_side.slens, lam, alpha, block=user_side.block, features=k,
+        implicit=True, slot_chunk=user_side.slot_chunk, dtype="float32",
+        spd_kernel=on_tpu, fused_gramian=False, kernel_interpret=not on_tpu,
+        gather_width=max(k, tr._GG_NARROW_FEATURES))
+    err = float(jnp.abs(x_pad - x_ref).max() / jnp.abs(x_ref).max())
+    out["padded_half_iteration_error"] = round(err, 6)
+    if not err < HALF_ITER_TOL:
+        raise SmokeFailure(f"one half-iteration through the padded gather "
+                           f"differs from the reference formulation by "
+                           f"{err:.3g} of max |x| > {HALF_ITER_TOL}")
+    findings.check()
+
     # (3) what als_train picks on a TPU, lowered for a TPU: the SPD kernel
-    # on both sides, and a side the gather-Gramian formulation the trainer's
-    # one gate answers for the opposite table that side gathers from — at
-    # this smoke's size both are small tables of narrow rows, so the rule
-    # says einsum; the count of kernel calls in each program must be what
-    # the gate said
+    # on both sides, and a side the gather-Gramian formulation and the gather
+    # width the trainer's one gate answers for the opposite table that side
+    # gathers from — at this smoke's size both are small tables of narrow
+    # rows, so the rule says einsum, unpadded; the count of kernel calls in
+    # each program must be what the gate said
     y_dev = next(iter(y0.devices()))
     if on_tpu and not pk.on_tpu(y0):
         raise SmokeFailure(f"the trainer's operands live on {y_dev}, not a TPU")
     out["formulation"] = {}
     for name, side, opp_rows in (("user", user_side, item_side.padded_rows),
                                  ("item", item_side, user_side.padded_rows)):
-        fused, why = tr._choose_formulation(None, True, k,
-                                            side.srows.shape[1], opp_rows)
+        chosen = tr._choose_formulation(None, True, k, side.srows.shape[1],
+                                        opp_rows)
+        runs = chosen.name(k)
         lowered = tr._solve_side_blocked_jit.trace(
             jax.ShapeDtypeStruct((opp_rows, k), jnp.float32), side.srows,
             side.scols, side.svals, side.slens, lam, alpha, block=side.block,
             features=k, implicit=True, slot_chunk=side.slot_chunk,
-            dtype="float32", spd_kernel=True, fused_gramian=fused,
-            kernel_interpret=False,
+            dtype="float32", spd_kernel=True, fused_gramian=chosen.fused,
+            kernel_interpret=False, gather_width=chosen.gather_width,
         ).lower(lowering_platforms=("tpu",)).as_text()
         calls = lowered.count("tpu_custom_call")
         out["formulation"][name] = {
-            "runs": tr._FORMULATION_NAMES[fused], "why": why,
-            "tpu_custom_calls": calls}
-        print(f"chip_smoke: the {name} half runs the "
-              f"{tr._FORMULATION_NAMES[fused]} ({why}); "
+            "runs": runs, "gather_width": chosen.gather_width,
+            "why": chosen.why, "tpu_custom_calls": calls}
+        print(f"chip_smoke: the {name} half runs the {runs}, gathering rows "
+              f"of {chosen.gather_width} columns ({chosen.why}); "
               f"{calls} tpu_custom_call(s)", file=sys.stderr)
-        if calls != 1 + fused:
+        if calls != 1 + chosen.fused:
             raise SmokeFailure(
                 f"the TPU-default {name} half-iteration lowers to {calls} "
-                f"tpu_custom_call(s) where the gate chose the "
-                f"{tr._FORMULATION_NAMES[fused]}: the SPD kernel is a TPU "
-                "default, the gather-Gramian kernel is the gate's to pick")
+                f"tpu_custom_call(s) where the gate chose the {runs}: the "
+                "SPD kernel is a TPU default, the gather-Gramian kernel is "
+                "the gate's to pick")
 
     # (2) train the reference formulation to the same iteration count
     xr, yy = x_ref, y0

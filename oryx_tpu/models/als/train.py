@@ -42,6 +42,7 @@ import functools
 import logging
 import math
 import os
+import typing
 from dataclasses import dataclass
 
 import jax
@@ -52,6 +53,17 @@ from oryx_tpu.common import metrics as metrics_mod
 from oryx_tpu.common import profiling
 from oryx_tpu.models.als.data import RatingBatch
 from oryx_tpu.ops import pallas_kernels as pk
+
+# XLA:TPU stages a gather of a multiple of 1,024 rows through HALF the scoped
+# buffer it gives any other count (compiled for a described v5e: 196,608 B
+# against 524,288 for rows of 64 columns out of a large table, 131,072 against
+# 262,144 out of a small one; PERF.md §6, PR 31), and the rows then arrive at
+# half the rate once the table is past 128 MiB of lane-padded rows: the
+# Netflix cell's item half read 1.39 s with chunks of 650, 652 or 654 slots
+# of T = 512 and 0.77 s with 651, 653 or 655 — by the seed's pack, a coin
+# toss; its user half 1.365 s against 1.28. A chunk's gather is kept off the
+# multiples (_solve_block).
+_GATHER_HALVED_ROWS = 1024
 
 # Budgets (in f32 elements) bounding the two big transients: the per-block
 # Gramian carry (B+1, k, k) and the per-chunk gather/Gramian buffers
@@ -603,6 +615,13 @@ def _solve_block(y, srow, scols, svals, slens, *, block, features, lam, alpha,
     device-platform decision into every Pallas kernel here (compiled on
     TPU, emulated elsewhere — one flag, so one kernel can never run
     compiled while the other is silently interpreted).
+
+    The einsum formulation gathers rows as wide as ``y`` is: where ``y``
+    arrives zero-padded past ``features`` columns (the gate's padded gather,
+    :func:`_rule`), the per-slot Gramians contract at that width and only
+    their leading ``features`` are kept, before the segment-sums — the padded
+    columns are zeros, so what is kept is what the unpadded contraction
+    computes, and everything from the accumulators on is at ``features``.
     """
     k = features
     t = scols.shape[-1]
@@ -621,25 +640,37 @@ def _solve_block(y, srow, scols, svals, slens, *, block, features, lam, alpha,
         )
     else:
         n_chunks = srow.shape[0] // slot_chunk
+        # one EMPTY slot more a chunk where its gather would otherwise fetch
+        # a multiple of _GATHER_HALVED_ROWS rows (T < that, so one is enough)
+        spare = slot_chunk * t % _GATHER_HALVED_ROWS == 0
 
         def body(carry, i):
             big_a, big_b, cnt = carry
-            sl = lambda a: jax.lax.dynamic_slice_in_dim(
-                a, i * slot_chunk, slot_chunk
-            )
-            rs, ls = sl(srow), sl(slens)
+
+            def sl(a, fill=0):
+                part = jax.lax.dynamic_slice_in_dim(
+                    a, i * slot_chunk, slot_chunk
+                )
+                if not spare:
+                    return part
+                return jnp.pad(part, ((0, 1),) + ((0, 0),) * (a.ndim - 1),
+                               constant_values=fill)
+
+            # the spare slot: no entries (every weight 0), owner = the spill
+            rs, ls = sl(srow, block), sl(slens)
             cs, vs = sl(scols), sl(svals)
             w, coef = _entry_weights(vs, ls, alpha, implicit, t)
-            yg = y[cs]  # (Sc, T, k) gather of the replicated opposite side
+            # (Sc, T, y's width) gather of the replicated opposite side
+            yg = y[cs]
             # per-slot Gramian: ONE batched MXU matmul, contraction over T
             ga = jnp.einsum(
                 "st,sti,stj->sij", w.astype(compute_dtype), yg, yg,
                 preferred_element_type=jnp.float32,
-            )  # (Sc, k, k)
+            )[:, :k, :k]  # (Sc, k, k)
             gb = jnp.einsum(
                 "st,sti->si", coef.astype(compute_dtype), yg,
                 preferred_element_type=jnp.float32,
-            )  # (Sc, k)
+            )[:, :k]  # (Sc, k)
             seg = functools.partial(
                 jax.ops.segment_sum, num_segments=block + 1,
                 indices_are_sorted=True,
@@ -681,19 +712,34 @@ def _solve_block(y, srow, scols, svals, slens, *, block, features, lam, alpha,
     return jnp.where((cnt > 0)[:, None], x, 0.0)
 
 
+def _gather_table(y, compute_dtype, features: int,
+                  gather_width: "int | None"):
+    """The opposite factor table as a half-iteration's chunks gather from
+    it: cast once to the compute dtype and, where the gate answered a gather
+    wider than ``features`` (:func:`_rule`; ``None``: as wide as they are),
+    zero-padded once to that many columns — a half-iteration's one copy of
+    the table, outside the map over blocks, and never the caller's to see."""
+    ys = y.astype(compute_dtype) if compute_dtype != y.dtype else y
+    if gather_width is not None and gather_width > features:
+        ys = jnp.pad(ys, ((0, 0), (0, gather_width - features)))
+    return ys
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
         "block", "features", "implicit", "slot_chunk", "dtype", "spd_kernel",
-        "fused_gramian", "kernel_interpret",
+        "fused_gramian", "kernel_interpret", "gather_width",
     ),
 )
 def _solve_side_blocked_jit(y, srows, scols, svals, slens, lam, alpha, *,
                             block, features, implicit, slot_chunk, dtype,
-                            spd_kernel, fused_gramian, kernel_interpret):
+                            spd_kernel, fused_gramian, kernel_interpret,
+                            gather_width=None):
     yty = (y.T @ y) if implicit else None  # (k,k) Gramian — one MXU matmul
     cd = jnp.dtype(dtype)
-    ys = y.astype(cd) if cd != y.dtype else y  # one cast, gathered per chunk
+    # one cast (and one pad), gathered per chunk
+    ys = _gather_table(y, cd, features, gather_width)
 
     def one(args):
         r, c, v, ln = args
@@ -708,19 +754,34 @@ def _solve_side_blocked_jit(y, srows, scols, svals, slens, lam, alpha, *,
     return out.reshape(-1, features)
 
 
-_FORMULATION_NAMES = {True: "fused kernel", False: "einsum"}
+class _Formulation(typing.NamedTuple):
+    """The gate's answer for one side (:func:`_choose_formulation`)."""
+
+    fused: bool  # the Pallas gather-Gramian kernel, else the einsum
+    gather_width: int  # columns of the rows the einsum gathers: ``features``,
+    # or more where it gathers from a zero-padded copy of the opposite table
+    why: str
+
+    def name(self, features: int) -> str:
+        if self.fused:
+            return "fused kernel"
+        return "einsum" if self.gather_width == features else "padded einsum"
+
+
+_FORMULATION_NAMES = ("einsum", "padded einsum", "fused kernel")
 _HALF_FORMULATION = metrics_mod.default_registry().gauge(
     "oryx_als_half_formulation",
     "1 for the gather-Gramian formulation the trainer's last generation "
-    "resolved this side's half-iteration to, 0 for the other",
+    "resolved this side's half-iteration to, 0 for the others",
     ("side", "formulation"),
 )
 
 # The gather-Gramian formulation is chosen a SIDE, from the opposite factor
-# table that side gathers from — its row width and its bytes. Placed from a
-# sweep on the chip (PERF.md §6, PR 29; one v5e, the Netflix cell's two packs
-# — T = 256 at 1.77 cells an entry, T = 512 at 1.05 — each against opposite
-# tables of 17,770 to 2M rows, seconds a half-iteration, einsum / kernel):
+# table that side gathers from — its row width and its bytes. Placed from two
+# sweeps on the chip (PERF.md §6, PRs 29 and 31; one v5e, the Netflix cell's
+# two packs — T = 256 at 1.77 cells an entry, T = 512 at 1.05 — each against
+# opposite tables of 17,770 to 2M rows, seconds a half-iteration). PR 29's,
+# einsum / kernel, the T = 256 pack:
 #
 #   features  9 MB-table    ~0.5M rows    ~1-2M rows     who wins
 #      32     0.94 / 2.10   2.46 / 2.98   8.75 / 3.07    einsum, then kernel
@@ -730,91 +791,129 @@ _HALF_FORMULATION = metrics_mod.default_registry().gauge(
 #     128     3.55 / 4.28   4.05 / 5.07   4.05 / 5.07    einsum
 #     250     12.0 / 11.3   12.6 / 13.3   12.6 / 17.7    kernel, then einsum
 #
-# (the T = 256 pack; the T = 512 pack orders the same way but for 250
-# features, where the two stay within 9% of each other at every size.) The
-# einsum's batched matmul over a materialised (Sc, T, k) gather beats the
-# kernel's slot-at-a-time contraction by 1.2-3.3x wherever XLA's gather holds
-# up, and it holds up at every size for rows of 64 features and more. For
-# NARROWER rows it collapses once the table outgrows something between 71 MB
-# (50 features x 355,400 rows: 1.75 / 3.20) and 96 MB (x 480,189 rows, the
-# Netflix user table: 2.34 / 2.18 on its own item side; 32 features x 480,189
-# = 61 MB still holds, 1.23 / 2.16) — there the kernel, whose copies cost 7.5
-# ns more an entry once the lane-padded table passes 128 MiB and no more
-# after that, is 1.07-3.2x faster. At 250 features the kernel's contraction
-# has caught up (ahead by 0.78 s and 0.22 s on the Netflix user and item
-# sides; interpolated linearly from 128 features' 0.73 and 0.71 s behind, a
-# whole iteration crosses at 200) — until its copies of sparse slots out of a
-# table past ~0.5-1 GB lose it again (480 MB: 0.92x / 1.05x the einsum's
-# seconds on the two packs; 960 MB: 0.97x / 1.17x; 1.9 GB: 1.01x / 1.40x).
-# Widths between the measured ones are unmeasured, and so is more than 2M rows.
+# (the T = 512 pack orders the same way but for 250 features, where the two
+# stay within 9% of each other at every size.) The einsum's batched matmul
+# over a materialised (Sc, T, k) gather beats the kernel's slot-at-a-time
+# contraction by 1.2-3.3x wherever XLA's gather holds up, and it holds up at
+# every size for rows of 64 features and more. For NARROWER rows it collapses
+# on a large table: the compiled program then writes the gathered rows
+# feature-major (f32[Sc*T, 50]{0,1}), where out of 64 columns it writes them
+# row-major. PR 31's sweep took the narrow rows' kernel out of the rule: the
+# SAME einsum over the table zero-padded to 64 columns (one pad a half, the
+# Gramians cut back to k before the segment-sums). Einsum / einsum padded to
+# 64 / kernel, the T = 512 pack ‖ the T = 256 pack, one process, the code as
+# it stands (a chunk's gather off the multiples of 1,024 rows):
+#
+#   50 features x 284,000 rows (56.8 MB)  0.837 / 0.837  ‖ 1.750 / 1.749
+#              x 355,400 rows (71.1 MB)   0.837 / 0.837  ‖ 1.750 / 1.749
+#              x 420,000 rows (84.0 MB)   0.837 / 0.838
+#              x 480,189 rows (96.0 MB)   2.466 / 0.837 / 2.185
+#                                       ‖ 4.410 / 1.749 / 3.268
+#              x 1,000,000 rows (200 MB)  5.186 / 0.828 / 2.186 ‖ 10.43 / 1.751
+#   32 features x 142,000 rows (18.2 MB)  0.434 / 0.434
+#              x 200,000 rows (25.6 MB)   0.477 / 0.475
+#              x 284,000 rows (36.4 MB)   0.817 / 0.818
+#              x 355,400 rows (45.5 MB)   1.314 / 0.818
+#              x 480,189 rows (61.5 MB)   1.334 / 0.818 / 2.161 ‖ 2.419 / 1.416
+#              x 1,000,000 rows (128 MB)  2.988 / 0.750
+#   50 features x 17,772 rows (3.6 MB, the cell's user half) ‖ 1.2757 / 1.2758
+#
+# Padded, the einsum never collapses and is ahead of the kernel by 1.9-2.9x
+# at every size; where the unpadded gather holds, the two are equal to the
+# millisecond (bit-equal on the 17,772-row table), so padding too early
+# costs nothing and padding too late costs 1.6-6x. The unpadded gather lets
+# go at a size that depends on the width: between 36.4 and 45.5 MB at 32
+# features, between 84.0 and 96.0 MB (the Netflix user table) at 50. One
+# crossover under the LOWEST of those serves every width measured; narrower
+# rows than 32, and widths between 32 and 50 and between 50 and 64, are
+# unmeasured. (The step every form takes between 200,000 and 284,000 rows is
+# the table passing 128 MiB of lane-padded rows: 262,144 of them.) At 250
+# features the kernel's contraction has caught up (ahead by 0.78 s and 0.22 s on the
+# Netflix user and item sides; interpolated linearly from 128 features' 0.73
+# and 0.71 s behind, a whole iteration crosses at 200) — until its copies of
+# sparse slots out of a table past ~0.5-1 GB lose it again (480 MB: 0.92x /
+# 1.05x the einsum's seconds on the two packs; 960 MB: 0.97x / 1.17x; 1.9 GB:
+# 1.01x / 1.40x). Widths between the measured ones are unmeasured, and so is
+# more than 2M rows.
 _GG_NARROW_FEATURES = 64  # rows under this: XLA's gather collapses when large
-_GG_NARROW_TABLE_BYTES = 80 << 20  # ... from here (between 71 and 96 MB)
+# ... from here (between 36.4 and 45.5 MB): pad them to _GG_NARROW_FEATURES
+_GG_NARROW_TABLE_BYTES = 40 << 20
 _GG_WIDE_FEATURES = 200  # rows from this: the kernel's contraction is ahead
 _GG_WIDE_TABLE_BYTES = 640 << 20  # ... up to here (between 480 and 960 MB)
 
 
-def _rule(features: int, table_rows: int) -> "tuple[bool, str]":
+def _rule(features: int, table_rows: int) -> _Formulation:
     """The sweep above as a function of what a half-iteration can observe of
-    the table it gathers from: (kernel?, the sizes against the crossover)."""
+    the table it gathers from: the formulation, the width its rows are
+    gathered at, and the sizes against the crossover."""
     nbytes = table_rows * features * 4
     seen = (f"the opposite table's {table_rows} rows of {features} features "
             f"are {nbytes / 1e6:.1f} MB")
     if features < _GG_NARROW_FEATURES:
-        fused = nbytes >= _GG_NARROW_TABLE_BYTES
-        return fused, (
-            f"{seen}, {'at or over' if fused else 'under'} the "
-            f"{_GG_NARROW_TABLE_BYTES / 1e6:.1f} MB where XLA's gather of "
-            f"rows under {_GG_NARROW_FEATURES} features collapses")
+        pad = nbytes >= _GG_NARROW_TABLE_BYTES
+        return _Formulation(
+            False, _GG_NARROW_FEATURES if pad else features,
+            f"{seen}, {'at or over' if pad else 'under'} the "
+            f"{_GG_NARROW_TABLE_BYTES / 1e6:.1f} MB from which XLA's gather "
+            f"of rows under {_GG_NARROW_FEATURES} features collapses"
+            + (f": gathered from the table zero-padded to "
+               f"{_GG_NARROW_FEATURES} columns" if pad else ""))
     if features >= _GG_WIDE_FEATURES:
         fused = nbytes < _GG_WIDE_TABLE_BYTES
-        return fused, (
+        return _Formulation(
+            fused, features,
             f"{seen}, {'under' if fused else 'at or over'} the "
             f"{_GG_WIDE_TABLE_BYTES / 1e6:.1f} MB up to which the kernel is "
             f"ahead at {_GG_WIDE_FEATURES} features and more")
-    return False, (f"{seen}: between {_GG_NARROW_FEATURES} and "
-                   f"{_GG_WIDE_FEATURES} features the einsum is ahead at "
-                   "every size")
+    return _Formulation(
+        False, features,
+        f"{seen}: between {_GG_NARROW_FEATURES} and {_GG_WIDE_FEATURES} "
+        "features the einsum is ahead at every size")
 
 
 def _choose_formulation(fused_gramian: "bool | None", on_tpu: bool,
                         features: int, slots: int,
-                        table_rows: int) -> "tuple[bool, str]":
-    """(run the fused gather-Gramian kernel?, why) for one side: the ONE
-    gate of every path that picks a formulation (single-device, mesh,
-    benches, the cost accounting and the pack's log line). ``slots`` is the
-    side's slots a block, ``table_rows`` the rows of the opposite factor
-    table it gathers from — under a mesh the whole all-gathered table, which
-    is what every shard reads.
+                        table_rows: int) -> _Formulation:
+    """(run the fused gather-Gramian kernel?, the width the einsum gathers
+    rows at, why) for one side: the ONE gate of every path that picks a
+    formulation (single-device, mesh, benches, the cost accounting and the
+    pack's log line). ``slots`` is the side's slots a block, ``table_rows``
+    the rows of the opposite factor table it gathers from — under a mesh the
+    whole all-gathered table, which is what every shard reads.
 
-    An explicit ``True`` / ``False`` forces a formulation; ``None`` is the
-    rule: off a TPU the einsum; on one whichever :func:`_rule` measured
-    faster for a table of this width and size. The kernel's own gates (VMEM
-    at ``features``, SMEM at ``slots``) are tested first: past them the
-    einsum runs instead of a program that cannot compile — and says so,
-    because on a TPU the difference can be large."""
+    An explicit ``True`` / ``False`` forces a formulation (the einsum then
+    gathers rows as wide as they are); ``None`` is the rule: off a TPU the
+    einsum; on one whatever :func:`_rule` measured fastest for a table of
+    this width and size. The kernel's own gates (VMEM at ``features``, SMEM
+    at ``slots``) are tested first: past them the einsum runs instead of a
+    program that cannot compile — and says so, because on a TPU the
+    difference can be large."""
     if fused_gramian is None:
         if not on_tpu:
-            return False, "not on a TPU"
+            return _Formulation(False, features, "not on a TPU")
     elif not fused_gramian:
-        return False, "asked for"
+        return _Formulation(False, features, "asked for")
     if not pk.gather_gramian_supported(features, slots):
         logging.getLogger(__name__).warning(
             "fused gather-Gramian kernel not used: features=%d, %d slots "
             "per block is past its VMEM/SMEM gates; using the einsum "
             "formulation", features, slots,
         )
-        return False, (f"features={features}, {slots} slots a block is past "
-                       "the kernel's gates")
+        return _Formulation(
+            False, features, f"features={features}, {slots} slots a block "
+            "is past the kernel's gates")
     if fused_gramian:
-        return True, "asked for"
+        return _Formulation(True, features, "asked for")
     return _rule(features, table_rows)
 
 
 def _resolve_fused(fused_gramian: "bool | None", on_tpu: bool,
-                   features: int, slots: int, table_rows: int) -> bool:
-    """:func:`_choose_formulation`'s answer without its reason."""
+                   features: int, slots: int,
+                   table_rows: int) -> "tuple[bool, int]":
+    """:func:`_choose_formulation`'s answer without its reason: (kernel?,
+    gather width), the two statics a solver is built from."""
     return _choose_formulation(fused_gramian, on_tpu, features, slots,
-                               table_rows)[0]
+                               table_rows)[:2]
 
 
 def solve_side_blocked(y, srows, scols, svals, slens, lam, alpha, *, block,
@@ -826,8 +925,11 @@ def solve_side_blocked(y, srows, scols, svals, slens, lam, alpha, *, block,
     ``spd_kernel=None`` picks the Pallas Gauss-Jordan solve on a TPU and
     XLA's cholesky elsewhere. ``fused_gramian=None`` picks the gather-Gramian
     formulation THIS side runs fastest (:func:`_choose_formulation`): off a
-    TPU the einsum; on one whichever the chip sweep measured faster for an
-    opposite table ``y`` of this width and size. Jit decisions are
+    TPU the einsum; on one whichever the chip sweep measured fastest for an
+    opposite table ``y`` of this width and size — the einsum over ``y``
+    zero-padded to 64 columns where narrower rows come out of a large table
+    (the padded copy lives inside the call: ``y`` and the answer keep
+    ``features`` columns). Jit decisions are
     static, so both are resolved here at call time, from the devices that
     hold ``y`` (``pallas_kernels.on_tpu``) and from the operands' shapes,
     never from the process default. The platform decision also sets the
@@ -837,26 +939,28 @@ def solve_side_blocked(y, srows, scols, svals, slens, lam, alpha, *, block,
     on_tpu = pk.on_tpu(y)
     if spd_kernel is None:
         spd_kernel = on_tpu
-    fused_gramian = _resolve_fused(fused_gramian, on_tpu, features,
-                                   srows.shape[1], y.shape[0])
+    fused, gather_width = _resolve_fused(fused_gramian, on_tpu, features,
+                                         srows.shape[1], y.shape[0])
     return _solve_side_blocked_jit(
         y, srows, scols, svals, slens, lam, alpha, block=block,
         features=features, implicit=implicit, slot_chunk=slot_chunk,
-        dtype=dtype, spd_kernel=bool(spd_kernel),
-        fused_gramian=bool(fused_gramian), kernel_interpret=not on_tpu,
+        dtype=dtype, spd_kernel=bool(spd_kernel), fused_gramian=fused,
+        kernel_interpret=not on_tpu, gather_width=gather_width,
     )
 
 
 @functools.lru_cache(maxsize=64)
 def _sharded_solver(mesh, row_axis, block, features, implicit, slot_chunk,
                     dtype="float32", spd_kernel=False, fused_gramian=False,
-                    kernel_interpret=None):
+                    kernel_interpret=None, gather_width=None):
     """jit(shard_map) for one half-iteration: blocks shard over ``row_axis``,
     opposite factors replicated, output factors row-partitioned (pinned by
     out_specs). Cached per (mesh, statics). ``kernel_interpret=None``
     resolves from the MESH's target devices — a caller that forgets the
     flag must never silently emulate the Pallas kernels on chip (the
-    kernel-interpret-default class; every production caller passes it)."""
+    kernel-interpret-default class; every production caller passes it).
+    ``gather_width`` is the gate's (``None``: ``features``): every shard pads
+    its own copy of the all-gathered table."""
     if kernel_interpret is None:
         kernel_interpret = not pk.on_tpu(mesh=mesh)
     from jax import shard_map
@@ -866,7 +970,7 @@ def _sharded_solver(mesh, row_axis, block, features, implicit, slot_chunk,
 
     def local(y, srows, scols, svals, slens, lam, alpha):
         yty = (y.T @ y) if implicit else None
-        ys = y.astype(cd) if cd != y.dtype else y
+        ys = _gather_table(y, cd, features, gather_width)
 
         def one(args):
             r, c, v, ln = args
@@ -984,7 +1088,7 @@ def prepare_blocked(
     on_tpu = pk.on_tpu(sides[0].scols)
     for name, side, opposite in (("user", sides[0], sides[1]),
                                  ("item", sides[1], sides[0])):
-        _log_gather_rows(name, side, *_choose_formulation(
+        _log_gather_rows(name, side, features, _choose_formulation(
             None, on_tpu, features, side.srows.shape[1],
             opposite.padded_rows))
     return sides
@@ -1006,12 +1110,13 @@ def init_item_factors(item_side: _BlockedSide, n_items: int, features: int,
     return _init_factors(item_side.padded_rows, n_items, features, key)
 
 
-def _log_gather_rows(name: str, side: _BlockedSide, fused: bool,
-                     reason: str) -> None:
+def _log_gather_rows(name: str, side: _BlockedSide, features: int,
+                     chosen: _Formulation) -> None:
     """One line a side: what the pack holds, the formulation the gate chose
-    for it and why, and the factor rows that formulation's gather issues."""
-    before, now = side.gather_rows_per_entry(fused)
-    if fused:
+    for it and why, the width its rows are gathered at, and the factor rows
+    that formulation's gather issues."""
+    before, now = side.gather_rows_per_entry(chosen.fused)
+    if chosen.fused:
         issues = ("fused kernel: each slot to its own length; %.3f%% of the "
                   "slots are fetched under the slot before, the rest open a "
                   "block's call"
@@ -1021,15 +1126,17 @@ def _log_gather_rows(name: str, side: _BlockedSide, fused: bool,
     logging.getLogger(__name__).info(
         "slotted COO %s side: %d entries in %d slots of T=%d (+%d of block "
         "padding); formulation: %s (%s); the gather moves %.3f factor rows "
-        "an entry (%s), %.3f if every slot were copied to its width",
+        "of %d columns an entry (%s), %.3f if every slot were copied to its "
+        "width",
         name, side.entries, side.real_slots, side.slot_width,
         int(side.srows.size) - side.real_slots,
-        _FORMULATION_NAMES[fused], reason, now, issues, before,
+        chosen.name(features), chosen.why, now, chosen.gather_width, issues,
+        before,
     )
 
 
 def _register_half_cost(name: str, side: _BlockedSide, features: int,
-                        dtype: str, fused: bool, reason: str) -> None:
+                        dtype: str, chosen: _Formulation) -> None:
     """Analytic per-half-iteration device cost for the trainer's cost
     accounting (common/profiling.py), under ``als.train.<name>_half``: the
     same useful-FLOP model the batch bench's MFU derives from (2·nnz·k²
@@ -1037,27 +1144,30 @@ def _register_half_cost(name: str, side: _BlockedSide, features: int,
     dominant HBM terms — the
     factor rows the gather ISSUES (``side.gather_rows``: one an entry under
     the fused kernel, whose copies are 32-bit whatever the compute dtype;
-    every slot cell at the compute dtype under the einsum formulation) plus
-    the per-row Gramian and factor writes. The blocked solver is a scan of
+    every slot cell at the compute dtype and the gate's gather width under
+    the einsum formulation) plus the per-row Gramian and factor writes. The
+    blocked solver is a scan of
     sub-programs rather than one compiled executable, so the trainer
     registers analytically where serving registers from
     ``cost_analysis()``; either way the label is one program signature
     multiplied by recorded calls. Which formulation the side resolved to is
     a fact of the run: ``oryx_als_half_formulation{side, formulation}``
-    reads 1 for it and 0 for the other."""
+    reads 1 for it and 0 for the others."""
+    fused = chosen.fused
     k = features
     nnz = side.entries
     rows = side.padded_rows
     flops = (2.0 * nnz * k * k + 2.0 * nnz * k
              + rows * (k ** 3 / 3.0 + 2.0 * k * k))
     gather_itemsize = 2.0 if dtype == "bfloat16" and not fused else 4.0
-    bytes_ = (float(side.gather_rows(fused)) * k * gather_itemsize
-              + rows * k * (k + 1) * 4.0)
+    bytes_ = (float(side.gather_rows(fused)) * chosen.gather_width
+              * gather_itemsize + rows * k * (k + 1) * 4.0)
     key = f"als.train.{name}_half"
     profiling.costs().register(key, flops, bytes_)
-    for which, label in _FORMULATION_NAMES.items():
-        _HALF_FORMULATION.labels(name, label).set(float(which == fused))
-    _log_gather_rows(key, side, fused, reason)
+    ran = chosen.name(k)
+    for label in _FORMULATION_NAMES:
+        _HALF_FORMULATION.labels(name, label).set(float(label == ran))
+    _log_gather_rows(key, side, k, chosen)
 
 
 def _recorded_half(key: str, fn):
@@ -1112,10 +1222,11 @@ def als_train(
     (``ops/pallas_kernels.gather_gramian_accumulate``) or the
     einsum+segment-sum formulation, whichever the chip sweep measured
     faster for the opposite factor table that side gathers from (at 50
-    features: the einsum under 80 MiB of factor rows, the kernel from
-    there), and the einsum everywhere off a TPU; ``True`` / ``False`` force
-    one on both sides (``True`` is interpret-emulated off-TPU — how the CPU
-    suite tests the exact path).
+    features: the einsum, gathering from that table zero-padded to 64
+    columns once it holds 40 MiB of factor rows), and the einsum
+    everywhere off a TPU; ``True`` / ``False`` force one on both sides
+    (``True`` is interpret-emulated off-TPU — how the CPU suite tests the
+    exact path).
 
     **Preemption tolerance**: ``checkpointer`` (a
     ``common/checkpoint.TrainerCheckpointer``) restores the newest valid
@@ -1186,7 +1297,7 @@ def als_train(
         side = item_fut.result()
         wait_s = time.perf_counter() - t1
         pool.shutdown(wait=False)
-        fused["item"] = resolve("item", side, user_side.padded_rows)
+        chosen["item"] = resolve("item", side, user_side.padded_rows)
         if layout_cache is not None:
             layout_cache.store_batch(batch.rows, batch.cols, batch.vals)
         if timings is not None:
@@ -1280,13 +1391,14 @@ def als_train(
         sharded_mode = mesh is not None and row_axis is not None
         on_tpu = pk.on_tpu(mesh=mesh) if sharded_mode else pk.on_tpu(y)
 
-        def resolve(name: str, side: _BlockedSide, table_rows: int) -> bool:
+        def resolve(name: str, side: _BlockedSide,
+                    table_rows: int) -> _Formulation:
             choice = _choose_formulation(fused_gramian, on_tpu, k,
                                          side.srows.shape[1], table_rows)
-            _register_half_cost(name, side, k, dtype, *choice)
-            return choice[0]
+            _register_half_cost(name, side, k, dtype, choice)
+            return choice
 
-        fused = {"user": resolve("user", user_side, y.shape[0])}
+        chosen = {"user": resolve("user", user_side, y.shape[0])}
 
         if sharded_mode:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1322,7 +1434,8 @@ def als_train(
             def sharded(name, side, blk):
                 return _sharded_solver(
                     mesh, row_axis, blk, k, implicit, side.slot_chunk, dtype,
-                    on_tpu, fused[name], not on_tpu)
+                    on_tpu, chosen[name].fused, not on_tpu,
+                    chosen[name].gather_width)
 
             solve_u = _recorded_half("als.train.user_half",
                                      sharded("user", user_side, block_u))
@@ -1345,10 +1458,13 @@ def als_train(
         def solve(side, opp, blk, ck):
             name = "user" if side is user_side else "item"
             profiling.costs().record(f"als.train.{name}_half")
-            return solve_side_blocked(
+            # solve_side_blocked's call, with the answer resolved above
+            return _solve_side_blocked_jit(
                 opp, side.srows, side.scols, side.svals, side.slens, lam,
                 alpha, block=blk, features=k, implicit=implicit,
-                slot_chunk=ck, dtype=dtype, fused_gramian=fused[name],
+                slot_chunk=ck, dtype=dtype, spd_kernel=on_tpu,
+                fused_gramian=chosen[name].fused, kernel_interpret=not on_tpu,
+                gather_width=chosen[name].gather_width,
             )
 
         if start_iter >= iterations:

@@ -85,11 +85,14 @@ def test_tiny_mode_passes_every_check_and_names_the_cpu():
     assert loop["coalescer"]["requests"] > loop["coalescer"]["flushes"] > 0
     assert loop["warmup"]["done"] == loop["warmup"]["total"] > 0
     # the programs a TPU trains with: the SPD kernel on both sides, and at
-    # this size (two tiny opposite tables) the gate's einsum on both
+    # this size (two tiny opposite tables) the gate's einsum on both, rows
+    # gathered as wide as they are; the padded gather ran beside them
     for side in ("user", "item"):
         chosen = loop["formulation"][side]
         assert chosen["runs"] == "einsum" and ", under the " in chosen["why"]
+        assert chosen["gather_width"] == summary["input"]["features"]
         assert chosen["tpu_custom_calls"] == 1
+    assert loop["padded_half_iteration_error"] < 1e-5
     assert set(summary["kernels"]) == {
         "gather_gramian/float32", "gather_gramian/bfloat16", "spd_solve",
         "kmeans",
@@ -172,12 +175,15 @@ def test_every_kernel_and_the_tpu_half_iteration_lower_for_tpu(
 
     # what als_train picks on a TPU, resolved by the trainer's own gate at
     # this width and slot count: the SPD kernel always; against the Netflix
-    # user table (480,201 rows) the gather-Gramian kernel at every one of
-    # these widths; against a 2,000-row table the einsum at 50 features (a
-    # small table of narrow rows) and the kernel at 250 and 256
+    # user table (480,201 rows) the einsum over rows padded to 64 columns at
+    # 50 features and the gather-Gramian kernel at 250 and 256; against a
+    # 2,000-row table the einsum at 50 features (a small table of narrow
+    # rows, gathered as they are) and the kernel at 250 and 256
     n_blocks, block, s, t = 2, 512, 1024, 32
-    for table_rows, fused in ((480201, True), (2000, k >= 250)):
-        assert tr._resolve_fused(None, True, k, s, table_rows) is fused
+    for table_rows, fused, width in ((480201, k >= 250, max(k, 64)),
+                                     (2000, k >= 250, k)):
+        assert tr._resolve_fused(None, True, k, s, table_rows) \
+            == (fused, width)
         text = tr._solve_side_blocked_jit.trace(
             jnp.zeros((2000, k), f32), jnp.zeros((n_blocks, s), jnp.int32),
             jnp.zeros((n_blocks, s, t), jnp.int32),
@@ -185,8 +191,12 @@ def test_every_kernel_and_the_tpu_half_iteration_lower_for_tpu(
             jnp.zeros((n_blocks, s), jnp.int32), 0.01, 1.0, block=block,
             features=k, implicit=True, slot_chunk=s, dtype="float32",
             spd_kernel=True, fused_gramian=fused, kernel_interpret=False,
+            gather_width=width,
         ).lower(lowering_platforms=("tpu",)).as_text()
         assert text.count("tpu_custom_call") == 1 + fused
+        # the einsum's gather reads rows of the gate's width
+        assert (f"slice_sizes = array<i64: 1, {width}>" in text) \
+            is (not fused), (k, table_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -194,7 +204,7 @@ def test_every_kernel_and_the_tpu_half_iteration_lower_for_tpu(
 # ---------------------------------------------------------------------------
 
 _COMPILE_ONLY = """
-import functools, os, sys
+import functools, os, re, sys
 os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
 os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
 import jax, jax.numpy as jnp
@@ -212,11 +222,11 @@ def spec(shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 def half(k, n_blocks, block, s, t, dtype, table_rows=20000, fused=True,
-         chunk=None):
+         chunk=None, width=None):
     fn = functools.partial(
         tr._solve_side_blocked_jit.__wrapped__, block=block, features=k,
         implicit=True, slot_chunk=chunk or s, dtype=dtype, spd_kernel=True,
-        fused_gramian=fused, kernel_interpret=False)
+        fused_gramian=fused, kernel_interpret=False, gather_width=width)
     return jax.jit(lambda y, a, b, c, d: fn(y, a, b, c, d, 0.01, 1.0)).lower(
         spec((table_rows, k)), spec((n_blocks, s), jnp.int32),
         spec((n_blocks, s, t), jnp.int32), spec((n_blocks, s, t)),
@@ -233,19 +243,38 @@ half(256, 2, 1000, 2048, 512, "float32")
 # the Netflix cell's two sides as the pack shapes them, each in the
 # formulation the trainer's gate picks for it from the opposite table it
 # gathers: the item side's 72,594 slots a block of T=512 against the user
-# table — the kernel (owner rows AND slot lengths whole in SMEM); the user
-# side's T=256 against the item table — the einsum, in ten chunks of 1,180
-# slots, and no gather-Gramian call in the program. Then the user side under
-# the kernel all the same (a forced formulation still has to compile), 250
-# features (a 1 KB row a copy) at both widths, and the slot gate itself at
-# the widest slot
-for s, table_rows, runs_kernel in ((72594, 59 * 8139, True),
-                                   (11800, 3 * 5924, False)):
-    assert tr._resolve_fused(None, True, 50, s, table_rows) is runs_kernel
-kernels = lambda compiled: compiled.as_text().count("tpu_custom_call")
-assert kernels(half(50, 3, 5924, 72594, 512, "float32", 59 * 8139, True)) == 2
-assert kernels(half(50, 2, 8139, 11800, 256, "float32", 3 * 5924, False,
-                    chunk=1180)) == 1  # the SPD solve's
+# table — the einsum in 111 chunks of 654 slots over that table zero-padded
+# to 64 columns: ONE kernel call in the program (the SPD solve's), and in the
+# OPTIMISED program a pad to 64 columns and gathers of (655, 512, 64) out of
+# it — a compiler that folded pad, gather and slice back into a gather of
+# 50-column rows (the slow one: PERF.md section 6, PR 31) fails here; the
+# user side's T=256 against the item table — the einsum as it was, in ten
+# chunks of 1,180 slots, gathering rows of 50. Then each side under the
+# kernel all the same (a forced formulation still has to compile: owner rows
+# AND slot lengths whole in SMEM at 72,594 slots), 250 features (a 1 KB row
+# a copy) at both widths, and the slot gate itself at the widest slot
+for s, table_rows, answer in ((72594, 59 * 8139, (False, 64)),
+                              (11800, 3 * 5924, (False, 50))):
+    assert tr._resolve_fused(None, True, 50, s, table_rows) == answer
+kernels = lambda text: text.count("tpu_custom_call")
+gathered = lambda text: set(re.findall(
+    r"= f32\\[(\\d+),\\d+,(\\d+)\\]\\S* gather\\(", text))
+item = half(50, 3, 5924, 72594, 512, "float32", 59 * 8139, False, chunk=654,
+            width=64).as_text()
+# 655 slots a gather, not the chunk's 654: 654 x 512 rows is a multiple of
+# 1,024, which XLA:TPU stages through half the buffer (train._GATHER_HALVED_
+# ROWS); the staging buffer of every gather fusion is the whole one
+assert kernels(item) == 1 and gathered(item) == {("655", "64")}, gathered(item)
+assert re.search(r"= f32\\[480201,64\\]\\S* pad\\(", item)
+staged = lambda text: set(re.findall(
+    r"kind=kCustom.*op_name=\\"[^\\"]*/gather\\".*\\"size\\":\\"(\\d+)\\"", text))
+assert staged(item) == {"524288"}, staged(item)
+user = half(50, 2, 8139, 11800, 256, "float32", 3 * 5924, False,
+            chunk=1180).as_text()
+assert kernels(user) == 1 and gathered(user) == {("1181", "50")}, gathered(user)
+assert staged(user) == {"262144"}, staged(user)
+assert kernels(half(50, 3, 5924, 72594, 512, "float32", 59 * 8139,
+                    True).as_text()) == 2
 half(50, 2, 8139, 11790, 256, "float32")
 half(250, 2, 1046, 12288, 512, "float32")
 half(250, 2, 1072, 2048, 256, "bfloat16")

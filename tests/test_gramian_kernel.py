@@ -321,13 +321,14 @@ def test_pack_logs_the_share_of_slots_fetched_under_a_predecessor(
     mostly prologue: a kernel side's log line carries prefetched ÷ real
     slots (an einsum side has no pipeline: tests/test_als_formulation.py).
     One block a side here, so all but one slot of each; the pack is asked as
-    on a TPU whose crossover every table is over."""
+    on a TPU where every width counts as one the kernel is ahead at."""
     import logging
 
     from oryx_tpu.ops import pallas_kernels as pk
 
     monkeypatch.setattr(pk, "on_tpu", lambda operand=None, mesh=None: True)
-    monkeypatch.setattr(tr, "_GG_NARROW_TABLE_BYTES", 0)
+    monkeypatch.setattr(tr, "_GG_NARROW_FEATURES", 0)
+    monkeypatch.setattr(tr, "_GG_WIDE_FEATURES", 0)
     batch, k = _skewed_batch(9)
     with caplog.at_level(logging.INFO, logger="oryx_tpu.models.als.train"):
         sides = tr.prepare_blocked(batch, k, block=512)
