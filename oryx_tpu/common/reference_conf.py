@@ -199,14 +199,18 @@ oryx = {
     #   "float32"  - force the f32 scan everywhere
     #   "bfloat16" - force the bf16 scoring copy (half the f32 HBM)
     #   "int8"     - per-row-scaled int8 factors ONLY on device (1/4 the f32
-    #                HBM: a 21M x 50f item side is ~1.1 GB instead of 4.2);
+    #                HBM: 20M x 250f is 5.16 GB on one v5e chip, measured,
+    #                where float32 is 20 GB and fits none);
     #                the scan returns rescore-factor x howMany candidates
     #                whose final ranking is an exact f32 rescore from the
     #                host factor arena (docs/admin.md "Choosing device-dtype")
     device-dtype = "auto"
     # int8 path: candidates scanned per request = rescore-factor x howMany
     # (pow2-rounded, floor 16). Higher = better recall under heavy
-    # quantization error, more rescore work; 4 holds recall@10 >= 0.99.
+    # quantization error, more rescore work. At 4 (64 candidates for
+    # howMany 10) the benchmark's 20M x 250f cell missed 0-0.12% of the
+    # true top 10 a run (PERF.md section 2); tests/test_factor_arena.py
+    # holds recall@10 >= 0.99 on planted-structure data.
     rescore-factor = 4
     # Device-resident IVF candidate generation (models/als/ivf.py): cluster
     # the item factors (in-tree k-means, deterministic seed), keep int8

@@ -44,9 +44,9 @@ from oryx_tpu.models.als.rescorer import load_rescorer_providers
 from oryx_tpu.models.als.known import KnownItems
 from oryx_tpu.models.als.topn import (_OVERFETCH_ROOM, _ArenaSnapshot,
                                       _Snapshot, _collect, _drop_rows,
-                                      _id_lists, _operands, _quantize_chunked,
+                                      _id_lists, _operands, _quantize_blocks,
                                       _quantize_rows, _room_for,
-                                      _round_up_pow2)
+                                      _round_up_pow2, _upload_blocks)
 from oryx_tpu.models.als.vectors import FeatureVectorStore
 from oryx_tpu.parallel.mesh import (put_row_sharded, replicated_sharding,
                                     row_sharding)
@@ -665,7 +665,10 @@ class _QuantSnapshot(_ArenaSnapshot):
     """Immutable int8 device view of Y (``oryx.serving.device-dtype = int8``):
     per-row-scaled int8 factors + exact f32 norms + optional LSH buckets.
     No f32 (or bf16) copy of Y ever lands in HBM — the whole point of the
-    mode is fitting a 21M × 50f item side per chip with headroom.
+    mode is holding on ONE chip a Y that float32 cannot: measured on a v5e
+    (PERF.md section 6, PR 33), 20M × 250f is 5.16 GB resident and 5.56 GB
+    at the peak of the whole warm ladder, a scan 8.0–8.3 ms for up to 64
+    queries; a mesh is not needed for that row.
 
     A speed microbatch of point updates requantizes only the
     changed/appended rows and lands them as row-index scatters, mirroring
@@ -701,23 +704,40 @@ class _QuantSnapshot(_ArenaSnapshot):
         return [a for a in (self.qmat, self.qscale, self.norms, self.buckets)
                 if a is not None]
 
+    host_copy = False  # ``build`` reads the pinned view a block at a time
+
     @classmethod
-    def build(cls, ids, host: np.ndarray, version: int,
+    def build(cls, ids, host: None, version: int,
               lsh: "LocalitySensitiveHash | None",
               row_view: tuple,
               prev: "_QuantSnapshot | None" = None,
               rescore_factor: float = 4.0):
-        """Full quantized build from one host matrix."""
+        """Full quantized build out of the pinned ``(slab, rows)`` view, a
+        block of rows at a time (``topn._QUANT_BLOCK``): quantized on the
+        host's threads, uploaded, written into its place on the device. No
+        float32 copy of Y is made on either side (``host`` is None:
+        ``host_copy``), and the host never holds all of the int8 rows."""
         slab, slab_rows = row_view
-        if len(ids) == 0 or host.size == 0:
+        if len(ids) == 0 or slab is None or not len(slab_rows):
             return cls(list(ids), version, None, None, None, None,
                        rescore_factor=rescore_factor)
-        q, scale, norms = _quantize_chunked(host)
-        buckets = None
-        if lsh and lsh.num_hashes:
-            buckets = jnp.asarray(lsh.assign_buckets(host))
-        return cls(list(ids), version, jnp.asarray(q), jnp.asarray(scale),
-                   jnp.asarray(norms), buckets, lsh, prev=prev,
+        hashed = bool(lsh and lsh.num_hashes)
+
+        def blocks():
+            for start, q, scale, norms in _quantize_blocks(slab, slab_rows):
+                block = (start, q, scale, norms)
+                if hashed:
+                    block += (lsh.assign_buckets(
+                        slab[slab_rows[start:start + len(q)]]),)
+                yield block
+
+        n, k = len(slab_rows), slab.shape[1]
+        with spans.span("snapshot.quantize",
+                        attributes={"rows": n, "bytes": n * (k + 4)}):
+            qmat, qscale, norms, *buckets = _upload_blocks(blocks(), n)
+            qmat.block_until_ready()  # the span times the build, not the enqueue
+        return cls(list(ids), version, qmat, qscale, norms,
+                   buckets[0] if hashed else None, lsh, prev=prev,
                    slab=slab, slab_rows=slab_rows,
                    rescore_factor=rescore_factor)
 
@@ -823,10 +843,14 @@ class ALSServingModel(ServingModel):
             )
         if device_dtype == "int8" and mesh is not None:
             # the sharded scan's shard_map programs are f32/bf16; quantized
-            # sharding is a later round — degrade loudly, never silently
+            # sharding is a later round — degrade loudly, never silently.
+            # Measured (PERF.md section 6, PR 33): int8 holds the 20M x 250f
+            # row on ONE v5e chip, so a mesh is not needed for it
             log.warning(
                 "device-dtype=int8 is not supported with sharded serving; "
-                "using bfloat16 for the sharded scoring copy"
+                "using bfloat16 for the sharded scoring copy (int8 holds "
+                "20M x 250f on one chip: 5.2 GB of rows and scales; turn "
+                "oryx.serving.compute.sharded off to serve from it)"
             )
             device_dtype = "bfloat16"
         if index_enabled and device_dtype != "int8":
@@ -886,14 +910,17 @@ class ALSServingModel(ServingModel):
         self.expected_item_ids.discard(item)
         self.yty_cache.set_dirty()
 
-    def bulk_load_users(self, ids, matrix) -> None:
-        """Whole-matrix X handoff keeping model bookkeeping consistent."""
-        self.x.bulk_load(ids, matrix)
+    def bulk_load_users(self, ids, matrix, adopt: bool = False) -> None:
+        """Whole-matrix X handoff keeping model bookkeeping consistent
+        (``adopt``: as ``bulk_load_items``)."""
+        self.x.bulk_load(ids, matrix, adopt=adopt)
         self.expected_user_ids.difference_update(ids)
 
-    def bulk_load_items(self, ids, matrix) -> None:
-        """Whole-matrix Y handoff keeping model bookkeeping consistent."""
-        self.y.bulk_load(ids, matrix)
+    def bulk_load_items(self, ids, matrix, adopt: bool = False) -> None:
+        """Whole-matrix Y handoff keeping model bookkeeping consistent.
+        ``adopt``: the caller gives ``matrix`` up and the arena takes it as
+        its slab where it can (``FeatureVectorStore.bulk_load``)."""
+        self.y.bulk_load(ids, matrix, adopt=adopt)
         self.expected_item_ids.difference_update(ids)
         self.yty_cache.set_dirty()
 
@@ -1099,7 +1126,9 @@ class ALSServingModel(ServingModel):
         if snap.rescore is not None:
             # an approximate backend's candidates, exact-f32-rescored from
             # the arena slab before the final cut
-            with spans.stage("topn.rescore"):
+            with spans.stage("topn.rescore") as sp:
+                sp.set_attribute("candidates", int(idx.size))
+                sp.set_attribute("width", int(idx.shape[1]))
                 vals, idx = snap.rescore(qs_host, vals, idx)
         with spans.stage("topn.ids"):
             dropped = None

@@ -12,12 +12,17 @@ Nothing here imports ``serving`` or ``ivf``: the arrows point one way.
 
 from __future__ import annotations
 
+import concurrent.futures
+import functools
 import math
+import os
+import queue
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from oryx_tpu.common import metrics as metrics_mod
 from oryx_tpu.common import profiling
 
 
@@ -138,6 +143,97 @@ def _quantize_chunked(host: np.ndarray):
         q[a:b], scale[a:b] = _quantize_rows(host[a:b])
         norms[a:b] = np.linalg.norm(host[a:b], axis=1)
     return q, scale, norms
+
+
+#: Rows a step of a full int8 build quantizes and uploads, in pieces of
+#: ``_QUANT_PIECE`` rows side by side on a few threads (numpy drops the GIL
+#: inside each pass). The host's transient is one block of int8 rows and two
+#: float32 pieces a thread — never a second copy of Y, which at 20M × 250f
+#: (20 GB) no one-chip host holds beside the arena.
+_QUANT_BLOCK = 1 << 20
+_QUANT_PIECE = 1 << 14
+_QUANT_WORKERS = min(8, os.cpu_count() or 1)
+
+
+def _quantize_piece(slab, rows, part, tmp, q, scale, norms) -> None:
+    """``slab[rows]`` into ``q``, ``scale`` and ``norms``: the passes of
+    :func:`_quantize_rows` and ``np.linalg.norm``, each into the caller's
+    float32 scratch (``part``, ``tmp``). A pass that allocated its result
+    would free 16 MB seven times a piece — 150 GB of it at 20M × 250f,
+    which a sandboxed host gives back more slowly than the threads ask."""
+    part, tmp = part[:len(rows)], tmp[:len(rows)]
+    # "clip": the rows are the store's own, and numpy would buffer ``out``
+    # whole under the default "raise"
+    np.take(slab, rows, axis=0, out=part, mode="clip")
+    np.abs(part, out=tmp)
+    amax = tmp.max(axis=1)
+    scale[:] = np.where(amax > 0, amax / 127.0, 1.0)
+    np.divide(part, scale[:, None], out=tmp)
+    np.rint(tmp, out=tmp)
+    np.clip(tmp, -127, 127, out=tmp)
+    q[:] = tmp
+    np.multiply(part, part, out=tmp)
+    np.sqrt(np.add.reduce(tmp, axis=1), out=norms)
+
+
+def _quantize_blocks(slab: np.ndarray, rows: np.ndarray):
+    """``(start, q, scale, norms)`` of each ``_QUANT_BLOCK`` rows of the
+    pinned view ``slab[rows]``, in order: the values are those of
+    :func:`_quantize_chunked` over the gathered copy, bit for bit."""
+    n, k = len(rows), slab.shape[1]
+    scratch: queue.SimpleQueue = queue.SimpleQueue()
+    for _ in range(_QUANT_WORKERS):
+        scratch.put(np.empty((2, min(n, _QUANT_PIECE), k), dtype=np.float32))
+    with concurrent.futures.ThreadPoolExecutor(_QUANT_WORKERS) as pool:
+        for start in range(0, n, _QUANT_BLOCK):
+            size = min(n, start + _QUANT_BLOCK) - start
+            q = np.empty((size, k), dtype=np.int8)
+            scale = np.empty(size, dtype=np.float32)
+            norms = np.empty(size, dtype=np.float32)
+
+            def piece(a: int):
+                b = min(size, a + _QUANT_PIECE)
+                mine = scratch.get()
+                try:
+                    _quantize_piece(slab, rows[start + a:start + b], *mine,
+                                    q[a:b], scale[a:b], norms[a:b])
+                finally:
+                    scratch.put(mine)
+
+            list(pool.map(piece, range(0, size, _QUANT_PIECE)))
+            yield start, q, scale, norms
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _set_rows(whole, block, start):
+    return jax.lax.dynamic_update_slice_in_dim(whole, block, start, axis=0)
+
+
+def _upload_blocks(blocks, n: int) -> list:
+    """The device arrays of ``n`` rows that the host ``blocks`` (``(start,
+    part, ...)``, in order) make up, each part written into its place as it
+    comes: the device never holds a block twice, the host never all of
+    them. One block that holds every row is the array itself."""
+    whole = None
+    for start, *parts in blocks:
+        parts = [jnp.asarray(p) for p in parts]
+        if len(parts[0]) == n:
+            return parts
+        if whole is None:
+            whole = [jnp.zeros((n,) + p.shape[1:], p.dtype) for p in parts]
+        whole = [_set_rows(w, p, start) for w, p in zip(whole, parts)]
+        # a block is on the device before the next is made: uploads left to
+        # queue behind a faster quantizer hold every block they wait with
+        # (at 20M x 250f, 5 GB of rows and as much again in staging)
+        jax.block_until_ready(whole)
+    return whole
+
+
+_RESCORED_ROWS = metrics_mod.default_registry().counter(
+    "oryx_serving_rescored_rows_total",
+    "Candidate rows gathered from the host factor arena for the exact "
+    "float32 rescore of an int8 or IVF scan's flushes",
+)
 
 
 class _Fed:
@@ -266,6 +362,11 @@ class _ArenaSnapshot(_Snapshot):
     next delta. A subclass supplies ``build`` (full) and ``from_delta``
     (incremental, or None when only a rebuild will do)."""
 
+    #: ``build`` reads the row-aligned float32 copy of the store; a subclass
+    #: that reads the rows out of the pinned ``(slab, rows)`` pair instead
+    #: says False and is handed None in the copy's place.
+    host_copy = True
+
     def __init__(self, ids, version: int, scanned, lsh, slab, slab_rows,
                  rescore_factor: float, prev=None, incremental: bool = False):
         super().__init__(ids, scanned, lsh, prev, incremental)
@@ -297,7 +398,7 @@ class _ArenaSnapshot(_Snapshot):
                 nxt = cls.from_delta(prev, delta)
                 if nxt is not None:
                     return nxt
-        ids, host, version, row_view = store.host_matrix()
+        ids, host, version, row_view = store.host_matrix(cls.host_copy)
         return cls.build(ids, host, version, lsh, row_view, prev=prev,
                          **build_options)
 
@@ -332,6 +433,7 @@ class _ArenaSnapshot(_Snapshot):
         stay -inf. For ``cosine`` the batch dimension is the query-vector
         set of ONE request (mean cosine)."""
         B, R = idx.shape
+        _RESCORED_ROWS.inc(B * R)
         rows = self.gather_rows(idx.reshape(-1)).reshape(B, R, -1)
         if cosine:
             # one request, many query vectors: qs_host (Q, k); rows (1, R, k)

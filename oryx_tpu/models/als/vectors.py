@@ -545,11 +545,18 @@ class FeatureVectorStore:
             self._version += 1
             self._log.append((self._version, row, was_new))
 
-    def bulk_load(self, ids, matrix: np.ndarray) -> None:
+    def bulk_load(self, ids, matrix: np.ndarray, adopt: bool = False) -> None:
         """Set many vectors in one write-lock pass — the fast path for whole-
         model handoffs (MODEL-REF factor files, synthetic bench models). The
         matrix is COPIED into the arena: later point updates rewrite slab
-        rows in place and must never mutate the caller's array."""
+        rows in place and must never mutate the caller's array.
+
+        ``adopt`` hands the array itself over, where the store is empty and
+        the array can be the slab as it is (C-contiguous float32 it may
+        write to, no larger capacity asked for): the caller gives it up. At
+        20M × 250f a copy is 20 GB beside the caller's 20 GB, more than a
+        one-chip host's memory holds (docs/performance.md "int8 on one
+        chip")."""
         matrix = np.asarray(matrix, dtype=np.float32)
         ids = list(ids)
         with self._lock.write():
@@ -564,8 +571,12 @@ class FeatureVectorStore:
                 # empty store: one slab-sized copy, rows in handoff order
                 k = matrix.shape[1]
                 cap = max(self._initial_rows, self._reserve_rows, len(ids), 1)
-                self._slab = np.zeros((cap, k), dtype=np.float32)
-                _copy_rows(self._slab[: len(ids)], matrix)
+                if (adopt and cap == len(ids) and matrix.flags.c_contiguous
+                        and matrix.flags.writeable):
+                    self._slab = matrix
+                else:
+                    self._slab = np.zeros((cap, k), dtype=np.float32)
+                    _copy_rows(self._slab[: len(ids)], matrix)
                 self._ids = _IdIndex(cap)
                 self._ids.add_many(ids, 0)
                 self._rowmap = np.arange(len(ids), dtype=np.int32)
@@ -663,10 +674,13 @@ class FeatureVectorStore:
         return self._n_pos / slab.shape[0]
 
     # -- host snapshot API (no device work; the int8 serving path) ----------
-    def host_matrix(self) -> "tuple[list, np.ndarray, int, tuple]":
+    def host_matrix(self, values: bool = True
+                    ) -> "tuple[list, np.ndarray | None, int, tuple]":
         """(ids, row-aligned float32 copy, version, (slab, rows)): the full
         host snapshot. The copy is one fancy-index gather of the live rows —
-        consumers own it. The trailing (slab, rows) pair pins THIS order
+        consumers own it (``values=False``: no copy, None in its place, for
+        a consumer that reads the rows out of the pinned pair a block at a
+        time). The trailing (slab, rows) pair pins THIS order
         epoch for later exact-rescore gathers (:class:`_QuantSnapshot`):
         row indices stay valid for the slab object they were captured with,
         no matter what the live store does afterwards.
@@ -680,10 +694,11 @@ class FeatureVectorStore:
             rows = self._live_rows().copy()
             index = self._ids
             version = self._version
-            host = slab[rows] if slab is not None and rows.size else None
+            empty = slab is None or not rows.size
+            host = None if empty or not values else slab[rows]
         dec = index.decode
         ids = [dec(int(r)) for r in rows]
-        if host is None:
+        if empty:
             return ids, np.zeros((0, 0), dtype=np.float32), version, (slab, rows)
         return ids, host, version, (slab, rows)
 

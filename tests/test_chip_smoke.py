@@ -301,3 +301,65 @@ def test_tpu_compiler_accepts_the_trainer_compile_only():
     if last.startswith("SKIP"):
         pytest.skip(f"no compile-only TPU topology here: {last}")
     assert proc.returncode == 0 and last == "COMPILED", proc.stderr[-3000:]
+
+
+_INT8_SCAN_COMPILE_ONLY = """
+import os, re, sys
+os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+except Exception as e:
+    print("SKIP", type(e).__name__, e)
+    sys.exit(0)
+from oryx_tpu.models.als import serving as S
+
+sharding = SingleDeviceSharding(topo.devices[0])
+def spec(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+# the reference's largest row as one chip holds it (ISSUE 33): 20M x 250
+# int8 rows and a float32 scale a row, 64 candidates a query
+n, k = 20_000_000, 250
+for batch in (256, 1):
+    compiled = S._quant_candidates.lower(
+        spec((n, k), jnp.int8), spec((n,), jnp.float32),
+        spec((batch, k), jnp.float32), None, k=64).compile()
+    memory = compiled.memory_analysis()
+    # the rows and their scales are the operands, nothing else of their size
+    assert 5.1e9 < memory.argument_size_in_bytes < 5.3e9, memory
+    # no (batch, n) float32 score matrix (20.5 GB at 256, which no chip
+    # holds) and no converted copy of the rows: at one query the scores are
+    # a vector of 80 MB, as in the bfloat16 scan
+    assert memory.temp_size_in_bytes <= n * 4 + (1 << 20), (batch, memory)
+    entry = compiled.as_text().split("ENTRY ", 1)[1]
+    wide = set(re.findall(r"= \\(?(?:f32|bf16|f16)\\[(?:\\d+,)?20000000(?:,\\d+)?\\]", entry))
+    assert not {w for w in wide if ",20000000]" in w and "[1," not in w}, wide
+    assert not {w for w in wide if "[20000000,250]" in w}, wide
+    if batch == 256:
+        assert memory.temp_size_in_bytes < (1 << 20), memory
+print("COMPILED")
+"""
+
+
+def test_tpu_compiler_fuses_the_int8_scan_at_the_largest_row_compile_only():
+    """The int8 scan at 20M x 250 for a described v5e: the top-k is fused
+    behind the matmul at the widest warmed batch (the per-row scale between
+    the two does not break it) and the convert rides the matmul's operand,
+    so the program's only large buffers are its operands (PERF.md section
+    6, PR 33). A compiler, or a change to the scan, that materialises the
+    scores or a float copy of the rows fails here, before any chip time."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("JAX_ENABLE_X64", None)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    proc = subprocess.run([sys.executable, "-c", _INT8_SCAN_COMPILE_ONLY],
+                          capture_output=True, text=True, timeout=240,
+                          env=env, cwd=REPO)
+    last = (proc.stdout.strip().splitlines() or [""])[-1]
+    if last.startswith("SKIP"):
+        pytest.skip(f"no compile-only TPU topology here: {last}")
+    assert proc.returncode == 0 and last == "COMPILED", proc.stderr[-3000:]
