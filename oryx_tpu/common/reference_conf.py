@@ -255,9 +255,18 @@ oryx = {
       # thread-fanned partition scans. 0 disables.
       coalesce-window-ms = 1.0
       coalesce-max-batch = 256
-      # Device calls allowed in flight at once. While one is out, arrivals
-      # queue and flush on its completion (batch-while-busy), so batch size
-      # tracks arrival-rate x device-latency; 2 overlaps transfer/compute.
+      # Calls allowed between dispatch and completion at once. It is a cap,
+      # not what opens a flush: while a flush has the chip, arrivals queue
+      # and the next flush opens when the chip will be free by the time its
+      # host stage (handoff, assembly, upload, dispatch) is over — the call
+      # reports its device phase, the coalescer reads the host stage and
+      # the scan off its own flushes — so batch size tracks arrival-rate x
+      # device-latency and no flush sits a scan long in the device's queue.
+      # At 2 a flush's rescore, id lists and wakeups run under the next
+      # flush's scan. A model that reports no device phase, or whose scan is
+      # too short against its host stage for a flush to sit long behind
+      # another's, is scheduled by the slots alone: a completion flushes
+      # what queued behind it.
       coalesce-inflight = 2
       # Upper bound on a request's queue wait behind in-flight batches: a
       # request older than this flushes even if it must exceed

@@ -33,6 +33,7 @@ from oryx_tpu.api.serving import ServingModel
 from oryx_tpu.ml.mlupdate import read_pmml_from_update_key_message
 from oryx_tpu.api.serving import AbstractServingModelManager
 from oryx_tpu.common import compilecache
+from oryx_tpu.common import devicephase
 from oryx_tpu.common import lineage
 from oryx_tpu.common import metrics as metrics_mod
 from oryx_tpu.common import profiling
@@ -355,7 +356,8 @@ def _dispatch(snap, qs_host: np.ndarray, width, register: bool):
             if register:
                 profiling.costs().record(cost_key)
         snap.dispatched(len(qs_host), width)
-        return out
+    devicephase.enqueued()
+    return out
 
 
 def _download(out):
@@ -364,7 +366,11 @@ def _download(out):
         # the program's run and the copy back: the first conversion
         # blocks until the device is done
         vals, idx = out
-        return np.asarray(vals), np.asarray(idx)
+        arrays = np.asarray(vals), np.asarray(idx)
+    # told once the arrays are here: the device was done two copies ago, and
+    # whoever scheduled the call has to learn that lag and allow for it
+    devicephase.device_done()
+    return arrays
 
 
 def _scan(snap, qs_host: np.ndarray, width, register: bool):

@@ -422,3 +422,240 @@ def test_dispatch_failure_releases_inflight_and_fails_futures():
         assert res[0][0] == "i2"
 
     asyncio.run(main())
+
+
+# -- the gate (ISSUE 34): a model that REPORTS its device phase, under a
+# controlled clock (tests/coalescer_sim.py). Times in ms of the virtual
+# clock; the fake's host stage is 1, its scan 10, its post-scan work 0.5.
+
+_H, _S, _P = 0.001, 0.010, 0.0005
+
+
+def _sim(scan_s=_S, reports=True, lag_s=0.0, **coalescer):
+    from tests.coalescer_sim import FifoDevice, SimModel, VirtualLoop
+
+    loop = VirtualLoop()
+    device = FifoDevice(loop, lambda b: scan_s)
+    model = SimModel(loop, device, _H, _P, reports, lag_s)
+    coal = TopNCoalescer(window_ms=1.0, max_batch=64, **coalescer)
+    return loop, device, model, coal
+
+
+async def _ask(coal, model, n):
+    res = await coal.top_n(model, np.array([float(n), 0.0]), 1)
+    assert res == [(f"i{n}", 0.0)]
+
+
+async def _one_behind_another(coal, model):
+    """A request onto a free chip, a second one 3 ms later: the second finds
+    the first's flush in its device phase. Returns the instant the first
+    arrived."""
+    loop = asyncio.get_running_loop()
+    t0 = loop.time()
+    first = asyncio.create_task(_ask(coal, model, 1))
+    await asyncio.sleep(0.003)
+    await _ask(coal, model, 2)
+    await first
+    return t0
+
+
+async def _learn(coal, model):
+    """What a model's first flushes show: one alone on the chip (the host
+    stage), then one whose programs sit behind another's, as the slots make
+    them while a width's scan is unknown (the scan)."""
+    await _ask(coal, model, 8)
+    await _one_behind_another(coal, model)
+    await asyncio.sleep(0.05)
+
+
+def test_second_flush_opens_when_the_chip_will_be_free_for_it():
+    loop, device, model, coal = _sim()
+
+    async def main():
+        await _learn(coal, model)
+        assert coal._s[1] == pytest.approx(_S)
+        return await _one_behind_another(coal, model)
+
+    t0 = loop.run(main())
+    (first_t, _), (second_t, _) = model.calls[3:]
+    # the first: the window, then enqueued at +2 and done at +12
+    assert first_t == pytest.approx(t0 + 0.001)
+    # the second waited at the gate: not at +4 (its window over, a slot
+    # free: where the slots alone put it), but at predicted free - h
+    assert second_t == pytest.approx(t0 + 0.012 - _H)
+    # so its programs reached the device as it came free
+    assert device.waits[-1] == pytest.approx(0.0, abs=1e-9)
+    assert model.most_in_flight == 2
+
+
+def test_with_no_estimate_the_second_flush_opens_at_device_done():
+    loop, device, model, coal = _sim()
+    t0 = loop.run(_one_behind_another(coal, model))
+    (first_t, _), (second_t, _) = model.calls
+    assert first_t == pytest.approx(t0 + 0.001)
+    assert second_t == pytest.approx(t0 + 0.012)  # the report itself
+    assert device.waits == [0.0, 0.0]
+
+
+def test_a_model_that_reports_nothing_is_scheduled_by_the_slots():
+    loop, device, model, coal = _sim(reports=False)
+    t0 = loop.run(_one_behind_another(coal, model))
+    (_, _), (second_t, _) = model.calls
+    assert second_t == pytest.approx(t0 + 0.004)  # its window, a free slot
+    assert device.waits[-1] == pytest.approx(0.007)  # behind the first's scan
+
+
+def test_a_change_of_model_object_resets_the_estimates():
+    from tests.coalescer_sim import SimModel
+
+    loop, device, model, coal = _sim()
+    slower = SimModel(loop, device, _H, _P)
+    device.scan_s = lambda b: _S if not slower.calls else 2 * _S
+
+    async def main():
+        await _learn(coal, model)
+        return await _one_behind_another(coal, slower)
+
+    t0 = loop.run(main())
+    (_, _), (second_t, _) = slower.calls
+    # by the old model's scan (10) the gate would have opened at +11; the
+    # new object's first flushes have no estimate: its report, at +22
+    assert second_t == pytest.approx(t0 + 0.022)
+
+
+def test_the_gate_keeps_the_cap_and_the_deadline_takes_one_over_it():
+    """A wedged device: no report comes and no estimate opens the gate. The
+    deadline flushes past the gate inside the cap, then ONE call past the
+    cap, then nothing more."""
+    loop, device, model, coal = _sim(scan_s=10.0, max_inflight=2,
+                                     deadline_ms=50.0)
+
+    async def main():
+        tasks = []
+        for n in range(5):
+            tasks.append(asyncio.create_task(_ask(coal, model, n)))
+            await asyncio.sleep(0.1)
+            assert coal._inflight <= 3
+        await asyncio.sleep(1.0)
+        while_wedged = len(model.calls), coal.deadline_flushes
+        await asyncio.gather(*tasks)
+        return while_wedged
+
+    assert loop.run(main()) == (3, 1)
+    assert model.most_in_flight == 3  # the cap of 2, and the one over it
+    assert [round(t, 3) for t, _ in model.calls[:3]] == [0.001, 0.15, 0.25]
+
+
+def test_a_report_outside_a_flush_goes_nowhere():
+    from oryx_tpu.common import devicephase
+
+    devicephase.enqueued()  # a direct top_n, the warm ladder: no reporter
+    devicephase.device_done()
+    loop, device, model, coal = _sim()
+
+    async def main():
+        await _ask(coal, model, 1)
+        # the same model called directly, beside the coalescer
+        await asyncio.get_running_loop().run_in_executor(
+            None, model.top_n_batch, np.array([[3.0, 0.0]]), 1)
+
+    loop.run(main())
+    assert len(model.calls) == 2
+    assert len(coal._host) == 1 and not coal._scan  # nothing sat in a queue
+    assert coal._last is None and coal._inflight == 0
+
+
+def _wakeups(loop):
+    """The instants an executor thread woke ``loop`` for a report."""
+    woken = []
+    real = loop.call_soon_threadsafe
+
+    def spy(callback, *args):
+        if getattr(callback, "__func__", None) is TopNCoalescer._kick:
+            woken.append(loop.time())
+        return real(callback, *args)
+
+    loop.call_soon_threadsafe = spy
+    return woken
+
+
+def test_a_report_wakes_the_loop_only_for_requests_held_at_the_gate():
+    """A wakeup hands the interpreter to the loop in the middle of the
+    flush's own path (between its results being ready and their copy
+    back): a flush nobody waits behind stamps its reports and leaves the
+    loop alone; one with requests held behind it wakes it."""
+    loop, device, model, coal = _sim()
+    woken = _wakeups(loop)
+
+    async def alone():
+        for n in (8, 9):
+            await _ask(coal, model, n)
+
+    loop.run(alone())
+    assert woken == []
+    assert len(coal._host) == 2  # read all the same, at the calls' ends
+
+    loop, device, model, coal = _sim()
+    woken = _wakeups(loop)
+    t0 = loop.run(_one_behind_another(coal, model))
+    # held from +3 behind a flush enqueued at +2 with no estimate of its
+    # scan: the *device done* report at +12 is what opens the gate
+    assert woken == [pytest.approx(t0 + 0.012)]
+
+
+def test_a_request_held_under_the_host_stage_is_aimed_by_the_estimate():
+    """Held before the flush ahead has launched, the gate is aimed from the
+    host stage the last flushes took; the *enqueued* report is a stamp and
+    wakes nobody."""
+    loop, device, model, coal = _sim()
+    woken = _wakeups(loop)
+
+    async def main():
+        await _learn(coal, model)
+        del woken[:]
+        t0 = asyncio.get_running_loop().time()
+        first = asyncio.create_task(_ask(coal, model, 1))
+        await asyncio.sleep(0.0015)  # the first's flush opened at +1
+        await _ask(coal, model, 2)
+        await first
+        return t0
+
+    t0 = loop.run(main())
+    assert woken == []
+    (_, _), (second_t, _) = model.calls[3:]
+    assert second_t == pytest.approx(t0 + 0.012 - _H)
+    assert device.waits[-1] == pytest.approx(0.0, abs=1e-9)
+
+
+def test_a_scan_not_worth_a_wait_is_scheduled_by_the_slots():
+    """A scan of 4 ms behind a host stage of 1 and reports that trail the
+    device by 2.2: by the slots alone a flush's programs sit 0.8 ms in the
+    device's queue, under a third of the scan, so the gate stands open and
+    the reporting model is scheduled as one that reports nothing, call for
+    call — once its first flushes have shown the three times."""
+    def settled_calls(reports):
+        loop, device, model, coal = _sim(scan_s=0.004, reports=reports,
+                                         lag_s=0.0022)
+        woken = _wakeups(loop)
+
+        async def main():
+            await _learn(coal, model)  # the host stage, then the scan
+            for n in range(8):  # and alone on the chip: the lag
+                await _ask(coal, model, n)
+            del woken[:]
+            t0 = asyncio.get_running_loop().time()
+            for _ in range(3):
+                await asyncio.sleep(0.05)
+                await _one_behind_another(coal, model)
+            return t0
+
+        t0 = loop.run(main())
+        if reports:
+            assert coal._s[1] == pytest.approx(0.004)
+            assert coal._lag_s == pytest.approx(0.0022)
+        return [round(t - t0, 6) for t, _ in model.calls if t >= t0], woken
+
+    reporting, woken = settled_calls(True)
+    silent, _ = settled_calls(False)
+    assert reporting == silent and len(silent) == 6
+    assert woken == []

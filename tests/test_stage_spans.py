@@ -146,6 +146,29 @@ def test_every_request_and_flush_leaves_its_stage_spans(model, n_requests):
         assert inside == pytest.approx(call.duration, abs=2e-3)
 
 
+def test_a_device_call_says_what_opened_it_and_how_long_it_queued(model):
+    """ISSUE 34: ``opened_by`` is the counter's label, flush for flush, and
+    ``device_wait_ms`` is there because the real model reports its device
+    phase (enqueued → the flush before it done; 0 on a free device)."""
+    before = dict(batcher._FLUSH_OPENED.samples())
+    for _ in range(3):
+        _serve(model, [f"u{j}" for j in range(4)])
+    calls = [s for s in spans.default_recorder().spans()
+             if s.name == "coalescer.device_call"]
+    assert calls
+    opened = {}
+    for call in calls:
+        by = call.attributes["opened_by"]
+        assert by in ("window", "full", "anticipated", "device_free",
+                      "completion", "deadline")
+        opened[(by,)] = opened.get((by,), 0) + 1
+        assert call.attributes["device_wait_ms"] >= 0.0
+    after = dict(batcher._FLUSH_OPENED.samples())
+    assert {k: after[k] - before.get(k, 0) for k in after
+            if after[k] != before.get(k, 0)} == opened
+    assert sum(opened.values()) == len(calls)
+
+
 def test_call_span_is_in_the_ring_before_any_waiter_resumes(model):
     """batcher.py's rule survives the wakeup span: whoever has an answer
     finds the device call that gave it in the ring."""

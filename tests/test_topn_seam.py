@@ -119,3 +119,44 @@ def test_a_float_view_flush_takes_no_lock_under_the_snapshot_lock(backend):
         flush.join()
         nested = [edge for edge in graph.edges() if "als/serving.py" in edge[0]]
     assert not nested
+
+
+@pytest.mark.parametrize("backend", list(BACKENDS))
+def test_a_flush_reports_its_device_phase(backend, monkeypatch):
+    """ISSUE 34, for every backend at once (the hooks are written once, in
+    ``_dispatch`` / ``_download``): *enqueued* once the last program is
+    launched, before anything waits for it; *device done* once
+    ``topn.wait_download`` has the arrays and before the ids are decoded;
+    each once, and the call's answers are those of a call nobody
+    scheduled."""
+    from oryx_tpu.common import devicephase
+    from oryx_tpu.common import spans
+
+    model = _model(backend)
+    qs = np.random.default_rng(34).standard_normal(
+        (4, FEATURES)).astype(np.float32)
+    unscheduled = model.top_n_batch(qs, 10)
+    events = []
+    real_stage = spans.stage
+
+    def stage(name, *args, **kwargs):
+        events.append(name)
+        return real_stage(name, *args, **kwargs)
+
+    class Reporter:
+        def enqueued(self):
+            events.append("enqueued")
+
+        def device_done(self):
+            events.append("device_done")
+
+    monkeypatch.setattr(spans, "stage", stage)
+    with devicephase.reporting(Reporter()):
+        scheduled = model.top_n_batch(qs, 10)
+    assert events.count("enqueued") == events.count("device_done") == 1
+    flush = [e for e in events if e in ("enqueued", "device_done",
+                                        "topn.dispatch", "topn.wait_download",
+                                        "topn.ids")]
+    assert flush[:5] == ["topn.dispatch", "enqueued", "topn.wait_download",
+                         "device_done", "topn.ids"]
+    assert scheduled == unscheduled
