@@ -148,6 +148,11 @@ class Span:
         if not self._ended:
             self.status = f"error: {type(exc).__name__}"
 
+    def elapsed_ms(self) -> float:
+        """Milliseconds since the span opened, on the clock ``duration`` is
+        measured by: a stamp inside a stage (``first_copy_ms``)."""
+        return round((time.perf_counter() - self._start_perf) * 1000.0, 3)
+
     def end(self) -> None:
         if self._ended:
             return

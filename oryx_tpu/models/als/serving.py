@@ -337,7 +337,11 @@ def _dispatch(snap, qs_host: np.ndarray, width, register: bool):
         # indexed by item bucket on device, fully vectorized over the batch
         lut = (snap.place(snap.lsh.get_candidate_lut(qs_host))
                if snap.lsh is not None else None)
-    with spans.stage("topn.dispatch"):
+    with spans.stage("topn.dispatch") as stage:
+        # a recorded stage names the programs it launched as the profiler
+        # names their modules, in launch order: a capture's device events
+        # are joined to the flush by them (docs/observability.md)
+        launched = None if stage is spans.NOOP_SPAN else []
         out = None
         for fn, args, cost_key in snap.plan(qs, lut, width):
             if out is not None:
@@ -353,20 +357,28 @@ def _dispatch(snap, qs_host: np.ndarray, width, register: bool):
                 snap.cost_keys_attempted.add(cost_key)
                 compilecache.aot_compile(fn, *args, cost_key=cost_key)
             out = fn(*args)
+            if launched is not None:
+                launched.append("jit_" + fn.__name__)
             if register:
                 profiling.costs().record(cost_key)
         snap.dispatched(len(qs_host), width)
+        if launched is not None:
+            stage.set_attribute("programs", launched)
     devicephase.enqueued()
     return out
 
 
 def _download(out):
     """``(vals, idx)`` of a dispatched call, on the host."""
-    with spans.stage("topn.wait_download"):
+    with spans.stage("topn.wait_download") as stage:
         # the program's run and the copy back: the first conversion
         # blocks until the device is done
         vals, idx = out
-        arrays = np.asarray(vals), np.asarray(idx)
+        first = np.asarray(vals)
+        if stage is not spans.NOOP_SPAN:
+            # a recorded stage says where the second copy began
+            stage.set_attribute("first_copy_ms", stage.elapsed_ms())
+        arrays = first, np.asarray(idx)
     # told once the arrays are here: the device was done two copies ago, and
     # whoever scheduled the call has to learn that lag and allow for it
     devicephase.device_done()

@@ -189,13 +189,6 @@ class _Flush:
         if coal._held and coal._last is self:
             self.loop.call_soon_threadsafe(coal._kick, self.loop, "device_free")
 
-    def device_wait_ms(self) -> "float | None":
-        """How long the launched programs sat behind the flush before:
-        None where the call reported no device phase."""
-        if self.start_t is None:
-            return None
-        return round((self.start_t - self.enq_t) * 1000.0, 3)
-
 
 class TopNCoalescer:
     """Gathers concurrent top-N requests into one batched device call.
@@ -404,12 +397,17 @@ class TopNCoalescer:
             # a width's scan shows when a flush of it sits behind another
             # (_release): until the slots have made one, they schedule it
             return 0.0
-        if scan - self._h - self._lag_s < _WORTH * scan:
+        if not self._worth_an_aim(scan):
             return 0.0
         free = self._free_at(last)
         # its report comes ``lag`` after the device is free: the next
         # flush's programs are due THEN, not at the report
         return None if free is None else free - self._lag_s - self._h
+
+    def _worth_an_aim(self, scan: float) -> bool:
+        """Would a flush opened at its slot leave its programs a share of
+        ``scan`` worth taking in the device's queue (class docstring)?"""
+        return scan - self._h - self._lag_s >= _WORTH * scan
 
     def _follow(self, model) -> None:
         """The estimates are of ONE model object's flushes: another's scan
@@ -443,6 +441,28 @@ class TopNCoalescer:
                 return None
             start = max(start, before)
         return start + scan
+
+    def _believed(self, loop, flush: _Flush, by: str) -> tuple:
+        """What the gate believed of the device when ``flush`` was opened,
+        for its call span — entered only where that span is recorded
+        (docs/observability.md "Request tracing"). ``(now, attributes)``:
+        the loop's clock at the span's start, which the offsets count from."""
+        now = loop.time()
+        told = {"gate.h_ms": _ms(self._h), "gate.lag_ms": _ms(self._lag_s)}
+        scan = self._s.get(flush.width)
+        if scan is not None:
+            told["gate.scan_ms"] = _ms(scan)
+        told["gate.engaged"] = scan is not None and self._worth_an_aim(scan)
+        if flush.prev is not None:
+            # the aim: when the device was to be free of the flush before —
+            # its report was expected ``lag`` after that (_opens_at)
+            free = self._free_at(flush.prev)
+            if free is not None:
+                told["gate.free_in_ms"] = _ms(free - self._lag_s - now)
+        if by == "anticipated":
+            # how late the loop ran the gate's timer (_arm_gate)
+            told["gate.late_ms"] = _ms(now - flush.opened_t)
+        return now, told
 
     def _arm_gate(self, loop, at: "float | None") -> None:
         """The gate's timer for ``at`` (None: only a report opens it). A
@@ -571,6 +591,8 @@ class TopNCoalescer:
             # waiter's trace only; ``call`` — the call span's id, on it and
             # on every stage — is what joins them to the other waiters
             call_span.set_attribute("call", call_span.span_id)
+            believed = (None if call_span is spans.NOOP_SPAN
+                        else self._believed(loop, flush, by))
             handoff = spans.start_span(
                 "coalescer.handoff", parent=call_span,
                 attributes={"call": call_span.span_id},
@@ -582,7 +604,7 @@ class TopNCoalescer:
                 spans.finish_span(p.wait_span)
             try:
                 loop.run_in_executor(None, self._execute, loop, model, group,
-                                     call_span, handoff, flush)
+                                     call_span, handoff, flush, believed)
             except Exception as e:  # noqa: BLE001 — executor/loop torn down
                 # dispatch itself failed (executor shut down mid-close): the
                 # slot was taken but _execute will never run, so _done will
@@ -647,7 +669,7 @@ class TopNCoalescer:
         self._kick(loop, "completion")
 
     def _execute(self, loop, model, group: list[_Pending], call_span,
-                 handoff, flush: _Flush) -> None:
+                 handoff, flush: _Flush, believed=None) -> None:
         """Executor thread: ONE batched device call for the whole group.
 
         The device call is a FAN-IN: ``call_span`` (opened at dispatch on
@@ -725,9 +747,8 @@ class TopNCoalescer:
                 faults.maybe_fail("serving.device_call")
                 with devicephase.reporting(flush):
                     results = model.top_n_batch(qs, want, alloweds, excluded)
-                waited = flush.device_wait_ms()
-                if waited is not None:
-                    call_span.set_attribute("device_wait_ms", waited)
+                if believed is not None:
+                    _tell_device_phase(call_span, flush, believed)
             if self.breaker is not None:
                 self.breaker.record_success()
             # trace completeness: the call span must land in the ring
@@ -797,6 +818,24 @@ class TopNCoalescer:
             else:
                 _DEGRADED.inc()
                 loop.call_soon_threadsafe(_set_result, p.future, res)
+
+
+def _ms(seconds: float) -> float:
+    return round(seconds * 1000.0, 3)
+
+
+def _tell_device_phase(call_span, flush: _Flush, believed: tuple) -> None:
+    """On a recorded call span, once, at the call's end: where the call
+    reported its device phase (offsets from the span's start; absent where
+    it reported nothing) and what the gate believed at the open
+    (``TopNCoalescer._believed``)."""
+    opened, told = believed
+    if flush.enq_t is not None:
+        told["enqueued_ms"] = _ms(flush.enq_t - opened)
+    if flush.done_t is not None:
+        told["device_done_ms"] = _ms(flush.done_t - opened)
+    for key, value in told.items():
+        call_span.set_attribute(key, value)
 
 
 def _set_result(future: asyncio.Future, value) -> None:

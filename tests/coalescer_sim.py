@@ -134,6 +134,7 @@ class FifoDevice:
         self.free_t = 0.0
         self.waits: list[float] = []  # each program: enqueued → started
         self.gaps: list[float] = []  # the device idle before each program
+        self.runs: list[tuple[float, float]] = []  # each program: start, end
         self._lock = threading.Lock()
 
     def enqueue(self, width: int) -> float:
@@ -144,6 +145,7 @@ class FifoDevice:
             self.waits.append(start - now)
             self.gaps.append(start - self.free_t)
             self.free_t = start + self.scan_s(width)
+            self.runs.append((start, self.free_t))
             return self.free_t
 
 

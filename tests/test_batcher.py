@@ -659,3 +659,124 @@ def test_a_scan_not_worth_a_wait_is_scheduled_by_the_slots():
     silent, _ = settled_calls(False)
     assert reporting == silent and len(silent) == 6
     assert woken == []
+
+
+# -- what the call span says of the device phase (ISSUE 35) ------------------
+
+
+def _device_calls():
+    from oryx_tpu.common import spans
+
+    return sorted((s for s in spans.default_recorder().spans()
+                   if s.name == "coalescer.device_call"),
+                  key=lambda s: s.start_walltime)
+
+
+@pytest.fixture
+def recorded_spans():
+    from oryx_tpu.common import spans
+
+    spans.default_recorder().reset()
+    spans.set_enabled(True)
+    yield spans
+    spans.set_enabled(True)
+    spans.default_recorder().reset()
+
+
+@pytest.mark.parametrize("reports", [True, False], ids=["reports", "silent"])
+def test_the_call_span_holds_the_device_phase_only_where_it_was_reported(
+        recorded_spans, reports):
+    """``enqueued_ms`` / ``device_done_ms`` are the call's own reports as
+    offsets from the span's start: absent for a model that reports nothing,
+    whose span still says what the gate believed (nothing yet)."""
+    loop, device, model, coal = _sim(reports=reports)
+    t0 = loop.run(_one_behind_another(coal, model))
+    first, second = _device_calls()
+    for call, (opened, _), (_, end) in zip((first, second), model.calls,
+                                           device.runs):
+        at = call.attributes
+        if reports:
+            assert at["enqueued_ms"] == pytest.approx(_H * 1e3)
+            assert at["device_done_ms"] == pytest.approx((end - opened) * 1e3)
+        else:
+            assert "enqueued_ms" not in at and "device_done_ms" not in at
+        # no call of this model was over when either was opened
+        assert (at["gate.h_ms"], at["gate.lag_ms"]) == (0.0, 0.0)
+        assert at["gate.engaged"] is False and "gate.scan_ms" not in at
+        assert "gate.free_in_ms" not in at and "gate.late_ms" not in at
+        assert set(at) - {"enqueued_ms", "device_done_ms"} == {
+            "route", "call", "batch.size", "batch.padded", "pad.waste_rows",
+            "opened_by", "queue_wait_max_ms", "gate.h_ms", "gate.lag_ms",
+            "gate.engaged"}
+    assert first.attributes["opened_by"] == "window"
+    assert model.calls[0][0] == pytest.approx(t0 + 0.001)
+
+
+def test_the_call_span_holds_the_estimates_as_they_stood_at_the_open(
+        recorded_spans):
+    """After a model's first flushes have shown h, S and (none) lag, a
+    flush opened behind another carries them, the aim, and — opened by the
+    gate's timer — how late that timer ran (on time, on this loop)."""
+    loop, device, model, coal = _sim()
+
+    async def main():
+        await _learn(coal, model)
+        return await _one_behind_another(coal, model)
+
+    t0 = loop.run(main())
+    first, second = _device_calls()[-2:]
+    at = second.attributes
+    assert at["opened_by"] == "anticipated" and at["gate.engaged"] is True
+    assert (at["gate.h_ms"], at["gate.lag_ms"], at["gate.scan_ms"]) == (
+        pytest.approx(_H * 1e3), 0.0, pytest.approx(_S * 1e3))
+    assert at["gate.late_ms"] == pytest.approx(0.0, abs=1e-6)
+    # opened at free − h: the device was to be free h later, and was
+    assert at["gate.free_in_ms"] == pytest.approx(_H * 1e3)
+    assert device.runs[-2][1] == pytest.approx(t0 + 0.012)
+    assert "gate.free_in_ms" not in first.attributes  # behind nothing
+
+
+def test_with_spans_off_a_flush_runs_none_of_the_device_phase_bookkeeping(
+        monkeypatch):
+    """ISSUE 35 adds nothing to a flush's path with tracing off but
+    identity checks on the span object: what assembles the attributes is
+    never entered, and the wait PR 34 computed on every flush is gone."""
+    from oryx_tpu.common import spans
+    from oryx_tpu.serving import batcher
+
+    entered = []
+
+    def counting(name, real):
+        def wrapper(*a, **kw):
+            entered.append(name)
+            return real(*a, **kw)
+        return wrapper
+
+    monkeypatch.setattr(batcher.TopNCoalescer, "_believed", counting(
+        "believed", batcher.TopNCoalescer._believed))
+    monkeypatch.setattr(batcher, "_tell_device_phase", counting(
+        "tell", batcher._tell_device_phase))
+    monkeypatch.setattr(batcher, "_ms", counting("ms", batcher._ms))
+    # a flush holds its stamps and the two reports that set them
+    assert {n for n, v in vars(batcher._Flush).items()
+            if callable(v) and not n.startswith("_")} == {
+        "enqueued", "device_done"}
+
+    def five_flushes():
+        loop, device, model, coal = _sim()
+
+        async def main():
+            await _learn(coal, model)
+            await _one_behind_another(coal, model)
+
+        loop.run(main())  # every flush answered its request (_ask asserts)
+        assert len(model.calls) == 5 and coal._inflight == 0
+
+    spans.set_enabled(False)
+    try:
+        five_flushes()
+    finally:
+        spans.set_enabled(True)
+    assert entered == []
+    five_flushes()
+    assert entered.count("believed") == entered.count("tell") == 5

@@ -24,6 +24,7 @@ import statistics
 
 import pytest
 
+from oryx_tpu.common import spans
 from oryx_tpu.serving import batcher
 from oryx_tpu.serving.batcher import TopNCoalescer
 from tests.coalescer_sim import (FifoDevice, SimModel, VirtualLoop, p50_p95_ms,
@@ -162,3 +163,67 @@ def test_the_gate_learns_what_the_reports_lag_by():
     assert gate_p95 <= 0.9 * slots_p95, (gate_p95, slots_p95)
     settled = device.gaps[len(device.gaps) // 2:]
     assert statistics.fmean(settled) < 0.5 * MS  # of 1.4 aimed at the report
+
+
+def _call_spans(monkeypatch, **run):
+    """One run with every ``coalescer.device_call`` span kept, in order of
+    opening — which is the order of the model's calls and, on a FIFO
+    device, of its runs."""
+    monkeypatch.setattr(spans._STATE, "recorder", spans.SpanRecorder(1 << 16))
+    monkeypatch.setattr(spans._STATE, "enabled", True)
+    rate, scan_ms, h_ms, p_ms = CELLS[INT8]
+    _, device, model = _run(rate, scan_ms, h_ms * MS, p_ms, reports=True,
+                            **run)
+    calls = sorted((s for s in spans.default_recorder().spans()
+                    if s.name == "coalescer.device_call"),
+                   key=lambda s: s.start_walltime)
+    assert len(calls) == len(model.calls) == len(device.runs)
+    return calls, model, device
+
+
+@pytest.mark.parametrize("launch_ms", [0.0, 0.4], ids=["instant", "launch"])
+def test_the_span_says_where_the_gate_believed_the_device_free(
+        monkeypatch, launch_ms):
+    """ISSUE 35: ``gate.free_in_ms`` is the aim — from the span's start to
+    where the gate believed the device free of the flush before — pinned
+    where the truth is known: the fake device's own end of that flush's
+    program. With an instant launch and reports on time the aim is the
+    truth; a launch of 0.4 ms is read into the ``lag`` (what a program that
+    found the device idle took beyond its scan), so the gate believes the
+    device free that much BEFORE it is: the error the chip's metric
+    (``gate_aim_err_ms``) is there to show."""
+    calls, model, device = _call_spans(monkeypatch, launch_ms=launch_ms)
+    errs, engaged = [], 0
+    for n, call in enumerate(calls):
+        at = call.attributes
+        opened = model.calls[n][0]  # the virtual clock at the span's start
+        assert at["enqueued_ms"] == pytest.approx(CELLS[INT8][2], abs=6e-4)
+        assert at["device_done_ms"] == pytest.approx(
+            (device.runs[n][1] - opened) * 1e3, abs=6e-4)  # rounded to a µs
+        engaged += at["gate.engaged"]
+        if "gate.free_in_ms" in at:
+            believed = opened + at["gate.free_in_ms"] * MS
+            errs.append(believed - device.runs[n - 1][1])
+    assert engaged >= 0.9 * len(calls)
+    settled = errs[len(errs) // 2:]
+    assert len(settled) > 100
+    assert statistics.median(settled) == pytest.approx(-launch_ms * MS,
+                                                       abs=0.02 * MS)
+    # the estimates on the span are the coalescer's own, in ms
+    last = calls[-1].attributes
+    assert last["gate.h_ms"] == pytest.approx(CELLS[INT8][2], abs=6e-4)
+    assert last["gate.lag_ms"] == pytest.approx(launch_ms, abs=6e-4)
+    assert last["gate.scan_ms"] in (7.2, 8.9)
+
+
+def test_the_span_says_how_late_the_loop_ran_the_gates_timer(monkeypatch):
+    """``gate.late_ms``: the loop's clock at the open minus the instant the
+    timer was armed for — on a selector that sleeps in whole milliseconds,
+    up to one; only on the flushes that timer opened."""
+    calls, _, _ = _call_spans(monkeypatch, tick_s=TICK_S)
+    late = [c.attributes["gate.late_ms"] for c in calls
+            if c.attributes["opened_by"] == "anticipated"]
+    assert len(late) > 0.5 * len(calls)
+    assert all(0.0 <= x <= 1.0 + 1e-6 for x in late) and max(late) > 0.5
+    assert not any("gate.late_ms" in c.attributes for c in calls
+                   if c.attributes["opened_by"] != "anticipated")

@@ -56,7 +56,10 @@ def test_the_cell_is_the_published_row_on_four_chips_with_nothing_cut():
     assert mix["rate_per_s"] <= 0.25 * mix["knee_req_per_s"] < \
         mix["rate_per_s"] + 10
     assert {"recommend_p95_ms", "setup_s"} == {m["name"] for m in c.end_to_end}
-    assert all(m["name"].endswith(".mesh") for m in c.per_layer)
+    # its own; the flush's device phase has ONE entry for every serving
+    # cell (ISSUE 35), read off every device plane
+    assert all(m["name"].endswith(".mesh") or len(m["workloads"]) == 4
+               for m in c.per_layer)
     # the one-chip readers of the scan are not pointed at this cell
     readers = {mf.load_json(mf.find("metrics", m["name"], ".json"))["reader"]
                for m in c.per_layer}

@@ -146,15 +146,18 @@ def test_every_request_and_flush_leaves_its_stage_spans(model, n_requests):
         assert inside == pytest.approx(call.duration, abs=2e-3)
 
 
-def test_a_device_call_says_what_opened_it_and_how_long_it_queued(model):
-    """ISSUE 34: ``opened_by`` is the counter's label, flush for flush, and
-    ``device_wait_ms`` is there because the real model reports its device
-    phase (enqueued → the flush before it done; 0 on a free device)."""
+def test_a_device_call_says_what_opened_it_and_where_its_device_phase_lay(model):
+    """ISSUE 34: ``opened_by`` is the counter's label, flush for flush.
+    ISSUE 35: the call span says where the real model reported its device
+    phase (``enqueued_ms`` ≤ ``device_done_ms``, offsets from the span's
+    start, inside it) and what the gate believed when the flush was opened;
+    ``topn.dispatch`` names the programs it launched as a profile names
+    their modules, ``topn.wait_download`` where its second copy began."""
     before = dict(batcher._FLUSH_OPENED.samples())
     for _ in range(3):
         _serve(model, [f"u{j}" for j in range(4)])
-    calls = [s for s in spans.default_recorder().spans()
-             if s.name == "coalescer.device_call"]
+    ring = spans.default_recorder().spans()
+    calls = [s for s in ring if s.name == "coalescer.device_call"]
     assert calls
     opened = {}
     for call in calls:
@@ -162,7 +165,19 @@ def test_a_device_call_says_what_opened_it_and_how_long_it_queued(model):
         assert by in ("window", "full", "anticipated", "device_free",
                       "completion", "deadline")
         opened[(by,)] = opened.get((by,), 0) + 1
-        assert call.attributes["device_wait_ms"] >= 0.0
+        at = call.attributes
+        assert 0.0 <= at["enqueued_ms"] <= at["device_done_ms"]
+        assert at["device_done_ms"] <= call.duration * 1e3 + 1e-3
+        assert at["gate.h_ms"] >= 0.0 and at["gate.lag_ms"] >= 0.0
+        assert at["gate.engaged"] in (True, False)
+        assert ("gate.late_ms" in at) == (by == "anticipated")
+        stages = {s.name: s for s in ring
+                  if s.attributes.get("call") == call.span_id}
+        assert stages["topn.dispatch"].attributes["programs"] == [
+            "jit__top_k_dot_batch"]
+        download = stages["topn.wait_download"]
+        assert (0.0 <= download.attributes["first_copy_ms"]
+                <= download.duration * 1e3)
     after = dict(batcher._FLUSH_OPENED.samples())
     assert {k: after[k] - before.get(k, 0) for k in after
             if after[k] != before.get(k, 0)} == opened
@@ -251,6 +266,32 @@ def test_with_spans_off_no_span_is_built_on_the_request_path(model, monkeypatch)
     _serve(model, ["u7"])
     assert {"serving.render", "annotation serving.render",
             *FLUSH_STAGES} <= set(built)
+
+
+def test_with_spans_off_the_download_reads_no_clock(monkeypatch):
+    """ISSUE 35's stamp after the first copy exists only on a recorded
+    stage: with spans off ``_download`` and ``_dispatch``'s bookkeeping make
+    no clock call and build no list of program names."""
+    from oryx_tpu.models.als import serving as als_serving
+
+    class _NoClock:
+        def __getattr__(self, name):
+            raise AssertionError(f"time.{name} read with spans off")
+
+    spans.set_enabled(False)
+    monkeypatch.setattr(spans, "time", _NoClock())
+    vals, idx = np.arange(6.0).reshape(2, 3), np.arange(6).reshape(2, 3)
+    with spans.activate(None):
+        got = als_serving._download((vals, idx))
+    assert got[0] is vals and got[1] is idx
+    monkeypatch.undo()
+    # on a recorded stage the stamp lies inside the stage, in ms
+    spans.set_enabled(True)
+    with spans.span("caller") as caller:
+        als_serving._download((vals, idx))
+    (stage,) = [s for s in spans.default_recorder().spans(
+        trace_id=caller.trace_id) if s.name == "topn.wait_download"]
+    assert 0.0 <= stage.attributes["first_copy_ms"] <= stage.duration * 1e3
 
 
 def test_a_stage_never_starts_a_trace_of_its_own(model):
