@@ -326,8 +326,9 @@ def _dispatch(snap, qs_host: np.ndarray, width, register: bool):
     of on this side, for every backend. ``width`` is the backend's static
     width (``snap.batch_width`` for a batch, with the room its exclusions
     need); ``register`` attributes the call to the program's cost key.
-    Returns ``(vals, idx)`` still on the device: what the host does before
-    :func:`_download` runs under the scan."""
+    Returns ``(vals, idx)`` still on the device, their copy to the host
+    already asked for: what the host does before :func:`_download` runs
+    under the scan."""
     with spans.stage("topn.upload"):
         # batch-shaped operands go from the host straight to where the view
         # lies (on a mesh to every device at once, not to one device and on
@@ -362,8 +363,16 @@ def _dispatch(snap, qs_host: np.ndarray, width, register: bool):
             if register:
                 profiling.costs().record(cost_key)
         snap.dispatched(len(qs_host), width)
+        # the last program's results (never a step's: the next step reads
+        # that on the device) are wanted whole on the host right after. Asked
+        # for here, the runtime starts both copies once they are ready, side
+        # by side; left to _download, each starts only when the host blocks
+        # on it
+        for array in out:
+            array.copy_to_host_async()
         if launched is not None:
             stage.set_attribute("programs", launched)
+            stage.set_attribute("copies_ahead", len(out))
     devicephase.enqueued()
     return out
 
@@ -371,16 +380,19 @@ def _dispatch(snap, qs_host: np.ndarray, width, register: bool):
 def _download(out):
     """``(vals, idx)`` of a dispatched call, on the host."""
     with spans.stage("topn.wait_download") as stage:
-        # the program's run and the copy back: the first conversion
-        # blocks until the device is done
+        # the program's run and the copies back that _dispatch asked for:
+        # the first conversion blocks until the device is done and its copy
+        # has landed, the second finds its own in flight or landed
         vals, idx = out
         first = np.asarray(vals)
         if stage is not spans.NOOP_SPAN:
-            # a recorded stage says where the second copy began
+            # a recorded stage says when the first copy was in hand: what
+            # follows is the wait for the second
             stage.set_attribute("first_copy_ms", stage.elapsed_ms())
         arrays = first, np.asarray(idx)
-    # told once the arrays are here: the device was done two copies ago, and
-    # whoever scheduled the call has to learn that lag and allow for it
+    # told once the arrays are here: the device was done a notification and
+    # the copies ago, and whoever scheduled the call has to learn that lag
+    # and allow for it
     devicephase.device_done()
     return arrays
 

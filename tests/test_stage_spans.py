@@ -294,6 +294,41 @@ def test_with_spans_off_the_download_reads_no_clock(monkeypatch):
     assert 0.0 <= stage.attributes["first_copy_ms"] <= stage.duration * 1e3
 
 
+def test_with_spans_off_the_dispatch_sets_nothing_and_reads_no_clock(
+        model, monkeypatch):
+    """ISSUE 37's ``copies_ahead`` exists only on a recorded stage: with spans
+    off ``_dispatch`` asks for its two copies and writes no attribute, nor
+    reads a clock; recorded, its ``topn.dispatch`` says it asked for two."""
+    from oryx_tpu.models.als import serving as als_serving
+
+    class _NoClock:
+        def __getattr__(self, name):
+            raise AssertionError(f"time.{name} read with spans off")
+
+    snap = model.y_snapshot()
+    qs = np.zeros((2, K), dtype=np.float32)
+    width = snap.batch_width(10, False)
+    written = []
+    spans.set_enabled(False)
+    monkeypatch.setattr(spans, "time", _NoClock())
+    monkeypatch.setattr(type(spans.NOOP_SPAN), "set_attribute",
+                        lambda self, key, value: written.append(key))
+    with spans.activate(None):
+        out = als_serving._dispatch(snap, qs, width, register=False)
+    monkeypatch.undo()
+    assert written == []
+    vals, idx = als_serving._download(out)
+    assert vals.shape == idx.shape == (2, width[0])
+    spans.set_enabled(True)
+    with spans.span("caller") as caller:
+        als_serving._download(
+            als_serving._dispatch(snap, qs, width, register=False))
+    (stage,) = [s for s in spans.default_recorder().spans(
+        trace_id=caller.trace_id) if s.name == "topn.dispatch"]
+    assert stage.attributes["copies_ahead"] == 2
+    assert stage.attributes["programs"] == ["jit__top_k_dot_batch"]
+
+
 def test_a_stage_never_starts_a_trace_of_its_own(model):
     """A direct call of the model (the warm ladder, a test) has no caller's
     span: its stages record nothing rather than seven orphan roots."""

@@ -8,6 +8,7 @@ is 0 in every serving cell; this is what holds it for every backend at once."""
 
 import threading
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -160,3 +161,160 @@ def test_a_flush_reports_its_device_phase(backend, monkeypatch):
     assert flush[:5] == ["topn.dispatch", "enqueued", "topn.wait_download",
                          "device_done", "topn.ids"]
     assert scheduled == unscheduled
+
+
+# ISSUE 37: a flush asks for the copy back of its last program's two results
+# at the launch. Every backend, and the known-item form of the flat one (the
+# default /recommend hands its user's known items over as codes).
+CASES = [*BACKENDS, "known"]
+
+
+def _case_model(case: str) -> ALSServingModel:
+    model = _model("flat" if case == "known" else case)
+    if case == "known":
+        model.add_known_items("u0", [f"i{j}" for j in range(1, 40)])
+    return model
+
+
+def _flush(model, case: str, qs):
+    """One flush of ``qs``; in the known case the first query leaves out its
+    user's known items as the default ``/recommend`` hands them over."""
+    excluded = None
+    if case == "known":
+        excluded = [model.known_item_codes("u0")] + [None] * (len(qs) - 1)
+    return model.top_n_batch(qs, 10, excluded=excluded)
+
+
+@pytest.fixture
+def copies(monkeypatch):
+    """``asked``: every array whose copy to the host was asked for ahead, in
+    order; ``dispatched``: every ``_dispatch``'s result; ``fed``: every step
+    result a plan's next step was handed."""
+    from oryx_tpu.models.als import serving as als_serving
+
+    asked, dispatched, fed = [], [], []
+    array_type = type(jnp.zeros(()))
+    real_copy = array_type.copy_to_host_async
+    real_dispatch = als_serving._dispatch
+    real_operands = als_serving._operands
+
+    def copy_spy(self):
+        asked.append(self)
+        return real_copy(self)
+
+    def dispatch_spy(*args, **kwargs):
+        out = real_dispatch(*args, **kwargs)
+        dispatched.append(out)
+        return out
+
+    def operands_spy(args, step_result=None):
+        if step_result is not None:
+            fed.append(step_result)
+        return real_operands(args, step_result)
+
+    monkeypatch.setattr(array_type, "copy_to_host_async", copy_spy)
+    monkeypatch.setattr(als_serving, "_dispatch", dispatch_spy)
+    monkeypatch.setattr(als_serving, "_operands", operands_spy)
+    return asked, dispatched, fed
+
+
+def _same(xs, ys) -> bool:
+    return len(xs) == len(ys) and all(x is y for x, y in zip(xs, ys))
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_a_flush_asks_for_the_copy_of_its_last_results_once(case, copies):
+    """Both arrays of the LAST program's ``(vals, idx)``, once each, in that
+    order, before ``_dispatch`` returns; a step result the next program reads
+    (the IVF probe's cells) stays on the device."""
+    model = _case_model(case)
+    qs = np.random.default_rng(37).standard_normal(
+        (4, FEATURES)).astype(np.float32)
+    _flush(model, case, qs)  # compiles, and fills the arena's views
+    asked, dispatched, fed = copies
+    del asked[:], dispatched[:], fed[:]
+    answers = _flush(model, case, qs)
+    assert len(answers) == 4 and all(len(a) == 10 for a in answers)
+    (out,) = dispatched
+    assert len(out) == 2 and _same(asked, out)
+    assert len(fed) == (1 if case == "ivf" else 0)
+    assert not any(a is f for a in asked for f in fed)
+
+
+def test_a_plan_of_two_steps_never_copies_its_intermediate(copies):
+    """A fake backend whose second program reads the first's result through
+    a ``_Fed`` operand: only the second's ``(vals, idx)`` are asked for."""
+    import jax
+
+    from oryx_tpu.models.als import serving as als_serving
+    from oryx_tpu.models.als.topn import _Fed
+
+    @jax.jit
+    def shift(qs):
+        return qs + 1.0
+
+    @jax.jit
+    def best(mat, shifted):
+        return jax.lax.top_k(shifted @ mat.T, 3)
+
+    class TwoSteps:
+        lsh = None
+        cost_keys_attempted: set = set()
+
+        def __init__(self):
+            self.mat = jnp.ones((8, FEATURES), jnp.float32)
+
+        def place(self, host):
+            return jnp.asarray(host)
+
+        def plan(self, qs, lut, width):
+            return ((shift, (qs,), "shift"),
+                    (best, (self.mat, _Fed(qs.shape, qs.dtype)), "best"))
+
+        def dispatched(self, batch, width):
+            pass
+
+    asked, _dispatched, fed = copies
+    out = als_serving._dispatch(TwoSteps(), np.zeros((2, FEATURES), np.float32),
+                                3, register=False)
+    (step,) = fed
+    assert step.shape == (2, FEATURES)
+    assert _same(asked, out) and not any(a is step for a in asked)
+    vals, idx = als_serving._download(out)
+    assert vals.shape == idx.shape == (2, 3)
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_answers_are_those_of_a_flush_that_asks_for_no_copy_ahead(
+        case, monkeypatch):
+    """The copy ahead changes when the arrays travel, never what they hold:
+    the answers are bit for bit those of the same flush with the request
+    left out (the conversion then starts the copy itself)."""
+    model = _case_model(case)
+    qs = np.random.default_rng(370).standard_normal(
+        (8, FEATURES)).astype(np.float32)
+    ahead = _flush(model, case, qs)
+    monkeypatch.setattr(type(jnp.zeros(())), "copy_to_host_async",
+                        lambda self: None)
+    assert _flush(model, case, qs) == ahead
+
+
+@pytest.mark.parametrize("backend", ["flat", "int8", "mesh", "ivf"])
+def test_a_single_query_widening_copies_no_score_vector(backend, copies):
+    """A single query's widening (a host filter that lets few items through)
+    asks for no copy of its ``(1, n)`` scores: the flat and int8 backends,
+    which widen over cached scores, ask for none at all; those that widen by
+    scanning again ask only for each scan's own results."""
+    model = _model(backend)
+    model.y_snapshot()  # the IVF build fetches its centroids by device_get
+    asked, dispatched, _fed = copies
+    del asked[:]
+    q = np.random.default_rng(3).standard_normal(FEATURES).astype(np.float32)
+    few = {f"i{j}" for j in range(7, N_ITEMS, 97)}
+    got = model.top_n(q, 5, allowed=few.__contains__)
+    assert len(got) == 5 and {i for i, _ in got} <= few
+    assert _same(asked, [a for out in dispatched for a in out])
+    if backend in ("flat", "int8"):
+        assert asked == [] and dispatched == []
+    else:
+        assert len(dispatched) >= 2  # it widened
