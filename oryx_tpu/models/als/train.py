@@ -775,6 +775,59 @@ _HALF_FORMULATION = metrics_mod.default_registry().gauge(
     "resolved this side's half-iteration to, 0 for the others",
     ("side", "formulation"),
 )
+_SOLVED_ROWS = metrics_mod.default_registry().counter(
+    "oryx_als_solved_rows_total",
+    "Rows the trainer's half-iterations solved, block padding included, by "
+    "the solve that ran them: the Pallas SPD kernel or XLA's cholesky",
+    ("side", "path"),
+)
+_SPD_TILE_ROWS = metrics_mod.default_registry().gauge(
+    "oryx_als_spd_tile_rows",
+    "Batch tile the SPD kernel ran this side's last half-iteration at; 0 "
+    "where XLA's cholesky solved it",
+    ("side",),
+)
+
+
+def _name_formulation(side: str, chosen: "_Formulation", features: int) -> None:
+    """``oryx_als_half_formulation{side, …}``: 1 for what ran, 0 else."""
+    ran = chosen.name(features)
+    for label in _FORMULATION_NAMES:
+        _HALF_FORMULATION.labels(side, label).set(float(label == ran))
+
+
+class _SpdSolve(typing.NamedTuple):
+    """How a half-iteration solves its rows' systems (:func:`_choose_spd`)."""
+
+    kernel: bool  # the Pallas Gauss-Jordan kernel, else XLA's cholesky
+    tile_rows: int  # the kernel's batch tile; 0 under the cholesky
+
+    @property
+    def path(self) -> str:
+        return "spd_kernel" if self.kernel else "cholesky"
+
+
+@functools.lru_cache(maxsize=None)
+def _choose_spd(asked: bool, features: int) -> _SpdSolve:
+    """The Pallas SPD kernel where it is asked for (by default: on a TPU) and
+    fits at ``features`` (``pk.spd_kernel_fits``), else XLA's cholesky —
+    taken on the host so that the run can count which solve ran. Said once
+    a width where the kernel was asked for and does not fit."""
+    if asked and pk.spd_kernel_fits(features):
+        return _SpdSolve(True, pk.spd_tile_b(features))
+    if asked:
+        logging.getLogger(__name__).warning(
+            "SPD kernel not used: features=%d is past its VMEM tile budget; "
+            "using XLA's cholesky", features)
+    return _SpdSolve(False, 0)
+
+
+def _count_half(side: str, rows: int, spd: _SpdSolve) -> None:
+    """One half-iteration's solve, counted on the host as it is dispatched:
+    nothing is added inside the jitted call."""
+    _SOLVED_ROWS.labels(side, spd.path).inc(rows)
+    _SPD_TILE_ROWS.labels(side).set(spd.tile_rows)
+
 
 # The gather-Gramian formulation is chosen a SIDE, from the opposite factor
 # table that side gathers from — its row width and its bytes. Placed from two
@@ -919,11 +972,17 @@ def _resolve_fused(fused_gramian: "bool | None", on_tpu: bool,
 def solve_side_blocked(y, srows, scols, svals, slens, lam, alpha, *, block,
                        features, implicit, slot_chunk, dtype="float32",
                        spd_kernel: "bool | None" = None,
-                       fused_gramian: "bool | None" = None):
+                       fused_gramian: "bool | None" = None,
+                       side: "str | None" = None):
     """One half-iteration, single device: lax.map over row blocks.
 
     ``spd_kernel=None`` picks the Pallas Gauss-Jordan solve on a TPU and
-    XLA's cholesky elsewhere. ``fused_gramian=None`` picks the gather-Gramian
+    XLA's cholesky elsewhere; past the kernel's tile budget the cholesky
+    runs whatever was asked (:func:`_choose_spd`). A named ``side``
+    (``user`` / ``item``) is counted in ``oryx_als_half_formulation``,
+    ``oryx_als_solved_rows_total`` and ``oryx_als_spd_tile_rows``, on the
+    host at every call; an unnamed half in none of them.
+    ``fused_gramian=None`` picks the gather-Gramian
     formulation THIS side runs fastest (:func:`_choose_formulation`): off a
     TPU the einsum; on one whichever the chip sweep measured fastest for an
     opposite table ``y`` of this width and size — the einsum over ``y``
@@ -937,15 +996,18 @@ def solve_side_blocked(y, srows, scols, svals, slens, lam, alpha, *, block,
     it emulated off-TPU, and no kernel can run in interpret mode on the
     chip."""
     on_tpu = pk.on_tpu(y)
-    if spd_kernel is None:
-        spd_kernel = on_tpu
-    fused, gather_width = _resolve_fused(fused_gramian, on_tpu, features,
-                                         srows.shape[1], y.shape[0])
+    spd = _choose_spd(on_tpu if spd_kernel is None else bool(spd_kernel),
+                      features)
+    chosen = _choose_formulation(fused_gramian, on_tpu, features,
+                                 srows.shape[1], y.shape[0])
+    if side is not None:
+        _name_formulation(side, chosen, features)
+        _count_half(side, srows.shape[0] * block, spd)
     return _solve_side_blocked_jit(
         y, srows, scols, svals, slens, lam, alpha, block=block,
         features=features, implicit=implicit, slot_chunk=slot_chunk,
-        dtype=dtype, spd_kernel=bool(spd_kernel), fused_gramian=fused,
-        kernel_interpret=not on_tpu, gather_width=gather_width,
+        dtype=dtype, spd_kernel=spd.kernel, fused_gramian=chosen.fused,
+        kernel_interpret=not on_tpu, gather_width=chosen.gather_width,
     )
 
 
@@ -1164,18 +1226,18 @@ def _register_half_cost(name: str, side: _BlockedSide, features: int,
               * gather_itemsize + rows * k * (k + 1) * 4.0)
     key = f"als.train.{name}_half"
     profiling.costs().register(key, flops, bytes_)
-    ran = chosen.name(k)
-    for label in _FORMULATION_NAMES:
-        _HALF_FORMULATION.labels(name, label).set(float(label == ran))
+    _name_formulation(name, chosen, k)
     _log_gather_rows(key, side, k, chosen)
 
 
-def _recorded_half(key: str, fn):
+def _recorded_half(name: str, rows: int, spd: _SpdSolve, fn):
     """Wrap a half-iteration solver so each dispatch lands in the device
-    cost counters (oryx_device_flops_total{program=key} et al.)."""
+    cost counters (oryx_device_flops_total{program=als.train.<name>_half}
+    et al.) and the solve counters (:func:`_count_half`)."""
 
     def call(*args):
-        profiling.costs().record(key)
+        profiling.costs().record(f"als.train.{name}_half")
+        _count_half(name, rows, spd)
         return fn(*args)
 
     return call
@@ -1390,6 +1452,7 @@ def als_train(
         # the solvers below are handed the same answer
         sharded_mode = mesh is not None and row_axis is not None
         on_tpu = pk.on_tpu(mesh=mesh) if sharded_mode else pk.on_tpu(y)
+        spd = _choose_spd(on_tpu, k)
 
         def resolve(name: str, side: _BlockedSide,
                     table_rows: int) -> _Formulation:
@@ -1432,18 +1495,17 @@ def als_train(
             u_arrays = put_side(user_side)
 
             def sharded(name, side, blk):
-                return _sharded_solver(
+                solver = _sharded_solver(
                     mesh, row_axis, blk, k, implicit, side.slot_chunk, dtype,
-                    on_tpu, chosen[name].fused, not on_tpu,
+                    spd.kernel, chosen[name].fused, not on_tpu,
                     chosen[name].gather_width)
+                return _recorded_half(name, side.padded_rows, spd, solver)
 
-            solve_u = _recorded_half("als.train.user_half",
-                                     sharded("user", user_side, block_u))
+            solve_u = sharded("user", user_side, block_u)
             x = solve_u(y, *u_arrays, lam, alpha)  # device busy; host packs
             item_side, _ = finish_item_pack()
             i_arrays = put_side(item_side)
-            solve_i = _recorded_half("als.train.item_half",
-                                     sharded("item", item_side, block_i))
+            solve_i = sharded("item", item_side, block_i)
             y = solve_i(x, *i_arrays, lam, alpha)
             completed = start_iter + 1
             _maybe_ckpt(completed, x, y)
@@ -1458,11 +1520,12 @@ def als_train(
         def solve(side, opp, blk, ck):
             name = "user" if side is user_side else "item"
             profiling.costs().record(f"als.train.{name}_half")
-            # solve_side_blocked's call, with the answer resolved above
+            _count_half(name, side.padded_rows, spd)
+            # solve_side_blocked's call, with the answers resolved above
             return _solve_side_blocked_jit(
                 opp, side.srows, side.scols, side.svals, side.slens, lam,
                 alpha, block=blk, features=k, implicit=implicit,
-                slot_chunk=ck, dtype=dtype, spd_kernel=on_tpu,
+                slot_chunk=ck, dtype=dtype, spd_kernel=spd.kernel,
                 fused_gramian=chosen[name].fused, kernel_interpret=not on_tpu,
                 gather_width=chosen[name].gather_width,
             )

@@ -217,6 +217,12 @@ def spd_tile_b(k: int) -> int:
                (_SPD_SCOPED_BUDGET_BYTES // (4 * max(1, k_padded))) & ~7)
 
 
+def spd_kernel_fits(k: int) -> bool:
+    """Whether the SPD kernel runs at ``k`` features: a tile of at least 8
+    rows fits its budget (to 256 features); else XLA's cholesky solves."""
+    return spd_tile_b(k) >= 8
+
+
 def spd_solve_batched(a, b, *, interpret: bool):
     """Solve ``a[i] @ x[i] = b[i]`` for a batch of SPD k×k systems.
 
@@ -227,8 +233,7 @@ def spd_solve_batched(a, b, *, interpret: bool):
     a = jnp.asarray(a, dtype=jnp.float32)
     b = jnp.asarray(b, dtype=jnp.float32)
     n, k = b.shape
-    tile_b = spd_tile_b(k)
-    if tile_b < 8:
+    if not spd_kernel_fits(k):
         # k so large (> 256 features with this budget) that even an 8-row
         # tile risks overflowing the scoped-VMEM stack: fall back to XLA's
         # cholesky rather than fail to compile — and say so, because the
@@ -239,6 +244,7 @@ def spd_solve_batched(a, b, *, interpret: bool):
         )
         chol = jax.scipy.linalg.cholesky(a, lower=True)
         return jax.scipy.linalg.cho_solve((chol, True), b[..., None])[..., 0]
+    tile_b = spd_tile_b(k)
     n_pad = _pad_dim(max(n, 1), tile_b)
     if n_pad != n:
         eye = jnp.broadcast_to(jnp.eye(k, dtype=jnp.float32),

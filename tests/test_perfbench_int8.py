@@ -109,7 +109,7 @@ def test_the_cell_reports_its_own_layers_and_leaves_the_others_theirs():
             m["name"] for m in mf.Cell(MANIFEST, other).per_layer}
     p95 = [m for m in MANIFEST["end_to_end"] if m["name"] == "recommend_p95_ms"]
     assert p95[0]["workloads"][-1] == CELL and p95[0]["bound"] == 0.07
-    assert len(MANIFEST["workloads"]) == 5 and len(MANIFEST["configs"]) == 5
+    assert len(MANIFEST["workloads"]) == 6 and len(MANIFEST["configs"]) == 6
     assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 1
 
 
