@@ -296,11 +296,12 @@ def run_batch_bench(
 def _kernel_vmem_rows(k: int, slot_width: int) -> list:
     """Static kernel VMEM/HBM rows (tools/analyze/kernelmodel.py) evaluated
     at the bench's shapes: features k, the pack's slot width T, and the spd
-    batch tile the runtime gate picks for k. Best-effort — an analysis
-    hiccup must never cost the bench its measured numbers."""
+    batch tile the runtime gate picks for k (the blocked kernel's past 128
+    features, 0 past 256 where XLA's cholesky solves). Best-effort — an
+    analysis hiccup must never cost the bench its measured numbers."""
     try:
         import oryx_tpu
-        from oryx_tpu.ops.pallas_kernels import spd_tile_b
+        from oryx_tpu.ops.pallas_kernels import spd_solve_path
         from oryx_tpu.tools.analyze.core import build_project
         from oryx_tpu.tools.analyze.kernelmodel import kernel_cost_report
 
@@ -309,8 +310,9 @@ def _kernel_vmem_rows(k: int, slot_width: int) -> list:
             [os.path.join(pkg, "ops", "pallas_kernels.py")],
             root=os.path.dirname(pkg),
         )
-        bindings = {"k": k, "t": slot_width, "tile_b": spd_tile_b(k),
-                    "kp": -(-k // 128) * 128}
+        bindings = {"k": k, "t": slot_width,
+                    "tile_b": spd_solve_path(k)[1],
+                    "kp": -(-k // 128) * 128, "kw": -(-(k + 1) // 128) * 128}
         rows = []
         for r in kernel_cost_report(project, bindings):
             rows.append({

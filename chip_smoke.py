@@ -91,7 +91,7 @@ N_RECOMMEND = 64  # concurrent /recommend requests (two coalescer waves)
 AUC_GATE = 0.75  # the planted-structure bar of tests/test_batch_resume_it.py
 GG_TOL_F32 = 1e-2  # of the largest |entry|: bf16-rounded MXU operands
 GG_TOL_BF16 = 2e-2  # the CPU differential gate's own bf16 tolerance
-SPD_TOL = {False: 1e-4, True: 1e-3}  # keyed by k >= 100; VPU-only float32
+SPD_TOL = {False: 1e-4, True: 1e-3}  # keyed by k >= 100; float32 throughout
 # sums go through the MXU once (points rounded: 2^-9 each); the cost is a
 # sum over 3000 points of a cancelling expression, each off by at most
 # 2|p||c|·2^-8 ≈ 8% of its d² on the smoke's data, independent in sign:
@@ -281,8 +281,9 @@ def check_kernels(features: tuple, on_tpu: bool) -> dict:
                 raise SmokeFailure(
                     f"gather_gramian k={k}: a row no slot names is not zero")
 
-        # SPD solve: a batch that straddles the kernel's tile
-        n = pk.spd_tile_b(k) * 2 + 5
+        # SPD solve: a batch that straddles the tile of the kernel that
+        # solves this width (blocked past 128 features)
+        n = pk.spd_solve_path(k)[1] * 2 + 5
         m = rng.standard_normal((n, k, k)).astype(np.float32) * 0.3
         a = np.einsum("bij,bkj->bik", m, m) + 2.0 * np.eye(k, dtype=np.float32)
         rhs = rng.standard_normal((n, k)).astype(np.float32)

@@ -778,13 +778,14 @@ _HALF_FORMULATION = metrics_mod.default_registry().gauge(
 _SOLVED_ROWS = metrics_mod.default_registry().counter(
     "oryx_als_solved_rows_total",
     "Rows the trainer's half-iterations solved, block padding included, by "
-    "the solve that ran them: the Pallas SPD kernel or XLA's cholesky",
+    "the solve that ran them: the Pallas SPD kernel (spd_kernel, to 128 "
+    "features), its blocked form (spd_blocked, to 256) or XLA's cholesky",
     ("side", "path"),
 )
 _SPD_TILE_ROWS = metrics_mod.default_registry().gauge(
     "oryx_als_spd_tile_rows",
-    "Batch tile the SPD kernel ran this side's last half-iteration at; 0 "
-    "where XLA's cholesky solved it",
+    "Batch tile the SPD kernel (unblocked or blocked) ran this side's last "
+    "half-iteration at; 0 where XLA's cholesky solved it",
     ("side",),
 )
 
@@ -799,27 +800,29 @@ def _name_formulation(side: str, chosen: "_Formulation", features: int) -> None:
 class _SpdSolve(typing.NamedTuple):
     """How a half-iteration solves its rows' systems (:func:`_choose_spd`)."""
 
-    kernel: bool  # the Pallas Gauss-Jordan kernel, else XLA's cholesky
+    path: str  # spd_kernel / spd_blocked (past 128 features) / cholesky
     tile_rows: int  # the kernel's batch tile; 0 under the cholesky
 
     @property
-    def path(self) -> str:
-        return "spd_kernel" if self.kernel else "cholesky"
+    def kernel(self) -> bool:
+        return self.path != "cholesky"
 
 
 @functools.lru_cache(maxsize=None)
 def _choose_spd(asked: bool, features: int) -> _SpdSolve:
-    """The Pallas SPD kernel where it is asked for (by default: on a TPU) and
-    fits at ``features`` (``pk.spd_kernel_fits``), else XLA's cholesky —
-    taken on the host so that the run can count which solve ran. Said once
-    a width where the kernel was asked for and does not fit."""
-    if asked and pk.spd_kernel_fits(features):
-        return _SpdSolve(True, pk.spd_tile_b(features))
+    """The Pallas SPD solve where it is asked for (by default: on a TPU) and
+    a kernel takes ``features`` (``pk.spd_solve_path``: the unblocked kernel
+    to 128, the blocked one to 256), else XLA's cholesky — taken on the host
+    so that the run can count which solve ran. Said once a width where a
+    kernel was asked for and none takes it."""
+    path, tile = pk.spd_solve_path(features)
+    if asked and path != "cholesky":
+        return _SpdSolve(path, tile)
     if asked:
         logging.getLogger(__name__).warning(
             "SPD kernel not used: features=%d is past its VMEM tile budget; "
             "using XLA's cholesky", features)
-    return _SpdSolve(False, 0)
+    return _SpdSolve("cholesky", 0)
 
 
 def _count_half(side: str, rows: int, spd: _SpdSolve) -> None:
@@ -976,9 +979,9 @@ def solve_side_blocked(y, srows, scols, svals, slens, lam, alpha, *, block,
                        side: "str | None" = None):
     """One half-iteration, single device: lax.map over row blocks.
 
-    ``spd_kernel=None`` picks the Pallas Gauss-Jordan solve on a TPU and
-    XLA's cholesky elsewhere; past the kernel's tile budget the cholesky
-    runs whatever was asked (:func:`_choose_spd`). A named ``side``
+    ``spd_kernel=None`` picks the Pallas Gauss-Jordan solve on a TPU (its
+    blocked form past 128 features) and XLA's cholesky elsewhere; past 256
+    features the cholesky runs whatever was asked (:func:`_choose_spd`). A named ``side``
     (``user`` / ``item``) is counted in ``oryx_als_half_formulation``,
     ``oryx_als_solved_rows_total`` and ``oryx_als_spd_tile_rows``, on the
     host at every call; an unnamed half in none of them.

@@ -16,6 +16,30 @@ Gramians and one write of the solutions:
 No pivoting: operands are regularized SPD (diagonal shift λ·n ≥ λ), for
 which diagonal pivots are bounded away from zero.
 
+A step's cost is set by its multipliers — column j, a masked reduction
+across the lanes of every vreg of the block — not by the elements it
+updates: ≈ 3.5 cycles a vreg on a v5e (PERF.md §6). Past one lane
+tile of features (k > 128) each of the k steps would reduce and sweep the
+whole (T, 256, 256) block, so the solve is BLOCKED there (129–256
+features, ``_spd_blocked_kernel``): the augmented [A | b] sits in two lane
+tiles of rows, zero past k, and the columns are eliminated a panel of
+``_SPD_PANEL`` (32) at a time:
+
+  per panel:  its columns, one at a time, on the panel's 32 rows alone,
+              over the lanes from the panel's tile on          (VPU)
+              → [0 | I | rest] in those rows
+              the panel out of every row below it: rows −=
+              (their panel columns) · (the panel's rows)        (MXU)
+  then:       back substitution a panel at a time from the last,
+              x_p = column k − (the panel's rows past it) · x    (MXU)
+
+A step reduces 4 vregs a system where the unblocked step reduced 32, and
+the MXU works at full float32 precision (``Precision.HIGHEST``). A
+narrower panel halves the reductions again but pays a whole (·, 128)
+contraction an update for fewer columns: 32 is where the two meet.
+Padding to 256 happens in VMEM, never in HBM; widths to 128 keep the
+unblocked kernel unchanged.
+
 ``gather_gramian_accumulate`` fuses the ALS trainer's entire Gramian
 accumulation — the opposite-factor gather, the per-slot (k, k) Gramian/RHS
 contraction, and the slot→row merge — into one pass over the slotted COO
@@ -169,9 +193,127 @@ def _spd_solve_kernel(a_ref, b_ref, x_ref, aug_ref):
     x_ref[:] = aug_ref[:, :, k]
 
 
+def _row_chunks(lo: int, hi: int):
+    """[lo, hi) cut at the lane-tile boundaries: an MXU operand a chunk."""
+    while lo < hi:
+        top = min(hi, (lo // _LANE + 1) * _LANE)
+        yield lo, top
+        lo = top
+
+
+def _spd_blocked_kernel(a_ref, b_ref, x_ref, m_ref):
+    k = a_ref.shape[-1]
+    nb, t = _SPD_PANEL, _LANE
+    rows = m_ref.shape[1]
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+
+    def each_system(update):
+        # the MXU's work a system at a time: a product batched over the
+        # tile compiles to one copy a system (16 s a shape for a v5e, not
+        # 2: 17 s more set-up for the 250-feature trainer, PERF.md §6)
+        def body(s, carry):
+            update(s)
+            return carry
+
+        jax.lax.fori_loop(0, m_ref.shape[0], body, 0)
+
+    # [A | b] in two lane tiles of rows: rows past k are zeros and never
+    # pivot, column k holds b and, at the end, x
+    m_ref[...] = jnp.zeros_like(m_ref)
+    m_ref[:, :k, :k] = a_ref[:]
+    m_ref[:, :k, k:k + 1] = b_ref[:][..., None]
+    lane_ids = jax.lax.broadcasted_iota(jnp.int32, (1, 1, t), 2)
+    row_ids = jax.lax.broadcasted_iota(jnp.int32, (1, nb, 1), 1)
+    panels = range(0, k, nb)
+
+    for c0 in panels:
+        t0 = c0 // t * t  # the lane tile the panel's columns lie in
+        r1 = c0 + nb
+
+        def step(j, carry, c0=c0, t0=t0):
+            # _spd_solve_kernel's step on the panel's rows alone, over the
+            # lanes from its tile on: the multipliers reduce one vreg a
+            # system and 8 rows, not the whole block
+            blk = m_ref[:, c0:c0 + nb, t0:]
+            # the pivot row over every lane: Mosaic loads a dynamic row
+            # only from lane 0
+            row_j = m_ref[:, pl.ds(c0 + j, 1), :][..., t0:]
+            is_lane_j = lane_ids == c0 - t0 + j
+            pivot = jnp.sum(jnp.where(is_lane_j, row_j[..., :t], 0.0),
+                            axis=2, keepdims=True)
+            fac = jnp.sum(jnp.where(is_lane_j, blk[..., :t], 0.0), axis=2,
+                          keepdims=True)
+            fac = fac - (row_ids == j).astype(jnp.float32)
+            m_ref[:, c0:c0 + nb, t0:] = blk - fac * (row_j / pivot)
+            return carry
+
+        jax.lax.fori_loop(0, min(nb, k - c0), step, 0)
+
+        # the panel's columns out of every row below it, on the MXU: the
+        # panel's multipliers (its lanes of those rows) times its rows,
+        # now [0 | I | the rest]. The product runs over the whole lane
+        # tile; the lanes off the panel are masked to zero
+        def below(s, c0=c0, t0=t0, r1=r1):
+            in_panel = ((lane_ids[0] >= c0 - t0) & (lane_ids[0] < r1 - t0))
+            for lo, hi in _row_chunks(r1, rows):
+                lhs = jnp.where(in_panel, m_ref[s, lo:hi, t0:t0 + t], 0.0)
+                m_ref[s, lo:hi, t0:] = m_ref[s, lo:hi, t0:] - dot(
+                    lhs, m_ref[s, t0:t0 + t, t0:])
+
+        each_system(below)
+
+    # back substitution, a panel at a time from the last: the panel's
+    # column k less its rows times the solution below it, on the MXU. The
+    # product spans column k's lane tile; only column k is written back
+    # (rows past k are zeros)
+    c = k // t * t
+    is_col_k = lane_ids[0] == k - c
+
+    def back(s):
+        for c0 in reversed(panels[:-1]):
+            r1 = c0 + nb
+            acc = 0.0
+            for lt in range(r1 // t * t, rows, t):
+                lhs = m_ref[s, c0:r1, lt:lt + t]
+                if lt < r1:
+                    lhs = jnp.where(lane_ids[0] >= r1 - lt, lhs, 0.0)
+                acc = acc + dot(lhs, m_ref[s, lt:lt + t, c:c + t])
+            cur = m_ref[s, c0:r1, c:c + t]
+            m_ref[s, c0:r1, c:c + t] = jnp.where(is_col_k, cur - acc, cur)
+
+    each_system(back)
+    x_ref[:] = m_ref[:, :k, k]
+
+
+def _spd_blocked_call(a, b, *, tile_b: int, interpret: bool):
+    b_pad, k = b.shape
+    kw = _pad_dim(k + 1, _LANE)
+    return pl.pallas_call(
+        _spd_blocked_kernel,
+        grid=(b_pad // tile_b,),
+        in_specs=[
+            pl.BlockSpec((tile_b, k, k), lambda i: (i, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((tile_b, k), lambda i: (i, 0),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec((tile_b, k), lambda i: (i, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((b_pad, k), jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((tile_b, _SPD_BLOCKED_ROWS, kw), jnp.float32)],
+        interpret=interpret,
+    )(a, b)
+
+
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
 def _spd_solve_call(a, b, *, tile_b: int, interpret: bool):
     b_pad, k = b.shape
+    if k > _LANE:
+        # traced inside this jit, so the device trace names the blocked
+        # kernel after it too
+        return _spd_blocked_call(a, b, tile_b=tile_b, interpret=interpret)
     grid = (b_pad // tile_b,)
     return pl.pallas_call(
         _spd_solve_kernel,
@@ -205,22 +347,62 @@ def _spd_solve_call(a, b, *, tile_b: int, interpret: bool):
 # tier-1.
 _SPD_SCOPED_BUDGET_BYTES = 3 << 20
 _SPD_MAX_TILE = 256
+# Past one lane tile of features (128) the solve is blocked: its rows are
+# two lane tiles, and it eliminates a panel of _SPD_PANEL columns at a time
+# on the vector unit, the MXU taking each panel out of the rows below it.
+# A step is paced by its multipliers' lane reductions, one vreg a system
+# and 8 rows of the panel; a narrower panel means more MXU updates, each a
+# whole (tile, 128) contraction however few columns it carries. 32 was the
+# fastest on a v5e at 250 features with the MXU products batched over the
+# tile (PERF.md §6: 128 / 64 / 32 / 16 / 8 columns 94.7 / 71.9 / 66.7 /
+# 77.5 / 122 µs a tile of 8 systems); looped a system at a time, 32 and 64
+# columns run 8.63 and 8.48 µs a system.
+_SPD_PANEL = 32
+_SPD_BLOCKED_ROWS = 256
+# The blocked kernel's values are one panel's rows and one system's MXU
+# operands, so the compiler allocates little beyond its buffers: the
+# largest, the (tile_b, 256, pad128(k+1)) scratch, is held to 4 MiB — 16
+# systems a tile to 255 features, 8 at 256 — where the unblocked kernel's
+# whole-block values hold its scratch to 3. Compiled for a v5e the 16-row
+# tile at 250 features takes 14.02 MiB of the 16 MiB scoped limit; on a v5e
+# 16 rows run 8.63 µs a system where 8 rows run 9.96 (PERF.md §6). Pinned
+# against the static kernel model, with the compiler's allocation past its
+# buffers as measured (tests/test_kernel_differential.py).
+_SPD_BLOCKED_BUDGET_BYTES = 4 << 20
 
 
 def spd_tile_b(k: int) -> int:
-    """The batch-tile height the SPD kernel runs at for ``k`` features: the
+    """The batch-tile height the unblocked SPD kernel runs at for ``k``
+    features, 1 to 128 (past one lane tile the blocked kernel solves): the
     largest multiple of 8 (≤ ``_SPD_MAX_TILE``) whose augmented scratch
-    tile_b × pad8(k) × pad128(k+1) × 4 B fits the scoped-VMEM budget.
-    Below 8 the kernel does not fit and callers fall back to cholesky."""
+    tile_b × pad8(k) × pad128(k+1) × 4 B fits the scoped-VMEM budget."""
+    if not 0 < k <= _LANE:
+        raise ValueError(f"the unblocked SPD kernel solves 1-{_LANE} "
+                         f"features, not {k}")
     k_padded = _pad_dim(k, 8) * _pad_dim(k + 1, _LANE)
     return min(_SPD_MAX_TILE,
                (_SPD_SCOPED_BUDGET_BYTES // (4 * max(1, k_padded))) & ~7)
 
 
-def spd_kernel_fits(k: int) -> bool:
-    """Whether the SPD kernel runs at ``k`` features: a tile of at least 8
-    rows fits its budget (to 256 features); else XLA's cholesky solves."""
-    return spd_tile_b(k) >= 8
+def spd_blocked_tile_b(k: int) -> int:
+    """The batch-tile height the blocked SPD kernel runs at for ``k``
+    features: the largest multiple of 8 (≤ ``_SPD_MAX_TILE``) whose scratch
+    tile_b × 256 × pad128(k+1) × 4 B fits ``_SPD_BLOCKED_BUDGET_BYTES``."""
+    scratch = _SPD_BLOCKED_ROWS * _pad_dim(k + 1, _LANE)
+    return min(_SPD_MAX_TILE,
+               (_SPD_BLOCKED_BUDGET_BYTES // (4 * scratch)) & ~7)
+
+
+def spd_solve_path(k: int) -> "tuple[str, int]":
+    """How ``spd_solve_batched`` solves systems of ``k`` features, and at
+    what batch tile: ``spd_kernel`` (the unblocked kernel) to one lane tile
+    (128), ``spd_blocked`` to two (256), ``cholesky`` (XLA's, tile 0) past
+    that. The trainer counts its halves by the same answer."""
+    if k <= _LANE:
+        return "spd_kernel", spd_tile_b(k)
+    if k <= _SPD_BLOCKED_ROWS and spd_blocked_tile_b(k) >= 8:
+        return "spd_blocked", spd_blocked_tile_b(k)
+    return "cholesky", 0
 
 
 def spd_solve_batched(a, b, *, interpret: bool):
@@ -233,18 +415,17 @@ def spd_solve_batched(a, b, *, interpret: bool):
     a = jnp.asarray(a, dtype=jnp.float32)
     b = jnp.asarray(b, dtype=jnp.float32)
     n, k = b.shape
-    if not spd_kernel_fits(k):
-        # k so large (> 256 features with this budget) that even an 8-row
-        # tile risks overflowing the scoped-VMEM stack: fall back to XLA's
-        # cholesky rather than fail to compile — and say so, because the
-        # performance difference is large
+    path, tile_b = spd_solve_path(k)
+    if path == "cholesky":
+        # k past two panels (> 256 features): fall back to XLA's cholesky
+        # rather than fail to compile — and say so, because the performance
+        # difference is large
         log.warning(
             "spd_solve_batched: k=%d exceeds the VMEM tile budget; using "
             "the XLA cholesky fallback", k,
         )
         chol = jax.scipy.linalg.cholesky(a, lower=True)
         return jax.scipy.linalg.cho_solve((chol, True), b[..., None])[..., 0]
-    tile_b = spd_tile_b(k)
     n_pad = _pad_dim(max(n, 1), tile_b)
     if n_pad != n:
         eye = jnp.broadcast_to(jnp.eye(k, dtype=jnp.float32),

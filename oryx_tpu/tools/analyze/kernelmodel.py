@@ -67,6 +67,20 @@ VMEM_LIMIT_BYTES = 16 << 20
 #: compiler's allocation for that kernel measures ~4.75× its largest buffer
 #: against the 16 MiB scoped limit, so 3 MiB is the most that leaves margin.
 SCOPED_BUDGET_BYTES = 3 << 20
+#: The same discipline for the BLOCKED SPD kernel (``_spd_blocked_call``,
+#: past 128 features): its values are one panel's rows and one system's MXU
+#: operands, not whole blocks, so the compiler's allocation is its
+#: double-buffered A block and its scratch plus a little: the largest
+#: buffer, the ``(tile_b, 256, pad128(k+1))`` scratch, may take 4 MiB.
+SPD_BLOCKED_BUDGET_BYTES = 4 << 20
+#: What the compiler allocates for the blocked kernel beyond the buffers
+#: this model counts, a system of the tile, rounded up from the largest
+#: measured. Compiled for a described v5e at the trainer's 1,072 systems
+#: (PERF.md §3): 14.02 MiB at 16 rows of 250 or 255 features against
+#: the model's 12.06 (0.123 MiB a row), 9.26 against 8.31 at 16 rows of 129,
+#: 6.99 against 6.03 at 8 rows of 250, 7.40 against 7.03 at 8 rows of 256.
+#: The drift gate holds the model's footprint plus this under the limit.
+SPD_BLOCKED_SLACK_BYTES_PER_ROW = 128 << 10
 #: Resident-state budget for accumulator kernels whose output blocks stay
 #: VMEM-resident across grid steps (the gather-Gramian shape): double-
 #: buffered (k, k) accumulators + the TWO gather buffers (this slot's rows

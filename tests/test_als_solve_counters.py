@@ -1,8 +1,9 @@
 """Which solve ran each half-iteration, as the trainer counts it on the host:
-``oryx_als_solved_rows_total{side, path}`` (``spd_kernel`` / ``cholesky``)
-and ``oryx_als_spd_tile_rows{side}``. The Pallas SPD kernel runs where it is
-asked for and a tile of 8 rows fits (to 256 features); past that, or where
-it is not asked for, XLA's cholesky runs and is counted as such."""
+``oryx_als_solved_rows_total{side, path}`` (``spd_kernel`` / ``spd_blocked``
+/ ``cholesky``) and ``oryx_als_spd_tile_rows{side}``. The Pallas SPD kernel
+runs where it is asked for: unblocked to 128 features, blocked to 256;
+past that, or where it is not asked for, XLA's cholesky runs and is counted
+as such."""
 
 import jax
 import numpy as np
@@ -39,18 +40,23 @@ def _half(k, spd_kernel, side_name):
         slot_chunk=users.slot_chunk, spd_kernel=spd_kernel, side=side_name))
 
 
+_PATHS = ("spd_kernel", "spd_blocked", "cholesky")
+
+
 @pytest.mark.parametrize("k,asked,path,tile", [
     (16, True, "spd_kernel", pk.spd_tile_b(16)),
     (16, False, "cholesky", 0),
+    # past 128 features: the blocked kernel, at its own tile
+    (250, True, "spd_blocked", pk.spd_blocked_tile_b(250)),
     # past the kernel's tile budget: the cholesky, whatever was asked
     (264, True, "cholesky", 0),
-], ids=["kernel", "not_asked", "past_the_tile_budget"])
+], ids=["kernel", "not_asked", "blocked", "past_the_tile_budget"])
 def test_a_half_counts_its_rows_under_the_solve_that_ran(k, asked, path, tile):
     side = f"counted_{k}_{asked}"
-    other = "cholesky" if path == "spd_kernel" else "spd_kernel"
     users, x = _half(k, asked, side)
     rows = users.n_blocks * users.block
-    assert _rows(side, path) == rows and _rows(side, other) == 0
+    assert _rows(side, path) == rows
+    assert all(_rows(side, other) == 0 for other in _PATHS if other != path)
     assert tr._SPD_TILE_ROWS.labels(side).value == tile
     _half(k, asked, side)  # every call counts
     assert _rows(side, path) == 2 * rows
@@ -75,13 +81,24 @@ def test_an_unnamed_half_is_counted_nowhere():
 
 
 def test_the_choice_is_the_kernels_own_tile_rule():
-    assert tr._choose_spd(True, 50) == (True, 104)
-    assert tr._choose_spd(True, 250) == (True, 8) == (True, pk.spd_tile_b(250))
-    assert tr._choose_spd(True, 256) == (True, 8)
-    assert tr._choose_spd(True, 257) == (False, 0)
-    assert pk.spd_kernel_fits(256) and not pk.spd_kernel_fits(257)
+    # to 128 features: the unblocked kernel and tile as they were
+    assert tr._choose_spd(True, 50) == ("spd_kernel", 104)
+    assert tr._choose_spd(True, 128) == ("spd_kernel", pk.spd_tile_b(128))
+    assert pk.spd_tile_b(50) == 104
+    # two panels: the blocked kernel at its own tile
+    assert tr._choose_spd(True, 129) == ("spd_blocked", 16)
+    assert tr._choose_spd(True, 250) == ("spd_blocked", 16) \
+        == ("spd_blocked", pk.spd_blocked_tile_b(250))
+    assert tr._choose_spd(True, 256) == ("spd_blocked", 8)
+    assert tr._choose_spd(True, 257) == ("cholesky", 0)
     assert tr._choose_spd(False, 50).path == "cholesky"
-    assert tr._choose_spd(True, 250).path == "spd_kernel"
+    assert tr._choose_spd(False, 250).path == "cholesky"
+    assert tr._choose_spd(True, 250).path == "spd_blocked"
+    assert tr._choose_spd(True, 250).kernel
+    assert not tr._choose_spd(True, 257).kernel
+    # the host's choice is the solve's own
+    for k in (1, 50, 128, 129, 200, 250, 255, 256, 257, 300):
+        assert tr._choose_spd(True, k) == pk.spd_solve_path(k), k
 
 
 def test_als_train_counts_each_side_every_iteration():

@@ -57,8 +57,12 @@ def _spd_cases():
         b = rng.choice((1, 2, 7, 9, 33))
         cases.append((b, k))
     cases.append((209, 50))   # straddles the k=50 tile (tile_b=104)
-    cases.append((2, 256))    # the LAST kernel k: tile_b == 8
+    cases.append((2, 256))    # the LAST blocked k: tile_b == 8
     cases.append((2, 264))    # first fallback k: cholesky path
+    # the blocked kernel: its first k (one column past a lane tile) and a
+    # batch that straddles its 16-row tile at the trainer's 250 features
+    cases.append((3, 129))
+    cases.append((19, 250))
     return cases
 
 
@@ -77,12 +81,21 @@ def test_spd_differential_matches_numpy(b, k):
 
 
 def test_spd_boundary_tile_is_the_modeled_boundary():
-    """The (2, 256) case above really did run at the smallest legal tile,
-    and 264 really fell back — the fuzz matrix covers the budget boundary,
-    not just round shapes."""
+    """The (2, 256) case above really did run at the smallest legal tile
+    (the blocked kernel's, where b takes a third lane tile), and 264 really
+    fell back — the fuzz matrix covers the budget boundary, not just round
+    shapes. The unblocked kernel's tile is defined to 128 features only."""
     assert pk.spd_tile_b(50) == 104
-    assert pk.spd_tile_b(256) == 8
-    assert pk.spd_tile_b(264) < 8
+    assert pk.spd_tile_b(128) == 24
+    for k in (0, 129, 256):
+        with pytest.raises(ValueError):
+            pk.spd_tile_b(k)
+    assert pk.spd_blocked_tile_b(256) == 8
+    assert pk.spd_solve_path(50) == ("spd_kernel", 104)
+    assert pk.spd_solve_path(128) == ("spd_kernel", 24)
+    assert pk.spd_solve_path(129) == ("spd_blocked", 16)
+    assert pk.spd_solve_path(256) == ("spd_blocked", 8)
+    assert pk.spd_solve_path(264) == ("cholesky", 0)
 
 
 # ---------------------------------------------------------------------------
@@ -325,8 +338,8 @@ def test_gg_max_slots_equals_modeled_smem_budget(ops_kernel_models):
 def test_spd_tile_formula_equals_modeled_budget(ops_kernel_models):
     """``spd_tile_b``'s hand math (pad8(k)·pad128(k+1) elements against the
     scoped budget) must match the parsed model's largest-single-buffer
-    bytes — the augmented (tile_b, k, k+1) scratch — at every k, including
-    the 8-row boundary and the fallback region."""
+    bytes — the augmented (tile_b, k, k+1) scratch — at every k it solves,
+    to one lane tile (128); the blocked kernel's tile covers 129–256."""
     from oryx_tpu.tools.analyze.kernelmodel import budgets
 
     spd = ops_kernel_models["_spd_solve_call"]
@@ -340,9 +353,51 @@ def test_spd_tile_formula_equals_modeled_budget(ops_kernel_models):
                 return tb
         return 0
 
-    for k in (1, 2, 8, 13, 50, 64, 100, 127, 128, 200, 250, 256, 257, 264,
-              296, 350, 480):
+    for k in (1, 2, 8, 13, 50, 64, 100, 120, 127, 128):
         assert pk.spd_tile_b(k) == modeled_tile(k), k
+        assert pk.spd_solve_path(k) == ("spd_kernel", pk.spd_tile_b(k)), k
+
+
+def test_spd_blocked_tile_formula_equals_modeled_budget(ops_kernel_models):
+    """``spd_blocked_tile_b``'s hand math (256 · pad128(k+1) elements of
+    scratch a system against the blocked budget) must match the parsed
+    blocked call's largest buffer at every k it solves — a buffer added to
+    the kernel, or the budget moved on either side alone, fails here — and
+    the model's whole footprint at that tile (double-buffered blocks and
+    the scratch), with the compiler's measured allocation past it, must fit
+    the scoped VMEM limit."""
+    from oryx_tpu.tools.analyze.kernelmodel import (
+        SPD_BLOCKED_BUDGET_BYTES,
+        SPD_BLOCKED_SLACK_BYTES_PER_ROW,
+        budgets,
+        pad_up,
+    )
+
+    blocked = ops_kernel_models["_spd_blocked_call"]
+    limit = budgets()["vmem_limit_bytes"]
+    assert pk._SPD_BLOCKED_BUDGET_BYTES == SPD_BLOCKED_BUDGET_BYTES
+
+    def bind(tb: int, k: int) -> dict:
+        # kw is the wrapper's lane-padded scratch width, pad128(k + 1)
+        return {"tile_b": tb, "k": k, "kw": pad_up(k + 1, 128)}
+
+    def modeled_tile(k: int) -> int:
+        for tb in range(pk._SPD_MAX_TILE, 0, -8):
+            nbytes = blocked.max_buffer_bytes(bind(tb, k))
+            assert nbytes is not None, "blocked model no longer evaluates"
+            if nbytes <= SPD_BLOCKED_BUDGET_BYTES:
+                return tb
+        return 0
+
+    for k in (129, 130, 200, 249, 250, 255, 256):
+        tile = pk.spd_blocked_tile_b(k)
+        assert tile == modeled_tile(k), k
+        assert pk.spd_solve_path(k) == ("spd_blocked", tile), k
+        assert (blocked.vmem_bytes(bind(tile, k))
+                + tile * SPD_BLOCKED_SLACK_BYTES_PER_ROW) <= limit, k
+    assert pk.spd_blocked_tile_b(250) == 16 and pk.spd_blocked_tile_b(256) == 8
+    # the scratch is the largest buffer: 4 MiB at 16 rows of 250 features
+    assert blocked.max_buffer_bytes(bind(16, 250)) == SPD_BLOCKED_BUDGET_BYTES
 
 
 def test_budget_knobs_registered_and_defaults_agree():

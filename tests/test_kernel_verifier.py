@@ -542,13 +542,27 @@ def test_real_kernels_parse_with_expected_structure(kernels_project):
     from oryx_tpu.tools.analyze.kernelmodel import kernel_models
 
     models = {m.name: m for m in kernel_models(kernels_project)}
-    assert {"_spd_solve_call", "gather_gramian_accumulate", "_call"} <= set(
-        models
-    )
+    assert {"_spd_solve_call", "_spd_blocked_call",
+            "gather_gramian_accumulate", "_call"} <= set(models)
     spd = models["_spd_solve_call"]
     assert [b.space for b in spd.inputs] == ["vmem", "vmem"]
     assert len(spd.scratch) == 1 and spd.scratch[0].space == "vmem"
     assert spd.interpret == ("param", "interpret")
+    # the blocked SPD solve past 128 features: the same blocks, and one
+    # scratch of two lane tiles of rows by the lane-padded k + 1
+    blocked = models["_spd_blocked_call"]
+    assert [(b.space, b.shape) for b in blocked.inputs] == [
+        ("vmem", ("tile_b", "k", "k")), ("vmem", ("tile_b", "k")),
+    ]
+    assert [(b.space, b.shape) for b in blocked.outputs] == [
+        ("vmem", ("tile_b", "k")),
+    ]
+    assert [(b.space, b.shape) for b in blocked.scratch] == [
+        ("vmem", ("tile_b", 256, "kw")),
+    ]
+    assert blocked.grid == ("b_pad // tile_b",)
+    assert blocked.interpret == ("param", "interpret")
+    assert all(b.pipelined for b in (*blocked.inputs, *blocked.outputs))
 
     gg = models["gather_gramian_accumulate"]
     # owner rows and slot lengths, each a whole (S,) vector in SMEM
