@@ -97,6 +97,9 @@ SPD_TOL = {False: 1e-4, True: 1e-3}  # keyed by k >= 100; float32 throughout
 # 2|p||c|·2^-8 ≈ 8% of its d² on the smoke's data, independent in sign:
 # ~8%/sqrt(3000) of the total. Counts must be exact.
 KMEANS_TOL = 1e-2
+# of the largest |score|: the kernel's float32 sums of bfloat16 products
+# against float64 sums of the same operands
+LSH_SCAN_TOL = 1e-4
 HALF_ITER_TOL = 5e-2  # factors, kernels vs reference formulation, of max |x|
 # the serving scan is bfloat16 on a TPU (models/als/serving.py): both
 # operands rounded, f32 accumulation; top-10 may swap near-ties, and
@@ -218,7 +221,7 @@ def input_lines(users, items) -> list:
 
 
 def check_kernels(features: tuple, on_tpu: bool) -> dict:
-    """The three kernels — compiled on a TPU, interpreted elsewhere —
+    """The four kernels — compiled on a TPU, interpreted elsewhere —
     against numpy references at each width, f32 and bf16, with empty rows
     and pad slots. Returns the worst relative error per kernel."""
     import jax
@@ -316,6 +319,42 @@ def check_kernels(features: tuple, on_tpu: bool) -> dict:
         note("kmeans", np.abs(sums - rs).max() / np.abs(rs).max(), KMEANS_TOL,
              "sums")
         note("kmeans", abs(float(cost) - rcost) / rcost, KMEANS_TOL, "cost")
+
+        # the LSH scan of listed windows: bfloat16 windows of 128 rows,
+        # one to three queries, each entry scored for the queries its
+        # owner bits name alone, rows past a window's real ones and a
+        # padded list's repeated last window masked
+        tile, n_win, step = 128, 48, pk.LSH_WINDOWS_A_STEP
+        mat = rng.standard_normal((n_win * tile, k)).astype(np.float32)
+        mat_j = jnp.asarray(mat).astype(jnp.bfloat16)
+        mat_ref = np.asarray(mat_j.astype(jnp.float32), dtype=np.float64)
+        listed, real = 4 * step, 4 * step - 3
+        windows = rng.choice(n_win, listed, replace=False).astype(np.int32)
+        windows[real:] = windows[real - 1]
+        win_valid = rng.integers(0, tile + 1, n_win).astype(np.int32)
+        for b in (1, 2, 3):
+            qs = rng.standard_normal((b, k)).astype(np.float32)
+            qs_j = jnp.asarray(qs).astype(jnp.bfloat16)
+            owners = rng.integers(1, 1 << b, listed).astype(np.int32)
+            owners[real:] = 0
+            got = np.asarray(pk.lsh_listed_scores(
+                mat_j, qs_j, jnp.asarray(windows << b | owners),
+                jnp.asarray(win_valid), tile=tile, interpret=interpret))
+            want = np.full((b, listed * tile), -np.inf)
+            q_ref = np.asarray(qs_j.astype(jnp.float32), dtype=np.float64)
+            for j in range(real):
+                win = windows[j]
+                rows = mat_ref[win * tile:win * tile + win_valid[win]]
+                for q in range(b):
+                    if owners[j] >> q & 1:
+                        want[q, j * tile:j * tile + len(rows)] = (
+                            rows @ q_ref[q])
+            if not np.array_equal(np.isneginf(got), np.isneginf(want)):
+                raise SmokeFailure(f"lsh_scan k={k} b={b}: masked positions "
+                                   "differ")
+            fin = np.isfinite(want)
+            note("lsh_scan", np.abs(got[fin] - want[fin]).max()
+                 / np.abs(want[fin]).max(), LSH_SCAN_TOL, f"b={b}")
     return {name: round(err, 7) for name, err in sorted(worst.items())}
 
 

@@ -40,7 +40,7 @@ from oryx_tpu.common import profiling
 from oryx_tpu.common import spans
 from oryx_tpu.models.als import ivf as ivf_mod
 from oryx_tpu.models.als import pmml_codec
-from oryx_tpu.models.als.lsh import LocalitySensitiveHash
+from oryx_tpu.models.als.lsh import BucketLayout, LocalitySensitiveHash
 from oryx_tpu.models.als.rescorer import load_rescorer_providers
 from oryx_tpu.models.als.known import KnownItems
 from oryx_tpu.models.als.topn import (_OVERFETCH_ROOM, _ArenaSnapshot,
@@ -52,6 +52,7 @@ from oryx_tpu.models.als.vectors import FeatureVectorStore
 from oryx_tpu.parallel.mesh import (put_row_sharded, replicated_sharding,
                                     row_sharding)
 from oryx_tpu.common.lockutils import RateLimitCheck
+from oryx_tpu.ops import pallas_kernels
 from oryx_tpu.ops.solver import SolverCache
 
 log = logging.getLogger(__name__)
@@ -85,6 +86,18 @@ _EXCLUSION_OVERFLOW = metrics_mod.default_registry().counter(
     "oryx_serving_exclusion_overflow_total",
     "Queries whose exclusions were longer than the widest warmed over-fetch "
     "room",
+)
+
+_LSH_SCANNED_ROWS = metrics_mod.default_registry().counter(
+    "oryx_serving_lsh_scanned_rows_total",
+    "Rows of the bucket-ordered scoring copy the device read for LSH "
+    "flushes, bucket padding included",
+)
+_LSH_CANDIDATE_BUCKETS = metrics_mod.default_registry().counter(
+    "oryx_serving_lsh_candidate_buckets_total",
+    "Candidate buckets of LSH flushes of a width that lists its windows: "
+    "the union of the flush's queries' balls (a bucket two balls share "
+    "counts once)",
 )
 
 
@@ -162,12 +175,115 @@ def _top_k_of_scores(scores, k: int):
     return jax.lax.approx_max_k(scores, k, recall_target=0.99)
 
 
-@functools.partial(jax.jit, static_argnames=("k",))
-def _top_k_dot_batch_masked(mat, qs, lut, buckets, k: int):
-    scores = _score(qs, mat)  # (B, n)
-    valid = jnp.take_along_axis(lut, buckets[None, :], axis=1)  # (B, n)
-    scores = jnp.where(valid, scores, -jnp.inf)
-    return jax.lax.approx_max_k(scores, k, recall_target=0.99)
+# -- LSH on one chip: the bucket-ordered scan -------------------------------
+# The scoring copy of Y is held in bucket order (lsh.BucketLayout): every
+# bucket's rows in whole windows. A flush of a few queries reads only the
+# windows of the union of their candidate buckets (the Pallas kernel, in
+# place), each query masked to its own; a wider flush, or a union past its
+# width's list, reads every window once in the fused matmul + top-k, each
+# position masked by its bucket's distance from each query's hash. Both
+# return item rows, as many as the flush's width (over-fetch included).
+
+#: Widest flush that lists its windows. Four queries' union at 0.3 holds
+#: ~3/4 of the buckets: listing it saved the chip a third of a full pass,
+#: and cost the flush's host stage more than that (PERF.md).
+_LSH_LIST_WIDEST = 2
+
+#: The THRESHOLD, in quarters of a layout's windows: no list is longer than
+#: 3/4 of them, and a flush whose union lists more takes the full pass. A
+#: listed row costs its bytes, its B float32 scores written and read back
+#: by the top-k (≤ 8 B against a 512 B row at B ≤ 2) and its share of a
+#: grid step (8 windows a step); the full pass reads every row and its
+#: bucket once, fused, with nothing written. Listing 3/4 still leaves a
+#: fifth of a pass for the steps' overhead and the list's padding (its last
+#: window repeated, which is not fetched again).
+_LSH_LIST_QUARTERS = 3
+
+
+class _Listed(tuple):
+    """A flush that lists its windows (``lsh.BucketLayout.windows_of``: a
+    window and its owners an entry), padded to its width's length."""
+
+    def __new__(cls, entries):
+        return super().__new__(cls, (entries,))
+
+
+class _Full(tuple):
+    """A full pass: the flush's query hashes."""
+
+    def __new__(cls, codes):
+        return super().__new__(cls, (codes,))
+
+
+@functools.partial(jax.jit, static_argnames=("k", "tile", "scan"))
+def _lsh_listed_top_k(mat, pos2row, win_valid, qs, entries, k: int,
+                      tile: int, scan: str):
+    """Top-k of the listed windows, as item rows. ``scan``: ``kernel`` (on
+    a TPU: ``pallas_kernels.lsh_listed_scores``), ``interpret`` (the same
+    kernel interpreted) or ``xla`` (elsewhere: its gathered formulation)."""
+    q = qs.astype(mat.dtype)
+    if scan == "xla":
+        scores = pallas_kernels.lsh_listed_scores_xla(
+            mat, q, entries, win_valid, tile=tile)
+    else:
+        scores = pallas_kernels.lsh_listed_scores(
+            mat, q, entries, win_valid, tile=tile,
+            interpret=scan == "interpret")
+    vals, idx = jax.lax.approx_max_k(scores, k, recall_target=0.99)
+    windows = entries[idx // tile] >> qs.shape[0]
+    return vals, pos2row[windows * tile + idx % tile]
+
+
+def _lsh_masked_scores(mat, pos_code, qs, codes, radius: int):
+    """(B, P) scores of every position, −∞ where its bucket lies more than
+    ``radius`` bits from the query's hash (padding: ``lsh.PAD_CODE``). The
+    mask is elementwise on two broadcast vectors, so XLA keeps it inside
+    the matmul's fusion with the top-k behind it."""
+    scores = _score(qs, mat)
+    near = jax.lax.population_count(codes[:, None] ^ pos_code[None, :])
+    return jnp.where(near <= radius, scores, -jnp.inf)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "radius"))
+def _lsh_full_top_k(mat, pos_code, pos2row, qs, codes, k: int, radius: int):
+    vals, pos = _top_k_of_scores(
+        _lsh_masked_scores(mat, pos_code, qs, codes, radius), k)
+    return vals, pos2row[pos]
+
+
+_lsh_single_scores = jax.jit(_lsh_masked_scores, static_argnames=("radius",))
+
+
+@jax.jit
+def _sign_codes(mat, hyperplanes):
+    """Each row's LSH bucket on the device: float32 projections onto the
+    hyperplanes, sign bits, first hyperplane the highest bit (as
+    ``LocalitySensitiveHash.assign_buckets``)."""
+    proj = jnp.matmul(mat, hyperplanes.T, precision=jax.lax.Precision.HIGHEST)
+    h = hyperplanes.shape[0]
+    weights = jnp.left_shift(1, jnp.arange(h - 1, -1, -1, dtype=jnp.int32))
+    return jnp.sum(jnp.where(proj > 0, weights, 0), axis=1).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("dtype",))
+def _rows_at(mat, rows, dtype):
+    """``mat[rows]`` in ``dtype``, a zero row where ``rows`` is -1
+    (padding): one fusion, so no float32 copy of the rows is made."""
+    got = jnp.take(mat, jnp.maximum(rows, 0), axis=0)
+    return jnp.where(rows[:, None] >= 0, got, 0).astype(dtype)
+
+
+@jax.jit
+def _lsh_moved(score_mat, pos2row, pos_code, mat, positions, rows, codes):
+    """The bucket-ordered arrays with ``positions`` set to what the moved
+    layout holds there (``rows``, ``codes``; -1 and ``lsh.PAD_CODE`` on
+    padding): the changed rows' scores from the float32 rows, nothing else
+    read from the host. ``positions`` comes padded to a power of two by
+    repeating its first entry, so a few shapes serve every step."""
+    return (score_mat.at[positions].set(
+                _rows_at(mat, rows, score_mat.dtype)),
+            pos2row.at[positions].set(rows),
+            pos_code.at[positions].set(codes))
 
 
 @functools.lru_cache(maxsize=64)
@@ -329,15 +445,15 @@ def _dispatch(snap, qs_host: np.ndarray, width, register: bool):
     Returns ``(vals, idx)`` still on the device, their copy to the host
     already asked for: what the host does before :func:`_download` runs
     under the scan."""
+    # a view that lists what a flush reads chooses it here (LSH on one
+    # chip: ``topn.lsh_select``); every other view has nothing to choose
+    sel = snap.select(qs_host, width)
     with spans.stage("topn.upload"):
         # batch-shaped operands go from the host straight to where the view
         # lies (on a mesh to every device at once, not to one device and on
         # from there inside the dispatch)
         qs = snap.place(qs_host)
-        # per-query LSH candidate masks: (B, num_buckets) lookup table
-        # indexed by item bucket on device, fully vectorized over the batch
-        lut = (snap.place(snap.lsh.get_candidate_lut(qs_host))
-               if snap.lsh is not None else None)
+        lut = snap.batch_operands(qs_host, sel)
     with spans.stage("topn.dispatch") as stage:
         # a recorded stage names the programs it launched as the profiler
         # names their modules, in launch order: a capture's device events
@@ -525,32 +641,6 @@ class _YSnapshot(_Snapshot):
         return (jnp.linalg.norm(mat, axis=1),
                 mat if score_dtype is None else mat.astype(score_dtype))
 
-    def _assign_buckets(self, prev, delta):
-        """(n_rows,) LSH bucket of every row."""
-        mat, lsh = self.mat, self.lsh
-        if prev is None or delta is None or prev.buckets is None:
-            return self._place_buckets(
-                lsh.assign_buckets(np.asarray(mat)[:self.n]))
-        # rehash only the delta: pull changed/new rows (not the whole
-        # matrix) to host for bucket assignment
-        return self._rehash(prev, *delta)
-
-    def _rehash(self, prev, ch, n_new):
-        mat, lsh, buckets = self.mat, self.lsh, prev.buckets
-        if len(ch):
-            ch_j = jnp.asarray(ch, dtype=jnp.int32)
-            new_b = jnp.asarray(lsh.assign_buckets(np.asarray(mat[ch_j])))
-            buckets = buckets.at[ch_j].set(new_b)
-        if n_new:
-            tail = np.asarray(mat[len(prev.ids):])
-            buckets = jnp.concatenate(
-                [buckets, jnp.asarray(lsh.assign_buckets(tail))]
-            )
-        return buckets
-
-    def _place_buckets(self, host: np.ndarray):
-        return jnp.asarray(host)
-
     def device_arrays(self) -> list:
         """The distinct arrays this snapshot holds on device (the scoring
         copy only where it is not the float32 matrix itself)."""
@@ -569,28 +659,21 @@ class _YSnapshot(_Snapshot):
         return self.n if self.mat is None else int(self.mat.shape[0])
 
     def batch_width(self, how_many: int, filtering: bool, room: int = 0):
-        # host filters and LSH masks consume candidates: ask for more
-        wide = filtering or self.lsh is not None
+        # host filters consume candidates: ask for more
         return min(self.n, _round_up_pow2(
-            (max(2 * how_many, 64) if wide else max(how_many, 16)) + room)
-        ), room
+            (max(2 * how_many, 64) if filtering else max(how_many, 16))
+            + room)), room
 
     def plan(self, qs, lut, width):
         k, room = width
-        key = _topn_cost_key(qs.shape[0], room)
-        if lut is not None:
-            return ((_top_k_dot_batch_masked,
-                     (self.score_mat, qs, lut, self.buckets, k), key),)
-        return ((_top_k_dot_batch, (self.score_mat, qs, None, k), key),)
+        return ((_top_k_dot_batch, (self.score_mat, qs, None, k),
+                 _topn_cost_key(qs.shape[0], room)),)
 
     def candidates(self, scan, q_host: np.ndarray, want: int, hooks: bool):
         # score once; widenings re-run only the top-k over the cached
-        # scores. The unfiltered hot path stays exactly matmul + top_k:
-        # the mask is None (static) unless LSH actually applies
-        valid = (_candidate_mask(self, q_host[None, :], self.n_rows)
-                 if self.lsh is not None else None)
+        # scores. The unfiltered hot path stays exactly matmul + top_k
         scores = _masked_scores(
-            self.score_mat, jnp.asarray(q_host[None, :]), valid)
+            self.score_mat, jnp.asarray(q_host[None, :]), None)
         return _widen_top_k(
             self, scores, _round_up_pow2(max(4 * want, 64)), q_host)
 
@@ -602,6 +685,211 @@ class _YSnapshot(_Snapshot):
             vals, idx = _top_k_cosine_sum(
                 self.mat, self.norms, qs, q_norms, valid, k)
             yield np.asarray(vals), np.asarray(idx)
+
+
+class _LSHYSnapshot(_YSnapshot):
+    """The float view on one chip under LSH (``sample-rate`` < 1): the
+    float32 ``mat``, its norms and each row's bucket (``buckets``) stay in
+    id order for the exact paths, and the scoring copy is held in BUCKET
+    order (``lsh.BucketLayout``) in its place — ``score_mat`` (P, k):
+    every bucket's rows in whole windows of ``tile``, zero on padding;
+    ``pos2row`` maps a position to its row (-1 on padding), ``pos_code``
+    gives its bucket, ``win_valid`` each window's real rows. A flush
+    answers each query from the buckets within ``max_bits_differing`` of
+    its hash and no others (``select`` → ``plan``).
+
+    The build hashes every row on the device (float32 projections, as
+    ``assign_buckets`` defines the hash). An incremental step rehashes only
+    the changed and appended rows on the host and places them again in the
+    layout (``BucketLayout.moved``): a row whose bucket did not change keeps
+    its position, one whose bucket changed takes a free position of its new
+    bucket, and only the positions that changed are written on the device —
+    so a row whose bucket changed is scored in its new bucket from this
+    snapshot on, and in no other. Where its new bucket has no free position
+    the layout is built again. Either is the span ``snapshot.lsh_order``."""
+
+    def __init__(self, ids, mat, lsh, prev=None, delta=None,
+                 device_dtype: str = "auto"):
+        self.layout = None
+        self._codes = None
+        super().__init__(ids, mat, lsh, prev=prev, delta=delta,
+                         device_dtype=device_dtype)
+        if mat is None or self.buckets is None:
+            return
+        with spans.span("snapshot.lsh_order",
+                        attributes={"rows": self.n}) as sp:
+            moved = (prev.layout.moved(self._codes, self._changed)
+                     if delta is not None and prev.layout is not None
+                     else None)
+            sp.set_attribute("incremental", moved is not None)
+            if moved is None:
+                self.layout = layout = BucketLayout(lsh, self._codes)
+                self.pos2row = jnp.asarray(layout.pos2row)
+                self.score_mat = _rows_at(mat, self.pos2row,
+                                          self._score_dtype)
+                self.pos_code = jnp.asarray(layout.pos_code)
+            else:
+                layout, positions = moved
+                self.layout = layout
+                sp.set_attribute("moved_positions", len(positions))
+                arrays = prev.score_mat, prev.pos2row, prev.pos_code
+                if len(positions):
+                    at = np.full(_round_up_pow2(max(16, len(positions))),
+                                 positions[0], dtype=np.int32)
+                    at[:len(positions)] = positions
+                    arrays = _lsh_moved(
+                        *arrays, mat, jnp.asarray(at),
+                        jnp.asarray(layout.pos2row[at]),
+                        jnp.asarray(layout.pos_code[at]))
+                self.score_mat, self.pos2row, self.pos_code = arrays
+            self.win_valid = jnp.asarray(layout.win_valid)
+            self.score_mat.block_until_ready()
+            sp.set_attribute("positions", layout.n_positions)
+            sp.set_attribute("tile", layout.tile)
+        # how the listed windows are scanned (``_lsh_listed_top_k``)
+        self._listing = ("kernel" if pallas_kernels.on_tpu(self.score_mat)
+                         else "xla")
+        # one list length a width that lists: as many balls as queries,
+        # each of an even share of the rows with three standard deviations
+        # of room, in windows, plus a window a bucket for its padding — in
+        # whole grid steps, at most the threshold. It follows from the
+        # layout's shape alone, so a compiled program serves every layout
+        # of as many rows and every step that moves rows in it; a union
+        # longer (hashes far from even) takes the full pass, warmed where
+        # the balls the layout holds may need it
+        step = pallas_kernels.LSH_WINDOWS_A_STEP
+        limit = (_LSH_LIST_QUARTERS * layout.n_windows // 4) // step * step
+        ball = len(lsh.ball())
+        rows = ((layout.n_windows - lsh.num_buckets) * layout.tile * ball
+                / lsh.num_buckets)
+        one = -(-int(rows + 3 * rows ** 0.5) // layout.tile) + ball
+        self._most = int(layout.ball_windows.max())
+        self._lengths = {}
+        for b in range(1, _LSH_LIST_WIDEST + 1):
+            length = min(limit, pallas_kernels._pad_dim(b * one, step))
+            if length and pallas_kernels.lsh_lists(length, layout.n_windows):
+                self._lengths[b] = length
+
+    def _derive(self, mat, score_dtype):
+        # no id-ordered scoring copy: the bucket-ordered one takes its place
+        self._score_dtype = mat.dtype if score_dtype is None else score_dtype
+        return jnp.linalg.norm(mat, axis=1), None
+
+    def _assign_buckets(self, prev, delta):
+        if prev is None or delta is None or prev.buckets is None:
+            # one chip: the matrix holds exactly the n rows
+            codes = _sign_codes(self.mat, jnp.asarray(self.lsh.hyperplanes))
+            self._codes = np.asarray(codes)
+            return codes
+        return self._rehash(prev, *delta)
+
+    def _rehash(self, prev, ch, n_new):
+        # the host keeps every row's bucket for the layout: the changed and
+        # appended rows alone come to the host, and go back to the
+        # id-ordered bucket vector on the device
+        ch = np.asarray(ch, dtype=np.int32)
+        n_old = len(prev._codes)
+        self._changed = np.concatenate(
+            [ch, np.arange(n_old, n_old + n_new, dtype=np.int32)])
+        codes = np.concatenate(
+            [prev._codes, np.zeros(n_new, dtype=np.int32)])
+        buckets = prev.buckets
+        if len(ch):
+            codes[ch] = self.lsh.assign_buckets(
+                np.asarray(self.mat[jnp.asarray(ch)]))
+            buckets = buckets.at[jnp.asarray(ch)].set(jnp.asarray(codes[ch]))
+        if n_new:
+            codes[n_old:] = self.lsh.assign_buckets(
+                np.asarray(self.mat[n_old:self.n]))
+            buckets = jnp.concatenate(
+                [buckets, jnp.asarray(codes[n_old:])])
+        self._codes = codes
+        return buckets
+
+    def device_arrays(self) -> list:
+        layout = [getattr(self, name, None)
+                  for name in ("pos2row", "pos_code", "win_valid")]
+        return [a for a in (self.mat, self.norms, self.buckets,
+                            self.score_mat, *layout) if a is not None]
+
+    def select(self, qs_host: np.ndarray, width):
+        """The flush's host stage under LSH, span ``topn.lsh_select``
+        (``buckets``: the union of its queries' balls, where it listed
+        them; ``rows``: what the device will read, padding included;
+        ``path``): the queries' hashes and, for a flush of a width that
+        lists, the union's windows, each over its owners' bits
+        (``BucketLayout.windows_of``), padded by repeating the last window,
+        with no owner, to the width's length — or the full pass where the
+        union is longer."""
+        layout = self.layout
+        with spans.stage("topn.lsh_select") as sp:
+            codes = self.lsh.assign_buckets(qs_host)
+            length = self._lengths.get(len(qs_host))
+            sel = None
+            if length is not None:
+                entries, buckets = layout.windows_of(codes)
+                _LSH_CANDIDATE_BUCKETS.inc(buckets)
+                sp.set_attribute("buckets", buckets)
+                if len(entries) <= length:
+                    rows = len(entries) * layout.tile
+                    # the last window again, with no owner
+                    width = len(codes)
+                    padded = np.full(length, entries[-1] >> width << width
+                                     if len(entries) else 0, dtype=np.int32)
+                    padded[:len(entries)] = entries
+                    sel = _Listed(padded)
+            if sel is None:
+                rows = layout.n_positions
+                sel = _Full(codes)
+            _LSH_SCANNED_ROWS.inc(rows)
+            sp.set_attribute("rows", rows)
+            sp.set_attribute("path", type(sel).__name__[1:].lower())
+        return sel
+
+    def batch_operands(self, qs_host: np.ndarray, sel):
+        return type(sel)(*map(self.place, sel))
+
+    def selections(self, batch: int, width):
+        """The list of a width that lists, and the full pass where a union
+        may pass the list — where its balls together may fill more windows
+        (a moved layout keeps its buckets' windows, so its balls' too): the
+        closed set a flush of ``batch`` can bring."""
+        codes = self.struct((batch,), jnp.int32)
+        length = self._lengths.get(batch)
+        out = [] if length is None else [
+            _Listed(self.struct((length,), jnp.int32))]
+        if length is None or length < batch * self._most:
+            out.append(_Full(codes))
+        return out
+
+    def plan(self, qs, lut, width):
+        k, room = width
+        key = _topn_cost_key(qs.shape[0], room)
+        if type(lut) is _Listed:
+            entries, = lut
+            listed, tile = entries.shape[0], self.layout.tile
+            return ((_lsh_listed_top_k,
+                     (self.score_mat, self.pos2row, self.win_valid, qs,
+                      entries, min(k, listed * tile), tile,
+                      self._listing),
+                     key + f"+lsh{listed}"),)
+        codes, = lut
+        return ((_lsh_full_top_k,
+                 (self.score_mat, self.pos_code, self.pos2row, qs, codes,
+                  min(k, self.layout.n_positions),
+                  self.lsh.max_bits_differing),
+                 key + "+lsh"),)
+
+    def candidates(self, scan, q_host: np.ndarray, want: int, hooks: bool):
+        # one masked pass over the bucket-ordered copy; widenings re-run
+        # only the top-k over its scores, positions mapped back to rows
+        scores = _lsh_single_scores(
+            self.score_mat, self.pos_code, jnp.asarray(q_host[None, :]),
+            jnp.asarray(self.lsh.assign_buckets(q_host[None, :])),
+            self.lsh.max_bits_differing)
+        for k in _doubling(_round_up_pow2(max(4 * want, 64)), self.n):
+            vals, pos = _top_k_of_scores(scores, k)
+            yield np.asarray(vals)[0], self.layout.pos2row[np.asarray(pos)[0]]
 
 
 class _ShardedYSnapshot(_YSnapshot):
@@ -628,6 +916,16 @@ class _ShardedYSnapshot(_YSnapshot):
             score_dtype,
         )(mat)
         return norms, mat if score is None else score
+
+    def _assign_buckets(self, prev, delta):
+        """(n_rows,) LSH bucket of every row."""
+        mat, lsh = self.mat, self.lsh
+        if prev is None or delta is None or prev.buckets is None:
+            return self._place_buckets(
+                lsh.assign_buckets(np.asarray(mat)[:self.n]))
+        # rehash only the delta: pull changed/new rows (not the whole
+        # matrix) to host for bucket assignment
+        return self._rehash(prev, *delta)
 
     def _rehash(self, prev, ch, n_new):
         # the bucket vector (4 B a row) makes the round trip; Y does not
@@ -900,11 +1198,11 @@ class ALSServingModel(ServingModel):
         self.rescore_factor = max(1.0, float(rescore_factor))
         self.mesh = mesh
         self.shard_axis = shard_axis
+        self.lsh = LocalitySensitiveHash(sample_rate, features) if sample_rate < 1.0 else None
         self.x = FeatureVectorStore()
         # under a mesh the store hands every device its own row block of Y
         # (int8 / IVF snapshots never use the store's device matrix)
         self.y = FeatureVectorStore(mesh=mesh, shard_axis=shard_axis)
-        self.lsh = LocalitySensitiveHash(sample_rate, features) if sample_rate < 1.0 else None
         self.known = KnownItems()
         self.expected_user_ids: set[str] = set()
         self.expected_item_ids: set[str] = set()
@@ -923,6 +1221,8 @@ class ALSServingModel(ServingModel):
         elif mesh is not None:
             backend, options = _ShardedYSnapshot, dict(
                 mesh=mesh, shard_axis=shard_axis, device_dtype=device_dtype)
+        elif self.lsh is not None and self.lsh.num_hashes:
+            backend, options = _LSHYSnapshot, dict(device_dtype=device_dtype)
         else:
             backend, options = _YSnapshot, dict(device_dtype=device_dtype)
         self._source = backend.source
@@ -1215,17 +1515,18 @@ class ALSServingModel(ServingModel):
         if not snap.servable:
             raise ValueError("no item factors to warm against yet")
         qs = snap.struct((batch_size, self.features), jnp.float32)
-        lut = (snap.struct((batch_size, snap.lsh.num_buckets), jnp.bool_)
-               if snap.lsh is not None else None)
         compiled = set()
         for room in _OVERFETCH_ROOM:
             width = snap.batch_width(how_many, False, room)
-            for fn, args, cost_key in snap.plan(qs, lut, width):
-                if cost_key in compiled:
-                    continue  # a step the widths share
-                compiled.add(cost_key)
-                compilecache.aot_compile(
-                    fn, *_operands(args), cost_key=cost_key)
+            # every selection a flush of this width can make (one, but for
+            # the lengths a listing view warms: topn._Snapshot.selections)
+            for lut in snap.selections(batch_size, width):
+                for fn, args, cost_key in snap.plan(qs, lut, width):
+                    if cost_key in compiled:
+                        continue  # a step the widths share
+                    compiled.add(cost_key)
+                    compilecache.aot_compile(
+                        fn, *_operands(args), cost_key=cost_key)
         # marked attempted: the lazy first-use registration in _dispatch would
         # otherwise re-lower and re-compile each signature the ladder just
         # registered — once per signature per generation, during the

@@ -275,6 +275,13 @@ class _Snapshot:
       ``jax.ShapeDtypeStruct``s (the warm ladder compiles them): the ladder
       compiles exactly what the flush dispatches. A later step takes the
       result of the one before it where its operands hold a :class:`_Fed`.
+    * ``select(qs_host, width)`` → the flush's host-side choice of what the
+      device reads (the view that lists its windows under LSH: its own
+      stage); ``batch_operands(qs_host, selection)`` → the per-batch
+      operands ``plan`` takes as ``lut``, on the device: None, the per-query
+      LSH lookup table of a view that masks every row, or the placed
+      selection; ``selections(batch, width)`` → every such operand a batch
+      can bring, as shapes, for the warm ladder.
     * ``dispatched(batch, width)``: called after a scan's last step is on
       its way — a backend's own counters.
     * ``place(host)`` / ``struct(shape, dtype)``: a batch-shaped operand on
@@ -301,6 +308,22 @@ class _Snapshot:
 
     def dispatched(self, batch: int, width) -> None:
         pass
+
+    def select(self, qs_host: np.ndarray, width):
+        return None
+
+    def batch_operands(self, qs_host: np.ndarray, selection):
+        """Per-query LSH candidate masks where the view masks every row by
+        its bucket: a (B, num_buckets) lookup table, indexed by each row's
+        bucket on the device."""
+        if self.lsh is None:
+            return None
+        return self.place(self.lsh.get_candidate_lut(qs_host))
+
+    def selections(self, batch: int, width) -> list:
+        if self.lsh is None:
+            return [None]
+        return [self.struct((batch, self.lsh.num_buckets), jnp.bool_)]
 
     def __init__(self, ids, scanned, lsh=None, prev=None,
                  incremental: bool = False):

@@ -119,6 +119,27 @@ Outputs revisit the same block every grid step (constant index map), the
 standard Pallas accumulation pattern: initialized at step 0 with ``pl.when``,
 accumulated thereafter.
 
+``lsh_listed_scores`` is the serving scan under LSH (``sample-rate`` < 1)
+on one chip: the scoring copy of Y is held in bucket order, each bucket in
+whole windows of ``tile`` rows (models/als/lsh.py:BucketLayout), and a
+flush reads the windows of the union of its queries' candidate buckets,
+each once, in place — no gathered copy of the rows, no ``(B, n)`` mask:
+
+  grid = listed windows / 8; step j:  the 8 entries ``entries[8j..8j+7]``
+                                      (scalar-prefetched: a window index
+                                      over B owner bits; a BlockSpec each,
+                                      so the pipeline fetches step j+1's
+                                      windows while step j computes)
+                                      scores (B, tile) a window     (MXU)
+                                      −∞ but for the queries whose bits
+                                      the entry carries, on the window's
+                                      real rows (the prefetched
+                                      ``win_valid``)                (VPU)
+
+The list is padded to a warmed length by repeating its last window with
+no owner: a block index that does not change is not fetched again. The
+top-k over the ``(B, listed × tile)`` scores is XLA's, after it.
+
 Every wrapper here takes ``interpret`` as a REQUIRED argument: the caller
 knows where its operands live (``on_tpu``) and passes ``not on_tpu``, so no
 kernel can be emulated on the chip — or compiled for a CPU — because a
@@ -664,6 +685,112 @@ def gather_gramian_accumulate(y, srow, slens, scols, w, coef, *, block: int,
         interpret=interpret,
     )(srow.reshape(s), slens.reshape(s), scols3, scols3, wc, y3, a0, b0)
     return big_a, big_b[:, 0, :]
+
+
+#: Windows a grid step of ``lsh_listed_scores`` reads: 8 × 128 rows of
+#: 250 bfloat16 features (256 lanes as the chip tiles them) is 512 KiB a
+#: step, over the ~0.35 µs a grid step costs on its own.
+LSH_WINDOWS_A_STEP = 8
+#: The int32 words ``lsh_listed_scores`` may prefetch — the list and every
+#: window's real rows — which ride in SMEM whole: the share of it
+#: prefetched operands may take (kernelmodel.SMEM_PREFETCH_BUDGET_BYTES;
+#: tests/test_kernel_differential.py holds the two equal). At 5M rows:
+#: 47,255 windows and a list of at most 27,560.
+LSH_PREFETCH_WORDS = 3 << 16
+
+
+def lsh_lists(listed: int, n_windows: int) -> bool:
+    """Whether a list of ``listed`` windows of a layout of ``n_windows``
+    fits one call's prefetched words."""
+    return listed + n_windows <= LSH_PREFETCH_WORDS
+
+
+def _make_lsh_scan_kernel(group: int, tile: int):
+    def kernel(entries_ref, valid_ref, qs_ref, *rest):
+        rows_refs, out_ref = rest[:group], rest[group]
+        j = pl.program_id(0)
+        q = qs_ref[...]
+        b = q.shape[0]
+        query = jax.lax.broadcasted_iota(jnp.int32, (b, tile), 0)
+        row = jax.lax.broadcasted_iota(jnp.int32, (b, tile), 1)
+        for g in range(group):
+            entry = entries_ref[j * group + g]
+            scores = jax.lax.dot_general(
+                q, rows_refs[g][...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            # the queries whose bits the entry carries, on its real rows
+            keep = (((entry >> query) & 1) == 1) & (
+                row < valid_ref[entry >> b])
+            out_ref[:, g * tile:(g + 1) * tile] = jnp.where(
+                keep, scores, -jnp.inf)
+
+    return kernel
+
+
+def lsh_listed_scores(mat, qs, entries, win_valid, *, tile: int,
+                      interpret: bool):
+    """Masked scores of the listed windows of a bucket-ordered layout.
+
+    Args:
+      mat: (P, k) the bucket-ordered scoring copy, ``P`` windows of
+        ``tile`` rows.
+      qs: (B, k) the queries in ``mat``'s dtype; every window index
+        shifted left by B fits an int32.
+      entries: (L,) int32, ``L`` a multiple of ``LSH_WINDOWS_A_STEP``:
+        entry j is a window index shifted left by B, bit b below it set
+        where query b may be answered from that window (none set on the
+        list's padding).
+      win_valid: (P / tile,) int32 the real rows at the front of each
+        window.
+
+    Returns (B, L × tile) float32: ``scores[b, j × tile + r]`` is query b's
+    dot product with row r of entry j's window (float32 accumulation), −∞
+    where entry j's bit b is clear or r is not a real row.
+    """
+    b, k = qs.shape
+    listed = entries.shape[0]
+    n_windows = win_valid.shape[0]
+    group = LSH_WINDOWS_A_STEP
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        # both ride in SMEM whole: lsh_lists says what fits
+        num_scalar_prefetch=2,
+        grid=(listed // group,),
+        in_specs=[pl.BlockSpec((b, k), lambda j, e, v: (0, 0),
+                               memory_space=pltpu.VMEM)] + [
+            # a step's windows, one block each: the pipeline fetches the
+            # next step's 8 while this step's are scored
+            pl.BlockSpec((tile, k),
+                         lambda j, e, v, g=g: (e[j * group + g] >> b, 0),
+                         memory_space=pltpu.VMEM)
+            for g in range(group)],
+        out_specs=pl.BlockSpec((b, group * tile), lambda j, e, v: (0, j),
+                               memory_space=pltpu.VMEM),
+    )
+    return pl.pallas_call(
+        _make_lsh_scan_kernel(group, tile),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, listed * tile), jnp.float32),
+        interpret=interpret,
+        name="lsh_listed_scores",
+    )(entries.reshape(listed), win_valid.reshape(n_windows), qs,
+      *([mat] * group))
+
+
+def lsh_listed_scores_xla(mat, qs, entries, win_valid, *, tile: int):
+    """``lsh_listed_scores`` by XLA: the listed windows' rows gathered into
+    a copy, then one matmul and the same masks. What runs off a TPU, where
+    the interpreted kernel takes ~20 ms a grid step; on the chip it would
+    write the copy the kernel exists to avoid."""
+    b = qs.shape[0]
+    windows = entries >> b
+    rows = (windows[:, None] * tile + jnp.arange(tile)[None, :]).reshape(-1)
+    scores = jnp.matmul(qs, jnp.take(mat, rows, axis=0).T,
+                        preferred_element_type=jnp.float32)
+    mine = (entries[None, :] >> jnp.arange(b)[:, None]) & 1
+    keep = ((jnp.repeat(mine, tile, axis=1) == 1)
+            & (jnp.tile(jnp.arange(tile), entries.shape[0])
+               < jnp.repeat(win_valid[windows], tile))[None, :])
+    return jnp.where(keep, scores, -jnp.inf)
 
 
 def _kernel(points_ref, weights_ref, centers_ref, sums_ref, counts_ref, cost_ref):

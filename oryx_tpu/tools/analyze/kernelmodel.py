@@ -502,6 +502,25 @@ def _local_value(fctx, fn_node, node):
     return found
 
 
+def _range_comprehension(fctx, fn_node, node) -> "int | None":
+    """How many elements ``[<spec> for g in range(N)]`` makes, ``N`` an int
+    literal or a name bound to a module constant (through one local hop):
+    one BlockSpec an element, the way ``lsh_listed_scores`` gives each of a
+    step's windows its own."""
+    if not (isinstance(node, ast.ListComp) and len(node.generators) == 1):
+        return None
+    it = node.generators[0].iter
+    if not (isinstance(it, ast.Call) and isinstance(it.func, ast.Name)
+            and it.func.id == "range" and len(it.args) == 1):
+        return None
+    arg = _local_value(fctx, fn_node, it.args[0])
+    if isinstance(arg, ast.Constant) and isinstance(arg.value, int):
+        return arg.value
+    if isinstance(arg, ast.Name):
+        return _module_consts(fctx).get(arg.id)
+    return None
+
+
 def _parse_block_spec(fctx, node, num_prefetch: int) -> "tuple | None":
     """(shape dims|None, space, index_map components|None) of one
     ``pl.BlockSpec(...)`` expression; None when it is not one."""
@@ -694,6 +713,11 @@ def _fill_model(fctx, fn_node, model: KernelModel) -> None:
         node = _local_value(fctx, fn_node, node)
         if isinstance(node, (ast.Tuple, ast.List)):
             return list(node.elts)
+        if isinstance(node, ast.BinOp) and isinstance(node.op, ast.Add):
+            return specs_of(node.left) + specs_of(node.right)
+        repeat = _range_comprehension(fctx, fn_node, node)
+        if repeat is not None:
+            return [node.elt] * repeat
         return [node] if node is not None else []
 
     def make_buffer(kind, idx, spec_node):

@@ -95,7 +95,7 @@ def test_tiny_mode_passes_every_check_and_names_the_cpu():
     assert loop["padded_half_iteration_error"] < 1e-5
     assert set(summary["kernels"]) == {
         "gather_gramian/float32", "gather_gramian/bfloat16", "spd_solve",
-        "kmeans",
+        "kmeans", "lsh_scan",
     }
     # set-up is reported as set-up; no rate, utilization or roofline figure
     flat = json.dumps(summary)
@@ -357,6 +357,69 @@ def test_tpu_compiler_fuses_the_int8_scan_at_the_largest_row_compile_only():
     env.pop("JAX_ENABLE_X64", None)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", _INT8_SCAN_COMPILE_ONLY],
+                          capture_output=True, text=True, timeout=240,
+                          env=env, cwd=REPO)
+    last = (proc.stdout.strip().splitlines() or [""])[-1]
+    if last.startswith("SKIP"):
+        pytest.skip(f"no compile-only TPU topology here: {last}")
+    assert proc.returncode == 0 and last == "COMPILED", proc.stderr[-3000:]
+
+
+_LSH_SCAN_COMPILE_ONLY = """
+import os, sys
+os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+except Exception as e:
+    print("SKIP", type(e).__name__, e)
+    sys.exit(0)
+from oryx_tpu.models.als import serving as S
+
+sharding = SingleDeviceSharding(topo.devices[0])
+def spec(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+# the published LSH row as one chip holds it: 5M x 250 in bucket order,
+# windows of 128, ceil(5M / 128) + 8,192 of them
+k, tile = 250, 128
+windows = -(-5_000_000 // tile) + 8192
+positions = windows * tile
+mat = spec((positions, k), jnp.bfloat16)
+ints = spec((positions,), jnp.int32)
+for batch, listed in ((1, 13_760), (2, 27_520)):
+    compiled = S._lsh_listed_top_k.lower(
+        mat, ints, spec((windows,), jnp.int32), spec((batch, k), jnp.float32),
+        spec((listed,), jnp.int32), 256, tile, "kernel").compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the listed windows' scores, and nothing of Y's size besides Y
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= batch * listed * tile * 4 + (8 << 20), memory
+for batch in (1, 256):
+    compiled = S._lsh_full_top_k.lower(
+        mat, ints, ints, spec((batch, k), jnp.float32),
+        spec((batch,), jnp.int32), 16, 5).compile()
+    # the mask rides the matmul's fusion: no (batch, positions) scores
+    assert compiled.memory_analysis().temp_size_in_bytes < (64 << 20), batch
+print("COMPILED")
+"""
+
+
+def test_tpu_compiler_takes_the_lsh_scans_at_the_published_row_compile_only():
+    """The LSH row's two programs for a described v5e: the listed scan
+    (Pallas, its list — a window and its owners an entry — and every
+    window's real rows prefetched into SMEM) at each list length the
+    layout warms and the widest over-fetch, and the full pass with its
+    per-position bucket mask fused behind the matmul at the widest batch —
+    what the compiler refuses here costs no chip time."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env.pop("JAX_ENABLE_X64", None)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    proc = subprocess.run([sys.executable, "-c", _LSH_SCAN_COMPILE_ONLY],
                           capture_output=True, text=True, timeout=240,
                           env=env, cwd=REPO)
     last = (proc.stdout.strip().splitlines() or [""])[-1]

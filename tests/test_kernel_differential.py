@@ -400,6 +400,88 @@ def test_spd_blocked_tile_formula_equals_modeled_budget(ops_kernel_models):
     assert blocked.max_buffer_bytes(bind(16, 250)) == SPD_BLOCKED_BUDGET_BYTES
 
 
+def _lsh_cases():
+    """Seeded: window tiles from the narrowest a layout takes to the
+    widest, one to three queries, float32 and bfloat16, lists of one grid
+    step and of several, with and without the repeated padding window."""
+    rng = random.Random(SEED + 40)
+    cases = []
+    for tile in (16, 64, 128):
+        for b in (1, 2, 3):
+            steps = rng.choice((1, 3))
+            pad = rng.choice((0, 5))
+            cases.append((tile, b, steps, pad, rng.randrange(1 << 16)))
+    return cases
+
+
+@pytest.mark.parametrize("tile,b,steps,pad,case_seed", _lsh_cases())
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_lsh_scan_differential_matches_numpy(tile, b, steps, pad, case_seed,
+                                             dtype):
+    rng = np.random.default_rng(case_seed)
+    listed = steps * pk.LSH_WINDOWS_A_STEP
+    n_win = listed + 7
+    width = 250 if tile == 128 else 128
+    mat = jnp.asarray(rng.standard_normal((n_win * tile, width),
+                                          dtype=np.float32)).astype(dtype)
+    qs = jnp.asarray(rng.standard_normal((b, width),
+                                         dtype=np.float32)).astype(dtype)
+    windows = rng.choice(n_win, listed, replace=False).astype(np.int32)
+    real = listed - pad
+    if pad:
+        windows[real:] = windows[real - 1]
+    win_valid = rng.integers(0, tile + 1, n_win).astype(np.int32)
+    owners = rng.integers(1, 1 << b, listed).astype(np.int32)
+    owners[real:] = 0
+    got = np.asarray(pk.lsh_listed_scores(
+        mat, qs, jnp.asarray(windows << b | owners), jnp.asarray(win_valid),
+        tile=tile, interpret=True))
+    m64 = np.asarray(mat.astype(jnp.float32), dtype=np.float64)
+    q64 = np.asarray(qs.astype(jnp.float32), dtype=np.float64)
+    want = np.full((b, listed * tile), -np.inf)
+    for j in range(real):
+        w, n = windows[j], win_valid[windows[j]]
+        for q in range(b):
+            if owners[j] >> q & 1:
+                want[q, j * tile:j * tile + n] = (
+                    m64[w * tile:w * tile + n] @ q64[q])
+    assert got.dtype == np.float32 and got.shape == want.shape
+    np.testing.assert_array_equal(np.isneginf(got), np.isneginf(want))
+    fin = np.isfinite(want)
+    if fin.any():
+        np.testing.assert_allclose(got[fin], want[fin], rtol=1e-5,
+                                   atol=1e-5 * np.abs(want[fin]).max())
+
+
+def test_lsh_prefetch_words_equal_the_modeled_smem_budget(ops_kernel_models):
+    """``LSH_PREFETCH_WORDS`` is the model's number: the listed call's
+    scalar-prefetched operands — the list and every window's real rows —
+    against the share of SMEM prefetched operands may take, and
+    ``lsh_lists`` agrees with the model at every size; at 5M rows the
+    longest warmed list fits. Its VMEM at the widest tile, width, dtype
+    and flush stays far inside the scoped limit."""
+    from oryx_tpu.tools.analyze.kernelmodel import (
+        SMEM_PREFETCH_BUDGET_BYTES, budgets)
+
+    scan = ops_kernel_models["lsh_listed_scores"]
+    assert scan.num_prefetch == 2
+    assert scan.prefetch_shapes == [("listed",), ("n_windows",)]
+
+    def fits(listed: int, n_windows: int) -> bool:
+        return scan.prefetch_smem_bytes(
+            {"listed": listed, "n_windows": n_windows}) \
+            <= SMEM_PREFETCH_BUDGET_BYTES
+
+    assert pk.LSH_PREFETCH_WORDS * 4 == SMEM_PREFETCH_BUDGET_BYTES
+    for listed, n_windows in ((8, 8), (27_520, 47_255), (100_000, 96_608),
+                              (100_000, 96_609), (150_000, 60_000)):
+        assert pk.lsh_lists(listed, n_windows) == fits(listed, n_windows), (
+            listed, n_windows)
+    assert pk.lsh_lists(27_520, 47_255)
+    bind = {"b": 2, "k": 250, "tile": 128, "group": pk.LSH_WINDOWS_A_STEP}
+    assert scan.vmem_bytes(bind) <= budgets()["vmem_limit_bytes"] / 4
+
+
 def test_budget_knobs_registered_and_defaults_agree():
     """The ``oryx.analyze.kernel.*`` keys exist in reference_conf and their
     registered defaults equal the module constants the checkers use when no

@@ -590,6 +590,19 @@ def test_real_kernels_parse_with_expected_structure(kernels_project):
     assert kernel_zeroes_param(gg, "a_ref")
     assert kernel_zeroes_param(gg, "b_ref")
 
+    # the LSH scan: the queries resident, a step's windows a block each,
+    # chosen by the scalar-prefetched list; its scores a block a step
+    scan = models["lsh_listed_scores"]
+    assert scan.num_prefetch == 2 and scan.grid == ("listed // group",)
+    assert [(b.space, b.shape) for b in scan.inputs] == (
+        [("vmem", ("b", "k"))] + [("vmem", ("tile", "k"))] * 8)
+    assert not scan.inputs[0].pipelined
+    assert all(b.pipelined for b in scan.inputs[1:])
+    assert all(b.revisits_across_grid(scan.grid) for b in scan.inputs[1:])
+    assert [(b.space, b.shape) for b in scan.outputs] == [
+        ("vmem", ("b", "group * tile"))]
+    assert scan.interpret == ("param", "interpret")
+
     km = models["_call"]
     assert all(b.revisits_across_grid(km.grid) for b in km.outputs)
     assert all(

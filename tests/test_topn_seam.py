@@ -267,6 +267,12 @@ def test_a_plan_of_two_steps_never_copies_its_intermediate(copies):
         def place(self, host):
             return jnp.asarray(host)
 
+        def select(self, qs_host, width):
+            return None
+
+        def batch_operands(self, qs_host, selection):
+            return None
+
         def plan(self, qs, lut, width):
             return ((shift, (qs,), "shift"),
                     (best, (self.mat, _Fed(qs.shape, qs.dtype)), "best"))
