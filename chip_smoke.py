@@ -39,6 +39,7 @@ from __future__ import annotations
 import argparse
 import concurrent.futures as cf
 import dataclasses
+import functools
 import json
 import logging
 import os
@@ -223,7 +224,8 @@ def input_lines(users, items) -> list:
 def check_kernels(features: tuple, on_tpu: bool) -> dict:
     """The four kernels — compiled on a TPU, interpreted elsewhere —
     against numpy references at each width, f32 and bf16, with empty rows
-    and pad slots. Returns the worst relative error per kernel."""
+    and pad slots; the gather-Gramian kernel's two forms against each
+    other, bit for bit. Returns the worst relative error per kernel."""
     import jax
     import jax.numpy as jnp
 
@@ -258,8 +260,11 @@ def check_kernels(features: tuple, on_tpu: bool) -> dict:
         coef = rng.standard_normal((s, t)).astype(np.float32) * mask
         y = rng.standard_normal((n_opp + 1, k)).astype(np.float32)
         y[n_opp] = np.nan
-        gg = jax.jit(lambda *a: pk.gather_gramian_accumulate(
-            *a, block=block, interpret=interpret))
+        # both forms: the table held in VMEM (what the rule picks for these
+        # 601 rows) and one DMA a row, which must agree to the bit
+        gg = {held: jax.jit(functools.partial(
+            pk.gather_gramian_accumulate, block=block, interpret=interpret,
+            _resident=held)) for held in (True, False)}
         for dtype, tol in ((jnp.float32, GG_TOL_F32), (jnp.bfloat16, GG_TOL_BF16)):
             yj = jnp.asarray(y).astype(dtype)
             # reference on the operands the MXU is handed, in float64
@@ -270,9 +275,16 @@ def check_kernels(features: tuple, on_tpu: bool) -> dict:
             rb = np.zeros((block + 1, k))
             np.add.at(ra, srow, np.einsum("st,sti,stj->sij", w, yg, yg))
             np.add.at(rb, srow, np.einsum("st,sti->si", coef, yg))
-            big_a, big_b = (np.asarray(o) for o in gg(
-                yj, jnp.asarray(srow), jnp.asarray(slens), jnp.asarray(scols),
-                jnp.asarray(w), jnp.asarray(coef)))
+            operands = (yj, jnp.asarray(srow), jnp.asarray(slens),
+                        jnp.asarray(scols), jnp.asarray(w), jnp.asarray(coef))
+            big_a, big_b = (np.asarray(o) for o in gg[True](*operands))
+            copied = [np.asarray(o) for o in gg[False](*operands)]
+            if not (np.array_equal(big_a, copied[0], equal_nan=True)
+                    and np.array_equal(big_b, copied[1], equal_nan=True)):
+                raise SmokeFailure(
+                    f"gather_gramian k={k} {jnp.dtype(dtype).name}: the "
+                    "table held in VMEM and the copied rows give different "
+                    "bits")
             if not (np.isfinite(big_a).all() and np.isfinite(big_b).all()):
                 raise SmokeFailure(f"gather_gramian k={k}: non-finite output")
             scale = max(np.abs(ra).max(), np.abs(rb).max())

@@ -782,6 +782,14 @@ _SOLVED_ROWS = metrics_mod.default_registry().counter(
     "features), its blocked form (spd_blocked, to 256) or XLA's cholesky",
     ("side", "path"),
 )
+_GATHERED_ROWS = metrics_mod.default_registry().counter(
+    "oryx_als_gathered_rows_total",
+    "Factor rows the fused gather-Gramian kernel gathered in the trainer's "
+    "half-iterations, one an entry, by where it took them from: the "
+    "opposite table held whole in VMEM (vmem) or a DMA a row out of HBM "
+    "(hbm)",
+    ("side", "source"),
+)
 _SPD_TILE_ROWS = metrics_mod.default_registry().gauge(
     "oryx_als_spd_tile_rows",
     "Batch tile the SPD kernel (unblocked or blocked) ran this side's last "
@@ -825,11 +833,27 @@ def _choose_spd(asked: bool, features: int) -> _SpdSolve:
     return _SpdSolve("cholesky", 0)
 
 
-def _count_half(side: str, rows: int, spd: _SpdSolve) -> None:
-    """One half-iteration's solve, counted on the host as it is dispatched:
-    nothing is added inside the jitted call."""
+def _gather_source(chosen: _Formulation, table_rows: int,
+                   features: int) -> "str | None":
+    """Where a fused half's kernel takes its factor rows from — ``vmem``
+    (the opposite table held whole) or ``hbm`` (a DMA a row) — by the
+    kernel's own rule on the table it is handed; ``None`` for an einsum
+    half, which has no such gather."""
+    if not chosen.fused:
+        return None
+    return ("vmem" if pk.gather_gramian_resident(table_rows, features)
+            else "hbm")
+
+
+def _count_half(side: str, rows: int, spd: _SpdSolve,
+                source: "str | None" = None, entries: int = 0) -> None:
+    """One half-iteration, counted on the host as it is dispatched (nothing
+    is added inside the jitted call): its solved rows, and a fused half's
+    gathered rows by ``source``."""
     _SOLVED_ROWS.labels(side, spd.path).inc(rows)
     _SPD_TILE_ROWS.labels(side).set(spd.tile_rows)
+    if source is not None:
+        _GATHERED_ROWS.labels(side, source).inc(entries)
 
 
 # The gather-Gramian formulation is chosen a SIDE, from the opposite factor
@@ -983,8 +1007,9 @@ def solve_side_blocked(y, srows, scols, svals, slens, lam, alpha, *, block,
     blocked form past 128 features) and XLA's cholesky elsewhere; past 256
     features the cholesky runs whatever was asked (:func:`_choose_spd`). A named ``side``
     (``user`` / ``item``) is counted in ``oryx_als_half_formulation``,
-    ``oryx_als_solved_rows_total`` and ``oryx_als_spd_tile_rows``, on the
-    host at every call; an unnamed half in none of them.
+    ``oryx_als_solved_rows_total``, ``oryx_als_spd_tile_rows`` and, where
+    the fused kernel runs it, ``oryx_als_gathered_rows_total``, on the host
+    at every call; an unnamed half in none of them.
     ``fused_gramian=None`` picks the gather-Gramian
     formulation THIS side runs fastest (:func:`_choose_formulation`): off a
     TPU the einsum; on one whichever the chip sweep measured fastest for an
@@ -1005,7 +1030,11 @@ def solve_side_blocked(y, srows, scols, svals, slens, lam, alpha, *, block,
                                  srows.shape[1], y.shape[0])
     if side is not None:
         _name_formulation(side, chosen, features)
-        _count_half(side, srows.shape[0] * block, spd)
+        source = _gather_source(chosen, y.shape[0], features)
+        # a fused half's entries: its slot lengths summed where they live,
+        # one scalar read back
+        _count_half(side, srows.shape[0] * block, spd, source,
+                    int(jnp.sum(slens)) if source else 0)
     return _solve_side_blocked_jit(
         y, srows, scols, svals, slens, lam, alpha, block=block,
         features=features, implicit=implicit, slot_chunk=slot_chunk,
@@ -1153,9 +1182,10 @@ def prepare_blocked(
     on_tpu = pk.on_tpu(sides[0].scols)
     for name, side, opposite in (("user", sides[0], sides[1]),
                                  ("item", sides[1], sides[0])):
-        _log_gather_rows(name, side, features, _choose_formulation(
-            None, on_tpu, features, side.srows.shape[1],
-            opposite.padded_rows))
+        chosen = _choose_formulation(None, on_tpu, features,
+                                     side.srows.shape[1], opposite.padded_rows)
+        _log_gather_rows(name, side, features, chosen, _gather_source(
+            chosen, opposite.padded_rows, features))
     return sides
 
 
@@ -1176,15 +1206,20 @@ def init_item_factors(item_side: _BlockedSide, n_items: int, features: int,
 
 
 def _log_gather_rows(name: str, side: _BlockedSide, features: int,
-                     chosen: _Formulation) -> None:
+                     chosen: _Formulation, source: "str | None") -> None:
     """One line a side: what the pack holds, the formulation the gate chose
-    for it and why, the width its rows are gathered at, and the factor rows
-    that formulation's gather issues."""
+    for it and why, the width its rows are gathered at, the factor rows
+    that formulation's gather issues and, under the kernel, where it takes
+    them from (:func:`_gather_source`)."""
     before, now = side.gather_rows_per_entry(chosen.fused)
-    if chosen.fused:
-        issues = ("fused kernel: each slot to its own length; %.3f%% of the "
-                  "slots are fetched under the slot before, the rest open a "
-                  "block's call"
+    if source == "vmem":
+        issues = ("fused kernel: each slot to its own length; source: vmem, "
+                  "the opposite table held whole in VMEM, a vector load a "
+                  "row")
+    elif source == "hbm":
+        issues = ("fused kernel: each slot to its own length; source: hbm, "
+                  "a DMA a row; %.3f%% of the slots are fetched under the "
+                  "slot before, the rest open a block's call"
                   % (100.0 * side.prefetched_slots / max(1, side.real_slots)))
     else:
         issues = "einsum: every cell of every slot"
@@ -1201,7 +1236,8 @@ def _log_gather_rows(name: str, side: _BlockedSide, features: int,
 
 
 def _register_half_cost(name: str, side: _BlockedSide, features: int,
-                        dtype: str, chosen: _Formulation) -> None:
+                        dtype: str, chosen: _Formulation,
+                        source: "str | None") -> None:
     """Analytic per-half-iteration device cost for the trainer's cost
     accounting (common/profiling.py), under ``als.train.<name>_half``: the
     same useful-FLOP model the batch bench's MFU derives from (2·nnz·k²
@@ -1230,17 +1266,18 @@ def _register_half_cost(name: str, side: _BlockedSide, features: int,
     key = f"als.train.{name}_half"
     profiling.costs().register(key, flops, bytes_)
     _name_formulation(name, chosen, k)
-    _log_gather_rows(key, side, k, chosen)
+    _log_gather_rows(key, side, k, chosen, source)
 
 
-def _recorded_half(name: str, rows: int, spd: _SpdSolve, fn):
+def _recorded_half(name: str, side: _BlockedSide, spd: _SpdSolve, fn,
+                   source: "str | None"):
     """Wrap a half-iteration solver so each dispatch lands in the device
     cost counters (oryx_device_flops_total{program=als.train.<name>_half}
     et al.) and the solve counters (:func:`_count_half`)."""
 
     def call(*args):
         profiling.costs().record(f"als.train.{name}_half")
-        _count_half(name, rows, spd)
+        _count_half(name, side.padded_rows, spd, source, side.entries)
         return fn(*args)
 
     return call
@@ -1461,9 +1498,11 @@ def als_train(
                     table_rows: int) -> _Formulation:
             choice = _choose_formulation(fused_gramian, on_tpu, k,
                                          side.srows.shape[1], table_rows)
-            _register_half_cost(name, side, k, dtype, choice)
+            sources[name] = _gather_source(choice, table_rows, k)
+            _register_half_cost(name, side, k, dtype, choice, sources[name])
             return choice
 
+        sources: dict = {}
         chosen = {"user": resolve("user", user_side, y.shape[0])}
 
         if sharded_mode:
@@ -1502,7 +1541,7 @@ def als_train(
                     mesh, row_axis, blk, k, implicit, side.slot_chunk, dtype,
                     spd.kernel, chosen[name].fused, not on_tpu,
                     chosen[name].gather_width)
-                return _recorded_half(name, side.padded_rows, spd, solver)
+                return _recorded_half(name, side, spd, solver, sources[name])
 
             solve_u = sharded("user", user_side, block_u)
             x = solve_u(y, *u_arrays, lam, alpha)  # device busy; host packs
@@ -1523,7 +1562,8 @@ def als_train(
         def solve(side, opp, blk, ck):
             name = "user" if side is user_side else "item"
             profiling.costs().record(f"als.train.{name}_half")
-            _count_half(name, side.padded_rows, spd)
+            _count_half(name, side.padded_rows, spd, sources[name],
+                        side.entries)
             # solve_side_blocked's call, with the answers resolved above
             return _solve_side_blocked_jit(
                 opp, side.srows, side.scols, side.svals, side.slens, lam,

@@ -86,6 +86,17 @@ computes — at most one round trip a slot (0.4 µs) is saved — so a start
 seems to hold the one instruction stream until the DMA unit takes the
 descriptor. Fewer copies, not better-placed ones, is what is left.
 
+So where the opposite table is small — its 32-bit, lane-padded rows within
+``_GG_RESIDENT_TABLE_BYTES`` (32 MiB; the Netflix item table is 18.2 MB,
+its user table 492 MB) — the kernel holds the table instead: one copy of
+the whole of it into a dense ``(R, pad128(k))`` VMEM buffer at the call's
+first step, then a slot's rows are vector loads out of it into a
+``(T, pad128(k))`` tile by the same indices in SMEM — no descriptor a row,
+no semaphore, nothing in flight between steps. The matmuls are the copying
+form's own (``_accumulate_slot``), so the two forms give the same bits;
+which one runs follows from ``y``'s static shape
+(``gather_gramian_resident``).
+
 Mosaic slices a DMA only along untiled (leading) dims, and a 16-bit row
 shares its 32-bit sublane word with its neighbour — so the wrapper hands the
 kernel the factors as float32 ``(R, 1, pad128(k))``: one row = one (1, 128)-
@@ -495,6 +506,33 @@ _GG_MAX_FEATURES = 256
 _GG_MAX_SLOTS = 3 << 15
 
 
+# The opposite table's bytes — 32-bit and lane-padded, as the kernel holds
+# it — up to which the gather-Gramian kernel keeps the WHOLE table in VMEM
+# for the call: one copy in at the first step, then each slot's rows taken
+# with vector loads, where the table in HBM costs a DMA descriptor a row —
+# ~20 ns a copy whatever the row holds (PERF.md §5: the 250-feature halves
+# cost the same at five times the bytes and 25 times the flops of 50). 32
+# MiB takes the Netflix item table (17,782 rows × 256 lanes, 18.2 MB) with
+# room and keeps well clear of its user table (480,201 rows, 492 MB), which
+# keeps the copies. Pinned against the static kernel model's footprint of
+# the resident form (tests/test_kernel_differential.py).
+_GG_RESIDENT_TABLE_BYTES = 32 << 20
+# What the resident form asks the compiler for past its table. Compiled for
+# a described v5e at the gate (k = 256, T = 512, a 32 MiB table) the call
+# fits at 1.3 MiB past the table and not at 1.2; its buffers there — the
+# tile, both accumulator blocks, the weights — are 1.01 MiB (the static
+# kernel model's count).
+_GG_RESIDENT_SCOPED_BYTES = 2 << 20
+
+
+def gather_gramian_resident(rows: int, k: int) -> bool:
+    """Whether the gather-Gramian kernel holds an opposite table of ``rows``
+    × ``k`` whole in VMEM: its 32-bit, lane-padded bytes (a bfloat16 table
+    is gathered as 32-bit rows all the same) within
+    ``_GG_RESIDENT_TABLE_BYTES``. Past it each row is a DMA out of HBM."""
+    return rows * _pad_dim(k, _LANE) * 4 <= _GG_RESIDENT_TABLE_BYTES
+
+
 def gather_gramian_supported(features: int, slots: int) -> bool:
     """Whether the fused gather-Gramian kernel fits its VMEM budget at
     ``features`` and its SMEM budget at ``slots`` slots per block."""
@@ -587,33 +625,134 @@ def _make_gather_gramian_kernel(t: int, k: int, kp: int, cd):
             _unrolled(n, wait)
 
             ygv = yg[buf].reshape(t, kp)[:, :k]  # (T, k) f32
-            wc = wc_ref[0]  # (2, T): Gramian weights, RHS coefficients
-            # the Gramian weights are a lane-major row and must scale the
-            # gathered rows along SUBLANES: mask the broadcast row to the
-            # diagonal and lane-reduce — iota/select/reduce only, where a
-            # (1, T) → (T, 1) reshape is a relayout Mosaic may not have
-            diag = (jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
-                    == jax.lax.broadcasted_iota(jnp.int32, (t, t), 1))
-            wcol = jnp.sum(jnp.where(diag, wc[0:1, :], 0.0), axis=1,
-                           keepdims=True)  # (T, 1)
-            # cast to the compute dtype AFTER the 32-bit gather so bf16
-            # inputs hit the MXU's bf16×bf16→f32 path like the einsum
-            # formulation does
-            ygc = ygv.astype(cd)
-            ga = jax.lax.dot_general(
-                (ygv * wcol).astype(cd), ygc, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # (k, k): sum_t w_t · y_t ⊗ y_t
-            gb = jnp.dot(wc[1:2, :].astype(cd), ygc,
-                         preferred_element_type=jnp.float32)  # (1, k)
-            a_ref[...] = a_ref[...] + ga[None]
-            b_ref[...] = b_ref[...] + gb[None]
+            _accumulate_slot(ygv, wc_ref[0], a_ref, b_ref, cd)
 
     return kernel
 
 
+def _accumulate_slot(ygv, wc, a_ref, b_ref, cd):
+    """One slot's Gramian and right-hand side, added into its owner row's
+    blocks: ``ygv`` (T, k) the gathered rows in float32, ``wc`` (2, T) the
+    Gramian weights and RHS coefficients. Both forms of the gather-Gramian
+    kernel contract here, so what they compute differs only in how the rows
+    arrived."""
+    t = ygv.shape[0]
+    # the Gramian weights are a lane-major row and must scale the gathered
+    # rows along SUBLANES: mask the broadcast row to the diagonal and
+    # lane-reduce — iota/select/reduce only, where a (1, T) → (T, 1) reshape
+    # is a relayout Mosaic may not have
+    diag = (jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+            == jax.lax.broadcasted_iota(jnp.int32, (t, t), 1))
+    wcol = jnp.sum(jnp.where(diag, wc[0:1, :], 0.0), axis=1,
+                   keepdims=True)  # (T, 1)
+    # cast to the compute dtype AFTER the 32-bit gather so bf16 inputs hit
+    # the MXU's bf16×bf16→f32 path like the einsum formulation does
+    ygc = ygv.astype(cd)
+    ga = jax.lax.dot_general(
+        (ygv * wcol).astype(cd), ygc, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # (k, k): sum_t w_t · y_t ⊗ y_t
+    gb = jnp.dot(wc[1:2, :].astype(cd), ygc,
+                 preferred_element_type=jnp.float32)  # (1, k)
+    a_ref[...] = a_ref[...] + ga[None]
+    b_ref[...] = b_ref[...] + gb[None]
+
+
+def _make_gather_gramian_resident_kernel(t: int, k: int, cd):
+    def kernel(srow_ref, slen_ref, scols_ref, wc_ref, y_ref, a0_ref, b0_ref,
+               a_ref, b_ref, table, yg, sem):
+        i = pl.program_id(0)
+        row = srow_ref[i]
+        prev_row = srow_ref[jnp.maximum(i - 1, 0)]
+        n = slen_ref[i]
+
+        # the whole table into VMEM at the call's first step, one copy; the
+        # gather tile cleared under it: rows of the tile past a slot's
+        # length keep what an earlier slot took there and meet weight 0 in
+        # the matmuls, so they must hold finite numbers from the start
+        @pl.when(i == 0)
+        def _():
+            copy = pltpu.make_async_copy(y_ref, table, sem.at[0])
+            copy.start()
+            yg[...] = jnp.zeros_like(yg)
+            copy.wait()
+
+        # first slot of a new output row: zero its freshly rotated-in
+        # blocks (slots are row-sorted, so a row's blocks flush once)
+        @pl.when(jnp.logical_or(i == 0, prev_row != row))
+        def _():
+            a_ref[...] = jnp.zeros_like(a_ref)
+            b_ref[...] = jnp.zeros_like(b_ref)
+
+        # a slot's first ``n`` rows, each a vector load out of the table by
+        # its index in SMEM; an empty slot (a pad slot) does nothing
+        @pl.when(n > 0)
+        def _():
+            def take(tt):
+                yg[pl.ds(tt, 1), :] = table[pl.ds(scols_ref[0, 0, tt], 1), :]
+
+            _unrolled(n, take)
+            _accumulate_slot(yg[...][:, :k], wc_ref[0], a_ref, b_ref, cd)
+
+    return kernel
+
+
+def _gather_gramian_resident(y, srow, slens, scols, w, coef, *, block: int,
+                             interpret: bool):
+    """:func:`gather_gramian_accumulate` with the table held in VMEM: the
+    same operands, the same outputs to the bit."""
+    s, t = scols.shape
+    r, k = y.shape
+    kp = _pad_dim(k, _LANE)
+    # dense (R, kp): a (R, 1, kp) table would pad every row to 8 sublanes
+    y2 = jnp.pad(y.astype(jnp.float32), ((0, 0), (0, kp - k)))
+    wc = jnp.stack([w, coef], axis=1)  # (S, 2, T)
+    scols3 = scols.reshape(s, 1, t)
+    a0 = jnp.zeros((block + 1, k, k), jnp.float32)
+    b0 = jnp.zeros((block + 1, 1, k), jnp.float32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(s,),
+        in_specs=[
+            # gather indices: SMEM, one slot per step
+            pl.BlockSpec((1, 1, t), lambda i, sr, sl: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 2, t), lambda i, sr, sl: (i, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pl.ANY),  # y: copied in once
+            pl.BlockSpec(memory_space=pl.ANY),  # big_a zero donor
+            pl.BlockSpec(memory_space=pl.ANY),  # big_b zero donor
+        ],
+        out_specs=[
+            pl.BlockSpec((1, k, k), lambda i, sr, sl: (sr[i], 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, k), lambda i, sr, sl: (sr[i], 0, 0),
+                         memory_space=pltpu.VMEM),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((r, kp), jnp.float32),  # the table, one buffer
+            pltpu.VMEM((t, kp), jnp.float32),  # this slot's rows
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+    )
+    big_a, big_b = pl.pallas_call(
+        _make_gather_gramian_resident_kernel(t, k, y.dtype),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((block + 1, k, k), jnp.float32),
+            jax.ShapeDtypeStruct((block + 1, 1, k), jnp.float32),
+        ],
+        input_output_aliases={5: 0, 6: 1},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_pad_dim(r, 8) * kp * 4
+            + _GG_RESIDENT_SCOPED_BYTES),
+        interpret=interpret,
+    )(srow.reshape(s), slens.reshape(s), scols3, wc, y2, a0, b0)
+    return big_a, big_b[:, 0, :]
+
+
 def gather_gramian_accumulate(y, srow, slens, scols, w, coef, *, block: int,
-                              interpret: bool):
+                              interpret: bool, _resident: "bool | None" = None):
     """Fused gather → per-slot Gramian → per-row accumulate for one block.
 
     Args:
@@ -632,7 +771,18 @@ def gather_gramian_accumulate(y, srow, slens, scols, w, coef, *, block: int,
 
     Returns (big_a (block+1, k, k) f32, big_b (block+1, k) f32). Rows with
     no slots return exact zeros (donated zero inputs).
+
+    Where ``y`` fits ``_GG_RESIDENT_TABLE_BYTES``
+    (:func:`gather_gramian_resident`, from its static shape) the kernel
+    holds it whole in VMEM and takes each row with a vector load; past it,
+    one DMA a row out of HBM. The two give the same answer to the bit.
+    ``_resident`` forces one of them (tests); ``None`` is the rule.
     """
+    if _resident is None:
+        _resident = gather_gramian_resident(*y.shape)
+    if _resident:
+        return _gather_gramian_resident(y, srow, slens, scols, w, coef,
+                                        block=block, interpret=interpret)
     s, t = scols.shape
     k = y.shape[1]
     kp = _pad_dim(k, _LANE)

@@ -93,6 +93,17 @@ SPD_BLOCKED_SLACK_BYTES_PER_ROW = 128 << 10
 #: of the 16 MiB scoped limit; PERF.md, PR 27). See docs/static_analysis.md
 #: "Pallas kernel family" for the evaluated math.
 RESIDENT_BUDGET_BYTES = 1_583_104
+#: VMEM the gather-Gramian kernel's TABLE-RESIDENT form may take in all: the
+#: opposite table held whole (``_GG_RESIDENT_TABLE_BYTES`` = 32 MiB of
+#: 32-bit, lane-padded rows) plus the rest of that form's footprint at the
+#: ``_GG_MAX_FEATURES = 256`` gate and the widest slot (T = 512): the
+#: (T, pad128(k)) gather tile, the double-buffered (1, k, k)/(1, 1, k)
+#: accumulator blocks and the (1, 2, T) weight block, 1,058,816 B. Like
+#: ``RESIDENT_BUDGET_BYTES`` it IS the footprint at the gate, so anything
+#: added to that form fails the drift gate until both sides are re-derived
+#: (tests/test_kernel_differential.py). Under a quarter of a v5e core's
+#: 128 MiB of VMEM.
+GG_TABLE_RESIDENT_BUDGET_BYTES = (32 << 20) + 1_058_816
 #: The SMEM one program may use (1 MiB on a v5e, from the compiler's own
 #: "Used 1.00M of 1.00M smem" message), and the share of it that
 #: scalar-prefetched operands — which ride there WHOLE, whatever the grid —

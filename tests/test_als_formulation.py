@@ -240,7 +240,9 @@ def test_the_run_names_the_formulation_that_ran(asked, described_tpu, caplog):
     formulation}`` say what ``_solve_block`` was handed, with the reason and
     the gather width: the observed table against the crossover under the
     rule, "asked for" when forced (rows then gathered as wide as they are).
-    The share of slots fetched a step early is a kernel side's."""
+    A kernel side names where it takes its rows from: these tables are
+    small, so out of VMEM, where no slot is fetched a step early (the share
+    of those is a copying side's: tests/test_gramian_kernel.py)."""
     handed = described_tpu
     batch, k = _batch()
     with caplog.at_level(logging.INFO, logger=tr.__name__):
@@ -257,7 +259,8 @@ def test_the_run_names_the_formulation_that_ran(asked, described_tpu, caplog):
             "einsum" if width == k else "padded einsum"
         assert f"formulation: {name} (" in lines[side]
         assert f"factor rows of {width} columns an entry" in lines[side]
-        assert ("fetched under the slot before" in lines[side]) is fused
+        assert ("source: vmem" in lines[side]) is fused
+        assert "fetched under the slot before" not in lines[side]
         for label in tr._FORMULATION_NAMES:
             assert tr._HALF_FORMULATION.labels(side, label).value \
                 == float(label == name)

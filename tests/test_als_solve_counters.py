@@ -3,9 +3,13 @@
 / ``cholesky``) and ``oryx_als_spd_tile_rows{side}``. The Pallas SPD kernel
 runs where it is asked for: unblocked to 128 features, blocked to 256;
 past that, or where it is not asked for, XLA's cholesky runs and is counted
-as such."""
+as such. And where a fused half took its factor rows from:
+``oryx_als_gathered_rows_total{side, source}`` (``vmem`` / ``hbm``)."""
+
+import logging
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -113,3 +117,104 @@ def test_als_train_counts_each_side_every_iteration():
     assert _rows("item", "cholesky") - before["item"] \
         == iterations * items.padded_rows
     assert tr._SPD_TILE_ROWS.labels("user").value == 0
+
+
+# ---------------------------------------------------------------------------
+# oryx_als_gathered_rows_total{side, source}
+# ---------------------------------------------------------------------------
+
+_SOURCES = ("vmem", "hbm")
+
+
+def _gathered(side, source):
+    return tr._GATHERED_ROWS.labels(side, source).value
+
+
+def _fused_half(side_name, fused, k=16):
+    batch, (users, items) = _sides(k)
+    y = tr.init_item_factors(items, 40, k, jax.random.PRNGKey(1))
+    x = tr.solve_side_blocked(
+        y, users.srows, users.scols, users.svals, users.slens, 0.01, 1.0,
+        block=users.block, features=k, implicit=True,
+        slot_chunk=users.slot_chunk, fused_gramian=fused, side=side_name)
+    return batch, users, np.asarray(x)
+
+
+@pytest.mark.parametrize("budget,source", [(None, "vmem"), (0, "hbm")],
+                         ids=["table_held", "table_copied"])
+def test_a_fused_half_counts_its_entries_under_its_source(
+        budget, source, monkeypatch):
+    if budget is not None:
+        monkeypatch.setattr(pk, "_GG_RESIDENT_TABLE_BYTES", budget)
+    side = f"gathered_{source}"
+    batch, users, x = _fused_half(side, True)
+    assert users.entries == len(batch.rows)
+    assert _gathered(side, source) == users.entries
+    assert all(_gathered(side, s) == 0 for s in _SOURCES if s != source)
+    _fused_half(side, True)  # every call counts
+    assert _gathered(side, source) == 2 * users.entries
+    assert np.isfinite(x).all()
+
+
+def test_an_einsum_half_gathers_nothing_the_counter_sees():
+    before = sorted(tr._GATHERED_ROWS.samples())
+    _fused_half("gathered_einsum", False)
+    assert sorted(tr._GATHERED_ROWS.samples()) == before
+
+
+def test_the_250f_cells_halves_gather_from_vmem_and_from_hbm(monkeypatch):
+    """The Netflix-shaped 250-feature cell as the pack shapes it: the user
+    half (448 blocks, ~1,550 slots a block) gathers from the 17,782-row
+    item table, held in VMEM; the item half (17 blocks of 72,594 slots)
+    from the 480,201-row user table, copied. Each half counts the cell's
+    100,480,507 entries under its source — decided from the table's size
+    alone, as on a TPU (the solve itself is not run)."""
+    monkeypatch.setattr(pk, "on_tpu", lambda operand=None, mesh=None: True)
+    monkeypatch.setattr(tr, "_solve_side_blocked_jit",
+                        lambda *a, **kw: None)
+    nnz, k = 100_480_507, 250
+    for side, table_rows, n_blocks, slots, source in (
+            ("cell_user", 17_782, 448, 1_560, "vmem"),
+            ("cell_item", 480_201, 17, 72_594, "hbm")):
+        chosen = tr._choose_formulation(None, True, k, slots, table_rows)
+        assert chosen.fused
+        assert tr._gather_source(chosen, table_rows, k) == source
+        slens = np.zeros((n_blocks, slots), np.int32)
+        q, r = divmod(nnz, slens.size)
+        slens[:] = q
+        slens.flat[:r] += 1
+        srows = np.zeros((n_blocks, slots), np.int32)
+        tr.solve_side_blocked(
+            jax.ShapeDtypeStruct((table_rows, k), jnp.float32), srows, None,
+            None, slens, 0.01, 1.0, block=1072, features=k, implicit=True,
+            slot_chunk=slots, side=side)
+        assert _gathered(side, source) == nnz
+        assert all(_gathered(side, s) == 0 for s in _SOURCES if s != source)
+
+
+@pytest.mark.parametrize("budget,source", [(None, "vmem"), (0, "hbm")],
+                         ids=["table_held", "table_copied"])
+def test_the_pack_log_line_names_the_source(budget, source, monkeypatch,
+                                            caplog):
+    monkeypatch.setattr(pk, "on_tpu", lambda operand=None, mesh=None: True)
+    monkeypatch.setattr(tr, "_GG_WIDE_FEATURES", 0)
+    monkeypatch.setattr(tr, "_GG_NARROW_FEATURES", 0)
+    if budget is not None:
+        monkeypatch.setattr(pk, "_GG_RESIDENT_TABLE_BYTES", budget)
+    with caplog.at_level(logging.INFO, logger="oryx_tpu.models.als.train"):
+        _sides(16)
+    lines = [r.getMessage() for r in caplog.records
+             if "slotted COO" in r.getMessage()]
+    assert len(lines) == 2
+    assert all(f"source: {source}" in line for line in lines), lines
+
+
+def test_als_train_counts_the_gathered_rows_of_each_side(monkeypatch):
+    k, iterations = 8, 2
+    batch, (users, items) = _sides(k, seed=5)
+    before = {s: _gathered(s, "vmem") for s in ("user", "item")}
+    tr.als_train(batch, k, 0.01, 1.0, True, iterations=iterations,
+                 key=jax.random.PRNGKey(2), block=32, fused_gramian=True)
+    for side in ("user", "item"):
+        assert _gathered(side, "vmem") - before[side] \
+            == iterations * len(batch.rows)

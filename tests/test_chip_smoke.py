@@ -278,6 +278,16 @@ assert kernels(half(50, 3, 5924, 72594, 512, "float32", 59 * 8139,
 half(50, 2, 8139, 11790, 256, "float32")
 half(250, 2, 1046, 12288, 512, "float32")
 half(250, 2, 1072, 2048, 256, "bfloat16")
+# the 250-feature cell's halves as the gather kernel's rule splits them: the
+# user half holds the 17,782-row item table in VMEM, the item half copies
+# rows out of the 480,201-row user table; each kernel keeps the name the
+# trace reader finds it by (closed_call.N f32[block + 1,250,250])
+for block, s, t, rows in ((1072, 1560, 256, 17782),
+                          (1046, 12288, 512, 480201)):
+    text = half(250, 2, block, s, t, "float32", rows).as_text()
+    assert kernels(text) == 2, rows
+    assert re.search(r"%%closed_call\\.\\d+ = \\(f32\\[%d,250,250\\]"
+                     % (block + 1), text), rows
 from oryx_tpu.ops import pallas_kernels as pk
 half(50, 1, 5924, pk._GG_MAX_SLOTS, 512, "float32")
 print("COMPILED")

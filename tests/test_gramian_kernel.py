@@ -24,6 +24,7 @@ from conftest import LenOnlyIDs as _IDs
 from oryx_tpu.models.als import train as tr
 from oryx_tpu.models.als.data import RatingBatch
 from oryx_tpu.ops.pallas_kernels import (
+    _GG_UNROLL,
     gather_gramian_accumulate,
     gather_gramian_supported,
 )
@@ -212,10 +213,14 @@ def _dma_on_wait():
                                  detect_races=True)
 
 
-def _run_kernel(fn, pack, interpret):
+def _run_kernel(fn, pack, interpret, resident=False):
+    """The kernel on a pack; the copying form unless ``resident`` (these
+    packs' tables are small enough that the rule would hold them in VMEM,
+    where no copy is in flight to test)."""
     block, _, y, srow, slens, scols, w, coef = pack
     big_a, big_b = jax.jit(
-        lambda *a: fn(*a, block=block, interpret=interpret)
+        lambda *a: fn(*a, block=block, interpret=interpret,
+                      _resident=resident)
     )(jnp.asarray(y), jnp.asarray(srow), jnp.asarray(slens),
       jnp.asarray(scols), jnp.asarray(w), jnp.asarray(coef))
     return np.asarray(big_a), np.asarray(big_b)
@@ -279,6 +284,104 @@ def test_pipeline_hazard_case_catches_a_planted_fault(fault):
                 and np.array_equal(big_b, rb, equal_nan=True))
 
 
+# ---------------------------------------------------------------------------
+# the table held in VMEM: the same answer as the copies, to the bit
+# ---------------------------------------------------------------------------
+
+_U = _GG_UNROLL
+
+# (T, [(owner row or None = the spill row, valid length), ...])
+_RESIDENT_CASES = {
+    # rows 1, 2, 4 and 6 have no slot; pad slots between rows and at the end
+    "empty-rows-and-pad-slots": (16, [(0, 16), (0, 5), (3, 9), (None, 0),
+                                      (5, 16), (7, 1), (None, 0),
+                                      (None, 0)]),
+    # lengths around the copy loops' trip: 1, U − 1, U, U + 1, 2U + 3, T
+    "lengths-off-the-unroll": (3 * _U, [(0, 1), (0, _U - 1), (1, _U),
+                                        (2, _U + 1), (2, 2 * _U + 3),
+                                        (3, 3 * _U), (4, 0)]),
+    "single-slot": (16, [(2, 11)]),
+    # a long slot after a short one, the tile's tail left as it was
+    "long-after-short": (16, [(0, 3), (0, 16), (1, 2), (1, 0), (3, 7)]),
+}
+
+
+def _resident_pack(case: str, k: int, dtype):
+    """Random float operands (the bits depend on the order of every sum);
+    padding columns name the table's last row, which is NaN and which no
+    slot gathers."""
+    t, slots = _RESIDENT_CASES[case]
+    block = 8
+    rng = np.random.default_rng([sorted(_RESIDENT_CASES).index(case), k])
+    srow = np.array([block if r is None else r for r, _ in slots], np.int32)
+    slens = np.array([n for _, n in slots], np.int32)
+    s, n_opp = len(slots), 3 * t + 5
+    valid = np.arange(t)[None, :] < slens[:, None]
+    scols = np.where(valid, np.sort(rng.integers(0, n_opp, (s, t)), axis=1),
+                     n_opp).astype(np.int32)
+    w = (rng.standard_normal((s, t)) * valid).astype(np.float32)
+    coef = (rng.standard_normal((s, t)) * valid).astype(np.float32)
+    y = rng.standard_normal((n_opp + 1, k)).astype(np.float32)
+    y[n_opp] = np.nan
+    return block, t, jnp.asarray(y).astype(dtype), srow, slens, scols, w, coef
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("k", [50, 250])
+@pytest.mark.parametrize("case", list(_RESIDENT_CASES))
+def test_resident_table_bit_identical_to_the_copies(case, k, dtype):
+    """The form that holds the table in VMEM and the form that copies a row
+    at a time out of HBM contract the same rows in the same order: the
+    Gramians and right-hand sides are equal to the bit, finite where the
+    NaN row is never gathered, and exact zeros on rows no slot names."""
+    pack = _resident_pack(case, k, dtype)
+    copied = _run_kernel(gather_gramian_accumulate, pack, True)
+    held = _run_kernel(gather_gramian_accumulate, pack, True, resident=True)
+    for a, b in zip(copied, held):
+        np.testing.assert_array_equal(a, b)
+        assert np.isfinite(b).all()
+    srow = pack[3]
+    for r in set(range(pack[0] + 1)) - set(srow.tolist()):
+        assert not held[0][r].any() and not held[1][r].any(), r
+
+
+def test_the_rule_holds_the_table_where_it_fits():
+    """The rule reads the table's 32-bit, lane-padded bytes against
+    ``_GG_RESIDENT_TABLE_BYTES``: the Netflix item table (17,770 rows of 250
+    features) is held, its user table (480,189) is not, and the answer
+    flips at the budget's last row whatever the width pads to."""
+    from oryx_tpu.ops import pallas_kernels as pk
+
+    assert pk.gather_gramian_resident(17_770, 250)
+    assert pk.gather_gramian_resident(17_782, 250)  # as the pack pads it
+    assert not pk.gather_gramian_resident(480_189, 250)
+    assert not pk.gather_gramian_resident(480_201, 250)
+    for k, lanes in ((250, 256), (256, 256), (50, 128), (129, 256)):
+        last = pk._GG_RESIDENT_TABLE_BYTES // (lanes * 4)
+        assert last * lanes * 4 <= pk._GG_RESIDENT_TABLE_BYTES
+        assert pk.gather_gramian_resident(last, k), k
+        assert not pk.gather_gramian_resident(last + 1, k), k
+
+
+def test_the_rule_is_the_default(monkeypatch):
+    """With no ``_resident`` the kernel takes the form the rule names for
+    the table it is handed."""
+    from oryx_tpu.ops import pallas_kernels as pk
+
+    taken = []
+    monkeypatch.setattr(pk, "_gather_gramian_resident",
+                        lambda *a, **kw: taken.append("vmem") or (0, 0))
+    pack = _resident_pack("single-slot", 50, "float32")
+    block, _, y, srow, slens, scols, w, coef = pack
+    gather_gramian_accumulate(y, srow, slens, scols, w, coef, block=block,
+                              interpret=True)
+    assert taken == ["vmem"]
+    monkeypatch.setattr(pk, "_GG_RESIDENT_TABLE_BYTES", 0)
+    gather_gramian_accumulate(y, srow, slens, scols, w, coef, block=block,
+                              interpret=True)
+    assert taken == ["vmem"]
+
+
 @pytest.mark.parametrize("fused", [True, False])
 def test_gather_rows_counted_as_issued(fused):
     """The pack counts its entries and the slots that hold them; the
@@ -329,6 +432,9 @@ def test_pack_logs_the_share_of_slots_fetched_under_a_predecessor(
     monkeypatch.setattr(pk, "on_tpu", lambda operand=None, mesh=None: True)
     monkeypatch.setattr(tr, "_GG_NARROW_FEATURES", 0)
     monkeypatch.setattr(tr, "_GG_WIDE_FEATURES", 0)
+    # tables past the resident budget: the copying form, whose pipeline this
+    # is (a table held in VMEM has no copies to fetch early)
+    monkeypatch.setattr(pk, "_GG_RESIDENT_TABLE_BYTES", 0)
     batch, k = _skewed_batch(9)
     with caplog.at_level(logging.INFO, logger="oryx_tpu.models.als.train"):
         sides = tr.prepare_blocked(batch, k, block=512)

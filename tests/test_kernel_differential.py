@@ -187,8 +187,22 @@ def _gg_case_id(case):
                          ids=[_gg_case_id(c) for c in _gg_cases()])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_gg_differential_matches_numpy(k, t, block, layout, case_seed, dtype):
+    """The copying form: a DMA a row out of HBM."""
     if dtype == "bfloat16" and k == 256:
         pytest.skip("one boundary run is enough; bf16 covered at small k")
+    _gg_differential(k, t, block, layout, case_seed, dtype, resident=False)
+
+
+@pytest.mark.parametrize("k,t,block,layout,case_seed", _gg_cases(),
+                         ids=[_gg_case_id(c) for c in _gg_cases()])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_gg_resident_differential_matches_numpy(k, t, block, layout,
+                                                case_seed, dtype):
+    """The form that holds the table in VMEM, on the same matrix."""
+    _gg_differential(k, t, block, layout, case_seed, dtype, resident=True)
+
+
+def _gg_differential(k, t, block, layout, case_seed, dtype, resident):
     rng = random.Random(case_seed)
     nrng = np.random.default_rng(case_seed)
     if isinstance(layout, list):
@@ -226,7 +240,7 @@ def test_gg_differential_matches_numpy(k, t, block, layout, case_seed, dtype):
 
     big_a, big_b = jax.jit(
         lambda *args: pk.gather_gramian_accumulate(
-            *args, block=block, interpret=True)
+            *args, block=block, interpret=True, _resident=resident)
     )(yj, jnp.asarray(srow), jnp.asarray(slens), jnp.asarray(scols),
       jnp.asarray(w), jnp.asarray(coef))
     big_a, big_b = np.asarray(big_a), np.asarray(big_b)
@@ -295,6 +309,42 @@ def test_gg_max_features_equals_modeled_budget(ops_kernel_models):
     assert modeled_max == pk._GG_MAX_FEATURES
     for k in (1, 7, 8, 50, 200, 249, 255, 256, 257, 264, 300, 511, 512):
         assert pk.gather_gramian_supported(k, 64) == fits(k), k
+
+
+def test_gg_resident_table_bytes_equal_the_modeled_budget(
+        ops_kernel_models):
+    """The table-resident form's drift gate: ``_GG_RESIDENT_TABLE_BYTES`` is
+    the largest table — rows of 256 lanes, at the ``_GG_MAX_FEATURES`` gate
+    and the widest slot — whose parsed footprint (the table, the gather
+    tile, the accumulator and weight blocks) fits the model's budget for
+    that form; the rule flips at that row, and the VMEM the wrapper asks
+    the compiler for covers the footprint it admits."""
+    from oryx_tpu.tools.analyze.kernelmodel import (
+        GG_TABLE_RESIDENT_BUDGET_BYTES,
+        pad_up,
+    )
+
+    held = ops_kernel_models["_gather_gramian_resident"]
+    k, kp, t = pk._GG_MAX_FEATURES, 256, pk._GG_SLOT_WIDTH_MAX
+
+    def footprint(rows: int) -> float:
+        nbytes = held.vmem_bytes({"k": k, "kp": kp, "t": t, "r": rows})
+        assert nbytes is not None, "resident model no longer evaluates"
+        return nbytes
+
+    step = 1024
+    modeled_max = max(r for r in range(step, 1 << 17, step)
+                      if footprint(r) <= GG_TABLE_RESIDENT_BUDGET_BYTES)
+    assert modeled_max * kp * 4 == pk._GG_RESIDENT_TABLE_BYTES
+    assert footprint(modeled_max + 1) > GG_TABLE_RESIDENT_BUDGET_BYTES
+    for rows in (1, 17_782, modeled_max - 1, modeled_max, modeled_max + 1,
+                 480_201):
+        fits = footprint(rows) <= GG_TABLE_RESIDENT_BUDGET_BYTES
+        assert pk.gather_gramian_resident(rows, k) == fits, rows
+        assert pk.gather_gramian_resident(rows, 250) == fits, rows
+    asked = (pad_up(modeled_max, 8) * kp * 4
+             + pk._GG_RESIDENT_SCOPED_BYTES)
+    assert footprint(modeled_max) <= asked <= 128 << 20
 
 
 def test_gg_max_slots_equals_modeled_smem_budget(ops_kernel_models):

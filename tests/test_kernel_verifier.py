@@ -638,6 +638,46 @@ def test_gg_vmem_model_matches_hand_computed_budget(kernels_project):
     assert at(256) == budget < at(264) == 2_395_136
 
 
+def test_gg_resident_model_matches_hand_computed_footprint(kernels_project):
+    """The table-resident form parses as its own call: the table and the
+    zero donors in ``ANY`` (the table is copied in once, by hand), one
+    index block in SMEM, the weights pipelined, the dense ``(r, kp)`` table
+    and the ``(t, kp)`` tile as single-buffered scratch. At the gate (k =
+    256, T = 512) its footprint past the table is 1,058,816 B; the table is
+    ``r`` rows of 256 lanes, 8 rows a tile — not 8 sublanes a row, which a
+    ``(r, 1, kp)`` table would take."""
+    from oryx_tpu.tools.analyze.kernelmodel import (
+        GG_TABLE_RESIDENT_BUDGET_BYTES,
+        kernel_models,
+        kernel_zeroes_param,
+    )
+
+    held = next(m for m in kernel_models(kernels_project)
+                if m.name == "_gather_gramian_resident")
+    assert held.num_prefetch == 2
+    assert held.prefetch_shapes == [("s",), ("s",)]
+    assert [b.space for b in held.inputs] == [
+        "smem", "vmem", "any", "any", "any"]
+    assert held.aliases == {5: 0, 6: 1}
+    assert [(b.space, b.shape) for b in held.scratch] == [
+        ("vmem", ("r", "kp")), ("vmem", ("t", "kp")), ("sem", (1,))]
+    assert not any(b.pipelined for b in held.scratch)
+    assert kernel_zeroes_param(held, "a_ref")
+    assert kernel_zeroes_param(held, "b_ref")
+    assert held.interpret == ("param", "interpret")
+    at = lambda r: held.vmem_bytes({"k": 256, "kp": 256, "t": 512, "r": r})
+    rest = (
+        2 * 256 * 256 * 4       # (1,256,256) f32 out block, double-buffered
+        + 2 * 1 * 256 * 4       # (1,1,256) out block, one (1,128)-tiled row
+        + 2 * 2 * 512 * 4       # (1,2,512) f32 weight block, double-buffered
+        + 512 * 256 * 4         # (512,256) gather tile
+    )
+    assert rest == 1_058_816
+    assert at(8) == rest + 8 * 256 * 4
+    assert at(17_782) == rest + 17_784 * 256 * 4  # 8 rows a tile
+    assert at(32_768) == GG_TABLE_RESIDENT_BUDGET_BYTES
+
+
 def test_gg_smem_model_matches_what_the_compiler_said(kernels_project):
     """The SMEM side of the model: two prefetched words a slot plus the two
     double-buffered (1, 1, T) index blocks (this slot's and the next
